@@ -1,5 +1,6 @@
 """Shared logger. Reference parity: dlrover/python/common/log.py."""
 
+import functools
 import logging
 import os
 import sys
@@ -24,3 +25,11 @@ def _build_logger() -> logging.Logger:
 
 
 default_logger = _build_logger()
+
+
+@functools.lru_cache(maxsize=None)
+def warning_once(msg: str, *args) -> None:
+    """Log a warning the first time this exact (msg, args) is seen —
+    for decisions taken at trace time, which would otherwise repeat
+    on every retrace (args must be hashable)."""
+    default_logger.warning(msg, *args)

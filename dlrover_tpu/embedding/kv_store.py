@@ -89,8 +89,8 @@ def _build_so() -> str:
                 # extensions SIGILL with no diagnostic. The ALU-bound
                 # hot kernels still get AVX2/FMA: the .cc dispatches
                 # per-host at load time (target_clones + a
-                # __builtin_cpu_supports-guarded NR adam kernel — see
-                # benchmarks/RESULTS.md), so no -march is needed HERE.
+                # __builtin_cpu_supports-guarded NR adam kernel), so
+                # no -march is needed HERE.
                 cmd = ["g++"] + _CXX_FLAGS + ["-o", tmp, _SRC]
                 logger.info(
                     "building kv_embedding native lib: %s", " ".join(cmd)

@@ -921,7 +921,7 @@ class KvTable {
       // row pointers stay stable) and sums there. The dedup index is
       // a reused thread_local flat table (DedupTable): constructing a
       // std::unordered_map per shard per call was ~14% of the
-      // update's wall clock (KV_PROF profile, benchmarks/RESULTS.md).
+      // update's wall clock (KV_PROF profile).
       static thread_local DedupTable uidx;
       uidx.begin(rows.size());
       std::vector<int64_t> ukeys;

@@ -21,20 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:  # jax >= 0.8 moved shard_map out of experimental
-    from jax import shard_map as _shard_map
-
-    shard_map = functools.partial(_shard_map, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = functools.partial(_shard_map, check_rep=False)
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept
-# both so the kernels (and their interpret-mode tests) run on either
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 NEG_INF = -1e30
 # Blocks as large as the VMEM budget allows: the 1024^2 score tile
@@ -258,12 +245,13 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
         ),
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -423,12 +411,13 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
                                lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
         ),
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta_p)
 
     dkv_kernel = functools.partial(
@@ -466,12 +455,13 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
         ),
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse_t, delta_t)
     return dq, dk, dv
 
@@ -574,20 +564,22 @@ def sharded_flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
-    """`flash_attention` shard_mapped over the serving mesh's "tp"
-    axis: each shard runs the unmodified kernel on its per-shard
-    heads. Attention is embarrassingly parallel over heads, so the
-    body needs NO collectives — and because scale, blocks and the
-    causal mask depend only on the (unsharded) seq/head_dim axes,
-    every shard runs the exact arithmetic the tp=1 kernel runs on its
-    head slice: output is byte-identical to tp=1 chunked by head.
-    The caller (models/decode.py) keeps the replicated-output
+    """`flash_attention` shard_mapped over `mesh`: each shard runs
+    the unmodified kernel on its own batch rows and heads (the TPU
+    compiler will not partition a Pallas kernel itself). Attention is
+    embarrassingly parallel over both, so the body needs NO
+    collectives — and because scale, blocks and the causal mask
+    depend only on the (unsharded) seq/head_dim axes, every shard
+    runs the exact arithmetic the one-device kernel runs on its
+    slice: output is byte-identical to it, chunked. Under a serving
+    mesh the caller (models/decode.py) keeps the replicated-output
     constraint before the out-projection.
 
-    q/k/v are GLOBAL [B, S, H, D] arrays (head axes divisible by tp —
-    `supports(..., tp=tp)` gates this); specs come from
-    parallel/mesh.py:serving_head_specs, the one layout source."""
-    from dlrover_tpu.parallel.mesh import serving_head_specs
+    q/k/v are GLOBAL [B, S, H, D] arrays (head axes divisible by the
+    head-shard degree — `supports(..., tp=...)` gates this); the spec
+    comes from parallel/mesh.py:attention_qkv_spec, the one layout
+    source for serving and training meshes alike."""
+    from dlrover_tpu.parallel.mesh import attention_qkv_spec
 
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
@@ -600,7 +592,7 @@ def sharded_flash_attention(
         )
         block_q = block_q or auto_q
         block_k = block_k or auto_k
-    spec = serving_head_specs(mesh)["qkv"]
+    spec, _ = attention_qkv_spec(mesh)
     fn = functools.partial(
         flash_attention, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k,

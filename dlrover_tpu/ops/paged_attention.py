@@ -26,8 +26,9 @@ over them — this module provides both halves:
   (fused into the score/accumulate dots — the cache reads stay int8
   in HBM, halving decode's memory-bound byte traffic). interpret=True
   on CPU keeps tier-1 runnable.
-- `impl="auto"`: the kernel on real TPU when `supports()` passes,
-  else the reference. CPU tier-1 therefore runs the reference —
+- `impl="auto"`: the kernel on a TPU when `supports()` passes, else
+  the reference (a decision the engine logs once and reports as
+  `kernel_path`). CPU tier-1 therefore runs the reference —
   which is what makes the engine parity sweep deterministic — unless
   DLROVER_TPU_FORCE_KERNELS=1 (the shard_map parity tests / bench)
   forces the interpret-mode kernel. Under a serving mesh (tp > 1)
@@ -75,10 +76,10 @@ def supports(q, pages: Dict, table, tp: int = 1) -> bool:
     k_probe = jax.ShapeDtypeStruct((b, 1, kv, d), q.dtype)
     if not fa.supports(q_probe, k_probe, block_q=1, block_k=1):
         return False
-    # a page is the kernel's key block: Mosaic wants the penultimate
-    # block dim to tile 8 lanes (or match the array dim, which it does
-    # by construction) — small pages still lower, but below 8 the
-    # grid overhead swamps the work
+    # a page is the kernel's key block and a major dim of it (the
+    # block's last two dims are the pool's own KV x hd), so any page
+    # size lowers — but below 8 cells the grid overhead swamps the
+    # work
     if page_size < 8:
         return False
     if table.ndim != 2 or table.shape[0] != b:
@@ -161,101 +162,98 @@ def _paged_kernel(table_ref, len_ref,  # scalar-prefetch operands
                   q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr,
                   *, scale, page_size, num_pages, n_rep, quant):
-    """Grid (B, KV, P): one invocation attends query row b's rep-group
-    of kv head h over physical page table[b, p]. Online softmax in
-    VMEM scratch across the page axis (sequential 'arbitrary' dim);
-    pages past the row's valid length are skipped whole."""
+    """Grid (B, P): one invocation attends query row b — every KV
+    head of it — over physical page table[b, p]. The page block is
+    the pool's own [page, KV, hd] slab: its last two dims ARE the
+    array's, which Mosaic's (8, 128) block rule accepts at any head
+    count (a block of ONE head on the KV axis, second to last, is
+    refused). KV heads sit on sublanes and head_dim on lanes, so the
+    score is a multiply + lane reduction per rep-group member and the
+    value sum a reduction over the page's cells — VPU/XLU work, which
+    a one-row decode query cannot feed the MXU with anyway, and
+    arithmetic that never mixes heads (the tp byte-parity argument).
+    Online softmax in VMEM scratch across the page axis (sequential
+    'arbitrary' dim); pages past the row's valid length are skipped
+    whole."""
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[bi]
 
     @pl.when(pi * page_size < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)       # [n_rep, hd]
+        k = k_ref[0][0].astype(jnp.float32)        # [page, KV, hd]
+        v = v_ref[0][0].astype(jnp.float32)
         if quant:
-            # [page, hd] int8 blocks, [page, 1] scales: the dequant
-            # multiply fuses into the VMEM-resident f32 staging that
-            # the dots read — HBM traffic stays int8
-            k_q, k_s, v_q, v_s = (
-                k_ref[0][0, :, 0], k_ref[1][0][0, :, 0],
-                v_ref[0][0, :, 0], v_ref[1][0][0, :, 0],
-            )
-            k = k_q.astype(jnp.float32) * k_s.astype(jnp.float32)
-            v = v_q.astype(jnp.float32) * v_s.astype(jnp.float32)
-        else:
-            k = k_ref[0][0, :, 0].astype(jnp.float32)  # [page, hd]
-            v = v_ref[0][0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                  # [n_rep, page]
-        cols = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
+            # int8 cells, [page, KV, 1] scales: the dequant multiply
+            # runs on the VMEM-resident block — HBM traffic stays int8
+            k = k * k_ref[1][0][0].astype(jnp.float32)
+            v = v * v_ref[1][0][0].astype(jnp.float32)
+        cells = pi * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, k.shape[:2] + (1,), 0
         )
-        s = jnp.where(cols < length, s, NEG_INF)
-        # scratch rows are padded to the 8-sublane minimum; the live
-        # online-softmax state is the leading n_rep rows
-        m_prev = m_scr[:n_rep, :1]
-        l_prev = l_scr[:n_rep, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:n_rep] = acc_scr[:n_rep] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:n_rep] = jnp.broadcast_to(m_new, (n_rep, m_scr.shape[1]))
-        l_scr[:n_rep] = jnp.broadcast_to(l_new, (n_rep, l_scr.shape[1]))
+        live = cells < length                      # [page, KV, 1]
+        for r in range(n_rep):
+            q = q_ref[0, r].astype(jnp.float32)    # [KV, hd]
+            s = jnp.sum(k * q[None], axis=2, keepdims=True) * scale
+            s = jnp.where(live, s, NEG_INF)        # [page, KV, 1]
+            m_prev = m_scr[r][:, :1]               # [KV, 1]
+            l_prev = l_scr[r][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])
+            l_new = alpha * l_prev + jnp.sum(p, axis=0)
+            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
+            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(pi == num_pages - 1)
     def _finalize():
-        l = l_scr[:n_rep, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:n_rep] / l).astype(o_ref.dtype)
+        for r in range(n_rep):
+            l = l_scr[r][:, :1]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
 
 
 def _kernel(q, pages, table, lengths, scale):
     """q [B, H, hd] → [B, H, hd]. The page table and lengths ride as
     scalar-prefetch operands so the k/v BlockSpec index maps can
     dereference table[b, p] — the pipeline then streams the PHYSICAL
-    pages, never a gathered copy."""
+    pages, never a gathered copy. q travels rep-major
+    ([B, n_rep, KV, hd]) so one rep-group member is a [KV, hd] tile
+    laid out like a page cell."""
     b, h, hd = q.shape
     n_pages, page_size, kv, _ = pages["k"].shape
     n_rep = h // kv
     num_pages = table.shape[1]
     quant = "k_scale" in pages
-    qg = q.reshape(b, kv, n_rep, hd)
+    qg = q.reshape(b, kv, n_rep, hd).swapaxes(1, 2)
 
-    def q_map(bi, hi, pi, tab, lens):
-        return (bi, hi, 0, 0)
+    def q_map(bi, pi, tab, lens):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, pi, tab, lens):
-        return (tab[bi, pi], 0, hi, 0)
+    def kv_map(bi, pi, tab, lens):
+        return (tab[bi, pi], 0, 0, 0)
 
-    kv_spec = pl.BlockSpec((1, page_size, 1, hd), kv_map)
-    sc_spec = pl.BlockSpec((1, page_size, 1, 1), kv_map)
-    in_specs = [pl.BlockSpec((1, 1, n_rep, hd), q_map)]
-    operands = [qg]
+    q_spec = pl.BlockSpec((1, n_rep, kv, hd), q_map)
+    kv_spec = pl.BlockSpec((1, page_size, kv, hd), kv_map)
+    sc_spec = pl.BlockSpec((1, page_size, kv, 1), kv_map)
     if quant:
-        in_specs += [
-            (kv_spec, (sc_spec,)), (kv_spec, (sc_spec,)),
-        ]
-        operands += [
+        in_specs = [q_spec, (kv_spec, (sc_spec,)), (kv_spec, (sc_spec,))]
+        operands = [
+            qg,
             (pages["k"], (pages["k_scale"],)),
             (pages["v"], (pages["v_scale"],)),
         ]
     else:
-        in_specs += [(kv_spec,), (kv_spec,)]
-        operands += [(pages["k"],), (pages["v"],)]
+        in_specs = [q_spec, (kv_spec,), (kv_spec,)]
+        operands = [qg, (pages["k"],), (pages["v"],)]
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size,
@@ -263,25 +261,26 @@ def _kernel(q, pages, table, lengths, scale):
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, num_pages),
+        grid=(b, num_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, n_rep, hd), q_map),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((max(n_rep, 8), 128), jnp.float32),
-            pltpu.VMEM((max(n_rep, 8), 128), jnp.float32),
-            pltpu.VMEM((max(n_rep, 8), hd), jnp.float32),
+            pltpu.VMEM((n_rep, kv, 128), jnp.float32),
+            pltpu.VMEM((n_rep, kv, 128), jnp.float32),
+            pltpu.VMEM((n_rep, kv, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, n_rep, hd), q.dtype),
-        compiler_params=fa.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((b, n_rep, kv, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=fa._interpret(),
+        name="paged_attention_decode",
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
-    return out.reshape(b, h, hd)
+    return out.swapaxes(1, 2).reshape(b, h, hd)
 
 
 def _sharded_kernel(q, pages, table, lengths, scale, mesh):

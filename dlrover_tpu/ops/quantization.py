@@ -19,14 +19,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # jax >= 0.8 moved shard_map out of experimental
-    from jax import shard_map as _shard_map
+from dlrover_tpu.common.log import warning_once
 
-    shard_map = functools.partial(_shard_map, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = functools.partial(_shard_map, check_rep=False)
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 INT8_MAX = 127.0
 DEFAULT_BLOCK = 256
@@ -265,21 +260,69 @@ def weight_quant_block(k: int, cap: int = DEFAULT_BLOCK) -> int:
     return b if b >= 8 else 0
 
 
-def use_quant_matmul_kernel(tp: int = 1) -> bool:
+# VMEM the fused kernel may plan for, in the two terms that grow with
+# the operands (both blocks hold the WHOLE contraction dim). Not a
+# model of Mosaic's allocator: bounds the v5e compiler accepted at
+# every shape probed under its 16 MiB scoped-VMEM default (K from
+# 4096 to 14336, bf16 and f32 activations) with room to spare —
+# tests/test_tpu_compile.py holds the 7B shapes to it.
+_DQMM_W_ELEMS = 3 * 1024 * 1024   # int8 weight block: bo * K
+_DQMM_X_BYTES = 2 * 1024 * 1024   # activation block: bt * K * itemsize
+
+
+def _dqmm_out_tile(k: int, o: int) -> int:
+    """Output-dim tile `bo` for an [O, K] weight: the largest lane
+    multiple (256, then 128) that divides O and keeps the [bo, K]
+    int8 block — and the dequant staging Mosaic derives from it —
+    inside the budget; O itself for a weight smaller than one tile.
+    0 when nothing fits (K too long for a whole-K block): the caller
+    takes the XLA reference, visibly."""
+    for bo in (256, 128):
+        if o % bo == 0 and bo * k <= _DQMM_W_ELEMS:
+            return bo
+    if o < 256 and o * k <= _DQMM_W_ELEMS:
+        return o
+    return 0
+
+
+def _dqmm_row_tile(t: int, k: int, itemsize: int) -> int:
+    """Row tile `bt`: the largest power of two (>= 8 sublanes) whose
+    [bt, K] activation block fits the budget, or all `t` rows when
+    they already do — so VMEM use stops growing with prompt length."""
+    bt = 8
+    while bt * 2 * k * itemsize <= _DQMM_X_BYTES:
+        bt *= 2
+    return t if t <= bt else bt
+
+
+def use_quant_matmul_kernel(tp: int = 1, w=None) -> bool:
     """Kernel-vs-reference gate for the fused dequant matmul, the
     KERNEL-001 shape shared with attention dispatch: the Pallas path
     is dispatchable on TPU or when force_kernels() opts the
     interpret-mode kernel in on CPU. tp > 1 stays on the XLA
     reference — the weights are GSPMD-sharded over the output axis
     and XLA partitions dequant+dot natively (per-shard pallas
-    dispatch for sharded weights is a real-TPU follow-up)."""
+    dispatch for sharded weights is a real-TPU follow-up). With a
+    QuantizedWeight `w` the gate also asks whether a block of it fits
+    VMEM: on TPU a weight that does not is a logged decision, never a
+    compile error."""
     from dlrover_tpu.ops.flash_attention import force_kernels
 
     if tp > 1:
         return False
-    if jax.default_backend() == "tpu":
+    if jax.default_backend() != "tpu":
+        return force_kernels()
+    if w is None:
         return True
-    return force_kernels()
+    o, k = w.q8.shape[-2:]
+    if _dqmm_out_tile(k, o):
+        return True
+    warning_once(
+        "int8 matmul [K=%d -> O=%d]: no whole-K block fits the fused "
+        "kernel's VMEM budget; this weight takes the XLA "
+        "dequant-then-dot reference", k, o,
+    )
+    return False
 
 
 def _dq_weight(q8: jax.Array, s8: jax.Array, block: int, dtype):
@@ -308,32 +351,40 @@ def _dqmm_kernel(x_ref, q_ref, s_ref, o_ref, *, block):
     o_ref[...] = _dqmm_dot(x_ref[...], wt).astype(o_ref.dtype)
 
 
-# output-tile for the fused kernel: q8 bytes + f32 dequant staging at
-# bo=256, K<=8192 stays ~10 MB VMEM alongside the x operand
-_DQMM_BO = 256
-
-
 def quantized_matmul_kernel(x: jax.Array, w: QuantizedWeight):
-    """Pallas fused dequant-matmul: grid tiles ONLY the output dim
-    (full K per instance — one pass over x, whole-row reduction), the
-    int8 block + its scales dequantize in VMEM right before the dot.
-    In interpret mode the grid collapses to one instance, so the body
-    runs the exact op sequence of `quantized_matmul_reference` —
-    that is the byte-parity oracle the tests and bench phase lock."""
+    """Pallas fused dequant-matmul: the grid tiles rows and the
+    output dim, every instance holds the full K (one pass, whole-row
+    reduction — no partial sums to reassociate), and the int8 block +
+    its scales dequantize in VMEM right before the dot. Tiles come
+    from the shapes (`_dqmm_row_tile`, `_dqmm_out_tile`); a ragged
+    last row tile is Pallas edge padding, harmless because rows never
+    mix. In interpret mode the grid collapses to one instance, so the
+    body runs the exact op sequence of `quantized_matmul_reference`
+    — that is the byte-parity oracle the tests and bench phase lock."""
     t, k = x.shape
     o = w.q8.shape[0]
-    bo = o if (_interpret() or o % _DQMM_BO) else _DQMM_BO
+    if _interpret():
+        bt, bo = t, o
+    else:
+        bt = _dqmm_row_tile(t, k, x.dtype.itemsize)
+        bo = _dqmm_out_tile(k, o)
+        if not bo:
+            raise ValueError(
+                f"int8 matmul kernel: no VMEM-sized block for a "
+                f"[{o}, {k}] weight; use the reference path"
+            )
     return pl.pallas_call(
         functools.partial(_dqmm_kernel, block=w.block),
-        grid=(o // bo,),
+        grid=(pl.cdiv(t, bt), o // bo),
         in_specs=[
-            pl.BlockSpec((t, k), lambda i: (0, 0)),
-            pl.BlockSpec((bo, k), lambda i: (i, 0)),
-            pl.BlockSpec((bo, w.s8.shape[-1]), lambda i: (i, 0)),
+            pl.BlockSpec((bt, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bo, k), lambda i, j: (j, 0)),
+            pl.BlockSpec((bo, w.s8.shape[-1]), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((t, bo), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((bt, bo), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, o), x.dtype),
         interpret=_interpret(),
+        name="int8_dequant_matmul",
     )(x, w.q8, w.s8)
 
 
@@ -353,7 +404,7 @@ def quantized_matmul(
     weight; x may carry leading batch dims ([..., K] -> [..., O])."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if use_quant_matmul_kernel(tp=tp):
+    if use_quant_matmul_kernel(tp=tp, w=w):
         y = quantized_matmul_kernel(x2, w)
     else:
         y = quantized_matmul_reference(x2, w)
@@ -382,14 +433,7 @@ def _ring_reduce_scatter_q(x, axis_name: str, block: int):
     [c, ...]. Each of the n-1 hops sends one quantized chunk to the next
     rank (ppermute), which dequantizes and accumulates its local data.
     """
-    # jax.lax.axis_size only landed after 0.4.x; psum of the literal 1
-    # folds to the static Python int (the `range(n)` perms below need
-    # a static size)
-    n = (
-        jax.lax.axis_size(axis_name)
-        if hasattr(jax.lax, "axis_size")
-        else jax.lax.psum(1, axis_name)
-    )
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     if x.shape[0] % n != 0:
         raise ValueError(

@@ -109,8 +109,6 @@ class DryRunner:
             return report
 
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
         report.flops = float(cost.get("flops", 0.0))
         report.bytes_accessed = float(cost.get("bytes accessed", 0.0))
         mem = compiled.memory_analysis()

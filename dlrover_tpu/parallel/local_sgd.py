@@ -25,14 +25,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
-
-    shard_map = functools.partial(_shard_map, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = functools.partial(_shard_map, check_rep=False)
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

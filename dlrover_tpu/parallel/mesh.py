@@ -353,6 +353,35 @@ def serving_head_specs(mesh: Mesh) -> Dict[str, PartitionSpec]:
     }
 
 
+def attention_qkv_spec(mesh: Mesh) -> Tuple[PartitionSpec, int]:
+    """(spec, head-shard degree) for shard_mapping a fused attention
+    kernel over ``[B, S, H, D]`` activations on ANY mesh this module
+    builds — the TPU compiler refuses to partition a Pallas kernel
+    itself, so under more than one device the kernel must run inside
+    a shard_map whose specs match what GSPMD already gave q/k/v:
+
+    - a serving mesh: heads on ``"tp"`` (`serving_head_specs`);
+    - a training mesh: batch on the batch axes (data, fsdp), heads on
+      ``"tensor"`` — the layout models/llama.py constrains q/k/v to.
+      The seq/pipe/expert axes stay out: sequence parallelism has its
+      own attention (parallel/sequence.py) and callers only come here
+      when those axes have size 1.
+
+    Attention mixes neither batch rows nor heads, so the body needs
+    no collectives either way."""
+    if SERVING_TP_AXIS in mesh.axis_names:
+        return (
+            serving_head_specs(mesh)["qkv"], serving_mesh_tp(mesh)
+        )
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    batch = tuple(a for a in BATCH_AXES if sizes.get(a, 1) > 1)
+    heads = "tensor" if sizes.get("tensor", 1) > 1 else None
+    return (
+        PartitionSpec(batch or None, None, heads, None),
+        sizes.get("tensor", 1),
+    )
+
+
 def serving_adapter_specs(mesh: Mesh) -> Dict[str, PartitionSpec]:
     """PartitionSpecs for the stacked device adapter banks a serving
     replica gathers per-slot LoRA deltas from (serving/adapters.py):

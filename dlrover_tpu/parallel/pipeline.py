@@ -27,30 +27,15 @@ Tree = Any
 
 
 def _shard_map_manual(f, mesh, in_specs, out_specs, axis: str):
-    """shard_map with only `axis` manual (jax>=0.9 axis_names API)."""
-    import inspect
-
-    # jax.shard_map is absent on 0.4.x (the module __getattr__ raises,
-    # so probe with getattr, not hasattr-then-touch)
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None and "axis_names" in inspect.signature(
-        sm
-    ).parameters:
-        return sm(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names={axis},
-            check_vma=False,
-        )
-    # older jax: auto = every other axis
-    auto = frozenset(a for a in mesh.axis_names if a != axis)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        auto=auto, check_rep=False,
+    """shard_map with only `axis` manual; every other mesh axis stays
+    under GSPMD."""
+    return jax.shard_map(
+        f,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        axis_names={axis},
+        check_vma=False,
     )
 
 

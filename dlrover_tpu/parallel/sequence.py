@@ -23,32 +23,13 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import functools as _ft
-
-try:
-    from jax import shard_map as _shard_map
-
-    # jax>=0.8: varying-manual-axes checking renamed check_rep→check_vma;
-    # our scan carries start replicated and become device-varying, so
-    # disable the check rather than pcast every init.
-    shard_map = _ft.partial(_shard_map, check_vma=False)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = _ft.partial(_shard_map, check_rep=False)
 from jax.sharding import Mesh, PartitionSpec as P
 
+# the scan carries start replicated and become device-varying, so the
+# varying-manual-axes check is disabled rather than pcast-ing every init
+shard_map = functools.partial(jax.shard_map, check_vma=False)
+
 NEG_INF = -1e30
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size. jax.lax.axis_size only landed after
-    0.4.x; psum of the literal 1 is the portable spelling (a non-tracer
-    operand folds to the Python int, so `range(sp)` / `h % sp` below
-    stay static under shard_map + jit)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -94,7 +75,7 @@ def ulysses_attention(
     attention on H/sp local heads, and converts back. Requires H % sp == 0;
     KV heads are broadcast up to a multiple of sp first if needed.
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % sp:
         raise ValueError(f"ulysses needs n_heads % sp == 0 ({h} % {sp})")
@@ -134,7 +115,7 @@ def ring_attention(
     step folds one chunk into an online-softmax accumulator. Handles GQA
     (H % KV == 0) and causal masking in global coordinates.
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5

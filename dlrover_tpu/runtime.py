@@ -74,54 +74,44 @@ def node_count() -> int:
     return _ctx.node_num
 
 
-def enable_compile_cache() -> Optional[str]:
-    """Point XLA's persistent compilation cache at a per-user disk dir.
+# where the persistent compile cache lives when nobody placed it from
+# outside: ONE fixed directory in the checkout (git-ignored). The path
+# is part of XLA's cache key, so it must never be built from a temp
+# name, a pid or the time — a directory that moves never hits.
+_IN_TREE_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".xla_cache",
+)
 
-    The measured recovery stall after a SIGKILL is dominated by the
-    respawned worker's jit recompile (~40 s of the r4 E2E's 40 s
-    stall; the shm state read is milliseconds) — and a respawned
-    worker compiles the exact program its predecessor already
-    compiled. The reference leans on torch's eager mode to sidestep
-    this; the XLA answer is the persistent cache: first process pays
-    the compile, every respawn (and every later job on the same
-    program) hits disk.
 
-    DLROVER_TPU_COMPILE_CACHE, when set, always wins: a path
-    overrides any pre-configured location, "0"/"off" disables even a
-    pre-configured cache. With the env var unset, an
-    already-configured jax cache dir is respected. Returns the dir in
-    effect (None = disabled)."""
+def enable_compile_cache() -> str:
+    """Turn on XLA's persistent compilation cache; returns its dir.
+
+    A respawned worker compiles the exact program its predecessor
+    already compiled, and that recompile — not the shm state read,
+    which is milliseconds — is what a SIGKILL recovery waits for. The
+    reference leans on torch's eager mode to sidestep this; the XLA
+    answer is the persistent cache: the first process pays the
+    compile, every respawn (and every later job on the same program)
+    reads it back from disk.
+
+    The directory is placed from OUTSIDE: where
+    JAX_COMPILATION_CACHE_DIR is set (or a caller configured
+    `jax_compilation_cache_dir` already), jax's own handling of it is
+    the cache and this function sets no directory. Only when nothing
+    is configured does it point jax at the fixed in-tree directory."""
     import jax
 
-    want = os.environ.get("DLROVER_TPU_COMPILE_CACHE", "")
-    if want.lower() in ("0", "off", "none"):
-        # an explicit disable wins even over a pre-configured cache
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:  # noqa: BLE001
-            pass
-        return None
-    current = getattr(jax.config, "jax_compilation_cache_dir", None)
-    if current and not want:
-        return current  # already configured and no explicit override
-    cache_dir = want or os.path.join(
-        os.path.expanduser("~"), ".cache", "dlrover_tpu", "xla_cache"
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        # thresholds FIRST: if these knob names don't exist on this
-        # jax, nothing is half-enabled when we bail
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 1.0
-        )
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # noqa: BLE001 — older jax knob names: no cache
-        logger.warning(
-            "persistent compilation cache unavailable", exc_info=True
-        )
-        return None
-    return cache_dir
+    # worth caching: anything that took a second to compile, however
+    # small the executable
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    current = jax.config.jax_compilation_cache_dir
+    if current:
+        return current
+    os.makedirs(_IN_TREE_COMPILE_CACHE, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _IN_TREE_COMPILE_CACHE)
+    return _IN_TREE_COMPILE_CACHE
 
 
 def init(

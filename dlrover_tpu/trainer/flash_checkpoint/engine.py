@@ -109,9 +109,8 @@ def flatten_state(state: Any) -> Tuple[Dict[str, np.ndarray], bytes]:
     # kick off the device→host DMA for EVERY leaf before draining any:
     # np.asarray on a jax.Array is a synchronous round-trip, and a
     # 300-leaf train state staged serially pays 300 transfer latencies
-    # back to back (pathological over a network-tunneled chip, and
-    # still a pipeline stall on directly-attached PCIe). After this
-    # pass the per-leaf np.asarray below finds bytes already in flight.
+    # back to back — a pipeline stall on the chip's host link. After
+    # this pass the per-leaf np.asarray below finds bytes already in flight.
     for _, leaf in leaves_with_paths:
         if isinstance(leaf, jax.Array):
             try:

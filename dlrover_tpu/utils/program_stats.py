@@ -103,9 +103,6 @@ def extract_program_stats(compiled: Any) -> ProgramStats:
     stats = ProgramStats()
     try:
         cost = compiled.cost_analysis() or {}
-        # jax <0.5 returned [dict]; newer returns dict
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         stats.flops = float(cost.get("flops", 0.0))
         stats.bytes_accessed = float(cost.get("bytes accessed", 0.0))
     except Exception:  # noqa: BLE001 — backend-dependent

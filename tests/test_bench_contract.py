@@ -1,8 +1,8 @@
-"""The driver contract on bench.py: ONE JSON line with
-metric/value/unit/vs_baseline (BENCH_r{N}.json is parsed from it), and
-the checkpoint evidence axes r4 added. Runs the CPU smoke mode in a
-subprocess — cheap insurance that a refactor can never silently break
-the round's only perf-evidence channel."""
+"""The contract on bench.py: ONE JSON line with
+metric/value/unit/vs_baseline and the checkpoint evidence axes. Runs
+the explicit CPU smoke mode in a subprocess — cheap insurance that a
+refactor cannot silently break the script; the line it prints names
+the CPU and is never a measurement."""
 
 import json
 import os
@@ -19,11 +19,10 @@ def test_bench_smoke_emits_driver_contract():
         [sys.executable, os.path.join(REPO, "bench.py")],
         env={
             **os.environ,
+            # the explicit CPU mode: without it bench.py fails on
+            # anything but a TPU
             "DLROVER_TPU_FORCE_CPU": "1",
             "JAX_PLATFORMS": "cpu",
-            # pin: an externally exported short timeout (debugging the
-            # sibling watchdog test) must not flip this into rc=3
-            "BENCH_PROBE_TIMEOUT": "600",
         },
         capture_output=True,
         text=True,
@@ -55,72 +54,10 @@ def test_bench_smoke_emits_driver_contract():
     ):
         assert key in detail, f"missing detail axis: {key}"
     assert detail["ckpt_roundtrip_ok"] is True
+    assert detail["device"]["platform"] == "cpu"
+    assert detail["mfu"] == 0.0  # no peak is claimed for a CPU
     assert detail["weight_bytes_device"] > 0
     assert detail["tok_per_sec_per_weight_gb"] > 0
-
-
-@pytest.mark.slow
-def test_bench_watchdog_emits_diagnosed_line():
-    # a dead backend must produce a parseable line naming the stuck
-    # phase, not a silent rc=1 (round-3 failure mode) — and since the
-    # infra fallback, a LABELED cpu-smoke metric instead of the bare
-    # 0.0 that reads like a perf regression in the driver's history.
-    # Slow lane: the fallback child is a FULL CPU-smoke bench run (the
-    # fast tier keeps the no-fallback sibling below, which pins the
-    # diagnosed-line contract without spawning a second bench)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env={
-            **os.environ,
-            "DLROVER_TPU_FORCE_CPU": "1",
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_PROBE_TIMEOUT": "0.1",
-        },
-        capture_output=True,
-        text=True,
-        timeout=900,
-        cwd=REPO,
-    )
-    assert proc.returncode == 3
-    lines = [
-        ln for ln in proc.stdout.splitlines() if ln.startswith("{")
-    ]
-    assert len(lines) == 1, f"expected ONE JSON line: {lines}"
-    d = json.loads(lines[0])
-    assert d["metric"] == "tokens_per_sec_per_chip"
-    assert d["value"] > 0
-    assert d["detail"]["backend"] == "cpu-smoke"
-    assert "infra_error" in d["detail"]
-
-
-def test_bench_no_fallback_pins_zero_line():
-    # the fallback child sets BENCH_NO_FALLBACK=1 on itself: a second
-    # infra failure inside it must emit the plain zero line, never
-    # recurse into another subprocess
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env={
-            **os.environ,
-            "DLROVER_TPU_FORCE_CPU": "1",
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_PROBE_TIMEOUT": "0.1",
-            "BENCH_NO_FALLBACK": "1",
-        },
-        capture_output=True,
-        text=True,
-        timeout=300,
-        cwd=REPO,
-    )
-    assert proc.returncode == 3
-    d = json.loads(
-        [
-            ln
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("{")
-        ][0]
-    )
-    assert d["value"] == 0.0
-    assert "error" in d["detail"]
 
 
 @pytest.mark.slow

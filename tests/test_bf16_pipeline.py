@@ -23,20 +23,6 @@ from dlrover_tpu.models import llama
 from dlrover_tpu.parallel.accelerate import Strategy, accelerate
 from dlrover_tpu.parallel.mesh import MeshSpec
 
-# same gate as tests/test_pipeline.py: the GPipe schedule needs the
-# jax>=0.9 shard_map axis_names (partial-manual) API; the 0.4.x
-# partial-auto fallback dies in XLA SPMD partitioning (PartitionId
-# UNIMPLEMENTED). Failing since the seed commit (1624165).
-import inspect as _inspect
-
-_sm = getattr(jax, "shard_map", None)
-pytestmark = pytest.mark.skipif(
-    _sm is None
-    or "axis_names" not in _inspect.signature(_sm).parameters,
-    reason="bf16 GPipe needs jax>=0.9 shard_map axis_names "
-    "(partial-manual) API",
-)
-
 
 @pytest.fixture(scope="module")
 def bf16_pipeline_acc():

@@ -9,28 +9,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import pytest
 
 from dlrover_tpu.models import llama
 from dlrover_tpu.parallel.accelerate import Strategy, accelerate
 from dlrover_tpu.parallel.mesh import MeshSpec
 from dlrover_tpu.parallel.pipeline import pipeline_apply
-
-# the GPipe schedule keeps ONLY the pipe axis manual, which needs the
-# jax>=0.9 shard_map axis_names API. On 0.4.x the partial-auto
-# fallback traces, but axis_index lowers to a PartitionId instruction
-# XLA's SPMD partitioner refuses (UNIMPLEMENTED) — and one variant
-# aborts the process outright. Failing (AttributeError) since the
-# seed commit (1624165); skip rather than crash the tier-1 run.
-import inspect as _inspect
-
-_sm = getattr(jax, "shard_map", None)
-pytestmark = pytest.mark.skipif(
-    _sm is None
-    or "axis_names" not in _inspect.signature(_sm).parameters,
-    reason="pipeline GPipe schedule needs jax>=0.9 shard_map "
-    "axis_names (partial-manual) API",
-)
 
 
 def test_pipeline_apply_generic():

@@ -403,9 +403,26 @@ class TestAdaptiveAndMetrics:
         )
         _drain(eng, prompts)
         st = eng.spec.stats()
-        assert st["slots_drafting"] < eng.n_slots or (
-            st["acceptance_rate"] >= 0.5
-        )
+        # what the controller promises is one step of back-off per
+        # LOSING scored round, and off after k_max of them in a row —
+        # not that 24 noise tokens contain k_max rounds: on noise the
+        # n-gram drafter seldom proposes at all (the token stream, and
+        # so the count of scored rounds, depends on the XLA build's
+        # numerics; jax 0.9.0 gives slot 1 a single one). So: every
+        # slot that was scored has backed off, and a run of k_max
+        # losing rounds does turn a slot off.
+        ctl = eng.spec.controller
+        if st["acceptance_rate"] < 0.5:
+            scored = [
+                i for i in range(eng.n_slots) if ctl._slots[i].seen
+            ]
+            assert scored
+            assert all(
+                ctl.current_k(i) < eng.spec.draft_len for i in scored
+            )
+        for _ in range(eng.spec.draft_len):
+            ctl.observe(0, 4, 0)
+        assert ctl.current_k(0) == 0
 
     def test_counters_are_consistent(self, model):
         cfg, params = model
