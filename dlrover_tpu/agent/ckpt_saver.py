@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.constants import CheckpointConstant
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import (
@@ -120,27 +121,31 @@ class SharedMemoryHandler:
                 )
             )
             offset += arr.nbytes
-        if (
-            self._segment is None
-            or self._segment.size < offset
-            or self._segment.is_stale()
-        ):
-            if self._segment is not None:
-                self._segment.close()
-            self._segment = SharedMemorySegment(
-                self.seg_name, size=max(offset, 1), create=True
-            )
-        buf = self._segment.buf
-        for tm, arr in zip(tensors, flat.values()):
-            if tm.nbytes == 0:
-                continue
-            # copy straight into the mapping: tobytes() would material-
-            # ize a second full host copy of every tensor per save
-            dst = np.frombuffer(
-                buf, dtype=np.uint8, count=tm.nbytes, offset=tm.offset
-            )
-            src = np.ascontiguousarray(arr)
-            np.copyto(dst, src.reshape(-1).view(np.uint8))
+        # the copy into the mapping (and, the first time or after a
+        # resize, the segment's creation), then the word to the agent
+        with trace.span("ckpt.shm_write", bytes=offset) as sp:
+            if (
+                self._segment is None
+                or self._segment.size < offset
+                or self._segment.is_stale()
+            ):
+                if self._segment is not None:
+                    self._segment.close()
+                self._segment = SharedMemorySegment(
+                    self.seg_name, size=max(offset, 1), create=True
+                )
+                sp.set(new_segment=1)
+            buf = self._segment.buf
+            for tm, arr in zip(tensors, flat.values()):
+                if tm.nbytes == 0:
+                    continue
+                # copy straight into the mapping: tobytes() would material-
+                # ize a second full host copy of every tensor per save
+                dst = np.frombuffer(
+                    buf, dtype=np.uint8, count=tm.nbytes, offset=tm.offset
+                )
+                src = np.ascontiguousarray(arr)
+                np.copyto(dst, src.reshape(-1).view(np.uint8))
         meta = CheckpointMeta(
             step=step,
             save_path=save_path,
@@ -148,7 +153,8 @@ class SharedMemoryHandler:
             aux=aux,
             total_bytes=offset,
         )
-        self.meta_dict.set("meta", pickle.dumps(meta))
+        with trace.span("ckpt.notify"):
+            self.meta_dict.set("meta", pickle.dumps(meta))
 
     # ---- read path (agent saver / trainer restore) ----------------------
 
@@ -162,6 +168,12 @@ class SharedMemoryHandler:
         meta = self.get_meta()
         if meta is None or meta.step < 0:
             return None, {}
+        with trace.span("ckpt.shm_read", bytes=meta.total_bytes):
+            return self._read_segment(meta)
+
+    def _read_segment(
+        self, meta: CheckpointMeta
+    ) -> Tuple[Optional[CheckpointMeta], Dict[str, np.ndarray]]:
         if (
             self._segment is None
             or self._segment.size < meta.total_bytes

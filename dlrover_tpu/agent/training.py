@@ -23,6 +23,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from dlrover_tpu.agent.master_client import MasterClient
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.constants import (
     JobConstant,
     NodeEnv,
@@ -413,12 +414,20 @@ class ElasticTrainingAgent:
         data otherwise: N MEMORY-only saves since the last DISK commit
         would roll training back to the old disk step. The saver skips
         stale steps, so this is a no-op when shm already hit storage."""
-        try:
-            self.ckpt_saver.save_shm_to_storage()
-        except Exception:  # noqa: BLE001
-            logger.exception("pre-restart checkpoint persist failed")
-        self._stop_worker()
-        return self._start_worker()
+        with trace.span("agent.persist", path="restart") as persist:
+            try:
+                self.ckpt_saver.save_shm_to_storage()
+            except Exception:  # noqa: BLE001
+                logger.exception("pre-restart checkpoint persist failed")
+        with trace.span("agent.respawn") as respawn:
+            self._stop_worker()
+            started = self._start_worker()
+        logger.info(
+            "worker restart: persist %.1f ms, respawn %.1f ms "
+            "(stop, rendezvous, start)",
+            persist.dur_s * 1e3, respawn.dur_s * 1e3,
+        )
+        return started
 
     # ---- main loop -------------------------------------------------------
 
@@ -469,13 +478,18 @@ class ElasticTrainingAgent:
 
     def _monitor_loop(self) -> int:
         while not self._stop.is_set():
-            self._wait_stop(self.config.monitor_interval)
-            if self._stop.is_set():
-                break
-            # snapshot: leave() (another thread / in-process E2E
-            # callers) nulls self.worker concurrently
-            w = self.worker
-            code = w.poll() if w else None
+            # one poll period: where it ends with an exit code, its
+            # extent bounds how long the exit went unnoticed
+            with trace.span("agent.detect") as detect:
+                self._wait_stop(self.config.monitor_interval)
+                if self._stop.is_set():
+                    break
+                # snapshot: leave() (another thread / in-process E2E
+                # callers) nulls self.worker concurrently
+                w = self.worker
+                code = w.poll() if w else None
+                if code is not None:
+                    detect.set(exit_code=code)
             if code is None:
                 if self._membership_changed():
                     logger.info(
@@ -501,11 +515,21 @@ class ElasticTrainingAgent:
                 continue
             # failure path: persist any staged shm checkpoint first
             # (reference _save_ckpt_to_storage training.py:674)
-            logger.warning("worker exited with code %d", code)
-            try:
-                self.ckpt_saver.save_shm_to_storage()
-            except Exception:  # noqa: BLE001
-                logger.exception("crash-path checkpoint persist failed")
+            logger.warning(
+                "worker exited with code %d (noticed within %.1f ms)",
+                code, detect.dur_s * 1e3,
+            )
+            with trace.span("agent.persist", path="crash") as persist:
+                try:
+                    self.ckpt_saver.save_shm_to_storage()
+                except Exception:  # noqa: BLE001
+                    logger.exception(
+                        "crash-path checkpoint persist failed"
+                    )
+            logger.info(
+                "crash-path checkpoint persist: %.1f ms",
+                persist.dur_s * 1e3,
+            )
             self.client.report_failure(
                 f"worker exit code {code}",
                 TrainingExceptionLevel.PROCESS_ERROR,

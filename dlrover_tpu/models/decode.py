@@ -258,17 +258,21 @@ def _block(
     one layer's stacked adapter bank slices for batched multi-adapter
     serving (see `_forward_cached`)."""
     lp = _compute_weights(cfg, layer_params)
-    h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
     tp = _mesh_tp(mesh)
-    q, k, v = _attn_qkv(cfg, None, h, lp, positions, lora=lora, tp=tp)
-    attn, layer_cache = _write_cache_and_attend(
-        q, k, v, layer_cache, positions, start, cfg.head_dim,
-        attn_impl=getattr(cfg, "attn_impl", "auto"),
-        plain_causal=plain_causal,
-        mesh=mesh,
-    )
-    x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
-    x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
+    with jax.named_scope("attn"):
+        h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
+        q, k, v = _attn_qkv(
+            cfg, None, h, lp, positions, lora=lora, tp=tp
+        )
+        attn, layer_cache = _write_cache_and_attend(
+            q, k, v, layer_cache, positions, start, cfg.head_dim,
+            attn_impl=getattr(cfg, "attn_impl", "auto"),
+            plain_causal=plain_causal,
+            mesh=mesh,
+        )
+        x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
+    with jax.named_scope("mlp"):
+        x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
     return x, layer_cache
 
 
@@ -377,7 +381,8 @@ def _forward_cached(
         if adapters is None
         else (params["layers"], dict(cache), dict(adapters["bank"]))
     )
-    x, scanned = jax.lax.scan(body, x, xs)
+    with jax.named_scope("layers"):
+        x, scanned = jax.lax.scan(body, x, xs)
     cache_new = scanned
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
@@ -858,9 +863,12 @@ def _write_pages_and_attend(
         writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     else:
         writes = {"k": k, "v": v}
-    for name, upd in writes.items():
-        arr = layer_pool[name]
-        out_pool[name] = arr.at[pids, offs].set(upd.astype(arr.dtype))
+    with jax.named_scope("kv_pool_writeback"):
+        for name, upd in writes.items():
+            arr = layer_pool[name]
+            out_pool[name] = arr.at[pids, offs].set(
+                upd.astype(arr.dtype)
+            )
     s = q.shape[1]
     # attn_impl='reference' is the byte-parity oracle knob: it pins
     # the gathered-view formulation even where use_kernel would take
@@ -871,13 +879,15 @@ def _write_pages_and_attend(
         q1 = q[:, 0]
         if pa.use_kernel(q1, out_pool, table, tp=_mesh_tp(mesh)):
             lengths = positions[:, 0] + 1
-            attn = pa.paged_attention(
-                q1, out_pool, table, lengths,
-                scale=float(head_dim) ** -0.5, impl="kernel",
-                mesh=mesh,
-            )
+            with jax.named_scope("paged_attn"):
+                attn = pa.paged_attention(
+                    q1, out_pool, table, lengths,
+                    scale=float(head_dim) ** -0.5, impl="kernel",
+                    mesh=mesh,
+                )
             return constrain(attn[:, None], mesh), out_pool
-    view = _paged_view(out_pool, table)
+    with jax.named_scope("kv_pool_slice"):
+        view = _paged_view(out_pool, table)
     attn = _cached_attention(
         q, view, positions, float(head_dim) ** -0.5
     )
@@ -893,16 +903,20 @@ def _block_paged(
     `_block` (including the per-slot `lora` deltas); only the cache
     write + view differ."""
     lp = _compute_weights(cfg, layer_params)
-    h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
     tp = _mesh_tp(mesh)
-    q, k, v = _attn_qkv(cfg, None, h, lp, positions, lora=lora, tp=tp)
-    attn, layer_pool = _write_pages_and_attend(
-        q, k, v, layer_pool, table, positions, cfg.head_dim,
-        mesh=mesh,
-        attn_impl=getattr(cfg, "attn_impl", "auto"),
-    )
-    x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
-    x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
+    with jax.named_scope("attn"):
+        h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
+        q, k, v = _attn_qkv(
+            cfg, None, h, lp, positions, lora=lora, tp=tp
+        )
+        attn, layer_pool = _write_pages_and_attend(
+            q, k, v, layer_pool, table, positions, cfg.head_dim,
+            mesh=mesh,
+            attn_impl=getattr(cfg, "attn_impl", "auto"),
+        )
+        x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
+    with jax.named_scope("mlp"):
+        x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
     return x, layer_pool
 
 
@@ -963,7 +977,11 @@ def _forward_paged(
         if adapters is None
         else (params["layers"], dict(pool), dict(adapters["bank"]))
     )
-    x, pool_new = jax.lax.scan(body, x, xs)
+    # the scan itself slices each layer's K and V out of the stacked
+    # pool and stacks them back: those copies carry this scope and no
+    # deeper one (layers/while/body/dynamic_slice, .../dynamic_update_slice)
+    with jax.named_scope("layers"):
+        x, pool_new = jax.lax.scan(body, x, xs)
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 
@@ -1025,9 +1043,10 @@ def gather_pool_view(
     per-step gather would copy the full cache once PER TOKEN, the
     dominant paged overhead on backends without the Pallas kernel)."""
     out = {}
-    for name, arr in pool.items():
-        g = arr[:, table]  # [L, B, P, page_size, ...]
-        out[name] = g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
+    with jax.named_scope("kv_pool_slice"):
+        for name, arr in pool.items():
+            g = arr[:, table]  # [L, B, P, page_size, ...]
+            out[name] = g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
     return out
 
 
@@ -1058,9 +1077,10 @@ def scatter_pool_window(
     offs = positions % ps
     idx = positions[None, :, :, None, None]  # broadcast L, KV, tail
     out = {}
-    for name, arr in pool.items():
-        cells = jnp.take_along_axis(view[name], idx, axis=2)
-        out[name] = arr.at[:, pids, offs].set(cells)
+    with jax.named_scope("kv_pool_writeback"):
+        for name, arr in pool.items():
+            cells = jnp.take_along_axis(view[name], idx, axis=2)
+            out[name] = arr.at[:, pids, offs].set(cells)
     return out
 
 

@@ -400,6 +400,15 @@ def _mlp_residual(cfg: LlamaConfig, mesh, x, layer_params, lp, tp: int = 1):
 def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
     """One decoder block on [B, S, D] activations."""
     lp = _compute_weights(cfg, layer_params)
+    with jax.named_scope("attn"):
+        x = _attn_block(cfg, mesh, x, layer_params, lp, positions)
+    with jax.named_scope("mlp"):
+        return _mlp_residual(cfg, mesh, x, layer_params, lp)
+
+
+def _attn_block(cfg: LlamaConfig, mesh, x, layer_params, lp, positions):
+    """The attention half of `_layer`: norm, projections, attention,
+    output projection and residual."""
     h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
     q, k, v = _attn_qkv(cfg, mesh, h, lp, positions)
     sp_live = (
@@ -431,8 +440,7 @@ def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
             tp=axes.get("tensor", 1) if flat else 1,
             mesh=mesh if flat else None,
         )
-    x = _attn_residual(cfg, mesh, x, attn, lp)
-    return _mlp_residual(cfg, mesh, x, layer_params, lp)
+    return _attn_residual(cfg, mesh, x, attn, lp)
 
 
 def apply(
@@ -452,8 +460,9 @@ def apply(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
 
-    x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
-    x = constrain(x, mesh, ("data", "fsdp"), "seq", None)
+    with jax.named_scope("embed"):
+        x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
+        x = constrain(x, mesh, ("data", "fsdp"), "seq", None)
 
     from dlrover_tpu.parallel.pipeline import num_stages, pipeline_apply
 
@@ -496,18 +505,23 @@ def apply(
             body = jax.checkpoint(
                 body, policy=resolve_policy(cfg.remat_policy)
             )
-        x, aux_per_layer = jax.lax.scan(body, x, params["layers"])
+        with jax.named_scope("layers"):
+            x, aux_per_layer = jax.lax.scan(body, x, params["layers"])
 
-    x = _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    with jax.named_scope("lm_head_loss"):
+        x = _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     if return_hidden:
         # pre-head hidden states for the fused-CE loss path (the
         # [B,S,V] logits are never formed there)
         if return_aux:
             return x, jnp.sum(aux_per_layer)
         return x
-    head = _head_matrix(cfg, params)
-    logits = (x @ head).astype(jnp.float32)
-    logits = constrain(logits, mesh, ("data", "fsdp"), "seq", "tensor")
+    with jax.named_scope("lm_head_loss"):
+        head = _head_matrix(cfg, params)
+        logits = (x @ head).astype(jnp.float32)
+        logits = constrain(
+            logits, mesh, ("data", "fsdp"), "seq", "tensor"
+        )
     if return_aux:
         return logits, jnp.sum(aux_per_layer)
     return logits
@@ -546,29 +560,31 @@ def loss_fn(
             cfg, params, tokens[:, :-1], mesh=mesh,
             return_aux=True, return_hidden=True,
         )
-        head = _head_matrix(cfg, params)
-        m = mask[:, 1:] if mask is not None else None
-        loss_sum, weight = fused_cross_entropy(
-            hidden, head, targets, m
-        )
-        weight = jnp.maximum(weight, 1.0)
-        loss = loss_sum / weight
+        with jax.named_scope("lm_head_loss"):
+            head = _head_matrix(cfg, params)
+            m = mask[:, 1:] if mask is not None else None
+            loss_sum, weight = fused_cross_entropy(
+                hidden, head, targets, m
+            )
+            weight = jnp.maximum(weight, 1.0)
+            loss = loss_sum / weight
     else:
         logits, aux = apply(
             cfg, params, tokens[:, :-1], mesh=mesh, return_aux=True
         )
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None], axis=-1
-        ).squeeze(-1)
-        if mask is not None:
-            m = mask[:, 1:].astype(nll.dtype)
-            total = jnp.maximum(m.sum(), 1.0)
-            loss = (nll * m).sum() / total
-            weight = total
-        else:
-            loss = nll.mean()
-            weight = jnp.asarray(nll.size, jnp.float32)
+        with jax.named_scope("lm_head_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1
+            ).squeeze(-1)
+            if mask is not None:
+                m = mask[:, 1:].astype(nll.dtype)
+                total = jnp.maximum(m.sum(), 1.0)
+                loss = (nll * m).sum() / total
+                weight = total
+            else:
+                loss = nll.mean()
+                weight = jnp.asarray(nll.size, jnp.float32)
     metrics = {"loss": loss, "loss_weight": weight}
     if cfg.n_experts > 0:
         loss = loss + aux

@@ -220,10 +220,11 @@ def accelerate(
         if ls is not None:
             grads = amp.unscale_grads(grads, ls)
 
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], params
-        )
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt_state"], params
+            )
+            new_params = optax.apply_updates(params, updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = optax.global_norm(grads)
         new_state = {

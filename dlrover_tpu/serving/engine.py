@@ -73,6 +73,7 @@ Device residency + async dispatch (the perf layer over both modes):
   §9 records the staleness contract this leaves the scheduler).
 """
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -83,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.decode import (
     _check_adapters,
@@ -356,14 +358,15 @@ def _build_chunk_program(
     # kv_layout="paged" reduces to the forward producing identical
     # logits, which the gathered-view attention guarantees.
     def _advance(logits, tok, pos, done, limit, keys):
-        if temperature <= 0.0:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-            keys, subs = pair[:, 0], pair[:, 1]
-            nxt = jax.vmap(
-                lambda l, kk: jax.random.categorical(kk, l)
-            )(_warp(logits), subs).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if temperature <= 0.0:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
+                keys, subs = pair[:, 0], pair[:, 1]
+                nxt = jax.vmap(
+                    lambda l, kk: jax.random.categorical(kk, l)
+                )(_warp(logits), subs).astype(jnp.int32)
         nxt = jnp.where(done, pad_id, nxt)
         hit_eos = (
             (nxt == eos_id)
@@ -573,14 +576,15 @@ def _build_pf_chunk_program(
         return logits
 
     def _advance(logits, tok, pos, done, limit, keys):
-        if temperature <= 0.0:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-            keys, subs = pair[:, 0], pair[:, 1]
-            nxt = jax.vmap(
-                lambda l, kk: jax.random.categorical(kk, l)
-            )(_warp(logits), subs).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if temperature <= 0.0:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
+                keys, subs = pair[:, 0], pair[:, 1]
+                nxt = jax.vmap(
+                    lambda l, kk: jax.random.categorical(kk, l)
+                )(_warp(logits), subs).astype(jnp.int32)
         nxt = jnp.where(done, pad_id, nxt)
         hit_eos = (
             (nxt == eos_id)
@@ -1525,7 +1529,11 @@ class ContinuousBatcher:
         self._stat_span_ms = 0.0
         self._stat_overlap_ms = 0.0
         self._stat_dispatches = 0
-        self._wait_this_step = 0.0
+        self._wait_this_step = 0.0   # s blocked on the device, this step
+        self._admit_this_step = 0.0  # s inside _admit, this step
+        # extent of the last engine.step span: the scheduler's
+        # straggler EWMA reads it instead of timing step() again
+        self.last_step_s = 0.0
         self.slot_req: List[Optional[_Request]] = [None] * n_slots
         self._queue: deque = deque()
         # ledger: idx -> request, plus the order generate_all returns.
@@ -2291,102 +2299,107 @@ class ContinuousBatcher:
         # below until the state scatters runs synchronously — with
         # prefill_chunk>0 it shrinks to host bookkeeping because the
         # prefill itself moves into the interleaved dispatches
-        t0 = time.perf_counter()
-        pf_start: Optional[int] = None
-        if req.adopted is not None:
-            # cross-replica handoff: install the shipped KV run and
-            # skip the prefill entirely. Cleared immediately — a later
-            # preemption of this slot replays from the prompt like any
-            # other request (the package is single-use by design).
-            from dlrover_tpu.serving import handoff as _handoff
+        with trace.span(
+            "engine.admit", prompt_tokens=p,
+            bucket=min(_pad_bucket(p), self.max_len),
+        ) as sp:
+            pf_start: Optional[int] = None
+            if req.adopted is not None:
+                # cross-replica handoff: install the shipped KV run and
+                # skip the prefill entirely. Cleared immediately — a later
+                # preemption of this slot replays from the prompt like any
+                # other request (the package is single-use by design).
+                from dlrover_tpu.serving import handoff as _handoff
 
-            pkg, req.adopted = req.adopted, None
-            _handoff.adopt_into_slot(self, slot, pkg)
-        elif self._prefill_chunk > 0:
-            # interleaved chunked admission: install the slot with a
-            # partial write frontier and NO prompt forward — the step
-            # loop streams the prefill in chunks fused with decode.
-            # The preempted flag clears only AFTER the allocation
-            # lands: a readmission that raises OutOfPages goes back
-            # to the queue still marked, so it keeps waiting instead
-            # of regaining preemption rights (see _admit_chunked_paged
-            # on why that would livelock)
-            pf_start = self._admit_chunked(slot, req, p)
-            if self._paged and req.preempted:
-                req.preempted = False
-                self._swap_resumes += 1
-        elif self._paged:
-            if req.preempted:
-                req.preempted = False
-                self._swap_resumes += 1
-            self._admit_paged(slot, req, p)
-        elif req.adapter_id is not None:
-            # adaptered admission: the prompt K/V must come from the
-            # ADAPTED projections, and it never installs from (or
-            # publishes into) the shared prefix pool — published
-            # prefixes are base-model K/V by contract
-            bucket = min(_pad_bucket(p), self.max_len)
-            self.cache = self._admit_lora_fn(
-                self.cache,
-                self.params,
-                jnp.asarray(self._pad_to(req.prompt, bucket)),
-                slot,
-                self._adapter_cache.bank,
-                req.adapter_slot,
+                pkg, req.adopted = req.adopted, None
+                _handoff.adopt_into_slot(self, slot, pkg)
+            elif self._prefill_chunk > 0:
+                # interleaved chunked admission: install the slot with a
+                # partial write frontier and NO prompt forward — the step
+                # loop streams the prefill in chunks fused with decode.
+                # The preempted flag clears only AFTER the allocation
+                # lands: a readmission that raises OutOfPages goes back
+                # to the queue still marked, so it keeps waiting instead
+                # of regaining preemption rights (see _admit_chunked_paged
+                # on why that would livelock)
+                pf_start = self._admit_chunked(slot, req, p)
+                if self._paged and req.preempted:
+                    req.preempted = False
+                    self._swap_resumes += 1
+            elif self._paged:
+                if req.preempted:
+                    req.preempted = False
+                    self._swap_resumes += 1
+                self._admit_paged(slot, req, p)
+            elif req.adapter_id is not None:
+                # adaptered admission: the prompt K/V must come from the
+                # ADAPTED projections, and it never installs from (or
+                # publishes into) the shared prefix pool — published
+                # prefixes are base-model K/V by contract
+                bucket = min(_pad_bucket(p), self.max_len)
+                self.cache = self._admit_lora_fn(
+                    self.cache,
+                    self.params,
+                    jnp.asarray(self._pad_to(req.prompt, bucket)),
+                    slot,
+                    self._adapter_cache.bank,
+                    req.adapter_slot,
+                )
+            elif self.prefix_cache is None:
+                bucket = min(_pad_bucket(p), self.max_len)
+                self.cache = self._admit_fn(
+                    self.cache,
+                    self.params,
+                    jnp.asarray(self._pad_to(req.prompt, bucket)),
+                    slot,
+                )
+            else:
+                self._admit_with_prefix(slot, req, p)
+            # carry = last REAL prompt token at its position: the first
+            # chunk step recomputes its logits (identical K/V rewrite)
+            # and samples the first new token from them
+            self.tok[slot] = req.prompt[-1]
+            self.pos[slot] = p - 1
+            self.limit[slot] = min(
+                p + (req.max_new or self.max_new), self.max_len
             )
-        elif self.prefix_cache is None:
-            bucket = min(_pad_bucket(p), self.max_len)
-            self.cache = self._admit_fn(
-                self.cache,
-                self.params,
-                jnp.asarray(self._pad_to(req.prompt, bucket)),
-                slot,
+            if req.prng_key is None:
+                self.key, sub = jax.random.split(self.key)
+                req.prng_key = np.asarray(sub, np.uint32)
+            self.slot_key[slot] = req.prng_key
+            self.done[slot] = False
+            # mirror the admission onto the device copies as one scatter
+            # (a failover re-admission's journaled key rides in key_v —
+            # the resume re-key is this same program, not a re-upload)
+            d = self._dev
+            d["tok"], d["pos"], d["done"], d["limit"], d["keys"] = (
+                _state_admit_prog(
+                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                    slot, int(self.tok[slot]), p - 1,
+                    int(self.limit[slot]), self.slot_key[slot],
+                )
             )
-        else:
-            self._admit_with_prefix(slot, req, p)
-        # carry = last REAL prompt token at its position: the first
-        # chunk step recomputes its logits (identical K/V rewrite)
-        # and samples the first new token from them
-        self.tok[slot] = req.prompt[-1]
-        self.pos[slot] = p - 1
-        self.limit[slot] = min(
-            p + (req.max_new or self.max_new), self.max_len
-        )
-        if req.prng_key is None:
-            self.key, sub = jax.random.split(self.key)
-            req.prng_key = np.asarray(sub, np.uint32)
-        self.slot_key[slot] = req.prng_key
-        self.done[slot] = False
-        # mirror the admission onto the device copies as one scatter
-        # (a failover re-admission's journaled key rides in key_v —
-        # the resume re-key is this same program, not a re-upload)
-        d = self._dev
-        d["tok"], d["pos"], d["done"], d["limit"], d["keys"] = (
-            _state_admit_prog(
-                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                slot, int(self.tok[slot]), p - 1,
-                int(self.limit[slot]), self.slot_key[slot],
-            )
-        )
-        if self._adapter_cache is not None:
-            self.adapt[slot] = req.adapter_slot
-            d["adapt"] = _state_adapt_prog(
-                d["adapt"], slot, int(req.adapter_slot)
-            )
-        if pf_start is not None:
-            # mid-prefill lifecycle state: the slot is occupied (host
-            # done=False, mirrors installed above) but FROZEN on
-            # device (done=True — the decode scans it rides through
-            # must not advance it) until the frontier reaches the
-            # prompt end and _flip_to_decode re-arms it
-            self._prefilling[slot] = True
-            self._frontier[slot] = pf_start
-            d["done"] = _state_cancel_prog(d["done"], slot)
-        if self._prefill_chunk > 0:
-            d["frontier"] = _state_frontier_prog(
-                d["frontier"], slot, pf_start if pf_start is not None else p
-            )
-        self._admission_stall_ms += (time.perf_counter() - t0) * 1e3
+            if self._adapter_cache is not None:
+                self.adapt[slot] = req.adapter_slot
+                d["adapt"] = _state_adapt_prog(
+                    d["adapt"], slot, int(req.adapter_slot)
+                )
+            if pf_start is not None:
+                # mid-prefill lifecycle state: the slot is occupied (host
+                # done=False, mirrors installed above) but FROZEN on
+                # device (done=True — the decode scans it rides through
+                # must not advance it) until the frontier reaches the
+                # prompt end and _flip_to_decode re-arms it
+                self._prefilling[slot] = True
+                self._frontier[slot] = pf_start
+                d["done"] = _state_cancel_prog(d["done"], slot)
+            if self._prefill_chunk > 0:
+                d["frontier"] = _state_frontier_prog(
+                    d["frontier"], slot,
+                    pf_start if pf_start is not None else p,
+                )
+        self._admission_stall_ms += sp.dur_s * 1e3
+        self._admit_this_step += sp.dur_s
         self.slot_req[slot] = req
         if self.spec is not None:
             self.spec.begin_slot(slot, req.prompt)
@@ -3252,83 +3265,90 @@ class ContinuousBatcher:
         same state sequence, so the dispatches (and the emitted token
         streams) are byte-identical across depths; only WHEN events
         surface shifts by one call."""
-        t0 = time.perf_counter()
-        self._wait_this_step = 0.0
-        self._maybe_commit_refresh()  # deferred swap at idle fence
-        try:
-            if self.chaos is not None:
-                # before any admission or dispatch: an injected fault
-                # leaves the queue, ledger and cache untouched, so the
-                # caller can snapshot + evacuate from consistent state
-                step_no = self._step_no
-                self._step_no += 1
-                self.chaos.on_engine_step(self.chaos_tag, step_no)
-            if self.kv_tier is not None:
-                # complete last step's demotion copies (started async
-                # at demote time — a whole dispatch has passed, so
-                # this is a completion, not a stall) and release their
-                # staging buffers
-                self.kv_tier.drain()
-            events = self._harvest()
-            for slot in range(self.n_slots):
-                if self.done[slot] and self._queue:
-                    req = self._queue.popleft()
-                    try:
-                        self._admit(slot, req)
-                    except OutOfPages:
-                        # chunked admission only: a preempted
-                        # readmission has no swap rights (the
-                        # anti-livelock gate), so a dry pool means
-                        # wait — requeue at the front and let the
-                        # live slots drain pages. Hard exhaustion
-                        # (nothing live to wait on) still raises,
-                        # same as the blocking path.
-                        if self._prefill_chunk == 0 or not any(
-                            self.slot_req[s] is not None
-                            for s in range(self.n_slots)
-                        ):
-                            raise
-                        self._queue.appendleft(req)
-                        break
-            can_decode = (
-                not self.done.all() and self.replica_role != "prefill"
-            )
-            pf_pending = (
-                self._prefill_chunk > 0 and bool(self._prefilling.any())
-            )
-            if can_decode or pf_pending:
-                # pf_pending dispatches even on a prefill-role replica
-                # (its chunked prefills advance ONLY through the fused
-                # program; the decode half is vacuous there) and
-                # bypasses speculation (a draft dispatch carries no
-                # prefill half — drafting resumes once no slot is
-                # mid-prefill)
-                if self.spec is not None and not pf_pending:
-                    drafts, dlens = self._collect_drafts()
-                    if int(dlens.max()) > 0:
-                        self._dispatch_spec(drafts, dlens)
+        with trace.span("engine.step") as sp:
+            self._wait_this_step = 0.0
+            self._admit_this_step = 0.0
+            self._maybe_commit_refresh()  # deferred swap at idle fence
+            try:
+                if self.chaos is not None:
+                    # before any admission or dispatch: an injected fault
+                    # leaves the queue, ledger and cache untouched, so the
+                    # caller can snapshot + evacuate from consistent state
+                    step_no = self._step_no
+                    self._step_no += 1
+                    self.chaos.on_engine_step(self.chaos_tag, step_no)
+                if self.kv_tier is not None:
+                    # complete last step's demotion copies (started async
+                    # at demote time — a whole dispatch has passed, so
+                    # this is a completion, not a stall) and release their
+                    # staging buffers
+                    self.kv_tier.drain()
+                events = self._harvest()
+                for slot in range(self.n_slots):
+                    if self.done[slot] and self._queue:
+                        req = self._queue.popleft()
+                        try:
+                            self._admit(slot, req)
+                        except OutOfPages:
+                            # chunked admission only: a preempted
+                            # readmission has no swap rights (the
+                            # anti-livelock gate), so a dry pool means
+                            # wait — requeue at the front and let the
+                            # live slots drain pages. Hard exhaustion
+                            # (nothing live to wait on) still raises,
+                            # same as the blocking path.
+                            if self._prefill_chunk == 0 or not any(
+                                self.slot_req[s] is not None
+                                for s in range(self.n_slots)
+                            ):
+                                raise
+                            self._queue.appendleft(req)
+                            break
+                can_decode = (
+                    not self.done.all() and self.replica_role != "prefill"
+                )
+                pf_pending = (
+                    self._prefill_chunk > 0 and bool(self._prefilling.any())
+                )
+                if can_decode or pf_pending:
+                    # pf_pending dispatches even on a prefill-role replica
+                    # (its chunked prefills advance ONLY through the fused
+                    # program; the decode half is vacuous there) and
+                    # bypasses speculation (a draft dispatch carries no
+                    # prefill half — drafting resumes once no slot is
+                    # mid-prefill)
+                    if self.spec is not None and not pf_pending:
+                        drafts, dlens = self._collect_drafts()
+                        if int(dlens.max()) > 0:
+                            self._dispatch_spec(drafts, dlens)
+                        else:
+                            # graceful degradation: every live slot's
+                            # controller has drafting off (or nothing
+                            # matched) — plain chunk scan at full speed;
+                            # disabled slots re-probe on schedule
+                            self._dispatch_chunk()
                     else:
-                        # graceful degradation: every live slot's
-                        # controller has drafting off (or nothing
-                        # matched) — plain chunk scan at full speed;
-                        # disabled slots re-probe on schedule
                         self._dispatch_chunk()
-                else:
-                    self._dispatch_chunk()
-                if self.async_depth == 0:
-                    # events is always [] here: sync mode harvested
-                    # at the END of the previous step
-                    events = self._harvest()
-        except Exception:
-            # a raising step (injected fault or real failure) orphans
-            # any in-flight dispatch: its results must never surface
-            # later — the caller snapshots from the last HARVESTED
-            # state, and failover replay regenerates the lost tokens
-            self._inflight = None
-            raise
-        self._stat_host_ms += (
-            (time.perf_counter() - t0) * 1e3 - self._wait_this_step
-        )
+                    if self.async_depth == 0:
+                        # events is always [] here: sync mode harvested
+                        # at the END of the previous step
+                        events = self._harvest()
+            except Exception:
+                # a raising step (injected fault or real failure) orphans
+                # any in-flight dispatch: its results must never surface
+                # later — the caller snapshots from the last HARVESTED
+                # state, and failover replay regenerates the lost tokens
+                self._inflight = None
+                raise
+            live = ~self.done
+            sp.set(
+                alive=int(live.sum()),
+                live_tokens=int(self.pos[live].sum()),
+                wait_s=self._wait_this_step,
+                admit_s=self._admit_this_step,
+            )
+        self.last_step_s = sp.dur_s
+        self._stat_host_ms += (sp.dur_s - self._wait_this_step) * 1e3
         return events
 
     def _dispatch_chunk(self) -> None:
@@ -3337,35 +3357,36 @@ class ContinuousBatcher:
             return
         d = self._dev
         k = self._next_chunk_len()
-        lora = self._adapter_args()
-        if self._paged:
-            pool, tok, pos, done, keys, emitted = self._run_chunk(
-                self.page_pool, self._table, self.params,
-                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                k, *lora,
+        with self._dispatch_span(chunk=k):
+            lora = self._adapter_args()
+            if self._paged:
+                pool, tok, pos, done, keys, emitted = self._run_chunk(
+                    self.page_pool, self._table, self.params,
+                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                    k, *lora,
+                )
+                self.page_pool = pool
+            else:
+                cache, tok, pos, done, keys, emitted = self._run_chunk(
+                    self.cache, self.params,
+                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                    k, *lora,
+                )
+                self.cache = cache
+            d.update(tok=tok, pos=pos, done=done, keys=keys)
+            # live steps form a prefix of the chunk (done is sticky), and
+            # pos advances once per live step — at harvest the first
+            # (new_pos - old_pos) emitted entries are exactly the real
+            # tokens, whatever their values
+            self._enqueue_fetch(
+                _Inflight(
+                    kind="chunk",
+                    arrays=(tok, pos, done, keys, emitted),
+                    dispatched_at=0.0,
+                    old_pos=self.pos.copy(),
+                    version=self._weight_version,
+                )
             )
-            self.page_pool = pool
-        else:
-            cache, tok, pos, done, keys, emitted = self._run_chunk(
-                self.cache, self.params,
-                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                k, *lora,
-            )
-            self.cache = cache
-        d.update(tok=tok, pos=pos, done=done, keys=keys)
-        # live steps form a prefix of the chunk (done is sticky), and
-        # pos advances once per live step — at harvest the first
-        # (new_pos - old_pos) emitted entries are exactly the real
-        # tokens, whatever their values
-        self._enqueue_fetch(
-            _Inflight(
-                kind="chunk",
-                arrays=(tok, pos, done, keys, emitted),
-                dispatched_at=0.0,
-                old_pos=self.pos.copy(),
-                version=self._weight_version,
-            )
-        )
 
     def _pf_chunk_len(self, rem: int) -> int:
         """Tokens of prefill this dispatch carries: prefill_chunk,
@@ -3403,53 +3424,54 @@ class ContinuousBatcher:
         start = int(self._frontier[slot])
         plen = self._pf_chunk_len(p - start)
         ptoks = jnp.asarray(req.prompt[start:start + plen])
-        lora = self._adapter_args()
-        if self._paged:
-            pool, tok, pos, done, keys, frontier, emitted = (
-                self._run_pf(
-                    self.page_pool, self._table, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"],
-                    d["keys"], d["frontier"], k, ptoks, slot, start,
-                    *lora,
+        with self._dispatch_span(chunk=k, prefill_tokens=plen):
+            lora = self._adapter_args()
+            if self._paged:
+                pool, tok, pos, done, keys, frontier, emitted = (
+                    self._run_pf(
+                        self.page_pool, self._table, self.params,
+                        d["tok"], d["pos"], d["done"], d["limit"],
+                        d["keys"], d["frontier"], k, ptoks, slot, start,
+                        *lora,
+                    )
+                )
+                self.page_pool = pool
+            else:
+                cache, tok, pos, done, keys, frontier, emitted = (
+                    self._run_pf(
+                        self.cache, self.params,
+                        d["tok"], d["pos"], d["done"], d["limit"],
+                        d["keys"], d["frontier"], k, ptoks, slot, start,
+                        *lora,
+                    )
+                )
+                self.cache = cache
+            d.update(
+                tok=tok, pos=pos, done=done, keys=keys, frontier=frontier
+            )
+            # which slots are mid-prefill DURING this dispatch — captured
+            # BEFORE the flip: harvest must treat their fetched done=True
+            # as the freeze (not a finish) and their fetched keys as
+            # drift (the scan splits every row's key, frozen or not)
+            pf = self._prefilling.copy()
+            # the host mirror is dispatch-authoritative (the value is
+            # host-deterministic — start + plen); the fetched device copy
+            # is never folded back, so an async harvest of dispatch N-1
+            # cannot regress the frontier eagerly advanced for N
+            self._frontier[slot] = start + plen
+            self._prefill_chunks_total += 1
+            if start + plen >= p:
+                self._flip_to_decode(slot)
+            self._enqueue_fetch(
+                _Inflight(
+                    kind="chunk",
+                    arrays=(tok, pos, done, keys, emitted),
+                    dispatched_at=0.0,
+                    old_pos=self.pos.copy(),
+                    version=self._weight_version,
+                    pf_mask=pf,
                 )
             )
-            self.page_pool = pool
-        else:
-            cache, tok, pos, done, keys, frontier, emitted = (
-                self._run_pf(
-                    self.cache, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"],
-                    d["keys"], d["frontier"], k, ptoks, slot, start,
-                    *lora,
-                )
-            )
-            self.cache = cache
-        d.update(
-            tok=tok, pos=pos, done=done, keys=keys, frontier=frontier
-        )
-        # which slots are mid-prefill DURING this dispatch — captured
-        # BEFORE the flip: harvest must treat their fetched done=True
-        # as the freeze (not a finish) and their fetched keys as
-        # drift (the scan splits every row's key, frozen or not)
-        pf = self._prefilling.copy()
-        # the host mirror is dispatch-authoritative (the value is
-        # host-deterministic — start + plen); the fetched device copy
-        # is never folded back, so an async harvest of dispatch N-1
-        # cannot regress the frontier eagerly advanced for N
-        self._frontier[slot] = start + plen
-        self._prefill_chunks_total += 1
-        if start + plen >= p:
-            self._flip_to_decode(slot)
-        self._enqueue_fetch(
-            _Inflight(
-                kind="chunk",
-                arrays=(tok, pos, done, keys, emitted),
-                dispatched_at=0.0,
-                old_pos=self.pos.copy(),
-                version=self._weight_version,
-                pf_mask=pf,
-            )
-        )
 
     def _flip_to_decode(self, slot: int) -> None:
         """The frontier reached the prompt end: leave the mid-prefill
@@ -3498,43 +3520,52 @@ class ContinuousBatcher:
         self, drafts: np.ndarray, dlens: np.ndarray
     ) -> None:
         d = self._dev
-        lora = self._adapter_args()
-        if self._paged:
-            (
-                pool, tok, pos, done, keys, emitted, n_emit, accepted
-            ) = self._run_spec(
-                self.page_pool, self._table, self.params,
-                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                jnp.asarray(drafts), jnp.asarray(dlens), *lora,
+        with self._dispatch_span(draft_len=int(dlens.max())):
+            lora = self._adapter_args()
+            if self._paged:
+                (
+                    pool, tok, pos, done, keys, emitted, n_emit, accepted
+                ) = self._run_spec(
+                    self.page_pool, self._table, self.params,
+                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                    jnp.asarray(drafts), jnp.asarray(dlens), *lora,
+                )
+                self.page_pool = pool
+            else:
+                (
+                    cache, tok, pos, done, keys, emitted, n_emit, accepted
+                ) = self._run_spec(
+                    self.cache, self.params,
+                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                    jnp.asarray(drafts), jnp.asarray(dlens), *lora,
+                )
+                self.cache = cache
+            d.update(tok=tok, pos=pos, done=done, keys=keys)
+            self._enqueue_fetch(
+                _Inflight(
+                    kind="spec",
+                    arrays=(
+                        tok, pos, done, keys, emitted, n_emit, accepted
+                    ),
+                    dispatched_at=0.0,
+                    dlens=dlens,
+                    was_live=~self.done,
+                    version=self._weight_version,
+                )
             )
-            self.page_pool = pool
-        else:
-            (
-                cache, tok, pos, done, keys, emitted, n_emit, accepted
-            ) = self._run_spec(
-                self.cache, self.params,
-                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                jnp.asarray(drafts), jnp.asarray(dlens), *lora,
-            )
-            self.cache = cache
-        d.update(tok=tok, pos=pos, done=done, keys=keys)
-        self._enqueue_fetch(
-            _Inflight(
-                kind="spec",
-                arrays=(
-                    tok, pos, done, keys, emitted, n_emit, accepted
-                ),
-                dispatched_at=0.0,
-                dlens=dlens,
-                was_live=~self.done,
-                version=self._weight_version,
-            )
-        )
 
     def _enqueue_fetch(self, pend: _Inflight) -> None:
         _start_host_copy(pend.arrays)
-        pend.dispatched_at = time.perf_counter()
         self._inflight = pend
+
+    @contextlib.contextmanager
+    def _dispatch_span(self, **counts):
+        """The `engine.dispatch` span around one enqueue; its end is
+        also the in-flight record's `dispatched_at` (the start of the
+        device span `_harvest` measures the overlap against)."""
+        with trace.span("engine.dispatch", **counts) as sp:
+            yield
+        self._inflight.dispatched_at = sp.t0 + sp.dur_s
 
     def _harvest(self) -> List[StepEvent]:
         """Complete the in-flight dispatch's host copies, refresh the
@@ -3545,44 +3576,46 @@ class ContinuousBatcher:
         self._inflight = None
         if pend is None:
             return []
-        w0 = time.perf_counter()
-        host = _to_host(*pend.arrays)
-        w1 = time.perf_counter()
-        wait_ms = (w1 - w0) * 1e3
-        span_ms = (w1 - pend.dispatched_at) * 1e3
-        self._wait_this_step += wait_ms
-        self._stat_wait_ms += wait_ms
-        self._stat_span_ms += span_ms
-        self._stat_overlap_ms += max(span_ms - wait_ms, 0.0)
-        self._stat_dispatches += 1
-        if pend.kind == "chunk":
-            tok, pos, done, keys, emitted = host
-            counts = pos - pend.old_pos
-        else:
-            tok, pos, done, keys, emitted, n_emit, accepted = host
-            counts = n_emit
-            for slot in range(self.n_slots):
-                if pend.was_live[slot]:
-                    self.spec.record(
-                        slot,
-                        int(pend.dlens[slot]),
-                        int(accepted[slot]),
-                        int(n_emit[slot]),
-                    )
-        self.tok, self.pos, self.slot_key = tok, pos, keys
-        if pend.pf_mask is not None:
-            # slots that were mid-prefill during this dispatch: the
-            # fetched key is drift (the scan split every row's key,
-            # frozen or not) — the journal and preempt-replay read
-            # the key mirror, so re-assert the ORIGINAL admission key
-            for slot in range(self.n_slots):
-                if pend.pf_mask[slot]:
-                    req = self.slot_req[slot]
-                    if req is not None and req.prng_key is not None:
-                        self.slot_key[slot] = req.prng_key
-        return self._emit_events(
-            emitted, counts, done, pend.version, pend.pf_mask
-        )
+        with trace.span("engine.harvest") as sp:
+            host = _to_host(*pend.arrays)
+            w1 = time.perf_counter()
+            wait_s = w1 - sp.t0
+            sp.set(wait_s=wait_s)
+            wait_ms = wait_s * 1e3
+            span_ms = (w1 - pend.dispatched_at) * 1e3
+            self._wait_this_step += wait_s
+            self._stat_wait_ms += wait_ms
+            self._stat_span_ms += span_ms
+            self._stat_overlap_ms += max(span_ms - wait_ms, 0.0)
+            self._stat_dispatches += 1
+            if pend.kind == "chunk":
+                tok, pos, done, keys, emitted = host
+                counts = pos - pend.old_pos
+            else:
+                tok, pos, done, keys, emitted, n_emit, accepted = host
+                counts = n_emit
+                for slot in range(self.n_slots):
+                    if pend.was_live[slot]:
+                        self.spec.record(
+                            slot,
+                            int(pend.dlens[slot]),
+                            int(accepted[slot]),
+                            int(n_emit[slot]),
+                        )
+            self.tok, self.pos, self.slot_key = tok, pos, keys
+            if pend.pf_mask is not None:
+                # slots that were mid-prefill during this dispatch: the
+                # fetched key is drift (the scan split every row's key,
+                # frozen or not) — the journal and preempt-replay read
+                # the key mirror, so re-assert the ORIGINAL admission key
+                for slot in range(self.n_slots):
+                    if pend.pf_mask[slot]:
+                        req = self.slot_req[slot]
+                        if req is not None and req.prng_key is not None:
+                            self.slot_key[slot] = req.prng_key
+            return self._emit_events(
+                emitted, counts, done, pend.version, pend.pf_mask
+            )
 
     def _emit_events(
         self, emitted: np.ndarray, counts: np.ndarray,

@@ -34,6 +34,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.serving.metrics import ServingMetrics
 from dlrover_tpu.serving.replica import NoHealthyReplicasError
@@ -175,6 +176,11 @@ class ServingGateway:
                 if reason is not None:
                     self._json(400, {"error": reason})
                     return
+                # request parsed -> answer written or stream closed
+                with trace.span("gateway.generate") as sp:
+                    self._generate(payload, sp)
+
+            def _generate(self, payload, sp):
                 adapter_id = payload.get("adapter_id")
                 if adapter_id is not None and not gw._adapter_known(
                     adapter_id
@@ -218,6 +224,7 @@ class ServingGateway:
                         headers={"Retry-After": gw._retry_after()},
                     )
                     return
+                sp.req = req.id
                 if payload.get("stream", True):
                     self._stream(req)
                 else:

@@ -58,6 +58,10 @@ class ServingMetrics:
         {
             "_ttft_ms",
             "_tpot_ms",
+            "_lock_wait_ms",
+            "_queue_wait_ms",
+            "_lock_held_s",
+            "_pump_wall_s",
             "_queue_depth",
             "_active_requests",
             "_requests_total",
@@ -156,6 +160,12 @@ class ServingMetrics:
         self._lock = threading.Lock()
         self._ttft_ms = _Window(window)
         self._tpot_ms = _Window(window)
+        # the front door's legs, from the stamps the scheduler keeps
+        # on each request (and leaves in the `request` trace event)
+        self._lock_wait_ms = _Window(window)
+        self._queue_wait_ms = _Window(window)
+        self._lock_held_s = 0.0  # pumps' locked sections, summed
+        self._pump_wall_s = 0.0  # wall time those pumps spanned
         self._queue_depth = 0
         self._active_requests = 0
         self._requests_total = 0
@@ -388,6 +398,23 @@ class ServingMetrics:
             self._tpot_ms.observe(ms)
             if tier in self._tier_tpot:
                 self._tier_tpot[tier].observe(ms)
+
+    def observe_lock_wait(self, ms: float):
+        """submit() entry -> scheduler lock acquired."""
+        with self._lock:
+            self._lock_wait_ms.observe(ms)
+
+    def observe_queue_wait(self, ms: float):
+        """Pushed on the waiting heap -> handed to the engine."""
+        with self._lock:
+            self._queue_wait_ms.observe(ms)
+
+    def observe_lock_held(self, held_s: float, wall_s: float):
+        """One pump: seconds it held the scheduler lock, and the wall
+        seconds since the previous pump ended."""
+        with self._lock:
+            self._lock_held_s += held_s
+            self._pump_wall_s += wall_s
 
     def observe_tokens(self, n: int, ts: Optional[float] = None):
         with self._lock:
@@ -1049,6 +1076,22 @@ class ServingMetrics:
                 "serving_tpot_ms",
                 "Mean time per output token after the first, ms.",
                 self._tpot_ms, tpot_q,
+            )
+            summary(
+                "serving_sched_lock_wait_ms",
+                "submit() entry to scheduler lock acquired, ms.",
+                self._lock_wait_ms, self._lock_wait_ms.quantiles(),
+            )
+            summary(
+                "serving_queue_wait_ms",
+                "Queued to handed to the engine, ms.",
+                self._queue_wait_ms, self._queue_wait_ms.quantiles(),
+            )
+            gauge(
+                "serving_sched_lock_held_ratio",
+                "Share of wall time pump() holds the scheduler lock.",
+                self._lock_held_s / self._pump_wall_s
+                if self._pump_wall_s > 0 else 0.0,
             )
             gauge(
                 "serving_queue_depth",

@@ -54,6 +54,7 @@ from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence
 
 import numpy as np
 
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.serving import handoff as handoff_mod
 from dlrover_tpu.serving.adapters import AdapterCacheFull
@@ -154,6 +155,17 @@ class ServeRequest:
         self.tokens: List[int] = []
         self.first_token_ts: Optional[float] = None
         self.finish_ts: Optional[float] = None
+        # the legs to the first token, on the scheduler's clock like
+        # submit_ts (entry of submit): lock acquired, pushed on the
+        # heap, handed to the engine. lock wait + time in submit +
+        # queue wait + admission-to-first-token sum exactly to
+        # first_token_ts - submit_ts; the `request` trace event and
+        # the /metrics families read them. submit_wall is time.time()
+        # at submit, the clock a trace window is cut on.
+        self.locked_ts: Optional[float] = None
+        self.queued_ts: Optional[float] = None
+        self.admitted_ts: Optional[float] = None
+        self.submit_wall: Optional[float] = None
         # failover state: the scheduler currently hosting the request
         # (re-pointed on re-admission), crash count, and the PRNG key
         # the next admission must continue from (None = engine draws)
@@ -309,6 +321,7 @@ class RequestScheduler:
         # sleeps in real time too.
         self._step_lat_ewma = 0.0
         self._step_lat_alpha = 0.25
+        self._pump_end = 0.0  # perf_counter at the previous pump's end
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -398,90 +411,98 @@ class RequestScheduler:
         `prng_key` pins the sampling key the first engine admission
         uses (deterministic replay / parity tests); None lets the
         engine draw one."""
-        arr = np.asarray(prompt, np.int32)
-        slo = self.slo
-        want = max_new or min(self.engine.max_new, slo.max_new_tokens)
-        tier = tier or "standard"
-        if tier not in TIERS:
-            self.metrics.request_rejected()
-            raise AdmissionError(
-                f"unknown tier {tier!r} (expected one of {TIERS})"
-            )
-        with self._cond:
-            if self.crashed:
-                self.metrics.request_rejected()
-                raise AdmissionError("replica crashed, pending restart")
-            if self._waiting_total_locked() >= slo.max_queue_depth:
+        with trace.span("sched.submit") as sp:
+            t_submit = self._clock()
+            arr = np.asarray(prompt, np.int32)
+            slo = self.slo
+            want = max_new or min(self.engine.max_new, slo.max_new_tokens)
+            tier = tier or "standard"
+            if tier not in TIERS:
                 self.metrics.request_rejected()
                 raise AdmissionError(
-                    f"queue full ({slo.max_queue_depth} waiting)"
+                    f"unknown tier {tier!r} (expected one of {TIERS})"
                 )
-            if want > slo.max_new_tokens:
-                self.metrics.request_rejected()
-                raise AdmissionError(
-                    f"token budget: max_new {want} > "
-                    f"{slo.max_new_tokens}"
-                )
-            if arr.ndim != 1 or arr.size == 0:
-                self.metrics.request_rejected()
-                raise AdmissionError("prompt must be non-empty 1-D")
-            # mirrors engine.submit()'s room-to-generate check — and
-            # stays correct with the prefix cache on: even a fully
-            # cached prompt still needs one cell past the prompt
-            # (limit >= p+1), and the engine clamps a matched depth
-            # until the SUFFIX bucket fits max_len, so no prompt the
-            # engine accepts cold becomes inadmissible warm (pinned by
-            # tests/test_serving_prefix_cache.py::test_admission_checks_agree)
-            if arr.size + 1 > self.engine.max_len:
-                self.metrics.request_rejected()
-                raise AdmissionError(
-                    f"prompt length {arr.size} leaves no room to "
-                    f"generate (max_len {self.engine.max_len})"
-                )
-            if adapter_id is not None:
-                reg = getattr(self.engine, "adapter_registry", None)
-                if reg is None or adapter_id not in reg:
+            with self._cond:
+                t_locked = self._clock()
+                sp.set(lock_wait_s=t_locked - t_submit)
+                if self.crashed:
+                    self.metrics.request_rejected()
+                    raise AdmissionError("replica crashed, pending restart")
+                if self._waiting_total_locked() >= slo.max_queue_depth:
                     self.metrics.request_rejected()
                     raise AdmissionError(
-                        f"unknown adapter {adapter_id!r}"
+                        f"queue full ({slo.max_queue_depth} waiting)"
                     )
-                quota = slo.max_active_per_adapter
-                if (
-                    quota > 0
-                    and self._adapter_load_locked(adapter_id) >= quota
-                ):
+                if want > slo.max_new_tokens:
                     self.metrics.request_rejected()
                     raise AdmissionError(
-                        f"adapter {adapter_id!r} at its per-tenant "
-                        f"quota ({quota} active)"
+                        f"token budget: max_new {want} > "
+                        f"{slo.max_new_tokens}"
                     )
-            budget = int((slo.tier_budgets or {}).get(tier, 0))
-            if budget > 0 and self._tier_load_locked(tier) >= budget:
-                self.metrics.request_rejected()
-                raise AdmissionError(
-                    f"tier {tier!r} at its admission budget "
-                    f"({budget} active)"
+                if arr.ndim != 1 or arr.size == 0:
+                    self.metrics.request_rejected()
+                    raise AdmissionError("prompt must be non-empty 1-D")
+                # mirrors engine.submit()'s room-to-generate check — and
+                # stays correct with the prefix cache on: even a fully
+                # cached prompt still needs one cell past the prompt
+                # (limit >= p+1), and the engine clamps a matched depth
+                # until the SUFFIX bucket fits max_len, so no prompt the
+                # engine accepts cold becomes inadmissible warm (pinned by
+                # test_serving_prefix_cache.py::test_admission_checks_agree)
+                if arr.size + 1 > self.engine.max_len:
+                    self.metrics.request_rejected()
+                    raise AdmissionError(
+                        f"prompt length {arr.size} leaves no room to "
+                        f"generate (max_len {self.engine.max_len})"
+                    )
+                if adapter_id is not None:
+                    reg = getattr(self.engine, "adapter_registry", None)
+                    if reg is None or adapter_id not in reg:
+                        self.metrics.request_rejected()
+                        raise AdmissionError(
+                            f"unknown adapter {adapter_id!r}"
+                        )
+                    quota = slo.max_active_per_adapter
+                    if (
+                        quota > 0
+                        and self._adapter_load_locked(adapter_id) >= quota
+                    ):
+                        self.metrics.request_rejected()
+                        raise AdmissionError(
+                            f"adapter {adapter_id!r} at its per-tenant "
+                            f"quota ({quota} active)"
+                        )
+                budget = int((slo.tier_budgets or {}).get(tier, 0))
+                if budget > 0 and self._tier_load_locked(tier) >= budget:
+                    self.metrics.request_rejected()
+                    raise AdmissionError(
+                        f"tier {tier!r} at its admission budget "
+                        f"({budget} active)"
+                    )
+                now = self._clock()
+                req = ServeRequest(
+                    req_id=self._next_id,
+                    prompt=arr,
+                    max_new=want,
+                    deadline=now + (deadline_s or slo.default_deadline_s),
+                    submit_ts=t_submit,
+                    adapter_id=adapter_id,
+                    tier=tier,
                 )
-            now = self._clock()
-            req = ServeRequest(
-                req_id=self._next_id,
-                prompt=arr,
-                max_new=want,
-                deadline=now + (deadline_s or slo.default_deadline_s),
-                submit_ts=now,
-                adapter_id=adapter_id,
-                tier=tier,
-            )
-            self._next_id += 1
-            req.scheduler = self
-            if prng_key is not None:
-                req.prng_key = np.asarray(prng_key, np.uint32)
-            self._push_waiting_locked(req, arr.size)
-            self.metrics.request_submitted()
-            self.metrics.tier_admitted(tier)
-            self.metrics.set_queue_depth(self._waiting_total_locked())
-            self._cond.notify_all()
-            return req
+                req.locked_ts, req.queued_ts = t_locked, now
+                req.submit_wall = sp.wall
+                sp.req = req.id
+                self._next_id += 1
+                req.scheduler = self
+                if prng_key is not None:
+                    req.prng_key = np.asarray(prng_key, np.uint32)
+                self._push_waiting_locked(req, arr.size)
+                self.metrics.request_submitted()
+                self.metrics.observe_lock_wait((t_locked - t_submit) * 1e3)
+                self.metrics.tier_admitted(tier)
+                self.metrics.set_queue_depth(self._waiting_total_locked())
+                self._cond.notify_all()
+                return req
 
     # ---- queries ---------------------------------------------------------
 
@@ -708,175 +729,131 @@ class RequestScheduler:
         request into resume tickets, and hands them to `on_failure`
         OUTSIDE its own lock (the failover manager re-admits them on
         peer schedulers, which take their locks)."""
+        with trace.span("sched.pump") as sp:
+            busy = self._pump(sp)
+        # the share of wall time the lock is held: this pump's locked
+        # sections over the time since the previous pump ended
+        end = sp.t0 + sp.dur_s
+        self.metrics.observe_lock_held(
+            sp.counts.get("held_s", 0.0),
+            end - self._pump_end if self._pump_end else sp.dur_s,
+        )
+        self._pump_end = end
+        return busy
+
+    def _pump(self, sp) -> bool:
+        """pump()'s two locked sections. `held_s` on `sp` is how long
+        they held the lock, summed from the spans that tile them:
+        sched.admit, the engine.step after it, and sched.deliver to
+        the end of sched.publish."""
         failure = None
+        events = []
         with self._cond:
             if self.crashed:
                 return False
             now = self._clock()
-            self._shed_expired_locked(now)
-            self._escalate_aged_locked(now)
-            try:
-                # admit only up to the engine's free slots so
-                # tier-then-EDF order, not engine-internal FIFO,
-                # decides dispatch
-                headroom_ok = getattr(
-                    self.engine, "admission_headroom_ok", None
-                )
-                while True:
-                    tier, req = self._peek_next_locked()
-                    if req is None:
-                        break
-                    room = (
-                        self.engine.queue_len()
-                        < self.engine.free_slots()
-                    )
-                    # memory-aware gate (paged KV): when the page pool
-                    # cannot back a worst-case admission and the engine
-                    # already has work, wait for it to drain rather
-                    # than force the engine into preempt-and-swap
-                    # thrash. With the engine empty we admit anyway —
-                    # it reclaims inline, so progress is guaranteed
-                    # either way.
-                    blocked = (
-                        headroom_ok is not None
-                        and not headroom_ok()
-                        and (
-                            self.engine.active_count() > 0
-                            or self.engine.queue_len() > 0
-                        )
-                    )
-                    if not room or blocked:
-                        # a latency-tier waiter blocked on capacity
-                        # reclaims it from batch work: evict one
-                        # victim (slot + pages free immediately) and
-                        # re-evaluate. No victim => genuinely full.
-                        if (
-                            req.effective_tier == TIERS[0]
-                            and self._preempt_for_admission_locked()
-                        ):
-                            continue
-                        break
-                    heapq.heappop(self._waiting[tier])
-                    pkg, req.handoff_pkg = req.handoff_pkg, None
-                    if pkg is not None and not req.tokens:
-                        # adopted prefill: install the shipped KV
-                        # instead of replaying the prompt. A package
-                        # outlived by emitted tokens (decode-side
-                        # crash after adoption) is stale — replay.
-                        idx = self.engine.submit_adopted(pkg)
-                    else:
-                        prompt, remaining = req.engine_spec()
-                        kw = {}
-                        if req.adapter_id is not None:
-                            kw["adapter_id"] = req.adapter_id
-                        try:
-                            idx = self.engine.submit(
-                                prompt,
-                                max_new=remaining,
-                                prng_key=req.prng_key,
-                                **kw,
-                            )
-                        except AdapterCacheFull:
-                            # every bank slot is pinned by requests
-                            # already decoding: put the request back
-                            # and stop admitting — a retire this chunk
-                            # releases a pin and the next pump retries
-                            self._push_waiting_locked(req, prompt.size)
-                            break
-                        except KeyError:
-                            # unregistered between admission and
-                            # dispatch: fail this request, keep the
-                            # replica alive
-                            req._end(RequestState.FAILED, now)
-                            self.metrics.request_failed()
-                            self.journal.close(req)
-                            continue
-                    req.state = RequestState.RUNNING
-                    self._running[idx] = req
-                    self.journal.open(req)
-                if self.engine.has_work():
-                    t_step = time.perf_counter()
-                    events = self.engine.step()
-                    dt = time.perf_counter() - t_step
-                    self._step_lat_ewma = (
-                        dt
-                        if self._step_lat_ewma == 0.0
-                        else self._step_lat_alpha * dt
-                        + (1.0 - self._step_lat_alpha)
-                        * self._step_lat_ewma
-                    )
-                else:
-                    events = []
-            except ChipLost as exc:
-                # the replica is ALIVE but its slice shrank: re-form
-                # the mesh live at the surviving tp instead of
-                # crashing the whole replica. In-flight requests are
-                # preempted to the engine queue and replayed
-                # byte-identically (serving/elastic.py); the
-                # scheduler's _running map keeps its entries — the
-                # engine re-admits the same indices after the resize.
-                events = []
-                handled = False
-                if self.elastic_resize:
-                    try:
-                        report = self.engine.resize(
-                            self.engine.surviving_chips()
-                        )
-                        logger.warning(
-                            "chip loss (%d gone): resized tp=%d -> "
-                            "tp=%d, %d request(s) replaying, "
-                            "%.1fms downtime",
-                            exc.n_chips, report.old_tp, report.new_tp,
-                            report.replayed, report.downtime_ms,
-                        )
-                        handled = True
-                    # graftlint: allow(EXC-001) reason=resize failure is logged and falls back to the crash/failover path below
-                    except Exception:
-                        logger.exception(
-                            "live resize after chip loss failed; "
-                            "crashing replica"
-                        )
-                if not handled:
+            with trace.span("sched.admit") as adm:
+                self._shed_expired_locked(now)
+                self._escalate_aged_locked(now)
+                try:
+                    adm.set(admitted=self._admit_waiting_locked(now))
+                # graftlint: allow(EXC-001) reason=failure is logged and dispatched outside the lock by _dispatch_failure below
+                except Exception as exc:
                     failure = (self._crash_locked(), exc)
-            # graftlint: allow(EXC-001) reason=failure is logged and dispatched outside the lock by _dispatch_failure below
-            except Exception as exc:
-                failure = (self._crash_locked(), exc)
-                events = []
+            held_s = adm.dur_s
+            if failure is None:
+                try:
+                    if self.engine.has_work():
+                        events = self.engine.step()
+                        # the engine.step span's own extent
+                        dt = self.engine.last_step_s
+                        held_s += dt
+                        self._step_lat_ewma = (
+                            dt
+                            if self._step_lat_ewma == 0.0
+                            else self._step_lat_alpha * dt
+                            + (1.0 - self._step_lat_alpha)
+                            * self._step_lat_ewma
+                        )
+                except ChipLost as exc:
+                    # the replica is ALIVE but its slice shrank: re-form
+                    # the mesh live at the surviving tp instead of
+                    # crashing the whole replica. In-flight requests are
+                    # preempted to the engine queue and replayed
+                    # byte-identically (serving/elastic.py); the
+                    # scheduler's _running map keeps its entries — the
+                    # engine re-admits the same indices after the resize.
+                    events = []
+                    handled = False
+                    if self.elastic_resize:
+                        try:
+                            report = self.engine.resize(
+                                self.engine.surviving_chips()
+                            )
+                            logger.warning(
+                                "chip loss (%d gone): resized tp=%d -> "
+                                "tp=%d, %d request(s) replaying, "
+                                "%.1fms downtime",
+                                exc.n_chips, report.old_tp, report.new_tp,
+                                report.replayed, report.downtime_ms,
+                            )
+                            handled = True
+                        # graftlint: allow(EXC-001) reason=resize failure is logged and falls back to the crash/failover path below
+                        except Exception:
+                            logger.exception(
+                                "live resize after chip loss failed; "
+                                "crashing replica"
+                            )
+                    if not handled:
+                        failure = (self._crash_locked(), exc)
+                # graftlint: allow(EXC-001) reason=failure is logged and dispatched outside the lock by _dispatch_failure below
+                except Exception as exc:
+                    failure = (self._crash_locked(), exc)
+                    events = []
         if failure is not None:
+            sp.set(held_s=held_s)
             self._dispatch_failure(failure[0], failure[1])
             return False
         with self._cond:
-            now = self._clock()
-            for idx, new_toks, finished in events:
-                req = self._running.get(idx)
-                if req is None:
-                    continue
-                if new_toks:
-                    if req.first_token_ts is None:
-                        req.first_token_ts = now
-                        self.metrics.observe_ttft(
-                            (now - req.submit_ts) * 1000.0,
-                            tier=req.tier,
-                        )
-                    req.tokens.extend(new_toks)
-                    req.stream.put(new_toks)
-                    self.metrics.observe_tokens(len(new_toks), now)
-                if finished:
-                    self.engine.retire(idx)
-                    del self._running[idx]
-                    self.journal.close(req)
-                    if (
-                        req.first_token_ts is not None
-                        and len(req.tokens) > 1
-                    ):
-                        self.metrics.observe_tpot(
-                            (now - req.first_token_ts)
-                            * 1000.0
-                            / (len(req.tokens) - 1),
-                            tier=req.tier,
-                        )
-                    req._end(RequestState.DONE, now)
-                    self.metrics.request_completed()
+            with trace.span("sched.deliver") as dlv:
+                now = self._clock()
+                n_tokens = 0
+                for idx, new_toks, finished in events:
+                    req = self._running.get(idx)
+                    if req is None:
+                        continue
+                    if new_toks:
+                        first = req.first_token_ts is None
+                        if first:
+                            req.first_token_ts = now
+                            self.metrics.observe_ttft(
+                                (now - req.submit_ts) * 1000.0,
+                                tier=req.tier,
+                            )
+                        req.tokens.extend(new_toks)
+                        req.stream.put(new_toks)
+                        self.metrics.observe_tokens(len(new_toks), now)
+                        n_tokens += len(new_toks)
+                        if first:
+                            self._first_tokens_out(req)
+                    if finished:
+                        self.engine.retire(idx)
+                        del self._running[idx]
+                        self.journal.close(req)
+                        if (
+                            req.first_token_ts is not None
+                            and len(req.tokens) > 1
+                        ):
+                            self.metrics.observe_tpot(
+                                (now - req.first_token_ts)
+                                * 1000.0
+                                / (len(req.tokens) - 1),
+                                tier=req.tier,
+                            )
+                        req._end(RequestState.DONE, now)
+                        self.metrics.request_completed()
+                        self._request_event(req)
+                dlv.set(tokens=n_tokens)
             # journal the post-dispatch per-slot keys: this is the
             # PRNG state a failover re-admission must continue from
             for idx, key in self.engine.live_request_keys().items():
@@ -889,84 +866,194 @@ class RequestScheduler:
             # slots, and dispatch to the coordinator OUTSIDE the lock
             # (it takes the target scheduler's lock)
             migrations = self._drain_prefilled_locked()
-            depth = self._waiting_total_locked()
-            self.metrics.set_queue_depth(depth)
-            self.metrics.set_role_queue_depth(
-                getattr(self.engine, "replica_role", "colocated"),
-                depth,
-            )
-            self.metrics.set_active_requests(len(self._running))
-            pc = getattr(self.engine, "prefix_cache", None)
-            if pc is not None:
-                self.metrics.update_prefix_cache(
-                    pc.hits, pc.misses, pc.evictions, pc.tokens_reused
+            with trace.span("sched.publish") as pub:
+                depth = self._waiting_total_locked()
+                self.metrics.set_queue_depth(depth)
+                self.metrics.set_role_queue_depth(
+                    getattr(self.engine, "replica_role", "colocated"),
+                    depth,
                 )
-            spec = getattr(self.engine, "spec", None)
-            if spec is not None:
-                self.metrics.update_speculative(
-                    spec.proposed, spec.accepted,
-                    spec.rounds, spec.emitted,
-                )
-            step_stats = getattr(self.engine, "step_stats", None)
-            if step_stats is not None:
-                st = step_stats()
-                self.metrics.update_step_timing(
-                    st["host_ms"], st["device_wait_ms"],
-                    int(st["dispatches"]), st["overlap_ratio"],
-                )
-                kp = getattr(self.engine, "kernel_path", None)
-                if kp is not None:
-                    self.metrics.update_kernel_path(
-                        kp, int(st["dispatches"])
+                self.metrics.set_active_requests(len(self._running))
+                pc = getattr(self.engine, "prefix_cache", None)
+                if pc is not None:
+                    self.metrics.update_prefix_cache(
+                        pc.hits, pc.misses, pc.evictions, pc.tokens_reused
                     )
-            paged_stats = getattr(self.engine, "paged_stats", None)
-            if paged_stats is not None:
-                ps = paged_stats()
-                if ps:
-                    self.metrics.update_paged(ps)
-            tier_stats = getattr(self.engine, "kv_tier_stats", None)
-            if tier_stats is not None:
-                ts = tier_stats()
-                if ts:
-                    self.metrics.update_kv_tier(ts)
-            mesh_shape = getattr(self.engine, "mesh_shape", None)
-            if mesh_shape is not None:
-                self.metrics.set_mesh(
-                    int(mesh_shape.get("tp", 1)),
-                    int(getattr(self.engine, "n_chips", 1)),
-                )
-            es = getattr(self.engine, "elastic_stats", None)
-            if es is not None:
-                self.metrics.update_elastic(es())
-            astats = getattr(self.engine, "adapter_stats", None)
-            if astats is not None:
-                a = astats()
-                if a:
-                    self.metrics.update_adapters(a)
-            pfstats = getattr(self.engine, "prefill_stats", None)
-            if pfstats is not None:
-                self.metrics.update_prefill(pfstats())
-            hstats = getattr(self.engine, "health_stats", None)
-            if hstats is not None:
-                h = hstats()
-                if h:
-                    self.metrics.update_kv_integrity(h)
-            wqstats = getattr(self.engine, "weight_quant_stats", None)
-            if wqstats is not None:
-                wq = wqstats()
-                if wq:
-                    self.metrics.update_weight_quant(
-                        wq,
-                        getattr(
-                            self.engine, "weight_quant_path", "none"
-                        ),
+                spec = getattr(self.engine, "spec", None)
+                if spec is not None:
+                    self.metrics.update_speculative(
+                        spec.proposed, spec.accepted,
+                        spec.rounds, spec.emitted,
                     )
+                step_stats = getattr(self.engine, "step_stats", None)
+                if step_stats is not None:
+                    st = step_stats()
+                    self.metrics.update_step_timing(
+                        st["host_ms"], st["device_wait_ms"],
+                        int(st["dispatches"]), st["overlap_ratio"],
+                    )
+                    kp = getattr(self.engine, "kernel_path", None)
+                    if kp is not None:
+                        self.metrics.update_kernel_path(
+                            kp, int(st["dispatches"])
+                        )
+                paged_stats = getattr(self.engine, "paged_stats", None)
+                if paged_stats is not None:
+                    ps = paged_stats()
+                    if ps:
+                        self.metrics.update_paged(ps)
+                tier_stats = getattr(self.engine, "kv_tier_stats", None)
+                if tier_stats is not None:
+                    ts = tier_stats()
+                    if ts:
+                        self.metrics.update_kv_tier(ts)
+                mesh_shape = getattr(self.engine, "mesh_shape", None)
+                if mesh_shape is not None:
+                    self.metrics.set_mesh(
+                        int(mesh_shape.get("tp", 1)),
+                        int(getattr(self.engine, "n_chips", 1)),
+                    )
+                es = getattr(self.engine, "elastic_stats", None)
+                if es is not None:
+                    self.metrics.update_elastic(es())
+                astats = getattr(self.engine, "adapter_stats", None)
+                if astats is not None:
+                    a = astats()
+                    if a:
+                        self.metrics.update_adapters(a)
+                pfstats = getattr(self.engine, "prefill_stats", None)
+                if pfstats is not None:
+                    self.metrics.update_prefill(pfstats())
+                hstats = getattr(self.engine, "health_stats", None)
+                if hstats is not None:
+                    h = hstats()
+                    if h:
+                        self.metrics.update_kv_integrity(h)
+                wqstats = getattr(self.engine, "weight_quant_stats", None)
+                if wqstats is not None:
+                    wq = wqstats()
+                    if wq:
+                        self.metrics.update_weight_quant(
+                            wq,
+                            getattr(
+                                self.engine, "weight_quant_path", "none"
+                            ),
+                        )
             busy = bool(self._running) or any(
                 self._waiting[t] for t in TIERS
             )
+        sp.set(held_s=held_s + pub.t0 + pub.dur_s - dlv.t0)
         for req, ticket, pkg in migrations:
             self._dispatch_handoff(req, ticket, pkg)
         return busy or bool(migrations)
+
+    def _first_tokens_out(self, req: ServeRequest) -> None:
+        """A request's first tokens went on its stream: its legs are
+        complete, so feed the queue-wait family and leave the
+        `request` trace event."""
+        if req.admitted_ts is not None and req.queued_ts is not None:
+            self.metrics.observe_queue_wait(
+                (req.admitted_ts - req.queued_ts) * 1e3
+            )
+        self._request_event(req)
+
+    def _request_event(self, req: ServeRequest) -> None:
+        trace.event(
+            "request", req.id,
+            submit_wall=req.submit_wall,
+            t_submit=req.submit_ts, t_locked=req.locked_ts,
+            t_queued=req.queued_ts, t_admitted=req.admitted_ts,
+            t_first=req.first_token_ts, t_end=req.finish_ts,
+            tokens=len(req.tokens),
+        )
+
+    def _admit_waiting_locked(self, now: float) -> int:
+        """Hand waiting requests to the engine, strict-priority EDF,
+        while it has room (preempting batch work for a blocked
+        latency arrival). Returns how many were admitted. Caller
+        holds the lock."""
+        # admit only up to the engine's free slots so
+        # tier-then-EDF order, not engine-internal FIFO,
+        # decides dispatch
+        admitted = 0
+        headroom_ok = getattr(
+            self.engine, "admission_headroom_ok", None
+        )
+        while True:
+            tier, req = self._peek_next_locked()
+            if req is None:
+                break
+            room = (
+                self.engine.queue_len()
+                < self.engine.free_slots()
+            )
+            # memory-aware gate (paged KV): when the page pool
+            # cannot back a worst-case admission and the engine
+            # already has work, wait for it to drain rather
+            # than force the engine into preempt-and-swap
+            # thrash. With the engine empty we admit anyway —
+            # it reclaims inline, so progress is guaranteed
+            # either way.
+            blocked = (
+                headroom_ok is not None
+                and not headroom_ok()
+                and (
+                    self.engine.active_count() > 0
+                    or self.engine.queue_len() > 0
+                )
+            )
+            if not room or blocked:
+                # a latency-tier waiter blocked on capacity
+                # reclaims it from batch work: evict one
+                # victim (slot + pages free immediately) and
+                # re-evaluate. No victim => genuinely full.
+                if (
+                    req.effective_tier == TIERS[0]
+                    and self._preempt_for_admission_locked()
+                ):
+                    continue
+                break
+            heapq.heappop(self._waiting[tier])
+            pkg, req.handoff_pkg = req.handoff_pkg, None
+            if pkg is not None and not req.tokens:
+                # adopted prefill: install the shipped KV
+                # instead of replaying the prompt. A package
+                # outlived by emitted tokens (decode-side
+                # crash after adoption) is stale — replay.
+                idx = self.engine.submit_adopted(pkg)
+            else:
+                prompt, remaining = req.engine_spec()
+                kw = {}
+                if req.adapter_id is not None:
+                    kw["adapter_id"] = req.adapter_id
+                try:
+                    idx = self.engine.submit(
+                        prompt,
+                        max_new=remaining,
+                        prng_key=req.prng_key,
+                        **kw,
+                    )
+                except AdapterCacheFull:
+                    # every bank slot is pinned by requests
+                    # already decoding: put the request back
+                    # and stop admitting — a retire this chunk
+                    # releases a pin and the next pump retries
+                    self._push_waiting_locked(req, prompt.size)
+                    break
+                except KeyError:
+                    # unregistered between admission and
+                    # dispatch: fail this request, keep the
+                    # replica alive
+                    req._end(RequestState.FAILED, now)
+                    self.metrics.request_failed()
+                    self.journal.close(req)
+                    continue
+            req.state = RequestState.RUNNING
+            req.admitted_ts = now
+            self._running[idx] = req
+            self.journal.open(req)
+            admitted += 1
+        return admitted
 
     # ---- phase handoff ---------------------------------------------------
 

@@ -32,6 +32,7 @@ from dlrover_tpu.agent.ckpt_saver import (
     ShmIntegrityError,
     read_tracker_step,
 )
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import SharedQueue, server_alive
@@ -44,6 +45,34 @@ from dlrover_tpu.common.storage import (
 class StorageType:
     MEMORY = "memory"
     DISK = "disk"
+
+
+def _log_legs(what: str, step: int, parent: trace.Span, extra: str = ""):
+    """One line per save and per restore: the spans recorded under
+    `parent`, each leg's own time (its extent less its children's),
+    and the bytes moved."""
+    by_parent: Dict[int, list] = {}
+    for r in trace.snapshot(since=parent.wall):
+        by_parent.setdefault(r[trace.PARENT], []).append(r)
+    legs: Dict[str, float] = {}
+    n_bytes = 0
+    todo = list(by_parent.get(parent.id, ()))
+    while todo:
+        r = todo.pop()
+        kids = by_parent.get(r[trace.ID], ())
+        todo.extend(kids)
+        name = r[trace.NAME].split(".", 1)[1]
+        legs[name] = (
+            legs.get(name, 0.0) + r[trace.DUR]
+            - sum(k[trace.DUR] for k in kids)
+        )
+        n_bytes = max(n_bytes, r[trace.COUNTS].get("bytes", 0))
+    logger.info(
+        "flash checkpoint %s step %d: %.1f ms, %d bytes; %s %s",
+        what, step, parent.dur_s * 1e3, n_bytes,
+        " ".join(f"{k}={v * 1e3:.1f}ms" for k, v in sorted(legs.items())),
+        extra,
+    )
 
 
 def _extract_npz(blob: bytes) -> Dict[str, np.ndarray]:
@@ -100,58 +129,62 @@ def flatten_state(state: Any) -> Tuple[Dict[str, np.ndarray], bytes]:
 
     Device arrays come back as the host view of their addressable data
     (on multi-host meshes each host stages only its shards — matching
-    the reference's per-rank shm layout)."""
+    the reference's per-rank shm layout). Span `ckpt.flatten` covers
+    all of it and `ckpt.d2h` inside it the copies off the device."""
     import jax
 
-    leaves_with_paths, treedef = jax.tree_util.tree_flatten_with_path(
-        state
-    )
-    # kick off the device→host DMA for EVERY leaf before draining any:
-    # np.asarray on a jax.Array is a synchronous round-trip, and a
-    # 300-leaf train state staged serially pays 300 transfer latencies
-    # back to back — a pipeline stall on the chip's host link. After
-    # this pass the per-leaf np.asarray below finds bytes already in flight.
-    for _, leaf in leaves_with_paths:
-        if isinstance(leaf, jax.Array):
-            try:
-                for shard in leaf.addressable_shards:
-                    shard.data.copy_to_host_async()
-            except Exception:  # noqa: BLE001 - best-effort prefetch
-                pass
-    flat = {}
-    paths = []
-    shard_meta = {}
-    for path, leaf in leaves_with_paths:
-        p = _leaf_path_str(path)
-        paths.append(p)
-        if isinstance(leaf, jax.Array):
-            # fully-addressable arrays: plain device_get; sharded
-            # multi-host arrays: concatenate local shards is wrong —
-            # stage each addressable shard separately and record how to
-            # reassemble them in aux.
-            if leaf.is_fully_addressable:
-                flat[p] = np.asarray(jax.device_get(leaf))
-            else:
-                entry = {
-                    "shape": tuple(leaf.shape),
-                    "dtype": str(leaf.dtype),
-                    "keys": [],
-                    "indices": [],
-                }
-                # keys carry the process index so shard files from
-                # different hosts can be merged without collisions
-                proc = jax.process_index()
-                for i, shard in enumerate(leaf.addressable_shards):
-                    key = f"{p}#shard{proc}_{i}"
-                    flat[key] = np.asarray(jax.device_get(shard.data))
-                    entry["keys"].append(key)
-                    entry["indices"].append(shard.index)
-                shard_meta[p] = entry
-        else:
-            flat[p] = np.asarray(leaf)
-    aux = pickle.dumps(
-        {"treedef": treedef, "paths": paths, "shards": shard_meta}
-    )
+    with trace.span("ckpt.flatten"):
+        leaves_with_paths, treedef = jax.tree_util.tree_flatten_with_path(
+            state
+        )
+        with trace.span("ckpt.d2h", leaves=len(leaves_with_paths)):
+            # kick off the device→host DMA for EVERY leaf before
+            # draining any: np.asarray on a jax.Array is a synchronous
+            # round-trip, and a 300-leaf train state staged serially
+            # pays 300 transfer latencies back to back — a pipeline
+            # stall on the chip's host link. After this pass the
+            # per-leaf np.asarray below finds bytes already in flight.
+            for _, leaf in leaves_with_paths:
+                if isinstance(leaf, jax.Array):
+                    try:
+                        for shard in leaf.addressable_shards:
+                            shard.data.copy_to_host_async()
+                    except Exception:  # noqa: BLE001 - best-effort prefetch
+                        pass
+            flat = {}
+            paths = []
+            shard_meta = {}
+            for path, leaf in leaves_with_paths:
+                p = _leaf_path_str(path)
+                paths.append(p)
+                if isinstance(leaf, jax.Array):
+                    # fully-addressable arrays: plain device_get; sharded
+                    # multi-host arrays: concatenate local shards is wrong —
+                    # stage each addressable shard separately and record how to
+                    # reassemble them in aux.
+                    if leaf.is_fully_addressable:
+                        flat[p] = np.asarray(jax.device_get(leaf))
+                    else:
+                        entry = {
+                            "shape": tuple(leaf.shape),
+                            "dtype": str(leaf.dtype),
+                            "keys": [],
+                            "indices": [],
+                        }
+                        # keys carry the process index so shard files from
+                        # different hosts can be merged without collisions
+                        proc = jax.process_index()
+                        for i, shard in enumerate(leaf.addressable_shards):
+                            key = f"{p}#shard{proc}_{i}"
+                            flat[key] = np.asarray(jax.device_get(shard.data))
+                            entry["keys"].append(key)
+                            entry["indices"].append(shard.index)
+                        shard_meta[p] = entry
+                else:
+                    flat[p] = np.asarray(leaf)
+        aux = pickle.dumps(
+            {"treedef": treedef, "paths": paths, "shards": shard_meta}
+        )
     return flat, aux
 
 
@@ -356,6 +389,7 @@ class CheckpointEngine:
         self._pending_backup = None  # latest-wins parked backup
         self._staging_thread = None
         self._staging_error = None
+        self._saved_once = False  # a process's first save costs more
         self.storage = storage or get_checkpoint_storage()
         self.job_name = job_name or os.environ.get(
             NodeEnv.JOB_NAME, "default"
@@ -454,14 +488,20 @@ class CheckpointEngine:
             ) from err
 
     def save_to_memory(self, step: int, state: Any) -> float:
-        """Stage state into shm; returns blocking seconds."""
-        t0 = time.monotonic()
-        # an in-flight async staging must land first — otherwise the
-        # older async snapshot could overwrite this newer state in shm
-        # (and a queued DISK persist for this step would be skipped)
-        self.wait_for_staging()
-        self._stage_to_shm(step, state)
-        return time.monotonic() - t0
+        """Stage state into shm; returns blocking seconds (the extent
+        of the `ckpt.save` span). One log line per save gives its legs
+        (docs/QUICKSTART.md)."""
+        first = int(not self._saved_once)
+        with trace.span("ckpt.save", step=int(step), first=first) as sp:
+            # an in-flight async staging must land first — otherwise
+            # the older async snapshot could overwrite this newer state
+            # in shm (and a queued DISK persist for this step would be
+            # skipped)
+            self.wait_for_staging()
+            self._stage_to_shm(step, state)
+        self._saved_once = True
+        _log_legs("save", step, sp, f"first_save={first}")
+        return sp.dur_s
 
     def _stage_to_shm(self, step: int, state: Any) -> None:
         flat, aux = flatten_state(state)
@@ -643,6 +683,13 @@ class CheckpointEngine:
         if its step >= the tracker's; else read storage. If `target`
         is given, the restored host state is device_put onto its
         shardings."""
+        with trace.span("ckpt.restore") as sp:
+            step, state = self._load(target)
+        if state is not None:
+            _log_legs("restore", step, sp)
+        return step, state
+
+    def _load(self, target: Any) -> Tuple[int, Optional[Any]]:
         # compare steps BEFORE paying for any unflatten/device_put
         shm_meta = self.shm_handler.get_meta()
         mem_step = shm_meta.step if shm_meta is not None else -1
@@ -726,7 +773,14 @@ class CheckpointEngine:
             if state is not None:
                 logger.info("restored step %d from replica", step)
         if state is not None and target is not None:
-            state = restore_to_shardings(state, target)
+            import jax
+
+            # the transfers are waited for inside the span, so that the
+            # leg is the copy and not only its dispatch
+            with trace.span("ckpt.h2d"):
+                state = jax.block_until_ready(
+                    restore_to_shardings(state, target)
+                )
         return step, state
 
     def wait_for_persist(

@@ -1,25 +1,17 @@
-"""Profiling: step timing, MFU, device timeline, HLO cost analysis.
+"""Profiling helpers: completion fences for timing, the chips' peak
+table, HLO cost analysis.
 
 Reference parity: atorch `AProfiler` (atorch/atorch/utils/prof.py:38 —
-module fwd/bwd hooks accumulating per-module flops/time + Chrome
-timeline), timers (utils/timer.py), trace parsing
-(utils/parse_trace_json.py).
+module fwd/bwd hooks accumulating per-module flops/time).
 
 TPU re-design: module hooks don't exist under jit — and aren't needed:
 XLA knows the flops. Per-op numbers come from
-`jax.jit(fn).lower(...).compile().cost_analysis()`; wall-clock comes
-from a step-boundary profiler; the timeline comes from
-`jax.profiler.trace` (perfetto, the Chrome-timeline analogue).
+`jax.jit(fn).lower(...).compile().cost_analysis()`. Host spans and
+their place on the device trace's clock are `common/trace.py`'s; the
+trace -> metrics reduction is `perfbench/trace_reduce.py`'s.
 """
 
-import contextlib
-import json
-import os
-import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
-
-from dlrover_tpu.common.log import default_logger as logger
+from typing import Callable, Dict
 
 # peak bf16 TFLOP/s per chip by generation (public spec sheets). A
 # device that is not in the table is an error, not a default: an MFU
@@ -30,122 +22,6 @@ PEAK_TFLOPS = {
     "v5p": 459.0,
     "v6e": 918.0,
 }
-
-
-class Timer:
-    """Accumulating named timer (reference atorch/utils/timer.py)."""
-
-    def __init__(self):
-        self.totals: Dict[str, float] = {}
-        self.counts: Dict[str, int] = {}
-
-    @contextlib.contextmanager
-    def record(self, name: str):
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            dt = time.monotonic() - t0
-            self.totals[name] = self.totals.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
-
-    def mean(self, name: str) -> float:
-        return self.totals.get(name, 0.0) / max(
-            self.counts.get(name, 0), 1
-        )
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        return {
-            k: {
-                "total_s": self.totals[k],
-                "count": self.counts[k],
-                "mean_s": self.mean(k),
-            }
-            for k in self.totals
-        }
-
-
-@dataclass
-class StepStats:
-    step: int
-    wall_s: float
-    tokens: int = 0
-    tflops: float = 0.0
-
-    @property
-    def tokens_per_sec(self) -> float:
-        return self.tokens / max(self.wall_s, 1e-9)
-
-
-class StepProfiler:
-    """Step-boundary profiler: throughput + MFU.
-
-    `flops_per_step` (e.g. 6*N*tokens for a decoder) divides by wall
-    time and the chip's peak to give MFU — the master's SpeedMonitor
-    consumes tokens/sec, the bench consumes MFU.
-    """
-
-    def __init__(
-        self,
-        tokens_per_step: int = 0,
-        flops_per_step: float = 0.0,
-        peak_tflops: Optional[float] = None,
-        window: int = 50,
-    ):
-        self.tokens_per_step = tokens_per_step
-        self.flops_per_step = flops_per_step
-        self.peak_tflops = peak_tflops
-        self.window = window
-        self.history: List[StepStats] = []
-        self._t0: Optional[float] = None
-        self._step = 0
-
-    def step_start(self):
-        self._t0 = time.monotonic()
-
-    def step_end(self, step: Optional[int] = None) -> StepStats:
-        wall = time.monotonic() - (self._t0 or time.monotonic())
-        self._step = step if step is not None else self._step + 1
-        st = StepStats(
-            step=self._step,
-            wall_s=wall,
-            tokens=self.tokens_per_step,
-            tflops=self.flops_per_step / 1e12,
-        )
-        self.history.append(st)
-        if len(self.history) > self.window:
-            self.history.pop(0)
-        return st
-
-    @contextlib.contextmanager
-    def step(self, step: Optional[int] = None):
-        self.step_start()
-        try:
-            yield
-        finally:
-            self.step_end(step)
-
-    @property
-    def mean_step_s(self) -> float:
-        if not self.history:
-            return 0.0
-        return sum(s.wall_s for s in self.history) / len(self.history)
-
-    @property
-    def tokens_per_sec(self) -> float:
-        return self.tokens_per_step / max(self.mean_step_s, 1e-9)
-
-    @property
-    def mfu(self) -> float:
-        """Achieved / peak flops per device."""
-        import jax
-
-        if not self.flops_per_step:
-            return 0.0
-        peak = self.peak_tflops or detect_peak_tflops()
-        achieved = self.flops_per_step / max(self.mean_step_s, 1e-9)
-        n_dev = jax.device_count()
-        return achieved / (peak * 1e12 * n_dev)
 
 
 def device_fence(out) -> None:
@@ -252,34 +128,3 @@ def cost_analysis(fn: Callable, *args, **kw) -> Dict[str, float]:
     except Exception:
         pass
     return out
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """Capture a device timeline viewable in perfetto/tensorboard —
-    the Chrome-timeline analogue of AProfiler(timeline=True)."""
-    import jax
-
-    os.makedirs(log_dir, exist_ok=True)
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("device trace written to %s", log_dir)
-
-
-def save_profile(path: str, profiler: StepProfiler, timer: Timer = None):
-    payload: Dict[str, Any] = {
-        "mean_step_s": profiler.mean_step_s,
-        "tokens_per_sec": profiler.tokens_per_sec,
-        "mfu": profiler.mfu,
-        "steps": [
-            {"step": s.step, "wall_s": s.wall_s} for s in profiler.history
-        ],
-    }
-    if timer is not None:
-        payload["timers"] = timer.summary()
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2)

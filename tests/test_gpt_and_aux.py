@@ -1,7 +1,4 @@
-"""GPT-2 model family, sparse PS executor failover, trace parsing,
-ICI monitor."""
-
-import json
+"""GPT-2 model family, sparse PS executor failover, ICI monitor."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +9,6 @@ from jax.sharding import Mesh
 
 from dlrover_tpu.models import gpt
 from dlrover_tpu.trainer.sparse_executor import SparseTrainingExecutor
-from dlrover_tpu.utils import trace_parse
 from dlrover_tpu.utils.ici_monitor import IciMonitor
 
 
@@ -134,44 +130,6 @@ class TestSparseExecutor:
         )
         out = ex.train(range(3))
         assert ex.global_step == 3 and out == {"loss": 0.0}
-
-
-class TestTraceParse:
-    def _trace(self):
-        return {
-            "traceEvents": [
-                {"ph": "X", "name": "fusion.1", "ts": 0, "dur": 100},
-                {"ph": "X", "name": "fusion.1", "ts": 200, "dur": 300},
-                {"ph": "X", "name": "copy.2", "ts": 600, "dur": 50},
-                {"ph": "M", "name": "meta", "ts": 0},
-                {"ph": "X", "name": "train_step", "ts": 0, "dur": 500},
-                {"ph": "X", "name": "train_step", "ts": 800, "dur": 500},
-            ]
-        }
-
-    def test_op_summary_orders_by_total(self):
-        ops = trace_parse.op_summary(self._trace())
-        assert ops[0]["name"] == "train_step"
-        byname = {o["name"]: o for o in ops}
-        assert byname["fusion.1"]["count"] == 2
-        assert byname["fusion.1"]["total_us"] == 400
-
-    def test_step_gaps(self):
-        gaps = trace_parse.step_gaps(self._trace())
-        assert gaps == [300.0]
-
-    def test_summarize_file(self, tmp_path):
-        p = tmp_path / "trace.json"
-        p.write_text(json.dumps(self._trace()))
-        out = trace_parse.summarize(str(p))
-        assert out["file"] == str(p) and out["ops"]
-
-    def test_find_newest(self, tmp_path):
-        (tmp_path / "a").mkdir()
-        f1 = tmp_path / "a" / "trace.json"
-        f1.write_text("{}")
-        assert trace_parse.find_trace_file(str(tmp_path)) == str(f1)
-        assert trace_parse.find_trace_file(str(tmp_path / "nope")) is None
 
 
 @pytest.mark.skipif(

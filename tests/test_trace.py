@@ -1,0 +1,549 @@
+"""The program's tracing primitive (dlrover_tpu/common/trace.py) and
+the spans, events and counts the serving path leaves in its ring:
+what a record may hold, how spans nest, and that every total the
+engine and the scheduler already kept is fed from the same clock
+readings as the span that covers the same boundary."""
+
+import dataclasses
+import gc
+import json
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+import weakref
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.common import trace
+from dlrover_tpu.common.trace import COUNTS, DUR, ID, NAME, PARENT, REQ, WALL
+from dlrover_tpu.models import llama
+from dlrover_tpu.serving.engine import ContinuousBatcher
+from dlrover_tpu.serving.gateway import ServingGateway
+from dlrover_tpu.serving.metrics import ServingMetrics
+from dlrover_tpu.serving.scheduler import RequestScheduler, SloConfig
+from dlrover_tpu.trainer.flash_checkpoint.engine import (
+    Checkpointer,
+    StorageType,
+)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(autouse=True)
+def empty_ring():
+    trace.clear()
+    yield
+    trace.clear()
+
+
+def _engine(cfg, params, **kw):
+    kw.setdefault("n_slots", 2)
+    kw.setdefault("max_len", 64)
+    kw.setdefault("max_new_tokens", 8)
+    kw.setdefault("chunk", 4)
+    kw.setdefault("pad_id", -1)
+    return ContinuousBatcher(cfg, params, **kw)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 250, size=n).tolist() for n in lengths]
+
+
+def _named(name, records=None):
+    records = trace.snapshot() if records is None else records
+    return [r for r in records if r[NAME] == name]
+
+
+class TestRing:
+    def test_bounded(self):
+        for i in range(trace.RING_SIZE + 10):
+            trace.event("e", i)
+        records = trace.snapshot()
+        assert len(records) == trace.RING_SIZE
+        assert records[0][REQ] == 10  # the oldest ten fell out
+
+    @pytest.mark.parametrize(
+        "value", [np.zeros(3), np.int64(3), [1, 2], object()],
+        ids=["array", "numpy_scalar", "list", "object"],
+    )
+    def test_a_record_holds_numbers_and_strings_only(self, value):
+        with pytest.raises(TypeError):
+            trace.span("s", bad=value)
+        with pytest.raises(TypeError):
+            trace.event("e", 1, bad=value)
+        with trace.span("s") as sp:
+            with pytest.raises(TypeError):
+                sp.set(bad=value)
+        assert _named("s")[0][COUNTS] == {}
+
+    def test_snapshot_cuts_a_window_and_clear_empties(self):
+        trace.event("early", 1)
+        t0 = time.time()
+        time.sleep(0.002)
+        with trace.span("inside"):
+            pass
+        time.sleep(0.002)
+        t1 = time.time()
+        time.sleep(0.002)
+        trace.event("late", 2)
+        assert [r[NAME] for r in trace.snapshot(t0, t1)] == ["inside"]
+        assert len(trace.snapshot()) == 3
+        trace.clear()
+        assert trace.snapshot() == []
+
+    def test_event_is_a_plain_tuple_without_extent(self):
+        with trace.span("outer") as sp:
+            trace.event("request", 7, t_first=1.5, t_end=None, tokens=3)
+        (ev,) = _named("request")
+        assert type(ev) is tuple and len(ev) == 7
+        assert ev[DUR] == 0.0 and ev[REQ] == 7 and ev[PARENT] == sp.id
+        assert ev[COUNTS] == {"t_first": 1.5, "t_end": None, "tokens": 3}
+
+
+class TestNesting:
+    def test_spans_name_their_parent(self):
+        with trace.span("a") as a:
+            with trace.span("b") as b:
+                with trace.span("c") as c:
+                    pass
+            with trace.span("d") as d:
+                pass
+        by_name = {r[NAME]: r for r in trace.snapshot()}
+        assert by_name["a"][PARENT] == 0
+        assert by_name["b"][PARENT] == a.id == by_name["a"][ID]
+        assert by_name["c"][PARENT] == b.id
+        assert by_name["d"][PARENT] == a.id
+        assert len({a.id, b.id, c.id, d.id}) == 4
+        # a parent contains its children on both clocks
+        assert by_name["a"][DUR] >= by_name["b"][DUR] + by_name["d"][DUR]
+        assert by_name["a"][WALL] <= by_name["b"][WALL]
+
+    def test_a_raising_block_still_records_and_unwinds(self):
+        with pytest.raises(ValueError):
+            with trace.span("outer"):
+                with trace.span("inner"):
+                    raise ValueError("boom")
+        assert [r[NAME] for r in trace.snapshot()] == ["inner", "outer"]
+        with trace.span("after"):
+            pass
+        assert _named("after")[0][PARENT] == 0
+
+    def test_nesting_is_per_thread(self):
+        inside = threading.Event()
+        release = threading.Event()
+
+        def other():
+            with trace.span("other.outer"):
+                inside.set()
+                assert release.wait(10)
+                with trace.span("other.inner"):
+                    pass
+
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with trace.span("main.outer") as main_outer:
+            with trace.span("main.inner"):
+                pass
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+        by_name = {r[NAME]: r for r in trace.snapshot()}
+        assert by_name["main.outer"][PARENT] == 0
+        assert by_name["main.inner"][PARENT] == main_outer.id
+        assert by_name["other.inner"][PARENT] == by_name["other.outer"][ID]
+
+    def test_16_threads_lose_nothing(self):
+        n_threads, per_thread = 16, 400
+        start = threading.Barrier(n_threads)
+
+        def work(k):
+            start.wait(10)
+            for i in range(per_thread):
+                with trace.span("w", req=k, i=i):
+                    pass
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [
+                threading.Thread(target=work, args=(k,))
+                for k in range(n_threads)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(old)
+        records = _named("w")
+        assert len(records) == n_threads * per_thread
+        assert len({r[ID] for r in records}) == len(records)
+        for k in range(n_threads):
+            mine = [r[COUNTS]["i"] for r in records if r[REQ] == k]
+            assert mine == list(range(per_thread))
+
+
+class TestWithoutJax:
+    def test_a_process_without_jax_traces_and_stays_without(self):
+        code = (
+            "import sys\n"
+            "from dlrover_tpu.common import trace\n"
+            "with trace.span('agent.detect', pid=3) as sp:\n"
+            "    trace.event('e', 1)\n"
+            "assert len(trace.snapshot()) == 2\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "assert 'numpy' not in sys.modules, 'numpy was imported'\n"
+            "print('ok')\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=60,
+        )
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "ok"
+
+    def test_the_annotation_carries_the_prefixed_name(self, monkeypatch):
+        opened = []
+
+        class Recorder:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                opened.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                opened.append(("exit", self.name))
+
+        monkeypatch.setattr(trace, "_annotate", Recorder)
+        with trace.span("engine.step"):
+            pass
+        assert opened == [
+            ("enter", "dlrover:engine.step"), ("exit", "dlrover:engine.step"),
+        ]
+
+
+class TestEngineSpans:
+    def test_step_is_wait_plus_host_and_feeds_the_totals(self, model):
+        cfg, params = model
+        eng = _engine(cfg, params)
+        for p in _prompts((5, 12, 3, 9), seed=1):
+            eng.submit(p)
+        n = 0
+        while eng.has_work():
+            eng.step()
+            n += 1
+            assert eng.last_step_s == _named("engine.step")[-1][DUR]
+        steps = _named("engine.step")
+        assert len(steps) == n
+        host_ms = sum(
+            (r[DUR] - r[COUNTS]["wait_s"]) * 1e3 for r in steps
+        )
+        stats = eng.step_stats()
+        assert stats["host_ms"] == pytest.approx(host_ms, rel=1e-9)
+        harvests = _named("engine.harvest")
+        assert stats["device_wait_ms"] == pytest.approx(
+            sum(r[COUNTS]["wait_s"] for r in harvests) * 1e3, rel=1e-9
+        )
+        assert stats["dispatches"] == len(harvests)
+        # every wait of a step is a harvest inside it
+        for step in steps:
+            inside = [r for r in harvests if r[PARENT] == step[ID]]
+            assert step[COUNTS]["wait_s"] == pytest.approx(
+                sum(r[COUNTS]["wait_s"] for r in inside), rel=1e-9
+            )
+            assert 0.0 <= step[COUNTS]["wait_s"] <= step[DUR]
+        assert steps[0][COUNTS]["alive"] == 2
+        assert steps[-1][COUNTS]["alive"] == 0
+        assert all(type(r[COUNTS]["live_tokens"]) is int for r in steps)
+
+    def test_admit_and_dispatch_carry_their_counts(self, model):
+        cfg, params = model
+        eng = _engine(cfg, params)
+        prompts = _prompts((5, 20), seed=2)
+        for p in prompts:
+            eng.submit(p)
+        while eng.has_work():
+            eng.step()
+        admits = _named("engine.admit")
+        assert [r[COUNTS]["prompt_tokens"] for r in admits] == [5, 20]
+        assert [r[COUNTS]["bucket"] for r in admits] == [16, 32]
+        assert eng.prefill_stats()["admission_stall_ms"] == pytest.approx(
+            sum(r[DUR] for r in admits) * 1e3, rel=1e-9
+        )
+        first = _named("engine.step")[0]
+        assert first[COUNTS]["admit_s"] == pytest.approx(
+            sum(r[DUR] for r in admits if r[PARENT] == first[ID]), rel=1e-9
+        )
+        dispatches = _named("engine.dispatch")
+        assert dispatches and all(
+            r[COUNTS]["chunk"] in (1, 2, 4) for r in dispatches
+        )
+        steps = {r[ID] for r in _named("engine.step")}
+        assert all(r[PARENT] in steps for r in admits + dispatches)
+
+    def test_a_full_ring_keeps_no_engine_alive(self, model):
+        cfg, params = model
+        eng = _engine(cfg, params)
+        sched = RequestScheduler(eng, slo=SloConfig(max_new_tokens=8))
+        for p in _prompts((5, 7), seed=3):
+            sched.submit(p)
+        sched.run_to_completion()
+        assert _named("engine.step") and _named("request")
+        while len(trace.snapshot()) < trace.RING_SIZE:
+            with trace.span("filler"):
+                pass
+        ref_engine, ref_sched = weakref.ref(eng), weakref.ref(sched)
+        del eng, sched
+        gc.collect()
+        assert ref_engine() is None and ref_sched() is None
+        assert len(trace.snapshot()) == trace.RING_SIZE
+
+
+class TestSchedulerSpans:
+    def test_four_legs_sum_to_the_time_to_first_token(self, model):
+        cfg, params = model
+        ticks = iter(range(10**6))
+        # a clock that moves by an uneven step at every reading
+        clock = lambda: next(ticks) * 0.37 + 100.0  # noqa: E731
+        sched = RequestScheduler(
+            _engine(cfg, params), slo=SloConfig(
+                max_new_tokens=8, default_deadline_s=1e9),
+            clock=clock,
+        )
+        reqs = [sched.submit(p) for p in _prompts((5, 12, 3, 9, 6), seed=4)]
+        sched.run_to_completion()
+        firsts = {
+            r[REQ]: r[COUNTS] for r in _named("request")
+            if r[COUNTS]["t_end"] is None
+        }
+        ends = {
+            r[REQ]: r[COUNTS] for r in _named("request")
+            if r[COUNTS]["t_end"] is not None
+        }
+        assert set(firsts) == set(ends) == {r.id for r in reqs}
+        for req in reqs:
+            c = firsts[req.id]
+            legs = [
+                c["t_locked"] - c["t_submit"],
+                c["t_queued"] - c["t_locked"],
+                c["t_admitted"] - c["t_queued"],
+                c["t_first"] - c["t_admitted"],
+            ]
+            assert all(leg >= 0 for leg in legs), legs
+            assert c["t_submit"] == req.submit_ts
+            assert c["t_first"] == req.first_token_ts
+            assert (c["t_locked"], c["t_queued"], c["t_admitted"]) == (
+                req.locked_ts, req.queued_ts, req.admitted_ts)
+            # the same four numbers telescope: exact, not approximate
+            assert (
+                ((legs[3] + c["t_admitted"]) - c["t_submit"])
+                == req.first_token_ts - req.submit_ts
+            )
+            assert sum(legs) == pytest.approx(
+                req.first_token_ts - req.submit_ts, abs=1e-9)
+            assert ends[req.id]["tokens"] == len(req.tokens) == 8
+            assert ends[req.id]["t_end"] == req.finish_ts
+            assert c["submit_wall"] == req.submit_wall
+
+    def test_submit_reads_its_wait_for_the_lock(self, model):
+        cfg, params = model
+        entered = threading.Event()
+        main = threading.current_thread()
+
+        def clock():
+            # submit's first reading is its entry stamp
+            if threading.current_thread() is not main:
+                entered.set()
+            return time.monotonic()
+
+        sched = RequestScheduler(
+            _engine(cfg, params), slo=SloConfig(max_new_tokens=8),
+            clock=clock,
+        )
+        free = sched.submit(_prompts((5,), seed=5)[0])
+        got = []
+        t = threading.Thread(
+            target=lambda: got.append(
+                sched.submit(_prompts((6,), seed=6)[0]))
+        )
+        with sched._cond:  # held while the other thread stands in submit
+            t.start()
+            assert entered.wait(10)
+            time.sleep(0.05)
+        t.join(10)
+        assert not t.is_alive()
+        (waited,) = got
+        by_req = {r[REQ]: r for r in _named("sched.submit")}
+        assert by_req[free.id][COUNTS]["lock_wait_s"] < 0.005
+        assert by_req[waited.id][COUNTS]["lock_wait_s"] >= 0.05
+        assert waited.locked_ts - waited.submit_ts == (
+            by_req[waited.id][COUNTS]["lock_wait_s"])
+        assert by_req[waited.id][DUR] >= 0.05
+
+    def test_pump_holds_the_lock_around_its_engine_step(self, model):
+        cfg, params = model
+        eng = _engine(cfg, params)
+        sched = RequestScheduler(eng, slo=SloConfig(max_new_tokens=8))
+        for p in _prompts((5, 12, 3), seed=7):
+            sched.submit(p)
+        sched.pump()
+        assert sched._step_lat_ewma == eng.last_step_s > 0
+        sched.run_to_completion()
+        pumps = _named("sched.pump")
+        steps = _named("engine.step")
+        assert len(pumps) >= len(steps) > 0
+        for step in steps:
+            (pump,) = [p for p in pumps if p[ID] == step[PARENT]]
+            kids = {
+                r[NAME]: r for r in trace.snapshot()
+                if r[PARENT] == pump[ID]
+            }
+            assert set(kids) == {
+                "sched.admit", "engine.step", "sched.deliver",
+                "sched.publish"}
+            held = pump[COUNTS]["held_s"]
+            assert step[DUR] <= held <= pump[DUR]
+            # what the lock covers besides the step: the other spans
+            assert held - step[DUR] >= (
+                kids["sched.admit"][DUR] + kids["sched.deliver"][DUR]
+                + kids["sched.publish"][DUR]) * (1 - 1e-9)
+        admitted = sum(r[COUNTS]["admitted"] for r in _named("sched.admit"))
+        assert admitted == 3
+        delivered = sum(r[COUNTS]["tokens"] for r in _named("sched.deliver"))
+        assert delivered == 3 * 8
+
+    def test_metrics_render_the_legs_from_the_same_stamps(self, model):
+        cfg, params = model
+        metrics = ServingMetrics()
+        sched = RequestScheduler(
+            _engine(cfg, params), slo=SloConfig(max_new_tokens=8),
+            metrics=metrics,
+        )
+        reqs = [sched.submit(p) for p in _prompts((5, 12, 3), seed=8)]
+        sched.run_to_completion()
+        text = metrics.render()
+        for family in (
+            "serving_sched_lock_wait_ms", "serving_queue_wait_ms",
+        ):
+            assert f"# TYPE {family} summary" in text
+            assert f'{family}{{quantile="0.5"}}' in text
+            assert f'{family}{{quantile="0.95"}}' in text
+            assert f"{family}_count 3" in text
+        (ratio,) = [
+            float(line.split()[1]) for line in text.splitlines()
+            if line.startswith("serving_sched_lock_held_ratio ")
+        ]
+        pumps = _named("sched.pump")
+        held = sum(p[COUNTS]["held_s"] for p in pumps)
+        assert 0.0 < ratio <= 1.0
+        assert ratio == pytest.approx(
+            held / (pumps[-1][WALL] + pumps[-1][DUR] - pumps[0][WALL]),
+            rel=0.05,
+        )
+        waits = sorted(
+            (r.admitted_ts - r.queued_ts) * 1e3 for r in reqs)
+        assert f"serving_queue_wait_ms_sum {sum(waits):.6g}" in text
+
+
+class TestGatewaySpan:
+    def test_generate_covers_the_request_from_parse_to_answer(self, model):
+        cfg, params = model
+        sched = RequestScheduler(
+            _engine(cfg, params), slo=SloConfig(max_new_tokens=8))
+        gateway = ServingGateway(sched)
+        sched.start()
+        gateway.start()
+        try:
+            body = json.dumps({
+                "tokens": _prompts((5,), seed=9)[0], "max_new": 4,
+                "stream": False,
+            }).encode()
+            with urllib.request.urlopen(urllib.request.Request(
+                gateway.addr + "/v1/generate", data=body,
+                headers={"Content-Type": "application/json"},
+            ), timeout=120) as resp:
+                answer = json.loads(resp.read())
+        finally:
+            gateway.stop()
+            sched.stop()
+        assert answer["state"] == "done" and len(answer["tokens"]) == 4
+        (door,) = _named("gateway.generate")
+        (submit,) = _named("sched.submit")
+        assert door[REQ] == submit[REQ] == answer["id"]
+        assert submit[PARENT] == door[ID]
+        (first,) = [
+            r for r in _named("request") if r[COUNTS]["t_end"] is None
+        ]
+        # the answer is written after the last token: the door's span
+        # outlasts the request's time to its first token
+        assert door[DUR] >= (
+            first[COUNTS]["t_first"] - first[COUNTS]["t_submit"])
+
+
+class TestCheckpointLegs:
+    def test_a_save_and_a_restore_leave_their_legs(self, tmp_path, caplog):
+        from dlrover_tpu.common.log import default_logger
+
+        default_logger.addHandler(caplog.handler)  # it does not propagate
+        ckpt = Checkpointer(
+            str(tmp_path / "ckpt"), job_name=f"trace_{time.time_ns()}")
+        state = {"w": jnp.arange(4096, dtype=jnp.float32), "step": 3}
+        try:
+            with caplog.at_level("INFO"):
+                blocked = ckpt.save_checkpoint(1, state, StorageType.MEMORY)
+                ckpt.save_checkpoint(2, state, StorageType.MEMORY)
+                step, restored = ckpt.load_checkpoint(target=state)
+        finally:
+            default_logger.removeHandler(caplog.handler)
+            ckpt.close()
+        assert step == 2
+        np.testing.assert_array_equal(
+            np.asarray(restored["w"]), np.asarray(state["w"]))
+        saves = _named("ckpt.save")
+        assert [r[COUNTS] for r in saves] == [
+            {"step": 1, "first": 1}, {"step": 2, "first": 0}]
+        assert blocked == saves[0][DUR]  # the span IS the blocking time
+        for save in saves:
+            kids = {
+                r[NAME]: r for r in trace.snapshot()
+                if r[PARENT] == save[ID]
+            }
+            assert set(kids) == {
+                "ckpt.flatten", "ckpt.shm_write", "ckpt.notify"}
+            (d2h,) = [
+                r for r in _named("ckpt.d2h")
+                if r[PARENT] == kids["ckpt.flatten"][ID]
+            ]
+            assert d2h[COUNTS]["leaves"] == 2
+            assert kids["ckpt.shm_write"][COUNTS]["bytes"] >= 4096 * 4
+            assert sum(k[DUR] for k in kids.values()) <= save[DUR]
+        assert saves[0][ID] in {
+            r[PARENT] for r in _named("ckpt.shm_write")
+            if r[COUNTS].get("new_segment") == 1
+        }
+        (restore,) = _named("ckpt.restore")
+        legs = {r[NAME] for r in trace.snapshot() if r[PARENT] == restore[ID]}
+        assert legs == {"ckpt.shm_read", "ckpt.h2d"}
+        lines = [
+            r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("flash checkpoint")
+        ]
+        assert len(lines) == 3
+        assert "save step 1" in lines[0] and "first_save=1" in lines[0]
+        for leg in ("d2h=", "flatten=", "shm_write=", "notify="):
+            assert leg in lines[0] and leg in lines[1]
+        assert "restore step 2" in lines[2]
+        assert "shm_read=" in lines[2] and "h2d=" in lines[2]
