@@ -793,6 +793,13 @@ def pool_put_row(
 # On a real TPU the S==1 decode step swaps the gathered view for the
 # Pallas paged-attention kernel (ops/paged_attention.py) that streams
 # physical pages without materializing the view.
+#
+# The pool is stored stacked over layers and WALKED stacked: the layer
+# loop carries it whole, a layer scatters its rows at
+# pool[layer, page, cell] and attends over pool[layer, table] (one
+# gather, or the kernel's index map), so a forward moves the rows it
+# writes and the pages it reads — never a layer's, let alone the
+# pool's, worth of bytes.
 # ---------------------------------------------------------------------------
 
 
@@ -804,7 +811,9 @@ def init_page_pool(
     int8 scheme as init_kv_cache, so quantized bytes match the dense
     bank's for the same values). Page id 0 is the TRASH page by
     engine convention: retired/done slots' table rows point there so
-    frozen rewrites land somewhere no live table reads."""
+    frozen rewrites land somewhere no live table reads. Every paged
+    forward takes the pool in this stacked form and addresses a
+    layer by its index (`_forward_paged`)."""
     kv_heads = getattr(cfg, "n_kv_heads", cfg.n_heads)
     shape = (cfg.n_layers, n_pages, page_size, kv_heads, cfg.head_dim)
     if not quant:
@@ -822,26 +831,32 @@ def init_page_pool(
 
 
 def _paged_view(
-    layer_pool: Dict[str, jax.Array], table: jax.Array
+    pool: Dict[str, jax.Array], layer, table: jax.Array
 ) -> Dict[str, jax.Array]:
-    """Gather one layer's pages into the dense [B, M, KV, ...] view
-    (M = P * page_size) — the shape `_cached_attention` attends over.
-    A pure gather; whatever dead pages hold is masked exactly."""
+    """Gather layer `layer`'s pages of the stacked pool into the dense
+    [B, M, KV, ...] view (M = P * page_size) — the shape
+    `_cached_attention` attends over. ONE gather per leaf
+    (`arr[layer, table]`, never `arr[layer][table]`: no layer is
+    sliced out first); whatever dead pages hold is masked exactly."""
     out = {}
-    for name, arr in layer_pool.items():
-        g = arr[table]  # [B, P, page_size, KV, ...]
+    for name, arr in pool.items():
+        g = arr[layer, table]  # [B, P, page_size, KV, ...]
         out[name] = g.reshape((g.shape[0], -1) + g.shape[3:])
     return out
 
 
 def _write_pages_and_attend(
-    q, k, v, layer_pool, table, positions, head_dim, mesh=None,
+    q, k, v, pool, layer, table, positions, head_dim, mesh=None,
     attn_impl: str = "auto",
 ):
-    """The paged counterpart of `_write_cache_and_attend`: scatter
-    this chunk's K/V into the slot's PAGES (row b, chunk position s →
-    pool[table[b, pos//ps], pos%ps]) and attend over the gathered
-    dense view with the identical position-masked attention.
+    """The paged counterpart of `_write_cache_and_attend`, on the
+    STACKED pool and a traced layer index: scatter this chunk's K/V
+    into the slot's PAGES of that layer (row b, chunk position s →
+    pool[layer, table[b, pos//ps], pos%ps]) and attend over the
+    layer's pages with the identical position-masked attention. The
+    pool is the layer loop's carry, so the scatter of B*S rows
+    updates it in place, and both attention paths address the layer
+    by its index: nothing slices a layer out or stacks it back.
 
     Within a chunk a row's positions are distinct, and across rows
     live tables never share a writable page (the allocator CoWs
@@ -853,11 +868,11 @@ def _write_pages_and_attend(
     q = constrain(q, mesh, None, None, SERVING_TP_AXIS, None)
     k = constrain(k, mesh, None, None, SERVING_TP_AXIS, None)
     v = constrain(v, mesh, None, None, SERVING_TP_AXIS, None)
-    ps = layer_pool["k"].shape[1]
+    ps = pool["k"].shape[2]
     pids = jnp.take_along_axis(table, positions // ps, axis=1)
     offs = positions % ps
-    out_pool = dict(layer_pool)
-    if "k_scale" in layer_pool:
+    out_pool = dict(pool)
+    if "k_scale" in pool:
         kq, ks = _kv_quantize(k)
         vq, vs = _kv_quantize(v)
         writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
@@ -865,8 +880,8 @@ def _write_pages_and_attend(
         writes = {"k": k, "v": v}
     with jax.named_scope("kv_pool_writeback"):
         for name, upd in writes.items():
-            arr = layer_pool[name]
-            out_pool[name] = arr.at[pids, offs].set(
+            arr = pool[name]
+            out_pool[name] = arr.at[layer, pids, offs].set(
                 upd.astype(arr.dtype)
             )
     s = q.shape[1]
@@ -883,11 +898,11 @@ def _write_pages_and_attend(
                 attn = pa.paged_attention(
                     q1, out_pool, table, lengths,
                     scale=float(head_dim) ** -0.5, impl="kernel",
-                    mesh=mesh,
+                    mesh=mesh, layer=layer,
                 )
             return constrain(attn[:, None], mesh), out_pool
     with jax.named_scope("kv_pool_slice"):
-        view = _paged_view(out_pool, table)
+        view = _paged_view(out_pool, layer, table)
     attn = _cached_attention(
         q, view, positions, float(head_dim) ** -0.5
     )
@@ -896,12 +911,13 @@ def _write_pages_and_attend(
 
 
 def _block_paged(
-    cfg, x, layer_params, layer_pool, table, positions, mesh=None,
+    cfg, x, layer_params, pool, layer, table, positions, mesh=None,
     lora=None,
 ):
     """Llama block over paged KV — identical projections/residuals to
     `_block` (including the per-slot `lora` deltas); only the cache
-    write + view differ."""
+    write + view differ. `pool` is the whole stacked pool and `layer`
+    this block's (traced) index into it."""
     lp = _compute_weights(cfg, layer_params)
     tp = _mesh_tp(mesh)
     with jax.named_scope("attn"):
@@ -909,42 +925,44 @@ def _block_paged(
         q, k, v = _attn_qkv(
             cfg, None, h, lp, positions, lora=lora, tp=tp
         )
-        attn, layer_pool = _write_pages_and_attend(
-            q, k, v, layer_pool, table, positions, cfg.head_dim,
+        attn, pool = _write_pages_and_attend(
+            q, k, v, pool, layer, table, positions, cfg.head_dim,
             mesh=mesh,
             attn_impl=getattr(cfg, "attn_impl", "auto"),
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):
         x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
-    return x, layer_pool
+    return x, pool
 
 
 def _block_gpt_paged(
-    cfg, x, lp, layer_pool, table, positions, mesh=None, lora=None
+    cfg, x, lp, pool, layer, table, positions, mesh=None, lora=None
 ):
     from dlrover_tpu.models import gpt
 
     tp = _mesh_tp(mesh)
     q, k, v = gpt._attn_qkv(cfg, x, lp, tp=tp)
-    attn, layer_pool = _write_pages_and_attend(
-        q, k, v, layer_pool, table, positions, cfg.head_dim,
+    attn, pool = _write_pages_and_attend(
+        q, k, v, pool, layer, table, positions, cfg.head_dim,
         mesh=mesh,
         attn_impl=getattr(cfg, "attn_impl", "auto"),
     )
     x = gpt._attn_residual(cfg, x, attn, lp, tp=tp)
     x = gpt._mlp_residual(cfg, x, lp, tp=tp)
-    return x, layer_pool
+    return x, pool
 
 
 def _forward_paged(
     cfg, params, tokens, pool, table, positions, mesh=None,
     adapters=None,
 ):
-    """tokens [B, S] → logits [B, S, V] over the paged pool; the
-    layer scan mirrors `_forward_cached` (the pool pytree scans over
-    its leading layer axis; the table is shared by every layer), as
-    does the optional `adapters` bank riding the xs."""
+    """tokens [B, S] → logits [B, S, V] over the paged pool. The
+    pool goes through the layer scan as a CARRY, whole and stacked
+    (`[L, n_pages, page_size, KV, hd]` leaves); the scan's xs are the
+    layers' parameters, their indices and the optional `adapters`
+    bank, and each layer writes and reads its own part of the pool
+    by its index. The table is shared by every layer."""
     _check_adapters(cfg, adapters)
     gpt = _is_gpt(cfg)
     if gpt:
@@ -958,30 +976,28 @@ def _forward_paged(
         block = _block_paged
 
     def body(carry, inp):
-        h = carry
+        h, pool = carry
         if adapters is None:
-            layer_params, layer_pool = inp
+            layer_params, layer = inp
             lora = None
         else:
-            layer_params, layer_pool, layer_bank = inp
+            layer_params, layer, layer_bank = inp
             lora = (layer_bank, adapters["idx"], adapters["scale"])
-        h, layer_pool = block(
-            cfg, h, layer_params, layer_pool, table, positions,
+        h, pool = block(
+            cfg, h, layer_params, pool, layer, table, positions,
             mesh=mesh,
             lora=lora,
         )
-        return h, layer_pool
+        return (h, pool), None
 
+    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
     xs = (
-        (params["layers"], dict(pool))
+        (params["layers"], layers)
         if adapters is None
-        else (params["layers"], dict(pool), dict(adapters["bank"]))
+        else (params["layers"], layers, dict(adapters["bank"]))
     )
-    # the scan itself slices each layer's K and V out of the stacked
-    # pool and stacks them back: those copies carry this scope and no
-    # deeper one (layers/while/body/dynamic_slice, .../dynamic_update_slice)
     with jax.named_scope("layers"):
-        x, pool_new = jax.lax.scan(body, x, xs)
+        (x, pool_new), _ = jax.lax.scan(body, (x, dict(pool)), xs)
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 

@@ -2,11 +2,16 @@
 re-design).
 
 The serving engine's paged KV layout (serving/engine.py kv_layout=
-"paged") stores K/V in a global page pool `[n_pages, page_size, KV,
-hd]` per layer; each batch row owns a page TABLE `[P]` of physical
-page ids covering logical positions [i*page_size, (i+1)*page_size).
-Decode attention must gather a row's pages and attend a single query
-over them — this module provides both halves:
+"paged") stores K/V in a global page pool stacked over layers,
+`[L, n_pages, page_size, KV, hd]`; each batch row owns a page TABLE
+`[P]` of physical page ids covering logical positions
+[i*page_size, (i+1)*page_size), shared by every layer. Decode
+attention must gather a row's pages of ONE layer and attend a single
+query over them. The pool comes in stacked, with the layer's index
+(`layer`, traced: the forward's layer loop carries the whole pool and
+never slices a layer out); a per-layer pool `[n_pages, page_size, KV,
+hd]` with no `layer` is the same thing with L = 1. This module
+provides both halves:
 
 - `paged_attention(..., impl="reference")`: gather the pages into a
   dense [B, M, KV, hd] view and run EXACTLY the grouped-einsum masked
@@ -19,13 +24,14 @@ over them — this module provides both halves:
   trash/stale page holds, so the gather may read anything dead.
 - `paged_attention(..., impl="kernel")`: a Pallas kernel in the
   flash_attention.py online-softmax style that never materializes the
-  dense view: the page table rides in as a SCALAR-PREFETCH operand
-  (pltpu.PrefetchScalarGridSpec), so the BlockSpec index map resolves
-  page ids before the body runs and the pipeline streams pages
-  HBM→VMEM directly. int8 pools dequantize inside the inner loop
-  (fused into the score/accumulate dots — the cache reads stay int8
-  in HBM, halving decode's memory-bound byte traffic). interpret=True
-  on CPU keeps tier-1 runnable.
+  dense view: the layer index and the page table ride in as
+  SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the
+  BlockSpec index map resolves (layer, page id) before the body runs
+  and the pipeline streams pages HBM→VMEM directly. int8 pools
+  dequantize inside the inner loop (fused into the score/accumulate
+  dots — the cache reads stay int8 in HBM, halving decode's
+  memory-bound byte traffic). interpret=True on CPU keeps tier-1
+  runnable.
 - `impl="auto"`: the kernel on a TPU when `supports()` passes, else
   the reference (a decision the engine logs once and reports as
   `kernel_path`). CPU tier-1 therefore runs the reference —
@@ -55,16 +61,17 @@ NEG_INF = -1e30
 
 def supports(q, pages: Dict, table, tp: int = 1) -> bool:
     """Whether the Pallas kernel handles these shapes. `q` is the
-    [B, H, hd] single-token query, `pages` the per-layer pool dict,
-    `table` the [B, P] page table. Reuses flash_attention's q_len==1
-    gate for the head_dim constraints, then checks the page axis.
+    [B, H, hd] single-token query, `pages` the pool dict (per-layer
+    or stacked: the gate reads the last four dims), `table` the
+    [B, P] page table. Reuses flash_attention's q_len==1 gate for the
+    head_dim constraints, then checks the page axis.
 
     `tp` is the serving tensor-parallel degree: the gate judges the
     PER-SHARD head counts (heads / tp), because that is what the
     kernel would see under GSPMD head sharding — a global count that
     doesn't divide over tp fails outright."""
     b, h, d = q.shape
-    n_pages, page_size, kv, _ = pages["k"].shape
+    page_size, kv = pages["k"].shape[-3:-1]
     shard = fa.per_shard_heads(h, kv, tp)
     if shard is None:
         return False
@@ -108,26 +115,39 @@ def use_kernel(q, pages: Dict, table, tp: int = 1) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def gather_pages(pages: Dict, table) -> Dict:
+def _stacked(pages: Dict, layer):
+    """(stacked pool, layer index as int32[1]). A per-layer pool
+    (`layer` None) is the stacked form with L = 1 and layer 0:
+    `arr[None]` is a bitcast, not a copy."""
+    if layer is None:
+        pages = {name: arr[None] for name, arr in pages.items()}
+        layer = 0
+    return pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def gather_pages(pages: Dict, table, layer=None) -> Dict:
     """Materialize the dense [B, M, KV, ...] view of each row's pages
-    (M = P * page_size). A pure read: XLA lowers it to a gather, no
-    pool mutation. Rows of `table` pointing at the trash page (or at
-    stale pages) surface garbage that the position mask must hide —
-    which it does, exactly (masked softmax columns are 0.0)."""
+    (M = P * page_size), of layer `layer` where the pool is stacked —
+    in ONE gather (`arr[layer, table]`), so no layer is sliced out
+    first. A pure read: XLA lowers it to a gather, no pool mutation.
+    Rows of `table` pointing at the trash page (or at stale pages)
+    surface garbage that the position mask must hide — which it does,
+    exactly (masked softmax columns are 0.0)."""
+    pages, layer = _stacked(pages, layer)
     out = {}
     for name, arr in pages.items():
-        g = arr[table]  # [B, P, page_size, KV, ...]
+        g = arr[layer[0], table]  # [B, P, page_size, KV, ...]
         out[name] = g.reshape((g.shape[0], -1) + g.shape[3:])
     return out
 
 
-def _reference(q, pages, table, lengths, scale):
+def _reference(q, pages, table, lengths, scale, layer=None):
     """The dense-bank formulation on the gathered view — kept
     OP-FOR-OP identical to models/decode.py::_cached_attention (same
     grouped einsum, same mask, same softmax axis) so the paged engine
     can be byte-compared against the dense oracle. q: [B, H, hd],
     single decode query per row at position lengths-1."""
-    view = gather_pages(pages, table)
+    view = gather_pages(pages, table, layer)
     k_cache, v_cache = view["k"], view["v"]
     if "k_scale" in view:
         k_cache = (
@@ -158,16 +178,17 @@ def _reference(q, pages, table, lengths, scale):
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(table_ref, len_ref,  # scalar-prefetch operands
+def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
                   q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr,
                   *, scale, page_size, num_pages, n_rep, quant):
     """Grid (B, P): one invocation attends query row b — every KV
-    head of it — over physical page table[b, p]. The page block is
-    the pool's own [page, KV, hd] slab: its last two dims ARE the
-    array's, which Mosaic's (8, 128) block rule accepts at any head
-    count (a block of ONE head on the KV axis, second to last, is
-    refused). KV heads sit on sublanes and head_dim on lanes, so the
+    head of it — over physical page table[b, p] of layer layer[0]
+    (the index map's business: the body never reads `layer_ref`).
+    The page block is the pool's own [page, KV, hd] slab: its last
+    two dims ARE the array's, which Mosaic's (8, 128) block rule
+    accepts at any head count (a block of ONE head on the KV axis,
+    second to last, is refused). KV heads sit on sublanes and head_dim on lanes, so the
     score is a multiply + lane reduction per rep-group member and the
     value sum a reduction over the page's cells — VPU/XLU work, which
     a one-row decode query cannot feed the MXU with anyway, and
@@ -188,13 +209,13 @@ def _paged_kernel(table_ref, len_ref,  # scalar-prefetch operands
 
     @pl.when(pi * page_size < length)
     def _compute():
-        k = k_ref[0][0].astype(jnp.float32)        # [page, KV, hd]
-        v = v_ref[0][0].astype(jnp.float32)
+        k = k_ref[0][0, 0].astype(jnp.float32)     # [page, KV, hd]
+        v = v_ref[0][0, 0].astype(jnp.float32)
         if quant:
             # int8 cells, [page, KV, 1] scales: the dequant multiply
             # runs on the VMEM-resident block — HBM traffic stays int8
-            k = k * k_ref[1][0][0].astype(jnp.float32)
-            v = v * v_ref[1][0][0].astype(jnp.float32)
+            k = k * k_ref[1][0][0, 0].astype(jnp.float32)
+            v = v * v_ref[1][0][0, 0].astype(jnp.float32)
         cells = pi * page_size + jax.lax.broadcasted_iota(
             jnp.int32, k.shape[:2] + (1,), 0
         )
@@ -221,29 +242,31 @@ def _paged_kernel(table_ref, len_ref,  # scalar-prefetch operands
             o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
 
 
-def _kernel(q, pages, table, lengths, scale):
-    """q [B, H, hd] → [B, H, hd]. The page table and lengths ride as
+def _kernel(q, pages, layer, table, lengths, scale):
+    """q [B, H, hd] → [B, H, hd] over layer `layer` (int32[1]) of the
+    stacked pool. The layer index, the page table and lengths ride as
     scalar-prefetch operands so the k/v BlockSpec index maps can
-    dereference table[b, p] — the pipeline then streams the PHYSICAL
-    pages, never a gathered copy. q travels rep-major
-    ([B, n_rep, KV, hd]) so one rep-group member is a [KV, hd] tile
-    laid out like a page cell."""
+    dereference (layer[0], table[b, p]) — the pipeline then streams
+    the PHYSICAL pages of that layer out of the whole pool, never a
+    sliced or gathered copy. q travels rep-major ([B, n_rep, KV, hd])
+    so one rep-group member is a [KV, hd] tile laid out like a page
+    cell."""
     b, h, hd = q.shape
-    n_pages, page_size, kv, _ = pages["k"].shape
+    page_size, kv = pages["k"].shape[2:4]
     n_rep = h // kv
     num_pages = table.shape[1]
     quant = "k_scale" in pages
     qg = q.reshape(b, kv, n_rep, hd).swapaxes(1, 2)
 
-    def q_map(bi, pi, tab, lens):
+    def q_map(bi, pi, lay, tab, lens):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, pi, tab, lens):
-        return (tab[bi, pi], 0, 0, 0)
+    def kv_map(bi, pi, lay, tab, lens):
+        return (lay[0], tab[bi, pi], 0, 0, 0)
 
     q_spec = pl.BlockSpec((1, n_rep, kv, hd), q_map)
-    kv_spec = pl.BlockSpec((1, page_size, kv, hd), kv_map)
-    sc_spec = pl.BlockSpec((1, page_size, kv, 1), kv_map)
+    kv_spec = pl.BlockSpec((1, 1, page_size, kv, hd), kv_map)
+    sc_spec = pl.BlockSpec((1, 1, page_size, kv, 1), kv_map)
     if quant:
         in_specs = [q_spec, (kv_spec, (sc_spec,)), (kv_spec, (sc_spec,))]
         operands = [
@@ -260,7 +283,7 @@ def _kernel(q, pages, table, lengths, scale):
         num_pages=num_pages, n_rep=n_rep, quant=quant,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, num_pages),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -279,27 +302,30 @@ def _kernel(q, pages, table, lengths, scale):
         ),
         interpret=fa._interpret(),
         name="paged_attention_decode",
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(
+        layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
+        *operands,
+    )
     return out.swapaxes(1, 2).reshape(b, h, hd)
 
 
-def _sharded_kernel(q, pages, table, lengths, scale, mesh):
+def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh):
     """`_kernel` shard_mapped over the serving mesh's "tp" axis: q
-    and the page pool split on their head axes, the page table and
-    lengths replicated (host-planned — every shard walks the same
-    pages, reading only its own KV-head slice of them). Attention is
-    per-KV-head local, so the body needs NO collectives, and the
-    kernel's grid/scratch shapes depend only on per-shard head
-    counts: output is byte-identical to the tp=1 kernel chunked by
-    head. Specs come from parallel/mesh.py:serving_head_specs, the
+    and the page pool split on their head axes, the layer index, the
+    page table and lengths replicated (host-planned — every shard
+    walks the same pages, reading only its own KV-head slice of
+    them). Attention is per-KV-head local, so the body needs NO
+    collectives, and the kernel's grid/scratch shapes depend only on
+    per-shard head counts: output is byte-identical to the tp=1
+    kernel chunked by head. Specs come from parallel/mesh.py:serving_head_specs, the
     one layout source."""
     from dlrover_tpu.parallel.mesh import serving_head_specs
 
     specs = serving_head_specs(mesh)
     rep = specs["replicated"]
 
-    def body(q, pages, table, lengths):
-        return _kernel(q, pages, table, lengths, scale)
+    def body(q, pages, layer, table, lengths):
+        return _kernel(q, pages, layer, table, lengths, scale)
 
     return fa.shard_map(
         body,
@@ -309,9 +335,10 @@ def _sharded_kernel(q, pages, table, lengths, scale, mesh):
             {name: specs["pool"] for name in pages},
             rep,
             rep,
+            rep,
         ),
         out_specs=specs["q1"],
-    )(q, pages, table, lengths)
+    )(q, pages, layer, table, lengths)
 
 
 def paged_attention(
@@ -322,11 +349,15 @@ def paged_attention(
     scale: Optional[float] = None,
     impl: str = "auto",
     mesh=None,
+    layer=None,
 ) -> jax.Array:
-    """Single-query attention over paged KV. impl: "reference" (the
-    dense-bank byte-parity formulation over a gathered view), "kernel"
-    (Pallas, pages streamed via scalar-prefetched table), or "auto"
-    (kernel when `use_kernel` passes, else reference).
+    """Single-query attention over paged KV. `pages` is the stacked
+    pool (`[L, n_pages, page_size, KV, ...]` leaves) with `layer` the
+    (traced) index of the layer to attend over, or one layer's pool
+    with `layer` None. impl: "reference" (the dense-bank byte-parity
+    formulation over a gathered view), "kernel" (Pallas, pages
+    streamed via scalar-prefetched table), or "auto" (kernel when
+    `use_kernel` passes, else reference).
 
     `mesh` (optional serving mesh with a "tp" axis) makes the kernel
     path dispatch shard_mapped over the head axes; the reference path
@@ -337,16 +368,15 @@ def paged_attention(
     from dlrover_tpu.parallel.mesh import serving_mesh_tp
 
     tp = serving_mesh_tp(mesh)
-    if impl == "reference":
-        return _reference(q, pages, table, lengths, scale)
-    if impl == "kernel":
-        if tp > 1:
-            return _sharded_kernel(q, pages, table, lengths, scale, mesh)
-        return _kernel(q, pages, table, lengths, scale)
-    if impl != "auto":
+    if impl not in ("reference", "kernel", "auto"):
         raise ValueError(f"unknown impl {impl!r}")
-    if use_kernel(q, pages, table, tp=tp):
-        if tp > 1:
-            return _sharded_kernel(q, pages, table, lengths, scale, mesh)
-        return _kernel(q, pages, table, lengths, scale)
-    return _reference(q, pages, table, lengths, scale)
+    if impl == "reference" or (
+        impl == "auto" and not use_kernel(q, pages, table, tp=tp)
+    ):
+        return _reference(q, pages, table, lengths, scale, layer)
+    pages, layer = _stacked(pages, layer)
+    if tp > 1:
+        return _sharded_kernel(
+            q, pages, layer, table, lengths, scale, mesh
+        )
+    return _kernel(q, pages, layer, table, lengths, scale)

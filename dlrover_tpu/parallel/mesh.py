@@ -330,8 +330,8 @@ def serving_head_specs(mesh: Mesh) -> Dict[str, PartitionSpec]:
       axis (dim 2) split, everything else shard-local.
     - ``"q1"``: the single-token decode query ``[B, H, hd]`` — head
       axis at dim 1.
-    - ``"pool"``: a per-layer page-pool array ``[pages, page_size,
-      KV, hd]`` (scales ride with hd==1) — KV head axis at dim 2.
+    - ``"pool"``: a stacked page-pool array ``[L, pages, page_size,
+      KV, hd]`` (scales ride with hd==1) — KV head axis at dim 3.
     - ``"replicated"``: host-planned operands (page tables, lengths)
       every shard reads whole.
 
@@ -348,7 +348,7 @@ def serving_head_specs(mesh: Mesh) -> Dict[str, PartitionSpec]:
     return {
         "qkv": PartitionSpec(None, None, ax, None),
         "q1": PartitionSpec(None, ax, None),
-        "pool": PartitionSpec(None, None, ax, None),
+        "pool": PartitionSpec(None, None, None, ax, None),
         "replicated": PartitionSpec(),
     }
 

@@ -228,7 +228,7 @@ class TestDispatchGates:
         specs = serving_head_specs(mesh2)
         assert tuple(specs["qkv"]) == (None, None, "tp", None)
         assert tuple(specs["q1"]) == (None, "tp", None)
-        assert tuple(specs["pool"]) == (None, None, "tp", None)
+        assert tuple(specs["pool"]) == (None, None, None, "tp", None)
         assert tuple(specs["replicated"]) == ()
 
 

@@ -149,3 +149,95 @@ def test_unknown_impl_rejected():
             q, pages, jnp.zeros((1, 2), jnp.int32),
             jnp.ones((1,), jnp.int32), impl="nope",
         )
+
+
+def _stacked_case(quant):
+    """A stacked pool [3, n_pages, page_size, KV, hd] of distinct
+    layers (bf16, or int8 with bf16 scales), with a bf16 query, a
+    table and lengths that end mid-page."""
+    rng = np.random.default_rng(6)
+    dtype = jnp.bfloat16
+    b, h, kv, hd, page_size, n_pages, per_row = 3, 4, 2, 32, 16, 9, 4
+    layers = [
+        _pool(rng, n_pages, page_size, kv, hd, quant=quant)
+        for _ in range(3)
+    ]
+    pool = {
+        name: jnp.stack([lp[name] for lp in layers])
+        for name in layers[0]
+    }
+    if not quant:
+        pool = {name: arr.astype(dtype) for name, arr in pool.items()}
+    q = jnp.asarray(rng.standard_normal((b, h, hd)), dtype)
+    table = jnp.asarray(
+        rng.integers(1, n_pages, size=(b, per_row)), jnp.int32
+    )
+    lengths = jnp.asarray([1, 37, per_row * page_size], jnp.int32)
+    return q, pool, table, lengths
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_stacked_pool_at_layer_equals_that_layer_alone(
+    quant, impl, layer
+):
+    """The forward hands the kernel the WHOLE pool and a traced layer
+    index; that must be, bit for bit, the kernel on `pool[layer]`."""
+    q, pool, table, lengths = _stacked_case(quant)
+    alone = pa.paged_attention(
+        q, {name: arr[layer] for name, arr in pool.items()},
+        table, lengths, impl=impl,
+    )
+    stacked = jax.jit(
+        lambda q, pool, table, lengths, l: pa.paged_attention(
+            q, pool, table, lengths, impl=impl, layer=l
+        )
+    )(q, pool, table, lengths, jnp.int32(layer))
+    assert stacked.dtype == alone.dtype
+    np.testing.assert_array_equal(
+        np.asarray(stacked, np.float32), np.asarray(alone, np.float32)
+    )
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_write_at_layer_leaves_other_layers_bytes(quant, layer):
+    """`_write_pages_and_attend` scatters a step's rows into layer
+    `layer` of the stacked pool: every leaf of every other layer keeps
+    its bytes, the written cells hold the step's K/V (quantized as the
+    dense path quantizes), and the rest of that layer is untouched."""
+    from dlrover_tpu.models import decode
+
+    q, pool, table, lengths = _stacked_case(quant)
+    b, _, hd = q.shape
+    kv = pool["k"].shape[3]
+    rng = np.random.default_rng(7)
+    k_new = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), q.dtype)
+    v_new = jnp.asarray(rng.standard_normal((b, 1, kv, hd)), q.dtype)
+    # three distinct pages, so no two rows write one cell
+    table = table.at[:, 0].set(jnp.asarray([1, 2, 3], jnp.int32))
+    positions = jnp.asarray([[0], [5], [15]], jnp.int32)
+    _, out = jax.jit(
+        lambda pool, l: decode._write_pages_and_attend(
+            q[:, None], k_new, v_new, pool, l, table, positions, hd
+        )
+    )(pool, jnp.int32(layer))
+    if quant:
+        kq, ks = decode._kv_quantize(k_new)
+        vq, vs = decode._kv_quantize(v_new)
+        wrote = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        wrote = {"k": k_new, "v": v_new}
+    assert set(out) == set(pool)
+    for name, before in pool.items():
+        after = np.asarray(out[name].astype(jnp.float32))
+        expect = np.asarray(before.astype(jnp.float32)).copy()
+        for row in range(b):
+            expect[layer, row + 1, int(positions[row, 0])] = np.asarray(
+                wrote[name][row, 0].astype(before.dtype).astype(
+                    jnp.float32
+                )
+            )
+        assert out[name].dtype == before.dtype
+        np.testing.assert_array_equal(after, expect, err_msg=name)

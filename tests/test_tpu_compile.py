@@ -18,6 +18,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -70,12 +71,17 @@ def chip(topo):
 S = jax.ShapeDtypeStruct
 
 
+def _on_chip(chip, shapes):
+    """A pytree of ShapeDtypeStructs, placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda s: S(s.shape, s.dtype, sharding=chip), shapes
+    )
+
+
 def _compile(chip, fn, *shapes):
     """Compile `fn` for the described chip; `shapes` are pytrees of
     ShapeDtypeStructs, placed on the chip here. Returns the text."""
-    args = jax.tree_util.tree_map(
-        lambda s: S(s.shape, s.dtype, sharding=chip), shapes
-    )
+    args = _on_chip(chip, shapes)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
     return text
@@ -136,12 +142,23 @@ def test_flash_under_a_training_mesh(topo):
 
 
 @pytest.mark.parametrize(
-    "kv_heads,quant",
-    [(32, False), (32, True), (8, False)],
-    ids=["mha-bf16", "mha-int8", "gqa8-bf16"],
+    "kv_heads,quant,stack",
+    [
+        (32, False, None), (32, True, None), (8, False, None),
+        (8, False, (3, 2)), (8, True, (3, 2)),
+    ],
+    ids=["mha-bf16", "mha-int8", "gqa8-bf16", "gqa8-bf16-stacked",
+         "gqa8-int8-stacked"],
 )
-def test_paged_decode(chip, kv_heads, quant):
+def test_paged_decode(chip, kv_heads, quant, stack):
+    """`stack` = (L, layer): the pool as the forward hands it over,
+    stacked over layers and addressed at one of them; None is one
+    layer's pool, the same kernel at L = 1."""
     cell = (N_PAGES, PAGE, kv_heads, CFG.head_dim)
+    layer = None
+    if stack is not None:
+        cell = (stack[0],) + cell
+        layer = stack[1]
     if quant:
         pool = {
             "k": S(cell, jnp.int8), "v": S(cell, jnp.int8),
@@ -155,9 +172,66 @@ def test_paged_decode(chip, kv_heads, quant):
     assert pa.supports(q, pool, table)
     _compile(
         chip,
-        functools.partial(pa.paged_attention, impl="kernel"),
+        functools.partial(
+            pa.paged_attention, impl="kernel", layer=layer
+        ),
         q, pool, table, S((SLOTS,), jnp.int32),
     )
+
+
+def test_paged_chunk_program_walks_pool_in_place(chip, monkeypatch):
+    """The serving engine's own paged chunk program (8 decode steps a
+    dispatch) at Mistral-7B-v0.3 widths, 48 slots x 1536 positions,
+    16-cell pages, 4 layers: the layer loop carries the stacked pool
+    and each layer addresses its part by index, so the compiled
+    program holds no slice of a layer out of the pool, no restack and
+    no copy of the pool — and far less than one leaf of temporaries."""
+    import re
+
+    from dlrover_tpu.models import decode, llama
+    from dlrover_tpu.serving import engine
+
+    # the dispatch gate asks jax.default_backend(), which is the CPU
+    # here: steer it from the test, as the topo fixture steers
+    # _interpret
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    slots, max_len, chunk, layers = 48, 1536, 8, 4
+    cfg = LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=layers, n_heads=32,
+        n_kv_heads=8, mlp_dim=14336, max_seq_len=max_len,
+        rope_theta=1e6, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    n_pages = slots * (max_len // PAGE) + 1
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    pool = jax.eval_shape(
+        lambda: decode.init_page_pool(cfg, n_pages, PAGE)
+    )
+    i32 = S((slots,), jnp.int32)
+    program = engine._build_chunk_program(cfg, -1, None, 0.0, 0, 1.0)
+    # (pool, table, params, tok, pos, done, limit, keys), then k
+    args = _on_chip(chip, (
+        pool, S((slots, max_len // PAGE), jnp.int32), params,
+        i32, i32, S((slots,), jnp.bool_), i32,
+        S((slots, 2), jnp.uint32),
+    ))
+    compiled = program["paged"].lower(*args, chunk).compile()
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text
+    leaf = tuple(pool["k"].shape)
+    pool_sized = {leaf, leaf[1:], (1,) + leaf[1:]}
+    moved = [
+        m.group(0)
+        for m in re.finditer(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* "
+            r"(dynamic-slice|dynamic-update-slice|copy)\(", text
+        )
+        if tuple(int(d) for d in m.group(2).split(",")) in pool_sized
+    ]
+    assert not moved, moved
+    leaf_bytes = 2 * np.prod(leaf)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
 
 
 def _int8_matmul(chip, rows, k, o):
