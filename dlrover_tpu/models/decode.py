@@ -44,6 +44,90 @@ def _mesh_tp(mesh) -> int:
     )
 
 
+# ---------------------------------------------------------------------------
+# Layers in PERIODS. A model whose layers differ in kind (window and
+# full attention mixed) repeats one period of kinds; the layer loops
+# below scan over periods and write the period's layers out inside the
+# body, each with its own static kind. A homogeneous model is a period
+# of one: the stacked leaves go through the scan as they are.
+# ---------------------------------------------------------------------------
+
+_EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+def _kinds(cfg) -> Tuple:
+    """The period's kinds, or (None,) for a homogeneous model (None:
+    the block takes the config's one rotary base and no window)."""
+    pattern = getattr(cfg, "layer_pattern", ())
+    return tuple(pattern) if pattern else (None,)
+
+
+def _by_period(cfg, tree):
+    """Leaves `[L, ...]` -> `[n_periods, period, ...]` (a bitcast)."""
+    n = len(_kinds(cfg))
+    if n == 1:
+        return tree
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), tree
+    )
+
+
+def _place(cfg, tree, j: int):
+    """The j-th layer of one period's slice of the leaves."""
+    if len(_kinds(cfg)) == 1:
+        return tree
+    return jax.tree_util.tree_map(lambda a: a[j], tree)
+
+
+def _stack_places(cfg, trees):
+    if len(trees) == 1:
+        return trees[0]
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
+
+
+def _dropless(cfg) -> bool:
+    return (
+        getattr(cfg, "n_experts", 0) > 0
+        and getattr(cfg, "moe_routing", "capacity") == "dropless"
+    )
+
+
+def _split_experts(cfg, layers):
+    """(the leaves the layer loop scans over, the experts' stacks).
+    Dropless experts stay OUT of the scan's xs: the grouped kernel
+    addresses `[L, E, ...]` by the layer's index, so no loop body ever
+    slices a layer's experts out of the stack."""
+    if not _dropless(cfg):
+        return layers, None
+    experts = {k: layers[k] for k in _EXPERT_LEAVES}
+    rest = {k: v for k, v in layers.items() if k not in experts}
+    return rest, experts
+
+
+def _window_of(cfg, kind) -> int:
+    return cfg.sliding_window if kind == "window" else 0
+
+
+def _ffn_residual(cfg, x, layer_params, lp, tp, layer, experts):
+    """The feed-forward half of a served block: llama's own
+    `_mlp_residual`, or where the configuration routes without
+    dropping, `moe.dropless_moe` over the stacked experts. Returns
+    (x, int32[E] routed pairs per expert, or None)."""
+    if experts is None:
+        x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
+        return x, None
+    from dlrover_tpu.models.moe import dropless_moe
+
+    b, s, d = x.shape
+    h = _rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
+    y, counts = dropless_moe(
+        h.reshape(b * s, d), layer_params["router"],
+        experts["we_gate"], experts["we_up"], experts["we_down"],
+        cfg.moe_top_k, layer=layer,
+    )
+    return x + y.reshape(b, s, d), counts
+
+
 # Why byte parity survives head sharding (the tp>1 oracle of
 # tests/test_serving_mesh.py): only OUTPUT dimensions of matmuls are
 # ever sharded — the QKV projections split their head/output columns,
@@ -107,7 +191,7 @@ def _kv_quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def _cached_attention(q, layer_cache, q_positions, scale):
+def _cached_attention(q, layer_cache, q_positions, scale, window=0):
     """q [B,S,H,hd] attends over the whole cache [B,M,KV,hd] under the
     causal position mask (cache col j visible to query at position p
     iff j <= p). Unwritten cache slots are masked out by the same rule.
@@ -136,7 +220,13 @@ def _cached_attention(q, layer_cache, q_positions, scale):
     ) * scale
     cols = jnp.arange(m)[None, None, None, None, :]   # [1,1,1,1,M]
     rows = q_positions[:, None, None, :, None]        # [B,1,1,S,1]
-    scores = jnp.where(cols <= rows, scores, -jnp.inf)
+    if window:
+        # a window layer: query at position p sees p - window < j <= p
+        scores = jnp.where(
+            (cols <= rows) & (cols > rows - window), scores, -jnp.inf
+        )
+    else:
+        scores = jnp.where(cols <= rows, scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkrsm,bmkd->bskrd", p, v_cache)
     return out.reshape(b, s, h, hd)
@@ -168,6 +258,7 @@ def _write_cache_and_attend(
     attn_impl: str = "auto",
     plain_causal: bool = False,
     mesh=None,
+    window: int = 0,
 ):
     """THE decode-specific core, shared by both family blocks: write
     this chunk's K/V into the cache at `start` and attend over the
@@ -227,11 +318,12 @@ def _write_cache_and_attend(
         impl = "reference" if attn_impl == "reference" else "auto"
         attn = dot_product_attention(
             q, k, v, causal=True, impl=impl, tp=_mesh_tp(mesh),
-            mesh=mesh,
+            mesh=mesh, window=window,
         )
     else:
         attn = _cached_attention(
-            q, out_cache, positions, float(head_dim) ** -0.5
+            q, out_cache, positions, float(head_dim) ** -0.5,
+            window=window,
         )
     attn = constrain(attn, mesh)
     return attn, out_cache
@@ -247,6 +339,9 @@ def _block(
     plain_causal: bool = False,
     mesh=None,
     lora=None,               # (bank slices, idx, scale) or None
+    kind=None,               # "full" | "window" | None (homogeneous)
+    layer=None,              # this block's index among all layers
+    experts=None,            # stacked dropless experts, or None
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decoder block writing its K/V into the cache. Prefill is
     S=prompt_len/start=0; decode is S=1/start=pos. The projections,
@@ -259,20 +354,23 @@ def _block(
     serving (see `_forward_cached`)."""
     lp = _compute_weights(cfg, layer_params)
     tp = _mesh_tp(mesh)
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn" if kind is None else "attn_" + kind):
         h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(
-            cfg, None, h, lp, positions, lora=lora, tp=tp
+            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind
         )
         attn, layer_cache = _write_cache_and_attend(
             q, k, v, layer_cache, positions, start, cfg.head_dim,
             attn_impl=getattr(cfg, "attn_impl", "auto"),
             plain_causal=plain_causal,
             mesh=mesh,
+            window=_window_of(cfg, kind),
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):
-        x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
+        x, _counts = _ffn_residual(
+            cfg, x, layer_params, lp, tp, layer, experts
+        )
     return x, layer_cache
 
 
@@ -282,6 +380,7 @@ def _block_gpt(
     mesh=None,
     lora=None,  # rejected upstream (_check_adapters); kept for the
                 # shared block-call signature
+    kind=None, layer=None, experts=None,  # llama-only; likewise
 ):
     """GPT-2 pre-LN block with cache write — built from gpt.py's own
     helpers; the cache write + masked attention are the only
@@ -358,32 +457,52 @@ def _forward_cached(
         x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
         block = _block
 
+    kinds = _kinds(cfg)
+    scanned_params, experts = _split_experts(cfg, params["layers"])
+
     def body(carry, inp):
+        # one PERIOD of layers, written out; a homogeneous model's
+        # period is its one layer
         h = carry
-        if adapters is None:
-            layer_params, layer_cache = inp
+        period_params, period_cache, first, period_bank = inp
+        caches = []
+        for j, kind in enumerate(kinds):
             lora = None
-        else:
-            layer_params, layer_cache, layer_bank = inp
-            lora = (layer_bank, adapters["idx"], adapters["scale"])
-        h, layer_cache = block(
-            cfg, h, layer_params, layer_cache, positions, start,
-            plain_causal=plain_causal,
-            mesh=mesh,
-            lora=lora,
-        )
-        return h, layer_cache
+            if adapters is not None:
+                lora = (
+                    _place(cfg, period_bank, j), adapters["idx"],
+                    adapters["scale"],
+                )
+            h, layer_cache = block(
+                cfg, h, _place(cfg, period_params, j),
+                _place(cfg, period_cache, j), positions, start,
+                plain_causal=plain_causal,
+                mesh=mesh,
+                lora=lora,
+                kind=kind,
+                layer=first + j,
+                experts=experts,
+            )
+            caches.append(layer_cache)
+        return h, _stack_places(cfg, caches)
 
     # the cache dict scans as a pytree: each layer body sees its own
     # {"k","v"[,"k_scale","v_scale"]} slice and emits the updated one
+    n_layers = cache["k"].shape[0]
     xs = (
-        (params["layers"], dict(cache))
-        if adapters is None
-        else (params["layers"], dict(cache), dict(adapters["bank"]))
+        _by_period(cfg, scanned_params),
+        _by_period(cfg, dict(cache)),
+        jnp.arange(0, n_layers, len(kinds), dtype=jnp.int32),
+        None if adapters is None
+        else _by_period(cfg, dict(adapters["bank"])),
     )
     with jax.named_scope("layers"):
         x, scanned = jax.lax.scan(body, x, xs)
     cache_new = scanned
+    if len(kinds) > 1:
+        cache_new = jax.tree_util.tree_map(
+            lambda a: a.reshape((n_layers,) + a.shape[2:]), scanned
+        )
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 
@@ -830,6 +949,32 @@ def init_page_pool(
     }
 
 
+def init_hybrid_pools(
+    cfg, n_pages_full: int, n_pages_window: int, page_size: int
+) -> Dict[str, Dict[str, jax.Array]]:
+    """Two CLASSES of pages for a model that mixes window and full
+    layers: `pool["full"]` `[L_full, n_pages_full, page, KV, hd]` keeps
+    every position of its slots, `pool["window"]` `[L_win,
+    n_pages_window, ...]` only the pages that still hold one of a
+    slot's last `sliding_window` positions (a ring a slot: logical
+    page p at ring entry p % R; the host frees the pages behind the
+    window between dispatches, serving/engine.py). Page 0 of each
+    class is its trash page."""
+    kv_heads, hd = cfg.n_kv_heads, cfg.head_dim
+
+    def one(kind, n_pages):
+        shape = (cfg.layers_of(kind), n_pages, page_size, kv_heads, hd)
+        return {
+            "k": jnp.zeros(shape, cfg.dtype),
+            "v": jnp.zeros(shape, cfg.dtype),
+        }
+
+    return {
+        "full": one("full", n_pages_full),
+        "window": one("window", n_pages_window),
+    }
+
+
 def _paged_view(
     pool: Dict[str, jax.Array], layer, table: jax.Array
 ) -> Dict[str, jax.Array]:
@@ -847,7 +992,7 @@ def _paged_view(
 
 def _write_pages_and_attend(
     q, k, v, pool, layer, table, positions, head_dim, mesh=None,
-    attn_impl: str = "auto",
+    attn_impl: str = "auto", window: int = 0,
 ):
     """The paged counterpart of `_write_cache_and_attend`, on the
     STACKED pool and a traced layer index: scatter this chunk's K/V
@@ -869,7 +1014,20 @@ def _write_pages_and_attend(
     k = constrain(k, mesh, None, None, SERVING_TP_AXIS, None)
     v = constrain(v, mesh, None, None, SERVING_TP_AXIS, None)
     ps = pool["k"].shape[2]
-    pids = jnp.take_along_axis(table, positions // ps, axis=1)
+    if window:
+        # a window layer's table is the slot's RING: logical page p
+        # lives at entry p % R (the host keeps the entries of the
+        # pages inside the window, serving/engine.py)
+        if q.shape[1] != 1 or "k_scale" in pool:
+            raise NotImplementedError(
+                "window layers over paged KV serve one query a slot "
+                "from an unquantized pool"
+            )
+        pids = jnp.take_along_axis(
+            table, (positions // ps) % table.shape[1], axis=1
+        )
+    else:
+        pids = jnp.take_along_axis(table, positions // ps, axis=1)
     offs = positions % ps
     out_pool = dict(pool)
     if "k_scale" in pool:
@@ -885,6 +1043,21 @@ def _write_pages_and_attend(
                 upd.astype(arr.dtype)
             )
     s = q.shape[1]
+    if window:
+        from dlrover_tpu.ops import paged_attention as pa
+
+        q1 = q[:, 0]
+        takes = attn_impl != "reference" and pa.use_kernel(
+            q1, out_pool, table, tp=_mesh_tp(mesh)
+        )
+        with jax.named_scope("paged_attn"):
+            attn = pa.paged_attention(
+                q1, out_pool, table, positions[:, 0] + 1,
+                scale=float(head_dim) ** -0.5,
+                impl="kernel" if takes else "reference",
+                mesh=mesh, layer=layer, window=window,
+            )
+        return constrain(attn[:, None], mesh), out_pool
     # attn_impl='reference' is the byte-parity oracle knob: it pins
     # the gathered-view formulation even where use_kernel would take
     # the Pallas path (real TPU, or forced interpret kernels)
@@ -912,7 +1085,7 @@ def _write_pages_and_attend(
 
 def _block_paged(
     cfg, x, layer_params, pool, layer, table, positions, mesh=None,
-    lora=None,
+    lora=None, kind=None, abs_layer=None, experts=None,
 ):
     """Llama block over paged KV — identical projections/residuals to
     `_block` (including the per-slot `lora` deltas); only the cache
@@ -920,24 +1093,30 @@ def _block_paged(
     this block's (traced) index into it."""
     lp = _compute_weights(cfg, layer_params)
     tp = _mesh_tp(mesh)
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn" if kind is None else "attn_" + kind):
         h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(
-            cfg, None, h, lp, positions, lora=lora, tp=tp
+            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind
         )
         attn, pool = _write_pages_and_attend(
             q, k, v, pool, layer, table, positions, cfg.head_dim,
             mesh=mesh,
             attn_impl=getattr(cfg, "attn_impl", "auto"),
+            window=_window_of(cfg, kind),
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):
-        x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
-    return x, pool
+        x, counts = _ffn_residual(
+            cfg, x, layer_params, lp, tp, abs_layer, experts
+        )
+    if experts is None:
+        return x, pool
+    return x, pool, counts
 
 
 def _block_gpt_paged(
-    cfg, x, lp, pool, layer, table, positions, mesh=None, lora=None
+    cfg, x, lp, pool, layer, table, positions, mesh=None, lora=None,
+    kind=None, abs_layer=None, experts=None,  # llama-only
 ):
     from dlrover_tpu.models import gpt
 
@@ -955,14 +1134,23 @@ def _block_gpt_paged(
 
 def _forward_paged(
     cfg, params, tokens, pool, table, positions, mesh=None,
-    adapters=None,
+    adapters=None, table_win=None,
 ):
     """tokens [B, S] → logits [B, S, V] over the paged pool. The
     pool goes through the layer scan as a CARRY, whole and stacked
-    (`[L, n_pages, page_size, KV, hd]` leaves); the scan's xs are the
-    layers' parameters, their indices and the optional `adapters`
-    bank, and each layer writes and reads its own part of the pool
-    by its index. The table is shared by every layer."""
+    (`[L, n_pages, page_size, KV, hd]` leaves); the scan runs over
+    PERIODS of layers (a homogeneous model: periods of one), its xs
+    are the periods' parameters, their first layers' indices and the
+    optional `adapters` bank, and each layer writes and reads its own
+    part of the pool by its index. The table is shared by every layer.
+
+    A model that mixes window and full layers brings two classes of
+    pages, `pool["full"]` and `pool["window"]`
+    (`init_hybrid_pools`), each carried whole, and a second table:
+    `table_win`, the slots' rings. A layer's index into its class's
+    pool is its rank among the layers of its kind. Where the experts
+    are routed without dropping, a third value comes back: int32[E],
+    the routed pairs per expert summed over the layers."""
     _check_adapters(cfg, adapters)
     gpt = _is_gpt(cfg)
     if gpt:
@@ -974,30 +1162,69 @@ def _forward_paged(
     else:
         x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
         block = _block_paged
+    kinds = _kinds(cfg)
+    classed = "k" not in pool  # {"full": {...}, "window": {...}}
+    scanned_params, experts = _split_experts(cfg, params["layers"])
+    # a layer's rank among its kind inside the period, and how many
+    # of its kind a period holds
+    rank = [kinds[:j].count(kind) for j, kind in enumerate(kinds)]
+    per_period = {kind: kinds.count(kind) for kind in kinds}
 
     def body(carry, inp):
-        h, pool = carry
+        h, pool, counts = carry
         if adapters is None:
-            layer_params, layer = inp
-            lora = None
+            period_params, first = inp
         else:
-            layer_params, layer, layer_bank = inp
-            lora = (layer_bank, adapters["idx"], adapters["scale"])
-        h, pool = block(
-            cfg, h, layer_params, pool, layer, table, positions,
-            mesh=mesh,
-            lora=lora,
-        )
-        return (h, pool), None
+            period_params, first, period_bank = inp
+        period = first // len(kinds)
+        for j, kind in enumerate(kinds):
+            lora = None
+            if adapters is not None:
+                lora = (
+                    _place(cfg, period_bank, j), adapters["idx"],
+                    adapters["scale"],
+                )
+            kw = dict(kind=kind, abs_layer=first + j, experts=experts)
+            if classed:
+                out = block(
+                    cfg, h, _place(cfg, period_params, j), pool[kind],
+                    period * per_period[kind] + rank[j],
+                    table_win if kind == "window" else table,
+                    positions, mesh=mesh, lora=lora, **kw,
+                )
+                pool = dict(pool, **{kind: out[1]})
+            else:
+                out = block(
+                    cfg, h, _place(cfg, period_params, j), pool,
+                    first + j, table, positions, mesh=mesh, lora=lora, **kw,
+                )
+                pool = out[1]
+            h = out[0]
+            if experts is not None:
+                counts = counts + out[2]
+        return (h, pool, counts), None
 
-    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
+    n_layers = jax.tree_util.tree_leaves(scanned_params)[0].shape[0]
+    firsts = jnp.arange(0, n_layers, len(kinds), dtype=jnp.int32)
     xs = (
-        (params["layers"], layers)
+        (_by_period(cfg, scanned_params), firsts)
         if adapters is None
-        else (params["layers"], layers, dict(adapters["bank"]))
+        else (
+            _by_period(cfg, scanned_params), firsts,
+            _by_period(cfg, dict(adapters["bank"])),
+        )
+    )
+    counts0 = (
+        jnp.zeros((cfg.n_experts,), jnp.int32)
+        if experts is not None else None
+    )
+    carry0 = (
+        x,
+        {c: dict(p) for c, p in pool.items()} if classed else dict(pool),
+        counts0,
     )
     with jax.named_scope("layers"):
-        (x, pool_new), _ = jax.lax.scan(body, (x, dict(pool)), xs)
+        (x, pool_new, counts), _ = jax.lax.scan(body, carry0, xs)
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 
@@ -1011,24 +1238,29 @@ def _forward_paged(
         )
         head = _head_matrix(cfg, params)
     logits = matmul_any(x, head, tp=_mesh_tp(mesh)).astype(jnp.float32)
+    if experts is not None:
+        return logits, pool_new, counts
     return logits, pool_new
 
 
 def paged_decode_step(
     cfg, params, token: jax.Array, pool, table, pos, mesh=None,
-    adapters=None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    adapters=None, table_win=None,
+):
     """One cached step over paged KV → (logits [B, V], pool). The
     paged twin of `decode_step` ([B] per-slot positions only — the
-    paged layout exists for continuous batching)."""
+    paged layout exists for continuous batching). `table_win`: the
+    slots' rings, where the pool has a window class; dropless
+    experts add their int32[E] routed pairs as a third value."""
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None]
-    logits, pool = _forward_paged(
+    logits, *rest = _forward_paged(
         cfg, params, token[:, None], pool, table, positions,
         mesh=mesh,
         adapters=adapters,
+        **({} if table_win is None else {"table_win": table_win}),
     )
-    return logits[:, 0], pool
+    return (logits[:, 0], *rest)
 
 
 def paged_verify_step(
@@ -1042,7 +1274,7 @@ def paged_verify_step(
     b, s = tokens.shape
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    logits, pool = _forward_paged(
+    logits, pool, *_counts = _forward_paged(
         cfg, params, tokens, pool, table, positions, mesh=mesh,
         adapters=adapters,
     )
@@ -1142,6 +1374,54 @@ def paged_install_row(
     return out
 
 
+def paged_install_hybrid(
+    cfg,
+    pools: Dict[str, Dict[str, jax.Array]],
+    row_cache: Dict[str, jax.Array],   # [L, 1, M, KV, hd], all layers
+    table_row_full: jax.Array,         # [P] the slot's full-class pages
+    table_row_win: jax.Array,          # [R] the slot's ring
+    prompt_len,                        # traced: the prompt's TRUE length
+    length: int,                       # STATIC: the prompt's bucket
+) -> Dict[str, Dict[str, jax.Array]]:
+    """Install a prefilled row into both classes of pages. The full
+    layers' cells [0, length) go where `paged_install_row` puts them.
+    The window layers keep only the prompt's last `sliding_window`
+    positions: cells [start, start + n) with n = min(window, length)
+    and start = max(prompt_len - n, 0), each into ring entry
+    (cell // page) % R. Cells at or past `prompt_len` (the bucket's
+    pad tail) land in pages the slot owns ahead of its frontier, where
+    the length mask hides them until decode overwrites them, or in the
+    trash page (the host leaves unowned entries at 0)."""
+    kinds = cfg.period
+    n_layers = row_cache["k"].shape[0]
+    layers_of = {
+        kind: [i for i in range(n_layers) if kinds[i % len(kinds)] == kind]
+        for kind in ("full", "window")
+    }
+    ps = pools["full"]["k"].shape[2]
+    ring = table_row_win.shape[0]
+    n = min(cfg.sliding_window, length)
+    start = jnp.maximum(jnp.asarray(prompt_len, jnp.int32) - n, 0)
+    cells = {
+        "full": jnp.arange(length, dtype=jnp.int32),
+        "window": start + jnp.arange(n, dtype=jnp.int32),
+    }
+    pids = {
+        "full": table_row_full[cells["full"] // ps],
+        "window": table_row_win[(cells["window"] // ps) % ring],
+    }
+    out = {}
+    for kind, pool in pools.items():
+        idx = jnp.asarray(layers_of[kind], jnp.int32)
+        out[kind] = {}
+        for name, arr in pool.items():
+            src = row_cache[name][idx, 0][:, cells[kind]]  # [Lk, n, KV, hd]
+            out[kind][name] = arr.at[
+                :, pids[kind], cells[kind] % ps
+            ].set(src.astype(arr.dtype))
+    return out
+
+
 def paged_prefill_chunk(
     cfg,
     params,
@@ -1166,7 +1446,7 @@ def paged_prefill_chunk(
     every chunk position maps to an owned page."""
     c = chunk.shape[0]
     positions = (jnp.asarray(start, jnp.int32) + jnp.arange(c))[None]
-    _, pool = _forward_paged(
+    _, pool, *_counts = _forward_paged(
         cfg, params, chunk[None], pool, table_row[None], positions,
         mesh=mesh,
         adapters=adapters,

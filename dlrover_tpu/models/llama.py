@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.ops.attention import dot_product_attention
@@ -28,6 +29,25 @@ from dlrover_tpu.parallel.remat import checkpoint_name
 from dlrover_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
+
+# kinds of layer a `layer_pattern` may name: "full" attends every
+# earlier position, "window" the last `sliding_window` of them
+LAYER_KINDS = ("full", "window")
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary parameters of one kind of layer. `yarn_factor` 0 is the
+    plain form; otherwise the static YaRN form (`yarn_frequencies`):
+    applied at every length, cos and sin scaled by
+    `attention_factor` (0 = 0.1 ln(factor) + 1)."""
+
+    theta: float = 10000.0
+    yarn_factor: float = 0.0
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +88,22 @@ class LlamaConfig:
     n_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # the router (the experts' SwiGLU width is mlp_dim): "capacity"
+    # is the GShard training layer (drops tokens over capacity);
+    # "dropless" sorts the (token, expert) pairs by expert and
+    # multiplies group by group (moe.dropless_moe): softmax before
+    # the top-k, no token dropped at any load. Serving only.
+    moe_routing: str = "capacity"
+    # width of a head where it is not dim // n_heads (0 = that)
+    attn_head_dim: int = 0
+    # one PERIOD of layer kinds, repeated n_layers / len times
+    # (() = every layer "full"); a "window" layer's query i sees keys
+    # j with i - sliding_window < j <= i
+    layer_pattern: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # rotary parameters per kind of layer (None = plain, rope_theta)
+    rope_full: Optional[RopeSpec] = None
+    rope_window: Optional[RopeSpec] = None
     # GPipe microbatch count when the mesh has a live "pipe" axis
     # (0 → default to the pipe degree)
     pipeline_microbatches: int = 0
@@ -88,7 +124,51 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The layer kinds of one period; a homogeneous model is a
+        period of one full layer."""
+        return self.layer_pattern or ("full",)
+
+    @property
+    def hybrid(self) -> bool:
+        """Whether some layer keeps less than every position."""
+        return "window" in self.layer_pattern
+
+    def layers_of(self, kind: str) -> int:
+        return (
+            self.n_layers // len(self.period)
+            * self.period.count(kind)
+        )
+
+    def rope_of(self, kind: str) -> RopeSpec:
+        spec = self.rope_window if kind == "window" else self.rope_full
+        return spec or RopeSpec(theta=self.rope_theta)
+
+    def __post_init__(self):
+        bad = [k for k in self.layer_pattern if k not in LAYER_KINDS]
+        if bad:
+            raise ValueError(
+                f"layer_pattern names unknown kinds {bad}; known: "
+                f"{LAYER_KINDS}"
+            )
+        if self.layer_pattern and self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is not a whole number of "
+                f"periods of {len(self.layer_pattern)} layers"
+            )
+        if self.hybrid and self.sliding_window < 1:
+            raise ValueError(
+                "a layer_pattern with window layers needs "
+                "sliding_window >= 1"
+            )
+        if self.moe_routing not in ("capacity", "dropless"):
+            raise ValueError(
+                f"moe_routing must be 'capacity' or 'dropless', got "
+                f"{self.moe_routing!r}"
+            )
 
     # ---- presets (sizes follow the reference's benchmark configs) ----
     @classmethod
@@ -161,7 +241,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         from dlrover_tpu.models.moe import init_moe_mlp
 
         mlp_weights = init_moe_mlp(
-            ks[7], cfg.moe, D, M, n_layers=L, param_dtype=pd
+            ks[7], cfg.moe, D, M, n_layers=L,
+            param_dtype=pd,
         )
     else:
         mlp_weights = {
@@ -245,15 +326,54 @@ def _rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x32 * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding on [B, S, H, D]."""
-    d = x.shape[-1]
-    freqs = jnp.exp(
-        -math.log(theta) * jnp.arange(0, d, 2, dtype=jnp.float32) / d
+def yarn_frequencies(spec: RopeSpec, d: int):
+    """(inverse frequencies [d/2] as a numpy array, cos/sin factor)
+    of one kind of layer. Plain: theta^(-2i/d) and 1. YaRN (static
+    form): between the dimension that turns `beta_fast` times over
+    `original_len` positions and the one that turns `beta_slow`
+    times, a linear ramp blends the plain frequency into the
+    frequency divided by `yarn_factor`."""
+    half = np.arange(0, d, 2, dtype=np.float64) / d
+    freqs = spec.theta ** -half
+    if not spec.yarn_factor:
+        return freqs.astype(np.float32), 1.0
+
+    def turns_dim(rotations):
+        return (
+            d * math.log(spec.original_len / (rotations * 2 * math.pi))
+            / (2 * math.log(spec.theta))
+        )
+
+    lo = max(math.floor(turns_dim(spec.beta_fast)), 0)
+    hi = min(math.ceil(turns_dim(spec.beta_slow)), d - 1)
+    ramp = np.clip(
+        (np.arange(d // 2, dtype=np.float64) - lo) / max(hi - lo, 1e-3),
+        0.0, 1.0,
     )
+    freqs = freqs * (1 - ramp) + freqs / spec.yarn_factor * ramp
+    factor = spec.attention_factor or (
+        0.1 * math.log(spec.yarn_factor) + 1.0
+    )
+    return freqs.astype(np.float32), factor
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta) -> jax.Array:
+    """Rotary embedding on [B, S, H, D]. `theta` is the plain base,
+    or a RopeSpec (a kind of layer's own parameters)."""
+    d = x.shape[-1]
+    factor = 1.0
+    if isinstance(theta, RopeSpec):
+        freqs, factor = yarn_frequencies(theta, d)
+        freqs = jnp.asarray(freqs)
+    else:
+        freqs = jnp.exp(
+            -math.log(theta) * jnp.arange(0, d, 2, dtype=jnp.float32) / d
+        )
     angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -309,7 +429,8 @@ def _slot_lora_delta(h, a, b, idx, scale):
 
 
 def _attn_qkv(
-    cfg: LlamaConfig, mesh, h, lp, positions, lora=None, tp: int = 1
+    cfg: LlamaConfig, mesh, h, lp, positions, lora=None, tp: int = 1,
+    kind: Optional[str] = None,
 ):
     """Projections + RoPE of one block — shared by the training layer
     and the KV-cache decoder (models/decode.py), so there is exactly
@@ -342,8 +463,13 @@ def _attn_qkv(
     q = constrain(q, mesh, ("data", "fsdp"), "seq", "tensor", None)
     k = constrain(k, mesh, ("data", "fsdp"), "seq", "tensor", None)
     v = constrain(v, mesh, ("data", "fsdp"), "seq", "tensor", None)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    # a kind's own rotary parameters where the config gives a layer
+    # pattern; else the one base every layer shares
+    rope = cfg.rope_theta if kind is None else cfg.rope_of(kind)
+    if isinstance(rope, RopeSpec) and not rope.yarn_factor:
+        rope = rope.theta
+    q = _rope(q, positions, rope)
+    k = _rope(k, positions, rope)
     return q, k, v
 
 
@@ -370,6 +496,12 @@ def _mlp_residual(cfg: LlamaConfig, mesh, x, layer_params, lp, tp: int = 1):
     """Dense-SwiGLU / MoE feed-forward + residual (shared with decode).
     Returns (x, moe aux loss — zero for dense)."""
     h = _rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
+    if cfg.n_experts > 0 and cfg.moe_routing == "dropless":
+        raise ValueError(
+            "moe_routing='dropless' is served through "
+            "models/decode.py (moe.dropless_moe); this layer is the "
+            "capacity-bounded training one and would drop tokens"
+        )
     if cfg.n_experts > 0:
         from dlrover_tpu.models.moe import moe_mlp
 
@@ -443,6 +575,27 @@ def _attn_block(cfg: LlamaConfig, mesh, x, layer_params, lp, positions):
     return _attn_residual(cfg, mesh, x, attn, lp)
 
 
+def refuse_training(cfg: LlamaConfig) -> None:
+    """`apply` is the training forward: every layer full, one rotary
+    base, capacity routing. A configuration that needs more is
+    refused by name, never run as something else."""
+    asked = [
+        name for name, on in (
+            ("layer_pattern with window layers", cfg.hybrid),
+            ("moe_routing='dropless'",
+             cfg.n_experts > 0 and cfg.moe_routing == "dropless"),
+            ("rope_full/rope_window",
+             cfg.rope_full is not None or cfg.rope_window is not None),
+        ) if on
+    ]
+    if asked:
+        raise ValueError(
+            "llama.apply (training) does not run " + ", ".join(asked)
+            + ": this configuration is served through "
+            "models/decode.py only"
+        )
+
+
 def apply(
     cfg: LlamaConfig,
     params: Params,
@@ -456,6 +609,7 @@ def apply(
     With return_aux, also returns the summed per-layer MoE aux loss.
     With return_hidden, returns post-final-norm hidden states [B,S,D]
     instead of logits (fused-CE path)."""
+    refuse_training(cfg)
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))

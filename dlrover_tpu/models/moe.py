@@ -178,3 +178,88 @@ def moe_mlp(
         "bsec,ebcd->bsd", combine, out.astype(jnp.float32)
     )
     return y.astype(x.dtype), metrics
+
+
+# ---------------------------------------------------------------------------
+# routing that drops no token (the serving path, models/decode.py)
+# ---------------------------------------------------------------------------
+
+
+def _tile_rows(pairs: int, n_experts: int) -> int:
+    """Rows of a tile of the padded layout: about half an expert's
+    mean run, between a bf16 sublane tile and the MXU's height, so
+    that the padding stays under a half of the rows at any load."""
+    tile = 16
+    while tile < 128 and tile * 2 * n_experts < pairs:
+        tile *= 2
+    return tile
+
+
+def dropless_moe(
+    h: jax.Array,              # [T, D] normed tokens
+    router: jax.Array,         # [D, E]
+    w_gate: jax.Array,         # [E, D, M], or [L, E, D, M] with `layer`
+    w_up: jax.Array,
+    w_down: jax.Array,         # [E, M, D] / [L, E, M, D]
+    top_k: int,
+    layer=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Softmax-routed experts without capacity: every (token, expert)
+    pair is computed, at any load. Returns (y [T, D] in h's dtype,
+    int32[E] pairs routed to each expert).
+
+    The router's softmax, the top-k weights and the combine are
+    float32. The T * top_k pairs are sorted by expert with a counting
+    sort (a pair's rank inside its expert is a running count, stable
+    in token order), laid out with every expert's run padded to whole
+    tiles of rows, multiplied group by group
+    (ops/grouped_matmul.expert_mlp: a Pallas kernel on the chip,
+    `lax.ragged_dot` elsewhere), and gathered back to their tokens,
+    where the k results are weighted and summed. One code path for a
+    prefill of thousands of tokens and a decode batch of tens."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    t, d = h.shape
+    e = router.shape[-1]
+    pairs = t * top_k
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(
+            h.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, top_k)      # [T, k]
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        flat = chosen.reshape(pairs)                        # pair -> expert
+        onehot = (
+            flat[:, None] == jnp.arange(e, dtype=flat.dtype)[None, :]
+        ).astype(jnp.int32)                                 # [pairs, E]
+        counts = jnp.sum(onehot, axis=0)                    # [E]
+        rank = jnp.sum(
+            (jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1
+        )                                                   # [pairs]
+        tile = _tile_rows(pairs, e)
+        rows = -(-(pairs + e * (tile - 1)) // tile) * tile  # static bound
+        group_rows = -(-counts // tile) * tile
+        ends = jnp.cumsum(group_rows)
+        dest = (ends - group_rows)[flat] + rank             # pair -> row
+        # row -> token (T: the appended zero row, for padding rows)
+        src = jnp.full((rows,), t, jnp.int32).at[dest].set(
+            jnp.arange(pairs, dtype=jnp.int32) // top_k
+        )
+        tile_group = jnp.minimum(
+            jnp.searchsorted(
+                ends, jnp.arange(rows // tile, dtype=jnp.int32) * tile,
+                side="right",
+            ),
+            e - 1,
+        )
+        x = jnp.concatenate([h, jnp.zeros((1, d), h.dtype)])[src]
+    y = gmm.expert_mlp(
+        x, w_gate, w_up, w_down, group_rows, tile_group, tile,
+        layer=layer,
+    )
+    with jax.named_scope("moe_combine"):
+        y = y[dest].reshape(t, top_k, d).astype(jnp.float32)
+        out = jnp.sum(y * weights[:, :, None], axis=1)
+    return out.astype(h.dtype), counts

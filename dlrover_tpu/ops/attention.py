@@ -34,8 +34,10 @@ def reference_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     segment_ids: Optional[jax.Array] = None,
+    window: int = 0,
 ) -> jax.Array:
-    """Plain XLA attention, softmax in f32. [B, S, H, D] in and out."""
+    """Plain XLA attention, softmax in f32. [B, S, H, D] in and out.
+    `window` > 0 (causal): query i sees keys i - window < j <= i."""
     orig_dtype = q.dtype
     n_rep = q.shape[2] // k.shape[2]
     k = _kv_repeat(k, n_rep)
@@ -50,7 +52,10 @@ def reference_attention(
     if causal:
         q_pos = jnp.arange(q_len)[:, None] + (k_len - q_len)
         k_pos = jnp.arange(k_len)[None, :]
-        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        keep = q_pos >= k_pos
+        if window:
+            keep = keep & (q_pos - k_pos < window)
+        logits = jnp.where(keep, logits, NEG_INF)
     if segment_ids is not None:
         seg_mask = (
             segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
@@ -76,8 +81,11 @@ def dot_product_attention(
     block_k: Optional[int] = None,
     tp: int = 1,
     mesh=None,
+    window: int = 0,
 ) -> jax.Array:
     """Main entry. impl: 'auto' | 'flash' | 'reference'.
+    `window` > 0 is a causal band (a window layer's serving prefill,
+    forward only, one device).
 
     'auto' uses the Pallas flash kernel on TPU when shapes allow
     (seq % block == 0, head_dim tile-able), else the XLA reference.
@@ -95,8 +103,12 @@ def dot_product_attention(
     without a mesh has no layout to shard_map over and stays on the
     reference.
     """
+    if window and mesh is not None and mesh.devices.size > 1:
+        raise ValueError("window attention is not sharded over a mesh")
     if impl == "reference":
-        return reference_attention(q, k, v, causal, scale, segment_ids)
+        return reference_attention(
+            q, k, v, causal, scale, segment_ids, window
+        )
     if impl in ("auto", "flash"):
         from dlrover_tpu.ops import flash_attention as fa
 
@@ -121,7 +133,7 @@ def dot_product_attention(
                 )
             return fa.flash_attention(
                 q, k, v, causal=causal, scale=scale,
-                block_q=block_q, block_k=block_k,
+                block_q=block_q, block_k=block_k, window=window,
             )
         if block_q or block_k:
             # explicit tuning blocks were given but the flash path was
@@ -142,5 +154,7 @@ def dot_product_attention(
                 tuple(q.shape), tuple(k.shape),
                 segment_ids is not None, tp, mesh is not None,
             )
-        return reference_attention(q, k, v, causal, scale, segment_ids)
+        return reference_attention(
+            q, k, v, causal, scale, segment_ids, window
+        )
     raise ValueError(f"unknown attention impl: {impl}")

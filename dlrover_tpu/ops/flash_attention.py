@@ -149,7 +149,7 @@ def supports(
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, num_kb):
+                *, scale, causal, block_q, block_k, num_kb, window=0):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -165,8 +165,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         last_ki = jnp.minimum(
             num_kb - 1, ((qi + 1) * block_q - 1) // block_k
         )
+    in_band = ki <= last_ki
+    if window:
+        # a causal band: key blocks wholly older than the block row's
+        # first query's window are skipped like those past the diagonal
+        first_ki = jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+        in_band = in_band & (ki >= first_ki)
 
-    @pl.when(ki <= last_ki)
+    @pl.when(in_band)
     def _compute():
         q = q_ref[0, 0]  # [bq, d]
         k = k_ref[0, 0]  # [bk, d]
@@ -181,7 +187,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             cols = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            keep = rows >= cols
+            if window:
+                keep = keep & (rows - cols < window)
+            s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[:, :1]  # [bq, 1]
         l_prev = l_scr[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -209,8 +218,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
-    """q,k,v: [B, H, S, D] (equal head counts). Returns (o, lse)."""
+def _fwd(q, k, v, causal, scale, block_q, block_k, window=0):
+    """q,k,v: [B, H, S, D] (equal head counts). Returns (o, lse).
+    `window` > 0 (with causal): query i sees keys i - window < j <= i."""
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
     num_qb = s_q // block_q
@@ -218,6 +228,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, num_kb=num_kb,
+        **({"window": window} if window else {}),
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -501,11 +512,19 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Flash attention on [B, S, H, D] tensors; returns [B, S, H, D].
 
     block_q/block_k default to the VMEM-budget auto choice (auto_blocks);
-    pass explicit sizes only for tuning experiments."""
+    pass explicit sizes only for tuning experiments. `window` > 0 is a
+    causal band (query i sees keys i - window < j <= i) in the
+    FORWARD kernel only — the serving prefill of a window layer; it
+    has no backward."""
+    if window and not (causal and q.shape[1] == k.shape[1]):
+        raise ValueError(
+            "a window needs causal attention over equal q/k lengths"
+        )
     if causal and q.shape[1] != k.shape[1]:
         if q.shape[1] == 1:
             # single-query decode: the query sits at the bottom-right
@@ -550,7 +569,10 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    o = _flash(qt, kt, vt, causal, scale, block_q, block_k)
+    if window:
+        o, _ = _fwd(qt, kt, vt, causal, scale, block_q, block_k, window)
+    else:
+        o = _flash(qt, kt, vt, causal, scale, block_q, block_k)
     return o.transpose(0, 2, 1, 3)
 
 

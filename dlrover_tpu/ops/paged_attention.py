@@ -141,7 +141,22 @@ def gather_pages(pages: Dict, table, layer=None) -> Dict:
     return out
 
 
-def _reference(q, pages, table, lengths, scale, layer=None):
+def ring_view(table, lengths, window: int, page_size: int):
+    """The pages of a window layer's RING table in logical order:
+    (first logical page that still holds a position inside the
+    window [B], page ids [B, R] of logical pages first .. first+R-1).
+    Logical page p of a slot lives at ring entry p % R."""
+    ring = table.shape[1]
+    first = jnp.maximum(lengths - window, 0) // page_size
+    logical = first[:, None] + jnp.arange(ring, dtype=jnp.int32)[None, :]
+    return first, jnp.take_along_axis(table, logical % ring, axis=1)
+
+
+def _reference(q, pages, table, lengths, scale, layer=None, window=None):
+    if window is not None:
+        return _reference_window(
+            q, pages, table, lengths, scale, layer, window
+        )
     """The dense-bank formulation on the gathered view — kept
     OP-FOR-OP identical to models/decode.py::_cached_attention (same
     grouped einsum, same mask, same softmax axis) so the paged engine
@@ -173,6 +188,32 @@ def _reference(q, pages, table, lengths, scale, layer=None):
     return out.reshape(b, h, hd)
 
 
+def _reference_window(q, pages, table, lengths, scale, layer, window):
+    """The window layer's formulation over the ring: gather the ring's
+    pages in logical order and mask the cells outside
+    (length - window, length). Same einsums as `_reference`."""
+    page_size = pages["k"].shape[-3]
+    first, ordered = ring_view(table, lengths, window, page_size)
+    view = gather_pages(pages, ordered, layer)
+    k_cache, v_cache = view["k"], view["v"]
+    b, h, hd = q.shape
+    m = k_cache.shape[1]
+    kv = k_cache.shape[2]
+    qg = q.reshape(b, 1, kv, h // kv, hd)
+    scores = jnp.einsum(
+        "bskrd,bmkd->bkrsm", qg, k_cache,
+        preferred_element_type=jnp.float32,
+    ) * scale
+    cols = (first * page_size)[:, None] + jnp.arange(m)[None, :]
+    live = (cols < lengths[:, None]) & (
+        cols >= (lengths - window)[:, None]
+    )
+    scores = jnp.where(live[:, None, None, None, :], scores, -jnp.inf)
+    p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkrsm,bmkd->bskrd", p, v_cache)
+    return out.reshape(b, h, hd)
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
@@ -181,7 +222,8 @@ def _reference(q, pages, table, lengths, scale, layer=None):
 def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
                   q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr,
-                  *, scale, page_size, num_pages, n_rep, quant):
+                  *, scale, page_size, num_pages, n_rep, quant,
+                  window=None):
     """Grid (B, P): one invocation attends query row b — every KV
     head of it — over physical page table[b, p] of layer layer[0]
     (the index map's business: the body never reads `layer_ref`).
@@ -206,8 +248,14 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[bi]
+    # a window layer walks its ring from the first logical page that
+    # still holds a position inside the window (the index map starts
+    # there too); `window=None` leaves the program as it was
+    page = pi  # the logical page this invocation attends over
+    if window is not None:
+        page = pi + jnp.maximum(length - window, 0) // page_size
 
-    @pl.when(pi * page_size < length)
+    @pl.when(page * page_size < length)
     def _compute():
         k = k_ref[0][0, 0].astype(jnp.float32)     # [page, KV, hd]
         v = v_ref[0][0, 0].astype(jnp.float32)
@@ -216,10 +264,13 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
             # runs on the VMEM-resident block — HBM traffic stays int8
             k = k * k_ref[1][0][0, 0].astype(jnp.float32)
             v = v * v_ref[1][0][0, 0].astype(jnp.float32)
-        cells = pi * page_size + jax.lax.broadcasted_iota(
+        cells = page * page_size + jax.lax.broadcasted_iota(
             jnp.int32, k.shape[:2] + (1,), 0
         )
         live = cells < length                      # [page, KV, 1]
+        if window is not None:
+            # the oldest page's cells that have left the window
+            live = live & (cells >= length - window)
         for r in range(n_rep):
             q = q_ref[0, r].astype(jnp.float32)    # [KV, hd]
             s = jnp.sum(k * q[None], axis=2, keepdims=True) * scale
@@ -242,7 +293,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
             o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
 
 
-def _kernel(q, pages, layer, table, lengths, scale):
+def _kernel(q, pages, layer, table, lengths, scale, window=None):
     """q [B, H, hd] → [B, H, hd] over layer `layer` (int32[1]) of the
     stacked pool. The layer index, the page table and lengths ride as
     scalar-prefetch operands so the k/v BlockSpec index maps can
@@ -261,8 +312,19 @@ def _kernel(q, pages, layer, table, lengths, scale):
     def q_map(bi, pi, lay, tab, lens):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, pi, lay, tab, lens):
-        return (lay[0], tab[bi, pi], 0, 0, 0)
+    if window is None:
+        def kv_map(bi, pi, lay, tab, lens):
+            return (lay[0], tab[bi, pi], 0, 0, 0)
+    else:
+        def kv_map(bi, pi, lay, tab, lens):
+            # ring entries past the last written page are not read
+            # again: they map to the last one (an unchanged block
+            # index starts no new copy)
+            first = jnp.maximum(lens[bi] - window, 0) // page_size
+            page = jnp.minimum(
+                first + pi, jnp.maximum(lens[bi] - 1, 0) // page_size
+            )
+            return (lay[0], tab[bi, page % num_pages], 0, 0, 0)
 
     q_spec = pl.BlockSpec((1, n_rep, kv, hd), q_map)
     kv_spec = pl.BlockSpec((1, 1, page_size, kv, hd), kv_map)
@@ -280,7 +342,7 @@ def _kernel(q, pages, layer, table, lengths, scale):
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size,
-        num_pages=num_pages, n_rep=n_rep, quant=quant,
+        num_pages=num_pages, n_rep=n_rep, quant=quant, window=window,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -301,7 +363,10 @@ def _kernel(q, pages, layer, table, lengths, scale):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=fa._interpret(),
-        name="paged_attention_decode",
+        name=(
+            "paged_attention_decode" if window is None
+            else "paged_attention_decode_window"
+        ),
     )(
         layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
         *operands,
@@ -309,7 +374,8 @@ def _kernel(q, pages, layer, table, lengths, scale):
     return out.swapaxes(1, 2).reshape(b, h, hd)
 
 
-def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh):
+def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh,
+                    window=None):
     """`_kernel` shard_mapped over the serving mesh's "tp" axis: q
     and the page pool split on their head axes, the layer index, the
     page table and lengths replicated (host-planned — every shard
@@ -325,7 +391,7 @@ def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh):
     rep = specs["replicated"]
 
     def body(q, pages, layer, table, lengths):
-        return _kernel(q, pages, layer, table, lengths, scale)
+        return _kernel(q, pages, layer, table, lengths, scale, window)
 
     return fa.shard_map(
         body,
@@ -350,6 +416,7 @@ def paged_attention(
     impl: str = "auto",
     mesh=None,
     layer=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Single-query attention over paged KV. `pages` is the stacked
     pool (`[L, n_pages, page_size, KV, ...]` leaves) with `layer` the
@@ -362,7 +429,13 @@ def paged_attention(
     `mesh` (optional serving mesh with a "tp" axis) makes the kernel
     path dispatch shard_mapped over the head axes; the reference path
     needs no wrapper — GSPMD partitions its gather+einsums per head
-    on its own."""
+    on its own.
+
+    `window` (static) makes this a WINDOW layer's attention: `table`
+    is then the slot's ring (logical page p at entry p % R), the walk
+    starts at the first page that still holds a position inside the
+    last `window` positions, and that page's older cells are masked.
+    `window=None` is the program as it was, operation for operation."""
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     from dlrover_tpu.parallel.mesh import serving_mesh_tp
@@ -373,10 +446,12 @@ def paged_attention(
     if impl == "reference" or (
         impl == "auto" and not use_kernel(q, pages, table, tp=tp)
     ):
-        return _reference(q, pages, table, lengths, scale, layer)
+        return _reference(
+            q, pages, table, lengths, scale, layer, window
+        )
     pages, layer = _stacked(pages, layer)
     if tp > 1:
         return _sharded_kernel(
-            q, pages, layer, table, lengths, scale, mesh
+            q, pages, layer, table, lengths, scale, mesh, window
         )
-    return _kernel(q, pages, layer, table, lengths, scale)
+    return _kernel(q, pages, layer, table, lengths, scale, window)

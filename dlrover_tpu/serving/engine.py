@@ -93,10 +93,12 @@ from dlrover_tpu.models.decode import (
     _mask_top_p,
     decode_step,
     gather_pool_view,
+    init_hybrid_pools,
     init_kv_cache,
     init_page_pool,
     install_exact_row,
     paged_decode_step,
+    paged_install_hybrid,
     paged_install_row,
     paged_prefill_chunk,
     paged_verify_step,
@@ -135,6 +137,7 @@ from dlrover_tpu.serving.paged_kv import (
     TRASH_PAGE,
     OutOfPages,
     PageAllocator,
+    WindowRings,
 )
 from dlrover_tpu.serving.prefix_cache import RadixPrefixCache
 from dlrover_tpu.serving.speculative import SpeculativeDecoder
@@ -206,6 +209,32 @@ def _parse_mesh_tp(mesh_spec) -> int:
         f"mesh_spec must be an int tp degree, a {{'tp': n}} dict or "
         f"a MeshSpec, got {mesh_spec!r}"
     )
+
+
+def _refuse_unserved(cfg, **asked) -> None:
+    """A model that mixes window and full attention layers, or routes
+    its experts without dropping, is served by the plain paged (or
+    dense) path only. Every other combination is refused here, at
+    construction and by name: none may mis-serve silently."""
+    what = [
+        name for name, on in (
+            ("window and full attention layers mixed",
+             getattr(cfg, "hybrid", False)),
+            ("experts routed without dropping",
+             getattr(cfg, "n_experts", 0) > 0
+             and getattr(cfg, "moe_routing", "") == "dropless"),
+        ) if on
+    ]
+    knobs = sorted(name for name, on in asked.items() if on)
+    if what and knobs:
+        raise ValueError(
+            f"a model with {' and '.join(what)} is not served with "
+            f"{', '.join(knobs)}: the prefix cache, the host KV tier, "
+            "the handoff between replicas, speculative decoding, "
+            "adapters, int8 weights or KV, chunked prefill and tp > 1 "
+            "know one class of cached state and dense feed-forward "
+            "layers only"
+        )
 
 
 def _pad_bucket(n: int, lo: int = 16) -> int:
@@ -312,6 +341,10 @@ def _paged_step_takes_kernel(cfg, n_slots, pool, table, mesh) -> bool:
     probe_q = jax.ShapeDtypeStruct(
         (n_slots, cfg.n_heads, cfg.head_dim), cfg.dtype
     )
+    if "k" not in pool:
+        # two classes of pages (a window and a full one) of one page
+        # shape: the full class answers for both
+        pool = pool["full"]
     probe_pool = {
         name: jax.ShapeDtypeStruct(arr.shape[1:], arr.dtype)
         for name, arr in pool.items()
@@ -418,13 +451,43 @@ def _build_chunk_program(
     #   between ~parity and >2x dense TPOT on the CPU smoke.
     @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
     def _run_chunk_paged(
-        pool, table, params, tok, pos, done, limit, keys, k
+        pool, table, params, tok, pos, done, limit, keys, k,
+        table_win=None,
     ):
         # done-at-entry rows read and write the trash page (page 0);
         # rows finishing MID-chunk still own their pages (the host
         # frees them only after harvesting this dispatch), so their
         # remaining frozen rewrites stay in-bounds either way
         table = jnp.where(done[:, None], 0, table)
+        if table_win is not None:
+            # a model with window layers: two classes of pages, two
+            # tables a slot (the second the slots' rings, as the host
+            # left them before this dispatch), always stepped page-
+            # natively; dropless experts add their routed pairs per
+            # expert, summed over the layers and the k steps
+            table_win = jnp.where(done[:, None], 0, table_win)
+
+            def body(carry, _):
+                pool, tok, pos, done, keys, pairs = carry
+                logits, pool, *counts = paged_decode_step(
+                    cfg, params, tok, pool, table, pos, mesh=mesh,
+                    table_win=table_win,
+                )
+                tok, pos, done, keys, nxt = _advance(
+                    logits, tok, pos, done, limit, keys
+                )
+                if counts:
+                    pairs = pairs + counts[0]
+                return (pool, tok, pos, done, keys, pairs), nxt
+
+            pairs0 = jnp.zeros(
+                (max(getattr(cfg, "n_experts", 0), 1),), jnp.int32
+            )
+            (pool, tok, pos, done, keys, pairs), emitted = jax.lax.scan(
+                body, (pool, tok, pos, done, keys, pairs0), None,
+                length=k,
+            )
+            return pool, tok, pos, done, keys, emitted.T, pairs
         if _paged_step_takes_kernel(
             cfg, tok.shape[0], pool, table, mesh
         ):
@@ -1031,6 +1094,20 @@ def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
     def _page_copy_fn(pages, src, dst):
         return pool_copy_page(pages, src, dst)
 
+    @partial(jax.jit, donate_argnums=(0,))
+    def _paged_cold_hybrid_fn(
+        pools, table, params, prompt, slot, table_row, ring_row, p
+    ):
+        """Window and full layers mixed: one prefill of the bucket,
+        then the full layers' cells into the full class and the
+        prompt's last `sliding_window` positions (`p` is its true
+        length) into the slot's ring."""
+        row = prefill_exact_row(cfg, params, prompt, max_len, mesh=mesh)
+        pools = paged_install_hybrid(
+            cfg, pools, row, table_row, ring_row, p, prompt.shape[0]
+        )
+        return pools, table.at[slot].set(table_row)
+
     progs = {
         "admit": _admit_fn,
         "cold": _admit_cold_fn,
@@ -1040,6 +1117,7 @@ def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
         "paged_cold": _paged_cold_fn,
         "paged_warm": _paged_warm_fn,
         "page_copy": _page_copy_fn,
+        "paged_cold_hybrid": _paged_cold_hybrid_fn,
     }
     if not adapters:
         return progs
@@ -1288,6 +1366,20 @@ class ContinuousBatcher:
                 f"'int8_stochastic', got {weight_quant!r}"
             )
         _check_positional_capacity(cfg, max_len)
+        _refuse_unserved(
+            cfg,
+            prefix_cache_rows=prefix_cache_rows > 0,
+            kv_tier_bytes=kv_tier_bytes > 0,
+            replica_role=replica_role != "colocated",
+            spec_draft_len=spec_draft_len > 0,
+            adapter_registry=adapter_registry is not None,
+            weight_quant=weight_quant != "none",
+            mesh_spec=(
+                mesh_spec is not None and _parse_mesh_tp(mesh_spec) > 1
+            ),
+            kv_quant=bool(kv_quant),
+            prefill_chunk=prefill_chunk > 0,
+        )
         # ---- serving mesh (GSPMD tensor slice) --------------------------
         # tp=1 (or the knob unset) keeps mesh=None: the compiled
         # programs are then literally the single-device ones (the mesh
@@ -1398,6 +1490,8 @@ class ContinuousBatcher:
             )
         self.kv_layout = kv_layout
         self._paged = kv_layout == "paged"
+        # window and full layers mixed: two classes of pages
+        self._hybrid = self._paged and bool(getattr(cfg, "hybrid", False))
         bank_len = max_len + spec_draft_len
         if self._paged:
             # auto page size: the largest power of two <= 16 dividing
@@ -1441,9 +1535,16 @@ class ContinuousBatcher:
             self.swap_headroom = max(0, swap_headroom)
             self._pages_per_slot = per_slot
             self.allocator = PageAllocator(n_pages, page_size)
-            self.page_pool = self._shard_bank(
-                init_page_pool(cfg, n_pages, page_size, quant=kv_quant)
-            )
+            if self._hybrid:
+                self.page_pool = init_hybrid_pools(
+                    cfg, n_pages, self._mint_window_class(), page_size
+                )
+            else:
+                self.page_pool = self._shard_bank(
+                    init_page_pool(
+                        cfg, n_pages, page_size, quant=kv_quant
+                    )
+                )
             # all rows start on the trash page (page 0); after that
             # the programs trash-route done rows on their own, so the
             # host only ever scatters rows at admission/CoW
@@ -1462,6 +1563,11 @@ class ContinuousBatcher:
             self.cache = self._shard_bank(
                 init_kv_cache(cfg, n_slots, bank_len, quant=kv_quant)
             )
+        # what the newest harvested dispatch routed to each expert,
+        # and the window class's counters of the current step
+        self._moe_pairs: Optional[np.ndarray] = None
+        self._moe_steps = 0
+        self._window_freed_this_step = 0
         # ---- multi-adapter LoRA serving (serving/adapters.py) -----------
         # One stacked device bank whose slot 0 is the permanent zero
         # adapter; every request gathers its slot's A/B slices inside
@@ -1621,6 +1727,26 @@ class ContinuousBatcher:
         self._bind_programs()
         self._probe_kernel_path()
 
+    def _mint_window_class(self) -> int:
+        """Host side of the second class of pages, where window and
+        full layers mix (the full class is the one there was:
+        `self.allocator`, `_slot_pages`, `_table`): its allocator and
+        the slots' rings (`self.rings`, serving/paged_kv.WindowRings),
+        sized so that every slot's ring is whole at once (and the
+        trash page): a ring never waits for a page. Returns the
+        class's page count."""
+        probe = WindowRings(
+            PageAllocator(2, self.page_size), 0,
+            self.cfg.sliding_window, self.chunk,
+        )
+        n_win = self.n_slots * probe.ring_pages + 1
+        self.allocator_win = PageAllocator(n_win, self.page_size)
+        self.rings = WindowRings(
+            self.allocator_win, self.n_slots,
+            self.cfg.sliding_window, self.chunk,
+        )
+        return n_win
+
     def _bind_programs(self) -> None:
         """(Re)bind the jitted programs for the CURRENT (cfg, sampling
         knobs, mesh, weight version). Called at construction, again by
@@ -1706,6 +1832,7 @@ class ContinuousBatcher:
         self._paged_cold_fn = admit["paged_cold"]
         self._paged_warm_fn = admit["paged_warm"]
         self._page_copy_fn = admit["page_copy"]
+        self._paged_cold_hybrid_fn = admit["paged_cold_hybrid"]
         self._admit_lora_fn = admit.get("admit_lora")
         self._paged_cold_lora_fn = admit.get("paged_cold_lora")
 
@@ -2189,6 +2316,7 @@ class ContinuousBatcher:
         site outside construction (graftlint ELASTIC-001)."""
         from dlrover_tpu.serving import elastic as elastic_mod
 
+        _refuse_unserved(self.cfg, resize=True)
         if n_chips is None:
             n_chips = self.surviving_chips()
         return elastic_mod.resize(self, n_chips)
@@ -2301,7 +2429,7 @@ class ContinuousBatcher:
         # prefill itself moves into the interleaved dispatches
         with trace.span(
             "engine.admit", prompt_tokens=p,
-            bucket=min(_pad_bucket(p), self.max_len),
+            bucket=self._prompt_bucket(p),
         ) as sp:
             pf_start: Optional[int] = None
             if req.adopted is not None:
@@ -2331,12 +2459,14 @@ class ContinuousBatcher:
                     req.preempted = False
                     self._swap_resumes += 1
                 self._admit_paged(slot, req, p)
+                if self._hybrid:
+                    sp.set(window_cells=min(p, self.cfg.sliding_window))
             elif req.adapter_id is not None:
                 # adaptered admission: the prompt K/V must come from the
                 # ADAPTED projections, and it never installs from (or
                 # publishes into) the shared prefix pool — published
                 # prefixes are base-model K/V by contract
-                bucket = min(_pad_bucket(p), self.max_len)
+                bucket = self._prompt_bucket(p)
                 self.cache = self._admit_lora_fn(
                     self.cache,
                     self.params,
@@ -2346,7 +2476,7 @@ class ContinuousBatcher:
                     req.adapter_slot,
                 )
             elif self.prefix_cache is None:
-                bucket = min(_pad_bucket(p), self.max_len)
+                bucket = self._prompt_bucket(p)
                 self.cache = self._admit_fn(
                     self.cache,
                     self.params,
@@ -2434,7 +2564,7 @@ class ContinuousBatcher:
         start = max(start, 0)
         work = None
         if start <= 0 or row is None:
-            bucket = min(_pad_bucket(p), self.max_len)
+            bucket = self._prompt_bucket(p)
             self.cache, work = self._admit_cold_fn(
                 self.cache,
                 self.params,
@@ -2841,6 +2971,9 @@ class ContinuousBatcher:
         install only the cells the shared pages don't already hold.
         Pool pressure is resolved inline: evict unreferenced prefix
         runs, then preempt-and-swap the coldest live request."""
+        if self._hybrid:
+            self._admit_paged_hybrid(slot, req, p)
+            return
         pc = self.prefix_cache
         # adaptered requests bypass the prefix cache both ways: a
         # published prefix holds base-model K/V (wrong bytes for this
@@ -2919,7 +3052,7 @@ class ContinuousBatcher:
             )
             pc.record_admission(start)
         elif lora:
-            bucket = min(_pad_bucket(p), self.max_len)
+            bucket = self._prompt_bucket(p)
             # adapted prefill; `work` stays None — the exact row this
             # program returns must never publish into the shared pool
             self.page_pool, self._table, _ = self._paged_cold_lora_fn(
@@ -2933,7 +3066,7 @@ class ContinuousBatcher:
                 req.adapter_slot,
             )
         else:
-            bucket = min(_pad_bucket(p), self.max_len)
+            bucket = self._prompt_bucket(p)
             self.page_pool, self._table, work = self._paged_cold_fn(
                 self.page_pool,
                 self._table,
@@ -2964,6 +3097,63 @@ class ContinuousBatcher:
         # page-aligned prompt), the SLOT must own its copy before
         # decode rewrites cell p-1
         self._cow_frontier(slot, p)
+
+    def _prompt_bucket(self, p: int) -> int:
+        """The padded length a cold prompt of `p` tokens is prefilled
+        at: the next power of two (`_pad_bucket`), capped at max_len.
+        Above 1024 a power of two pads a 2100-token prompt by 70%, and
+        a blocking prefill stalls every slot for its padding too, so
+        buckets there step by 512, wherever nothing else reckons a
+        prompt's run in powers of two: the prefix cache, the host tier
+        and the handoff between replicas do (their stored and shipped
+        rows), and with one of them the buckets stay powers of two."""
+        free = (
+            self.prefix_cache is None and self.kv_tier is None
+            and self.replica_role == "colocated"
+        )
+        if free and p > 1024:
+            return min(-(-p // 512) * 512, self.max_len)
+        return min(_pad_bucket(p), self.max_len)
+
+    def _admit_paged_hybrid(self, slot: int, req: _Request, p: int):
+        """Admission where window and full layers mix: the full class
+        gets the request's whole run (sized off its own limit, as
+        ever), the window class the pages of the prompt's last
+        `sliding_window` positions, and one program prefills the
+        bucket and installs both. No prefix cache, tier or adapter
+        reaches here (`_refuse_unserved`)."""
+        run = self._alloc_pages(self._request_pages(req))
+        self._slot_pages[slot] = run
+        window = self.cfg.sliding_window
+        self.rings.hold(slot, max(p - window, 0), p - 1)
+        vals = np.full(self._pages_per_slot, TRASH_PAGE, np.int32)
+        vals[: len(run)] = run
+        bucket = self._prompt_bucket(p)
+        self.page_pool, self._table = self._paged_cold_hybrid_fn(
+            self.page_pool,
+            self._table,
+            self.params,
+            self._pad_to(req.prompt, bucket),
+            slot,
+            vals,
+            self.rings.table[slot].copy(),
+            p,
+        )
+
+    def _hold_rings(self, k: int) -> None:
+        """Between dispatches, never per token: every live slot's ring
+        is moved up to the cells the next `k` steps read and write.
+        The pages wholly behind the window go back to the window
+        class, the pages ahead of the frontier are allocated."""
+        window = self.cfg.sliding_window
+        for slot in range(self.n_slots):
+            if self.done[slot] or self.slot_req[slot] is None:
+                continue
+            pos = int(self.pos[slot])
+            self._window_freed_this_step += self.rings.hold(
+                slot, max(pos - window + 1, 0),
+                min(pos + k - 1, int(self.limit[slot]) - 1),
+            )
 
     def _alloc_pages(self, n: int, swap_ok: bool = True) -> List[int]:
         """Allocate with reclaim: on a dry pool, evict LRU
@@ -3084,6 +3274,8 @@ class ContinuousBatcher:
         if run:
             self.allocator.free(run)
             self._slot_pages[slot] = []
+        if self._hybrid:
+            self.rings.release(slot)
 
     def _cow_frontier(self, slot: int, p: int) -> None:
         """Ensure the slot exclusively owns the page holding its
@@ -3135,7 +3327,15 @@ class ContinuousBatcher:
             self._pages_per_slot + self.swap_headroom,
             self.allocator.capacity,
         )
-        return self.allocator.free_pages >= pending + want
+        if self.allocator.free_pages < pending + want:
+            return False
+        if self._hybrid:
+            # the window class: a ring a queued request and one more
+            ring = self.rings.ring_pages
+            return self.allocator_win.free_pages >= ring * (
+                len(self._queue) + 1
+            )
+        return True
 
     def paged_stats(self) -> Dict[str, float]:
         """Page-pool telemetry for ServingMetrics / the gateway:
@@ -3146,6 +3346,11 @@ class ContinuousBatcher:
         s = self.allocator.stats()
         s["swap_preemptions"] = float(self._swap_preemptions)
         s["swap_resumes"] = float(self._swap_resumes)
+        if self._hybrid:
+            s["window_pages_held"] = float(self.rings.pages_held)
+            s["window_pages_freed"] = float(
+                self.rings.pages_freed_behind
+            )
         return s
 
     def adapter_stats(self) -> Dict[str, float]:
@@ -3268,6 +3473,8 @@ class ContinuousBatcher:
         with trace.span("engine.step") as sp:
             self._wait_this_step = 0.0
             self._admit_this_step = 0.0
+            self._window_freed_this_step = 0
+            self._moe_pairs = None
             self._maybe_commit_refresh()  # deferred swap at idle fence
             try:
                 if self.chaos is not None:
@@ -3347,6 +3554,20 @@ class ContinuousBatcher:
                 wait_s=self._wait_this_step,
                 admit_s=self._admit_this_step,
             )
+            if self._hybrid:
+                sp.set(
+                    pages_full=self.allocator.used_pages,
+                    pages_window=self.rings.pages_held,
+                    window_pages_freed=self._window_freed_this_step,
+                )
+            if self._moe_pairs is not None and self.cfg.n_experts > 0:
+                pairs = self._moe_pairs
+                sp.set(
+                    moe_steps=self._moe_steps,
+                    moe_pairs=int(pairs.sum()),
+                    moe_max_load=int(pairs.max()),
+                    moe_mean_load=float(pairs.mean()),
+                )
         self.last_step_s = sp.dur_s
         self._stat_host_ms += (sp.dur_s - self._wait_this_step) * 1e3
         return events
@@ -3359,7 +3580,18 @@ class ContinuousBatcher:
         k = self._next_chunk_len()
         with self._dispatch_span(chunk=k):
             lora = self._adapter_args()
-            if self._paged:
+            pairs = ()
+            if self._hybrid:
+                self._hold_rings(k)
+                pool, tok, pos, done, keys, emitted, *pairs = (
+                    self._run_chunk(
+                        self.page_pool, self._table, self.params,
+                        d["tok"], d["pos"], d["done"], d["limit"],
+                        d["keys"], k, self.rings.table.copy(),
+                    )
+                )
+                self.page_pool = pool
+            elif self._paged:
                 pool, tok, pos, done, keys, emitted = self._run_chunk(
                     self.page_pool, self._table, self.params,
                     d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
@@ -3381,7 +3613,9 @@ class ContinuousBatcher:
             self._enqueue_fetch(
                 _Inflight(
                     kind="chunk",
-                    arrays=(tok, pos, done, keys, emitted),
+                    # the experts' routed pairs, where there are any,
+                    # ride the one fetch with the tokens
+                    arrays=(tok, pos, done, keys, emitted, *pairs),
                     dispatched_at=0.0,
                     old_pos=self.pos.copy(),
                     version=self._weight_version,
@@ -3589,7 +3823,10 @@ class ContinuousBatcher:
             self._stat_overlap_ms += max(span_ms - wait_ms, 0.0)
             self._stat_dispatches += 1
             if pend.kind == "chunk":
-                tok, pos, done, keys, emitted = host
+                tok, pos, done, keys, emitted, *pairs = host
+                if pairs:
+                    self._moe_pairs = pairs[0]
+                    self._moe_steps = emitted.shape[1]
                 counts = pos - pend.old_pos
             else:
                 tok, pos, done, keys, emitted, n_emit, accepted = host
@@ -3809,12 +4046,18 @@ class ContinuousBatcher:
             # dense bank — rebuild pool, allocator, and tables, and
             # drop every host-side run record with them
             self.allocator = PageAllocator(self.n_pages, self.page_size)
-            self.page_pool = self._shard_bank(
-                init_page_pool(
-                    self.cfg, self.n_pages, self.page_size,
-                    quant=self._kv_quant,
+            if self._hybrid:
+                self.page_pool = init_hybrid_pools(
+                    self.cfg, self.n_pages, self._mint_window_class(),
+                    self.page_size,
                 )
-            )
+            else:
+                self.page_pool = self._shard_bank(
+                    init_page_pool(
+                        self.cfg, self.n_pages, self.page_size,
+                        quant=self._kv_quant,
+                    )
+                )
             self._table = self._replicate(
                 jnp.zeros(
                     (self.n_slots, self._pages_per_slot), jnp.int32

@@ -96,6 +96,17 @@ def _validate_generate(payload) -> Optional[str]:
     return None
 
 
+class _Server(ThreadingHTTPServer):
+    """The listen backlog of `socketserver` is 5: when a fleet's
+    workers connect at once (192 of them in the benchmark's closed
+    loop), the connections over it wait out the kernel's SYN
+    retransmits, 1, 3 or 7 s each (one of 46 chip runs of PR 31 lost
+    a client for more than 6 s that way)."""
+
+    request_queue_size = 1024
+    daemon_threads = True
+
+
 class ServingGateway:
     """HTTP server routing generation requests into a backend.
 
@@ -286,8 +297,7 @@ class ServingGateway:
 
             handler_version = "dlrover-tpu-serving"
 
-        self._server = ThreadingHTTPServer((host, port), _Handler)
-        self._server.daemon_threads = True
+        self._server = _Server((host, port), _Handler)
         self._thread: Optional[threading.Thread] = None
 
     @staticmethod

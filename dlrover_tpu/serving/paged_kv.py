@@ -27,6 +27,8 @@ rewrites land where no live table reads.
 
 from typing import Dict, List, Tuple
 
+import numpy as np
+
 TRASH_PAGE = 0
 
 
@@ -222,3 +224,108 @@ class PageAllocator:
             "pages_adopted": self.pages_adopted,
             "pages_promoted": self.pages_promoted,
         }
+
+
+class WindowRings:
+    """Host-side page accounting of the WINDOW class of a model that
+    mixes window and full attention layers: a window layer attends a
+    slot's last `window` positions only, so a slot holds just the
+    pages that still have one of them (and the pages the next
+    dispatch will write). Each slot's table row is a RING of
+    `ring_pages` entries: logical page p (cells [p * page_size, (p +
+    1) * page_size)) lives at entry p % ring_pages, which is free
+    again by the time page p + ring_pages is needed.
+
+    `hold(slot, first_cell, last_cell)` makes a slot hold exactly the
+    pages of those cells: the pages wholly behind `first_cell` go back
+    to the class's allocator, the pages up to `last_cell` are
+    allocated. The engine calls it for every live slot between
+    dispatches (never per token); `table` (numpy, [n_slots,
+    ring_pages], 0 = the class's trash page) rides into the next
+    dispatch as it stands."""
+
+    def __init__(self, allocator: PageAllocator, n_slots: int,
+                 window: int, chunk: int):
+        self.allocator = allocator
+        self.window = window
+        ps = allocator.page_size
+        # the cells a dispatch of `chunk` steps reads and writes span
+        # window + chunk - 1; unaligned, they touch one page more
+        self.ring_pages = -(-(window + chunk - 1) // ps) + 1
+        self.table = np.full(
+            (n_slots, self.ring_pages), TRASH_PAGE, np.int32
+        )
+        # logical pages [lo, hi) each slot holds
+        self.lo = [0] * n_slots
+        self.hi = [0] * n_slots
+        self.pages_freed_behind = 0  # monotonic: freed by a hold()
+
+    def hold(self, slot: int, first_cell: int, last_cell: int) -> int:
+        """Hold exactly the logical pages of cells [first_cell,
+        last_cell]; returns how many pages went back to the
+        allocator. Raises OutOfPages with the slot's holding
+        consistent (what was allocated so far is held)."""
+        ps = self.allocator.page_size
+        lo, hi = first_cell // ps, last_cell // ps + 1
+        if hi - lo > self.ring_pages:
+            raise ValueError(
+                f"cells [{first_cell}, {last_cell}] span {hi - lo} "
+                f"pages, the ring has {self.ring_pages}"
+            )
+        row = self.table[slot]
+        freed = 0
+        for p in range(self.lo[slot], min(self.hi[slot], lo)):
+            self.allocator.free([int(row[p % self.ring_pages])])
+            row[p % self.ring_pages] = TRASH_PAGE
+            freed += 1
+        self.lo[slot] = lo
+        self.hi[slot] = max(self.hi[slot], lo)
+        self.pages_freed_behind += freed
+        while self.hi[slot] < hi:
+            [page] = self.allocator.alloc(1)
+            row[self.hi[slot] % self.ring_pages] = page
+            self.hi[slot] += 1
+        return freed
+
+    def release(self, slot: int) -> None:
+        """Drop everything the slot holds (finish, cancel, preempt)."""
+        row = self.table[slot]
+        for p in range(self.lo[slot], self.hi[slot]):
+            self.allocator.free([int(row[p % self.ring_pages])])
+        row[:] = TRASH_PAGE
+        self.lo[slot] = self.hi[slot] = 0
+
+    def held(self, slot: int) -> int:
+        return self.hi[slot] - self.lo[slot]
+
+    @property
+    def pages_held(self) -> int:
+        return sum(h - l for l, h in zip(self.lo, self.hi))
+
+    def check(self, slot: int, first_cell: int) -> None:
+        """The window class's invariants for one slot: no page is held
+        that lies wholly behind `first_cell` (the oldest cell a
+        dispatch may still read), every held page has an entry, and
+        no entry outside the held range is set."""
+        ps = self.allocator.page_size
+        if self.held(slot) and (self.lo[slot] + 1) * ps <= first_cell:
+            raise AssertionError(
+                f"slot {slot} holds page {self.lo[slot]}, wholly "
+                f"behind cell {first_cell}"
+            )
+        held = {
+            p % self.ring_pages
+            for p in range(self.lo[slot], self.hi[slot])
+        }
+        for entry, page in enumerate(self.table[slot]):
+            if (page != TRASH_PAGE) != (entry in held):
+                raise AssertionError(
+                    f"slot {slot} ring entry {entry} = {page}, held "
+                    f"entries {sorted(held)}"
+                )
+            if page != TRASH_PAGE and self.allocator.refcount(
+                int(page)
+            ) != 1:
+                raise AssertionError(
+                    f"slot {slot} ring page {page} is not allocated"
+                )

@@ -10,6 +10,7 @@ from dlrover_tpu.serving.paged_kv import (
     TRASH_PAGE,
     OutOfPages,
     PageAllocator,
+    WindowRings,
 )
 
 pytestmark = pytest.mark.paged
@@ -140,6 +141,90 @@ def test_property_fuzz_random_ops():
     assert a.used_pages == 0
     assert a.free_pages == a.capacity
     a.check()
+
+
+@pytest.mark.parametrize("seed,chunk", [(0, 1), (1, 4), (2, 8)])
+def test_property_fuzz_two_classes(seed, chunk):
+    """The property test over TWO classes of pages, as a model with
+    window and full layers holds them: slots admit (a run of the full
+    class for the request's limit, the window class's pages of the
+    prompt's last `window` cells), advance by up to `chunk` positions
+    a dispatch, finish or are preempted. After every step: no page of
+    the window class is held that lies wholly behind the window, none
+    was freed that does not (every cell a dispatch may read is still
+    mapped, to a page no other slot holds), and both allocators'
+    `check()` hold."""
+    rng = np.random.default_rng(seed)
+    ps, window, n_slots, max_len = 4, 10, 4, 96
+    full = PageAllocator(n_slots * (max_len // ps) + 1, ps)
+    probe = WindowRings(PageAllocator(2, ps), 0, window, chunk)
+    win = PageAllocator(n_slots * probe.ring_pages + 1, ps)
+    rings = WindowRings(win, n_slots, window, chunk)
+    assert rings.ring_pages * ps >= window + chunk - 1 + (ps - 1)
+    slots = {}  # slot -> dict(pos, limit, run)
+    freed_total = 0
+
+    def readable(pos):
+        return max(pos - window + 1, 0)
+
+    for _ in range(1500):
+        op = rng.integers(0, 4)
+        free = [s for s in range(n_slots) if s not in slots]
+        if op == 0 and free:            # admit
+            slot = free[0]
+            p = int(rng.integers(1, 50))
+            limit = min(p + int(rng.integers(1, 40)), max_len)
+            run = full.alloc((limit - 1) // ps + 1)
+            rings.hold(slot, max(p - window, 0), p - 1)
+            slots[slot] = dict(pos=p - 1, limit=limit, run=run)
+        elif op in (1, 2) and slots:    # a dispatch of k steps
+            k = int(rng.choice([1, 2, 4, 8][: 1 + int(np.log2(chunk))]))
+            for slot, st in list(slots.items()):
+                first = readable(st["pos"])
+                last = min(st["pos"] + k - 1, st["limit"] - 1)
+                freed = rings.hold(slot, first, last)
+                freed_total += freed
+                # nothing behind the window is held ...
+                rings.check(slot, first)
+                assert rings.lo[slot] == first // ps
+                # ... and nothing inside it was freed: every cell the
+                # k steps read or write maps to a page of its own
+                pages = {
+                    int(rings.table[slot, (c // ps) % rings.ring_pages])
+                    for c in range(first, last + 1)
+                }
+                assert TRASH_PAGE not in pages
+                assert len(pages) == last // ps - first // ps + 1
+                st["pos"] = min(st["pos"] + k, st["limit"] - 1)
+                if st["pos"] + 1 >= st["limit"]:   # finished
+                    full.free(st["run"])
+                    rings.release(slot)
+                    del slots[slot]
+        elif op == 3 and slots:         # preempt (or cancel) one
+            slot = int(rng.choice(list(slots)))
+            full.free(slots.pop(slot)["run"])
+            rings.release(slot)
+        full.check()
+        win.check()
+        held = [
+            int(p) for row in rings.table for p in row if p != TRASH_PAGE
+        ]
+        assert len(held) == len(set(held)) == win.used_pages
+        assert rings.pages_held == win.used_pages
+        assert full.used_pages == sum(len(s["run"]) for s in slots.values())
+    assert freed_total == rings.pages_freed_behind > 100
+    for slot, st in slots.items():
+        full.free(st["run"])
+        rings.release(slot)
+    assert win.used_pages == 0 and full.used_pages == 0
+    full.check()
+    win.check()
+
+
+def test_window_rings_refuse_a_span_wider_than_the_ring():
+    rings = WindowRings(PageAllocator(32, 4), 1, window=10, chunk=4)
+    with pytest.raises(ValueError, match="ring"):
+        rings.hold(0, 0, rings.ring_pages * 4)
 
 
 def test_pages_for():

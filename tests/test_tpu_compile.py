@@ -231,7 +231,172 @@ def test_paged_chunk_program_walks_pool_in_place(chip, monkeypatch):
     ]
     assert not moved, moved
     leaf_bytes = 2 * np.prod(leaf)
-    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf_bytes
+    # as on the commit before the layer loop went over periods (a
+    # homogeneous model is a period of one, through the same code):
+    # 135,120,896 bytes of temporaries there and here
+    assert temp <= 136 * 1000 * 1000, temp
+
+
+def _mellum2_served(depth):
+    """The Mellum2 configuration as the benchmark serves it (the
+    published widths, `depth` layers, 64 slots x 3584 positions,
+    16-cell pages, 8 steps a dispatch): (cfg, params, pools, ring
+    pages, table width), all shapes only."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _mellum2_tiny as tiny
+    from dlrover_tpu.models import decode, llama
+    from dlrover_tpu.serving.paged_kv import PageAllocator, WindowRings
+
+    slots, max_len, chunk = 64, 3584, 8
+    cfg = tiny.config(
+        tiny.published_model(depth), dtype=jnp.bfloat16, max_seq_len=max_len
+    )
+    ring = WindowRings(
+        PageAllocator(2, PAGE), 0, cfg.sliding_window, chunk
+    ).ring_pages
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    pools = jax.eval_shape(
+        lambda: decode.init_hybrid_pools(
+            cfg, slots * (max_len // PAGE) + 1, slots * ring + 1, PAGE
+        )
+    )
+    return cfg, params, pools, ring, slots, max_len, chunk
+
+
+def _served_depth():
+    import json
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "mellum2-12b-a2.5b.serve-1chip.json",
+    )
+    with open(path) as f:
+        return json.load(f)["num_hidden_layers"]
+
+
+V5E_HBM_BYTES = int(15.75 * 2**30)
+
+
+def test_mellum2_chunk_program_fits_the_chip(chip, monkeypatch):
+    """The hybrid chunk program (k = 8) at the published widths and
+    the depth the benchmark serves: both paged kernels (the window
+    one too) and the experts' grouped kernels inside, no copy of
+    either class of pages or of a layer's experts, and arguments +
+    temporaries under the chip's memory with 1 GB to spare."""
+    import re
+
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pools, ring, slots, max_len, chunk = _mellum2_served(
+        _served_depth()
+    )
+    i32 = S((slots,), jnp.int32)
+    program = engine._build_chunk_program(cfg, -1, None, 0.0, 0, 1.0)
+    args = _on_chip(chip, (
+        pools, S((slots, max_len // PAGE), jnp.int32), params,
+        i32, i32, S((slots,), jnp.bool_), i32, S((slots, 2), jnp.uint32),
+    ))
+    ring_table = _on_chip(chip, S((slots, ring), jnp.int32))
+    compiled = program["paged"].lower(*args, chunk, ring_table).compile()
+    text = compiled.as_text()
+    for kernel in ("paged_attention_decode_window", "moe_grouped_gate_up",
+                   "moe_grouped_down"):
+        assert kernel in text
+    assert re.search(r"paged_attention_decode(\.\d+)? = ", text)
+    big = {
+        tuple(pools[c]["k"].shape) for c in pools
+    } | {tuple(params["layers"]["we_gate"].shape),
+         tuple(params["layers"]["we_down"].shape)}
+    big |= {s[1:] for s in big} | {(1,) + s[1:] for s in big}
+    moved = [
+        m.group(0)
+        for m in re.finditer(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* "
+            r"(dynamic-slice|dynamic-update-slice|copy)\(", text
+        )
+        if tuple(int(d) for d in m.group(2).split(",")) in big
+    ]
+    assert not moved, moved
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
+
+
+def test_mellum2_largest_prefill_fits_the_chip(chip, monkeypatch):
+    """The admission program of the largest prompt bucket (min(4096,
+    max_len) = 3584 tokens: 28672 routed pairs a layer, a causal band
+    of 1024 on the window layers) beside the resident pools."""
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pools, ring, slots, max_len, _ = _mellum2_served(
+        _served_depth()
+    )
+    program = engine._build_admit_programs(cfg, max_len)["paged_cold_hybrid"]
+    args = _on_chip(chip, (
+        pools, S((slots, max_len // PAGE), jnp.int32), params,
+        S((max_len,), jnp.int32), S((), jnp.int32),
+        S((max_len // PAGE,), jnp.int32), S((ring,), jnp.int32),
+        S((), jnp.int32),
+    ))
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "moe_grouped_gate_up" in text
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
+
+
+@pytest.mark.parametrize("rows,tile", [(1472, 16), (36864, 128)])
+def test_grouped_expert_kernels(chip, rows, tile):
+    """The experts' two kernels at Mellum2's widths: a decode batch's
+    padded rows in tiles of 16 and a prefill's in tiles of 128, whole
+    [2304, 896] matrices as blocks."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    d, m, e, layers = 2304, 896, 64, 2
+    text = _compile(
+        chip,
+        lambda x, wg, wu, wd, sizes, groups: gmm.expert_mlp_kernel(
+            x, wg, wu, wd, groups, tile, layer=1),
+        S((rows, d), jnp.bfloat16), S((layers, e, d, m), jnp.bfloat16),
+        S((layers, e, d, m), jnp.bfloat16),
+        S((layers, e, m, d), jnp.bfloat16), S((e,), jnp.int32),
+        S((rows // tile,), jnp.int32),
+    )
+    assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
+
+
+def test_window_paged_decode(chip):
+    """The paged kernel with a static window over a ring of 66 pages."""
+    cell = (3, 64 * 66 + 1, PAGE, 4, 128)
+    pool = {"k": S(cell, jnp.bfloat16), "v": S(cell, jnp.bfloat16)}
+    text = _compile(
+        chip,
+        functools.partial(
+            pa.paged_attention, impl="kernel", layer=2, window=1024),
+        S((64, 32, 128), jnp.bfloat16), pool, S((64, 66), jnp.int32),
+        S((64,), jnp.int32),
+    )
+    assert "paged_attention_decode_window" in text
+
+
+def test_flash_forward_with_a_band(chip):
+    text = _compile(
+        chip,
+        functools.partial(fa.flash_attention, window=1024),
+        S((1, 3584, 32, 128), jnp.bfloat16),
+        S((1, 3584, 4, 128), jnp.bfloat16),
+        S((1, 3584, 4, 128), jnp.bfloat16),
+    )
+    assert "flash_attention_fwd" in text
 
 
 def _int8_matmul(chip, rows, k, o):
