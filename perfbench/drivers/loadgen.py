@@ -34,12 +34,14 @@ class Client(threading.Thread):
         self.host, self.port = url.hostname, url.port
         self.close_at, self.timeout = close_at, timeout
         self.done = []      # one record per request that ended
+        self.sent = 0       # requests handed to the server so far
         self.ran_out = False
 
     def run(self):
         for k, request in enumerate(self.requests):
             if time.time() >= self.close_at:
                 return
+            self.sent = k + 1
             record = self.one(k, request)
             self.done.append(record)
             if record["state"] == "open_at_close":
@@ -129,6 +131,10 @@ def main() -> int:
         "open_at": args.open_at, "close_at": close_at,
         "started_at": t_started, "records": records,
         "clients_ran_out": [c.index for c in clients if c.ran_out],
+        # the loop's margin: the fewest requests any client still had
+        # to send when it stopped (0 with its last one sent)
+        "least_requests_left": min(
+            len(c.requests) - c.sent for c in clients),
         "clients_stuck": stuck,
         "send_gap_ms": {
             "n": len(gaps),

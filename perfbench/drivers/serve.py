@@ -27,6 +27,7 @@ import threading
 import time
 import urllib.request
 
+import flops
 import generate
 import lib
 import weights
@@ -34,6 +35,17 @@ import weights
 SPANNED = ("_harvest", "_admit", "_dispatch_chunk")
 PAGED_KERNEL = "paged_attention_decode"
 FLASH_KERNEL = "flash_attention_fwd"
+
+
+def decode_step_bytes(model, slots, contexts):
+    """Bytes that one decode step of a full batch has to read: every
+    weight a token is multiplied by, in bfloat16, and the K and V of
+    every live position (`contexts`: (positions, share of slot time)
+    pairs, closed_loop.live_contexts). closed_loop.py takes the cell's
+    roofline rate from it."""
+    live = sum(c * w for c, w in contexts)
+    return (2 * flops.matmul_params(model)
+            + flops.paged_decode_needs(model, slots * live, slots)["bytes"])
 
 
 def rehearsal_sizes(model, run, mix):
@@ -466,6 +478,7 @@ def run(cell, args, t_start: float) -> dict:
         "compilations": counter.count,
         "send_gap_ms": load["send_gap_ms"],
         "clients_ran_out": load["clients_ran_out"],
+        "least_requests_left": load["least_requests_left"],
         "clients_stuck": load["clients_stuck"],
         "submit_wait_ms": {
             "n": len(window["submit_wait_s"]),
@@ -500,7 +513,7 @@ def run(cell, args, t_start: float) -> dict:
 
     failed = len(window["ended"]) - len(window["good"])
     out = {
-        "correct": checks.ok and failed == 0,
+        "correct": checks.ok and failed == 0, "checks": checks.compared,
         "attempted": len(window["ended"]), "failed": failed,
         "device": dict(device, memory_peak_bytes=memory_peak),
     }

@@ -23,6 +23,7 @@ import sys
 import tempfile
 import time
 
+import flops_mellum2
 import generate
 import lib
 import weights_mellum2
@@ -77,6 +78,19 @@ def mellum2_config(model: dict, run: dict):
         tie_embeddings=bool(model["tie_word_embeddings"]),
         attn_impl="auto", remat=False,
     )
+
+
+def decode_step_bytes(model, slots, contexts):
+    """serve.decode_step_bytes for this configuration: all 64 experts
+    of every layer (a batch of 64 slots routes 512 pairs a layer and
+    touches them all), no embedding (a gather), and of a window
+    layer's K and V only a slot's last `sliding_window` positions."""
+    full = sum(c * w for c, w in contexts)
+    window = sum(min(c, model["sliding_window"]) * w for c, w in contexts)
+    embedding = 2 * model["vocab_size"] * model["hidden_size"]
+    return (flops_mellum2.weight_bytes(model) - embedding
+            + flops_mellum2.hybrid_paged_decode_needs(
+                model, slots * full, slots * window, slots)["bytes"])
 
 
 def rehearsal_sizes(model, run, mix):
@@ -326,6 +340,7 @@ def run(cell, args, t_start: float) -> dict:
         "compilations": counter.count,
         "send_gap_ms": load["send_gap_ms"],
         "clients_ran_out": load["clients_ran_out"],
+        "least_requests_left": load["least_requests_left"],
         "clients_stuck": load["clients_stuck"],
         "kernel_path": engine.kernel_path,
         "paged": engine.paged_stats(),
@@ -369,7 +384,7 @@ def run(cell, args, t_start: float) -> dict:
 
     failed = len(window["ended"]) - len(window["good"])
     out = {
-        "correct": checks.ok and failed == 0,
+        "correct": checks.ok and failed == 0, "checks": checks.compared,
         "attempted": len(window["ended"]), "failed": failed,
         "device": dict(device, memory_peak_bytes=memory_peak),
     }

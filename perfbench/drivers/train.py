@@ -179,7 +179,7 @@ def limit_of(limits: dict, name: str):
     return limits["loss_rel" if name.startswith("loss_") else name]["limit"]
 
 
-def judge(cell, events, rehearsal: bool) -> bool:
+def judge(cell, events, rehearsal: bool) -> lib.Checks:
     mix, limits = cell["mix"], cell["model"]["limits"]
     checks = lib.Checks()
     window = of(events, "window")[0]
@@ -218,7 +218,7 @@ def judge(cell, events, rehearsal: bool) -> bool:
             abs(after["loss"] - before.get(after["step"], math.nan)),
             limits["resumed_loss_abs_gap"]["limit"],
         )
-    return checks.ok
+    return checks
 
 
 def run(cell, args, t_start: float) -> dict:
@@ -260,8 +260,9 @@ def result(cell, args, t_start, events) -> dict:
         "tokens_per_step": tokens_per_step, "rehearsal": args.rehearsal,
         "device_kind": up["device"]["kind"],
     }
+    checks = judge(cell, events, args.rehearsal)
     out = {
-        "correct": judge(cell, events, args.rehearsal),
+        "correct": checks.ok, "checks": checks.compared,
         "attempted": window["steps"] + len(steps_after),
         "failed": failed,
         "device": device,

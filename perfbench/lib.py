@@ -227,17 +227,24 @@ def layer_metrics(cell: dict, run: dict) -> dict:
 
 
 class Checks:
-    """Every number compared, printed beside its limit."""
+    """Every number compared, printed beside its limit, and kept
+    (`compared`) for the result line's last key and the run's last
+    lines on standard error."""
 
     def __init__(self):
         self.ok = True
+        self.compared = {}
 
     def at_most(self, name, value, limit):
         good = value == value and value <= limit
         self.ok &= good
+        self.compared[name] = {
+            "value": value if value == value else None, "limit": limit}
         log(f"CHECK {name} value={value!r} limit={limit!r} "
                 f"{'ok' if good else 'FAILED'}")
 
     def require(self, name, good, detail=""):
         self.ok &= bool(good)
+        # a condition counts as the number of times it was broken
+        self.compared[name] = {"value": 0 if good else 1, "limit": 0}
         log(f"CHECK {name} {'ok' if good else 'FAILED'} {detail}")

@@ -5,10 +5,10 @@
 runs one cell of BENCHMARK.json once in a new process: set-up,
 warm-up, a measured window, a check of what the window produced
 against the plain reference, and as the LAST line of stdout one JSON
-object (correct, attempted, failed, metrics, device, and in a traced
-run breakdown). A platform other than `tpu`, or another number of
-chips than the cell asks for, is a failure: exit code 1, no result
-line. `--rehearsal` runs the same control flow at tiny sizes on the
+object (correct, attempted, failed, metrics, device, in a traced run
+breakdown, and last `checks`: each number compared beside its limit).
+A platform other than `tpu`, or another number of chips than the cell
+asks for, is a failure: exit code 1, no result line. `--rehearsal` runs the same control flow at tiny sizes on the
 CPU and says so; it proves nothing about the chip.
 """
 
@@ -67,6 +67,13 @@ def main() -> int:
             result["device"].pop(key, None)
     lib.log(f"[perfbench] {args.workload} seed {args.seed}: "
             f"{time.time() - T_START:.0f} s in all")
+    # every number compared beside its limit: the last lines of
+    # standard error, and the last key of the result line
+    result["checks"] = result.pop("checks", {})
+    for name, c in result["checks"].items():
+        print(f"CHECK {name} value={c['value']!r} limit={c['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -44,14 +44,16 @@ def test_a_train_step_that_returns_its_state_unchanged():
         ]
 
     sound = acc.train_step
-    assert train.judge(cell, events_of(sound), rehearsal=True)
+    assert train.judge(cell, events_of(sound), rehearsal=True).ok
 
     def frozen(state, batch):  # computes, and keeps the state it was given
         kept = jax.tree_util.tree_map(lambda x: x + 0, state)
         _, metrics = sound(state, batch)
         return kept, metrics
 
-    assert not train.judge(cell, events_of(frozen), rehearsal=True)
+    frozen_checks = train.judge(cell, events_of(frozen), rehearsal=True)
+    assert not frozen_checks.ok
+    assert any(c["value"] > c["limit"] for c in frozen_checks.compared.values())
 
 
 def test_a_served_token_altered_where_it_is_produced(monkeypatch):

@@ -34,7 +34,7 @@ def test_requests_repeat_and_seeds_share_the_sizes():
         )
 
     full = sorted(generate.request_sizes(mix))
-    assert len(full) == 144 * mix["requests_per_client"]
+    assert len(full) == 144 * mix["requests_per_client"] == 144 * 12
     prompts = [p for p, _ in full]
     outputs = [o for _, o in full]
     assert min(prompts) >= 64 and max(prompts) <= 512
