@@ -24,14 +24,18 @@ provides both halves:
   trash/stale page holds, so the gather may read anything dead.
 - `paged_attention(..., impl="kernel")`: a Pallas kernel in the
   flash_attention.py online-softmax style that never materializes the
-  dense view: the layer index and the page table ride in as
-  SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the
-  BlockSpec index map resolves (layer, page id) before the body runs
-  and the pipeline streams pages HBM→VMEM directly. int8 pools
-  dequantize inside the inner loop (fused into the score/accumulate
-  dots — the cache reads stay int8 in HBM, halving decode's
-  memory-bound byte traffic). interpret=True on CPU keeps tier-1
-  runnable.
+  dense view. The layer index, the page table and the lengths ride in
+  as SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec) and the
+  pool stays in HBM: one grid step per slot copies that slot's pages
+  of that layer itself, a BLOCK of pages at a time (one DMA a page,
+  the next block's started before this block's arithmetic, two
+  buffers), from the first page the window still reaches to the page
+  of the last position and no further. Per KV head the scores and the
+  value sums are matrix products on the MXU (the group's query rows
+  against the block's cells), the online softmax stays f32. int8
+  pools are multiplied by their scales in VMEM, head by head, before
+  the products: the cache reads stay int8 in HBM. interpret=True on
+  CPU keeps tier-1 runnable.
 - `impl="auto"`: the kernel on a TPU when `supports()` passes, else
   the reference (a decision the engine logs once and reports as
   `kernel_path`). CPU tier-1 therefore runs the reference —
@@ -42,8 +46,10 @@ provides both halves:
   streams the pages of its own KV-head slice (no collectives).
 
 The single-query shape gate reuses ops/flash_attention.supports()
-(fixed to accept q_len == 1 decode shapes): head_dim lane/tile
-constraints are identical between the two kernels.
+(fixed to accept q_len == 1 decode shapes) for the head_dim range
+and the GQA grouping; what the walk itself asks of the pool (head_dim
+in whole 128-lane tiles on a TPU, sub-word heads in whole sublane
+words) is `supports()`'s own.
 """
 
 import functools
@@ -62,9 +68,10 @@ NEG_INF = -1e30
 def supports(q, pages: Dict, table, tp: int = 1) -> bool:
     """Whether the Pallas kernel handles these shapes. `q` is the
     [B, H, hd] single-token query, `pages` the pool dict (per-layer
-    or stacked: the gate reads the last four dims), `table` the
+    or stacked: the gate reads the last three dims), `table` the
     [B, P] page table. Reuses flash_attention's q_len==1 gate for the
-    head_dim constraints, then checks the page axis.
+    head_dim range and the GQA grouping, then checks what the walk
+    itself needs of the pool.
 
     `tp` is the serving tensor-parallel degree: the gate judges the
     PER-SHARD head counts (heads / tp), because that is what the
@@ -76,22 +83,25 @@ def supports(q, pages: Dict, table, tp: int = 1) -> bool:
     if shard is None:
         return False
     h, kv = shard
-    # flash's single-query gate owns the d / GQA lane constraints
-    # (probed with the per-shard head counts); the key-side
-    # "sequence" a page kernel streams is one page long
     q_probe = jax.ShapeDtypeStruct((b, 1, h, d), q.dtype)
     k_probe = jax.ShapeDtypeStruct((b, 1, kv, d), q.dtype)
     if not fa.supports(q_probe, k_probe, block_q=1, block_k=1):
         return False
-    # a page is the kernel's key block and a major dim of it (the
-    # block's last two dims are the pool's own KV x hd), so any page
-    # size lowers — but below 8 cells the grid overhead swamps the
-    # work
+    # Mosaic copies no page whose head_dim is not whole 128-lane tiles
+    if d % _LANES and not fa._interpret():
+        return False
+    # a sub-word pool packs 2 (bf16) or 4 (int8) neighbouring heads a
+    # sublane word: whole words, or the one head that goes without
+    # its axis
+    if kv > 1 and kv % (4 // pages["k"].dtype.itemsize):
+        return False
+    # a page is a block's unit and a major dim of it, so any size
+    # lowers — but below 8 cells a copy moves too little
     if page_size < 8:
         return False
     if table.ndim != 2 or table.shape[0] != b:
         return False
-    return True
+    return _vmem_bytes(pages, table, kv) <= _VMEM_BUDGET
 
 
 def use_kernel(q, pages: Dict, table, tp: int = 1) -> bool:
@@ -218,149 +228,375 @@ def _reference_window(q, pages, table, lengths, scale, layer, window):
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+# What ONE KV head's K (or V) rows of one block may take of a block
+# buffer: the block's cells follow from it (512 of bf16 at head_dim
+# 128), so a tensor-parallel shard walks the blocks of tp = 1 whatever
+# its head count (byte parity).
+_HEAD_BLOCK_BYTES = 128 * 1024
+# Two buffers each of K and V blocks, every KV head of them: 4 MiB at
+# 8 KV heads of bf16, inside the 16 MiB of scoped VMEM a v5e kernel
+# has by default. Wider pools (32 heads: 16 MiB) have the scoped limit
+# raised to what they need, up to this much of the chip's 128 MiB.
+_VMEM_BUDGET = 32 * 1024 * 1024
+_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
+# Beside the buffers: a block's unpacked operands, the scores, the
+# compiler's own temporaries (an int8 MHA block spills 10 MiB).
+_VMEM_HEADROOM = 12 * 1024 * 1024
+_LANES, _SUBLANES = 128, 8
+# Pages whose copies start, and are waited for, as one straight run.
+_RUN_PAGES = 8
+
+
+def _pad(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def _pages_per_block(pages: Dict, table) -> int:
+    """Pages one block of the walk holds: as many as keep one KV
+    head's rows inside `_HEAD_BLOCK_BYTES` (int8 counted as the two
+    bytes a cell is unpacked to before the products), at most the
+    table."""
+    page_size, _, hd = pages["k"].shape[-3:]
+    page = page_size * _pad(hd, _LANES) * max(
+        2, pages["k"].dtype.itemsize
+    )
+    return min(max(1, _HEAD_BLOCK_BYTES // page), table.shape[1])
+
+
+def _vmem_bytes(pages: Dict, table, kv: int) -> int:
+    """The buffers of a kernel over `kv` KV heads, padded as VMEM
+    tiles them: two blocks of K and two of V (heads on sublanes,
+    head_dim on lanes) and, for an int8 pool, the scales of a slot's
+    whole table, K's and V's, twice (heads on lanes)."""
+    page_size, _, hd = pages["k"].shape[-3:]
+    cell = _pad(kv, _SUBLANES) * _pad(hd, _LANES) * pages["k"].dtype.itemsize
+    total = _pages_per_block(pages, table) * page_size * cell
+    if "k_scale" in pages:
+        total += (
+            table.shape[1] * page_size * _pad(kv, _LANES)
+            * pages["k_scale"].dtype.itemsize
+        )
+    return 2 * 2 * total
+
+
+def _head_rows(buf):
+    """g -> KV head g's [cells, hd] rows, f32, out of one block as
+    the pool lays it out (`buf` [cells, KV, hd]: heads on sublanes).
+    A sub-word dtype packs neighbouring heads into one 32-bit sublane
+    word, and a plain `buf[:, g, :]` lowers to a load, a rotate and a
+    select per CELL; so the rows are read as words with a sublane
+    stride (one load per 8 cells, whichever the head) and a word's
+    heads split with shifts. Mosaic's strided load wants whole lane
+    tiles and the words whole: another head_dim, or a head count that
+    does not fill a word (a one-head shard of a bf16 pool), takes the
+    plain way."""
+    if len(buf.shape) == 2:  # one head, its axis dropped (`_kernel`)
+        return lambda g: buf[...].astype(jnp.float32)
+    cells, kv, hd = buf.shape
+    pack = 4 // buf.dtype.itemsize
+    if hd % _LANES or kv % pack:
+        return lambda g: buf[:, g, :].astype(jnp.float32)
+    per_cell = kv // pack
+    words = buf.bitcast(jnp.uint32) if pack > 1 else buf
+    words = words.reshape(cells * per_cell, hd)
+    loaded = {}
+
+    def rows(g):
+        word, part = divmod(g, pack)
+        if word not in loaded:
+            loaded.clear()  # heads come in order: one word live
+            loaded[word] = words[pl.ds(word, cells, stride=per_cell), :]
+        w = loaded[word]
+        if pack == 1:
+            return w.astype(jnp.float32)
+        if pack == 4:
+            # int8: byte `part` up to the top, back down with its sign
+            w = pltpu.bitcast(w << (24 - 8 * part), jnp.int32) >> 24
+            return w.astype(jnp.float32)
+        # bf16 is the top half of an f32
+        w = w & jnp.uint32(0xFFFF0000) if part else w << 16
+        return pltpu.bitcast(w, jnp.float32)
+
+    return rows
+
 
 def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
-                  q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr,
-                  *, scale, page_size, num_pages, n_rep, quant,
-                  window=None):
-    """Grid (B, P): one invocation attends query row b — every KV
-    head of it — over physical page table[b, p] of layer layer[0]
-    (the index map's business: the body never reads `layer_ref`).
-    The page block is the pool's own [page, KV, hd] slab: its last
-    two dims ARE the array's, which Mosaic's (8, 128) block rule
-    accepts at any head count (a block of ONE head on the KV axis,
-    second to last, is refused). KV heads sit on sublanes and head_dim on lanes, so the
-    score is a multiply + lane reduction per rep-group member and the
-    value sum a reduction over the page's cells — VPU/XLU work, which
-    a one-row decode query cannot feed the MXU with anyway, and
-    arithmetic that never mixes heads (the tp byte-parity argument).
-    Online softmax in VMEM scratch across the page axis (sequential
-    'arbitrary' dim); pages past the row's valid length are skipped
-    whole."""
-    bi = pl.program_id(0)
-    pi = pl.program_id(1)
+                  q_ref, k_hbm, v_hbm, scales, o_ref,
+                  k_buf, v_buf, sems, turn,
+                  m_scr, l_scr, acc_scr, s_scr, pv_scr,
+                  *, scale, page_size, per_block, window=None):
+    """Grid (B,): one invocation attends query row b, every KV head of
+    it, over the pages its length covers, `per_block` pages at a time.
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    K and V stay in HBM (`k_hbm`, `v_hbm`: the stacked pool's
+    leaves); a slot's pages are not contiguous, so the body copies
+    them itself, one DMA a page and leaf into buffer `cur` of two,
+    and starts the next block's copies — the next SLOT's first block
+    after a slot's last — before it waits for this block's (`turn`
+    carries the buffer's parity over grid steps). The walk runs from
+    the first page that still holds a position inside the window
+    (page 0 without one) to the page of position length - 1: pages
+    past the length are never read, a slot of length 0 reads nothing
+    and writes zeros.
 
-    length = len_ref[bi]
-    # a window layer walks its ring from the first logical page that
-    # still holds a position inside the window (the index map starts
-    # there too); `window=None` leaves the program as it was
-    page = pi  # the logical page this invocation attends over
-    if window is not None:
-        page = pi + jnp.maximum(length - window, 0) // page_size
+    Per KV head (`_head_rows`), scores are the group's [n_rep, hd]
+    query rows against [hd, cells] and the value sum the probabilities
+    [n_rep, cells] against [cells, hd], both on the MXU with f32
+    accumulation, operands in the query's dtype (an int8 head's rows
+    are first multiplied by their scales, in VMEM: HBM traffic stays
+    int8; `scales` is (K's, V's) of the slot's walk, [1, cells of
+    the walk, KV], or empty). Between the two the heads' scores stand
+    together in `s_scr` [H, cells], so that the online softmax (max,
+    sum, rescale: f32, in VMEM scratch) is ONE chain of whole
+    registers a block and not one chain of quarter-filled ones a
+    head. Arithmetic never mixes KV heads, and a block's cells do not
+    depend on how many heads the kernel sees (the tp byte-parity
+    argument)."""
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    kv, n_rep = q_ref.shape[1:3]
+    ring = table_ref.shape[1]
+    cells = per_block * page_size
+    run_pages = min(_RUN_PAGES, per_block)
 
-    @pl.when(page * page_size < length)
-    def _compute():
-        k = k_ref[0][0, 0].astype(jnp.float32)     # [page, KV, hd]
-        v = v_ref[0][0, 0].astype(jnp.float32)
-        if quant:
-            # int8 cells, [page, KV, 1] scales: the dequant multiply
-            # runs on the VMEM-resident block — HBM traffic stays int8
-            k = k * k_ref[1][0][0, 0].astype(jnp.float32)
-            v = v * v_ref[1][0][0, 0].astype(jnp.float32)
-        cells = page * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, k.shape[:2] + (1,), 0
-        )
-        live = cells < length                      # [page, KV, 1]
+    def walk(slot):
+        """(first logical page, pages) of the slot's walk."""
+        first = 0
+        if window is not None:
+            first = jnp.maximum(len_ref[slot] - window, 0) // page_size
+        last = (len_ref[slot] + page_size - 1) // page_size
+        return first, jnp.minimum(last - first, ring)
+
+    def copies(slot, block, buf, start):
+        """Start, or wait for, the copies of one block of `slot`
+        into buffer `buf`: only the pages the walk covers, in runs of
+        `run_pages` while they last and then page by page."""
+        first, pages = walk(slot)
+        at = block * per_block
+        count = jnp.clip(pages - at, 0, per_block)
+        entry0 = first + at
+        if window is not None:
+            entry0 = entry0 % ring  # logical page p at entry p % R
+
+        def page(i, carry=None):
+            entry = entry0 + i
+            if window is not None:
+                entry = jnp.where(entry >= ring, entry - ring, entry)
+            src = (layer_ref[0], table_ref[slot, entry])
+            for hbm, vmem in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[src], vmem.at[buf, i], sems.at[buf]
+                )
+                copy.start() if start else copy.wait()
+            return carry
+
+        def run(r, carry):
+            if start:
+                # unrolled: the scalar core overlaps the pages' table
+                # reads and address arithmetic
+                for i in range(run_pages):
+                    page(r * run_pages + i)
+                return carry
+            # a semaphore counts bytes: one wait a leaf for the run's
+            # pages (the source only gives the size)
+            for vmem in (k_buf, v_buf):
+                dst = vmem.at[buf, pl.ds(r * run_pages, run_pages)]
+                pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
+            return carry
+
+        runs = count // run_pages
+        jax.lax.fori_loop(0, runs, run, 0)
+        jax.lax.fori_loop(runs * run_pages, count, page, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        # a block's unfilled tail is masked, but 0 x NaN is NaN: the
+        # buffers only ever hold zeros or cells of the pool
+        for vmem in (k_buf, v_buf):
+            vmem[...] = jnp.zeros_like(vmem)
+        turn[0] = 0
+
+    first, pages = walk(b)
+    blocks = (pages + per_block - 1) // per_block
+    length = len_ref[b]
+    buf0 = turn[0]
+    # nobody has started the very first block; and a slot with no
+    # block starts the next slot's first in its stead
+    ahead = jnp.where((b == 0) & (blocks > 0), b, b + 1)
+
+    @pl.when(((b == 0) | (blocks == 0)) & (ahead < slots))
+    def _ahead():
+        copies(ahead, 0, buf0, True)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block(j, carry):
+        cur = (buf0 + j) % 2
+        # the next block's copies, or the next SLOT's first block's
+        # after this slot's last, before this block's are waited for
+        more = j + 1 < blocks
+
+        @pl.when(more | (b + 1 < slots))
+        def _next():
+            copies(
+                jnp.where(more, b, b + 1), jnp.where(more, j + 1, 0),
+                1 - cur, True,
+            )
+
+        copies(b, j, cur, False)
+        at = (first + j * per_block) * page_size
+        pos = at + jax.lax.broadcasted_iota(jnp.int32, (1, cells), 1)
+        live = pos < length
         if window is not None:
             # the oldest page's cells that have left the window
-            live = live & (cells >= length - window)
-        for r in range(n_rep):
-            q = q_ref[0, r].astype(jnp.float32)    # [KV, hd]
-            s = jnp.sum(k * q[None], axis=2, keepdims=True) * scale
-            s = jnp.where(live, s, NEG_INF)        # [page, KV, 1]
-            m_prev = m_scr[r][:, :1]               # [KV, 1]
-            l_prev = l_scr[r][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[None])
-            l_new = alpha * l_prev + jnp.sum(p, axis=0)
-            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * v, axis=0)
-            m_scr[r] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[r] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            live = live & (pos >= length - window)
+        dtype = q_ref.dtype
+        k_rows, v_rows = (
+            _head_rows(x.at[cur].reshape((cells,) + x.shape[3:]))
+            for x in (k_buf, v_buf)
+        )
+        k_scale = v_scale = None
+        if scales:
+            # an int8 block's scales [cells, KV], heads on lanes; a
+            # dead cell's is dropped, so that whatever it holds meets
+            # a zero
+            dead = at + jax.lax.broadcasted_iota(
+                jnp.int32, (cells, 1), 0) >= length
+            k_scale, v_scale = (
+                jnp.where(
+                    dead, 0.0,
+                    x[0, pl.ds(j * cells, cells), :].astype(jnp.float32),
+                )
+                for x in scales
+            )
 
-    @pl.when(pi == num_pages - 1)
-    def _finalize():
-        for r in range(n_rep):
-            l = l_scr[r][:, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, r] = (acc_scr[r] / l).astype(o_ref.dtype)
+        def operand(rows, cell_scale, g):
+            x = rows(g)
+            if cell_scale is not None:
+                # exact in f32, rounded once to the query's dtype
+                x = x * cell_scale[:, g:g + 1]
+            return x.astype(dtype)
+
+        for g in range(kv):
+            s_scr[g * n_rep:(g + 1) * n_rep] = jax.lax.dot_general(
+                q_ref[0, g], operand(k_rows, k_scale, g),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        s = jnp.where(live, s_scr[...] * scale, NEG_INF)   # [H, cells]
+        m_prev = m_scr[:, :1]                               # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        s_scr[...] = p
+        for g in range(kv):
+            # probabilities rounded to the query's dtype before the
+            # value product, as `_reference` rounds them
+            pv_scr[g * n_rep:(g + 1) * n_rep] = jnp.dot(
+                s_scr[g * n_rep:(g + 1) * n_rep].astype(dtype),
+                operand(v_rows, v_scale, g),
+                preferred_element_type=jnp.float32,
+            )
+        acc_scr[...] = acc_scr[...] * alpha + pv_scr[...]
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    jax.lax.fori_loop(0, blocks, block, 0)
+    turn[0] = (buf0 + blocks) % 2
+    l = l_scr[:, :1]
+    o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype
+    )
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
 def _kernel(q, pages, layer, table, lengths, scale, window=None):
     """q [B, H, hd] → [B, H, hd] over layer `layer` (int32[1]) of the
-    stacked pool. The layer index, the page table and lengths ride as
-    scalar-prefetch operands so the k/v BlockSpec index maps can
-    dereference (layer[0], table[b, p]) — the pipeline then streams
-    the PHYSICAL pages of that layer out of the whole pool, never a
-    sliced or gathered copy. q travels rep-major ([B, n_rep, KV, hd])
-    so one rep-group member is a [KV, hd] tile laid out like a page
-    cell."""
+    stacked pool. (Jitted so that the engine's chunk programs, one a
+    chunk length, and a period's layers of one kind trace and lower
+    the body once between them: set-up time.) The layer index, the
+    page table and lengths ride as scalar-prefetch operands; the
+    pool's leaves are handed over whole and stay in HBM (`pl.ANY`),
+    so the body's copies dereference (layer[0], table[b, p]) and
+    stream the PHYSICAL pages of that layer out of the pool — never a
+    sliced or gathered copy. q travels group-major ([B, KV, n_rep,
+    hd]): one KV head's query rows are one leading index. An int8
+    pool's scales are the one thing gathered (`_walk_scales`: Mosaic
+    copies no page whose last dim is not whole lane tiles, and theirs
+    is 1)."""
     b, h, hd = q.shape
     page_size, kv = pages["k"].shape[2:4]
     n_rep = h // kv
-    num_pages = table.shape[1]
-    quant = "k_scale" in pages
-    qg = q.reshape(b, kv, n_rep, hd).swapaxes(1, 2)
-
-    def q_map(bi, pi, lay, tab, lens):
-        return (bi, 0, 0, 0)
-
-    if window is None:
-        def kv_map(bi, pi, lay, tab, lens):
-            return (lay[0], tab[bi, pi], 0, 0, 0)
-    else:
-        def kv_map(bi, pi, lay, tab, lens):
-            # ring entries past the last written page are not read
-            # again: they map to the last one (an unchanged block
-            # index starts no new copy)
-            first = jnp.maximum(lens[bi] - window, 0) // page_size
-            page = jnp.minimum(
-                first + pi, jnp.maximum(lens[bi] - 1, 0) // page_size
-            )
-            return (lay[0], tab[bi, page % num_pages], 0, 0, 0)
-
-    q_spec = pl.BlockSpec((1, n_rep, kv, hd), q_map)
-    kv_spec = pl.BlockSpec((1, 1, page_size, kv, hd), kv_map)
-    sc_spec = pl.BlockSpec((1, 1, page_size, kv, 1), kv_map)
-    if quant:
-        in_specs = [q_spec, (kv_spec, (sc_spec,)), (kv_spec, (sc_spec,))]
-        operands = [
-            qg,
-            (pages["k"], (pages["k_scale"],)),
-            (pages["v"], (pages["v_scale"],)),
-        ]
-    else:
-        in_specs = [q_spec, (kv_spec,), (kv_spec,)]
-        operands = [qg, (pages["k"],), (pages["v"],)]
-
+    per_block = _pages_per_block(pages, table)
+    need = _vmem_bytes(pages, table, kv)
+    if need > _VMEM_BUDGET:
+        raise ValueError(
+            f"paged kernel: {need} bytes of block buffers for {kv} KV "
+            f"heads, over {_VMEM_BUDGET}: supports() refuses this"
+        )
+    cells_kv = [pages["k"], pages["v"]]
+    if kv == 1:
+        # Mosaic pads a lone sub-word head to a whole word and then
+        # cannot copy a page of it: the pool goes without the axis
+        cells_kv = [x.reshape(x.shape[:3] + (hd,)) for x in cells_kv]
+    scales = ()
+    if "k_scale" in pages:
+        scales = _walk_scales(
+            pages, layer, table, lengths, window, per_block
+        )
+    q_spec = pl.BlockSpec(
+        (1, kv, n_rep, hd), lambda bi, lay, tab, lens: (bi, 0, 0, 0)
+    )
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size,
-        num_pages=num_pages, n_rep=n_rep, quant=quant, window=window,
+        per_block=per_block, window=window,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, num_pages),
-        in_specs=in_specs,
-        out_specs=q_spec,
+        grid=(b,),
+        in_specs=[
+            q_spec,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            tuple(
+                pl.BlockSpec(
+                    (1,) + x.shape[1:],
+                    lambda bi, lay, tab, lens: (bi, 0, 0),
+                )
+                for x in scales
+            ),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, h, hd), lambda bi, lay, tab, lens: (bi, 0, 0)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((n_rep, kv, 128), jnp.float32),
-            pltpu.VMEM((n_rep, kv, 128), jnp.float32),
-            pltpu.VMEM((n_rep, kv, hd), jnp.float32),
+            pltpu.VMEM((2, per_block) + x.shape[2:], x.dtype)
+            for x in cells_kv
+        ] + [
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, _LANES), jnp.float32),        # max
+            pltpu.VMEM((h, _LANES), jnp.float32),        # sum
+            pltpu.VMEM((h, hd), jnp.float32),            # accumulator
+            pltpu.VMEM((h, per_block * page_size), jnp.float32),
+            pltpu.VMEM((h, hd), jnp.float32),            # a block's p v
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_rep, kv, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a step starts the next slot's first copies and hands
+            # over the buffer's parity: in order, on one core
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(
+                _VMEM_SCOPED_DEFAULT, need + _VMEM_HEADROOM
+            ),
         ),
         interpret=fa._interpret(),
         name=(
@@ -369,9 +605,25 @@ def _kernel(q, pages, layer, table, lengths, scale, window=None):
         ),
     )(
         layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
-        *operands,
+        q.reshape(b, kv, n_rep, hd), *cells_kv, scales,
     )
-    return out.swapaxes(1, 2).reshape(b, h, hd)
+
+
+def _walk_scales(pages, layer, table, lengths, window, per_block):
+    """An int8 pool's (K, V) scales of each slot's walk, [B, cells,
+    KV] with the cells in the walk's order (the ring's logical order
+    under a window) and padded to whole blocks."""
+    page_size = pages["k"].shape[2]
+    if window is not None:
+        _, table = ring_view(table, lengths, window, page_size)
+    view = gather_pages(
+        {n: pages[n] for n in ("k_scale", "v_scale")}, table, layer
+    )
+    short = -table.shape[1] % per_block * page_size
+    return tuple(
+        jnp.pad(view[n][..., 0], ((0, 0), (0, short), (0, 0)))
+        for n in ("k_scale", "v_scale")
+    )
 
 
 def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh,
@@ -381,10 +633,11 @@ def _sharded_kernel(q, pages, layer, table, lengths, scale, mesh,
     page table and lengths replicated (host-planned — every shard
     walks the same pages, reading only its own KV-head slice of
     them). Attention is per-KV-head local, so the body needs NO
-    collectives, and the kernel's grid/scratch shapes depend only on
-    per-shard head counts: output is byte-identical to the tp=1
-    kernel chunked by head. Specs come from parallel/mesh.py:serving_head_specs, the
-    one layout source."""
+    collectives, and a block's cells are sized from ONE head's rows
+    (`_pages_per_block`), so every head's products and partial sums
+    are those of tp=1: output is byte-identical to the tp=1 kernel
+    chunked by head. Specs come from
+    parallel/mesh.py:serving_head_specs, the one layout source."""
     from dlrover_tpu.parallel.mesh import serving_head_specs
 
     specs = serving_head_specs(mesh)

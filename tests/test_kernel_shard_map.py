@@ -163,6 +163,35 @@ class TestShardedPagedParity:
         )
         assert _bytes_equal(tp1, sharded)
 
+    def test_a_shard_walks_the_blocks_of_tp1(self, forced, mesh2):
+        """head_dim 128 in f32: 128 KiB of ONE head's rows are 256
+        cells a block, for the two heads of tp = 1 and for a shard's
+        one alike, and a walk of 23 pages crosses a block's boundary.
+        Sized from all the heads a kernel sees, tp = 1 would walk 128
+        cells a block and the shard 256: other partial sums, other
+        bytes."""
+        q, pool, table, lengths = _paged_case(
+            seed=9, d=128, n_pages=49, p=24
+        )
+        lengths = jnp.asarray([23 * 16 - 3, 16 * 16 + 1], jnp.int32)
+        shard = {n: a[:, :, :1] for n, a in pool.items()}
+        assert pa._pages_per_block(pool, table) == 16
+        assert pa._pages_per_block(shard, table) == 16
+        tp1 = pa.paged_attention(q, pool, table, lengths, impl="kernel")
+        sharded = pa.paged_attention(
+            q, pool, table, lengths, impl="kernel", mesh=mesh2
+        )
+        assert _bytes_equal(tp1, sharded)
+        by_head = jnp.concatenate([
+            pa.paged_attention(
+                q[:, 2 * g:2 * g + 2],
+                {n: a[:, :, g:g + 1] for n, a in pool.items()},
+                table, lengths, impl="kernel",
+            )
+            for g in range(2)
+        ], axis=1)
+        assert _bytes_equal(tp1, by_head)
+
     def test_kernel_allclose_reference(self, forced, mesh2):
         q, pool, table, lengths = _paged_case(seed=6)
         sharded = pa.paged_attention(

@@ -241,3 +241,225 @@ def test_write_at_layer_leaves_other_layers_bytes(quant, layer):
             )
         assert out[name].dtype == before.dtype
         np.testing.assert_array_equal(after, expect, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# the block walk: a block of pages a step, no further than the length
+# ---------------------------------------------------------------------------
+
+PS = 16
+
+
+def _walk_case(kv, n_rep, hd, per_row, lengths, dtype=jnp.float32,
+               quant=False, layers=None, window=None, seed=11):
+    """A pool of b * per_row + 1 pages (page 0 is nobody's), disjoint
+    tables in a shuffled order, `lengths` a slot."""
+    rng = np.random.default_rng(seed)
+    b = len(lengths)
+    n_pages = b * per_row + 1
+    pool = _pool(rng, n_pages, PS, kv, hd, quant=quant)
+    if not quant:
+        pool = {n: a.astype(dtype) for n, a in pool.items()}
+    if layers:
+        pool = {
+            n: jnp.stack([a * (1 + i) if a.dtype != jnp.int8 else a
+                          for i in range(layers)])
+            for n, a in pool.items()
+        }
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, n_pages)).reshape(b, per_row),
+        jnp.int32,
+    )
+    q = jnp.asarray(
+        rng.standard_normal((b, kv * n_rep, hd)),
+        jnp.bfloat16 if quant else dtype,
+    )
+    return q, pool, table, jnp.asarray(lengths, jnp.int32), window
+
+
+def _both(q, pool, table, lengths, window, layer=None):
+    ref = pa.paged_attention(
+        q, pool, table, lengths, impl="reference", layer=layer,
+        window=window,
+    )
+    ker = pa.paged_attention(
+        q, pool, table, lengths, impl="kernel", layer=layer,
+        window=window,
+    )
+    return np.asarray(ker, np.float32), np.asarray(ref, np.float32)
+
+
+def _block_cells(hd, dtype, per_row):
+    pages = {"k": jax.ShapeDtypeStruct((1, PS, 1, hd), dtype)}
+    table = jax.ShapeDtypeStruct((1, per_row), jnp.int32)
+    return pa._pages_per_block(pages, table) * PS
+
+
+def test_block_is_sized_from_one_head_and_the_dtype():
+    """128 KiB of one KV head's rows: 256 cells of f32, 512 of bf16
+    and of int8 (unpacked to two bytes before the products) at
+    head_dim 128, whatever the head count; never more than the
+    table."""
+    assert _block_cells(128, jnp.float32, 80) == 256
+    assert _block_cells(128, jnp.bfloat16, 80) == 512
+    assert _block_cells(128, jnp.int8, 80) == 512
+    assert _block_cells(128, jnp.bfloat16, 4) == 4 * PS
+    # a head_dim under a lane tile still takes a tile's lanes in VMEM
+    assert _block_cells(64, jnp.float32, 80) == 256
+
+
+@pytest.mark.parametrize(
+    "length", [0, 1, 127, 128, 129, 255, 256, 257, 20 * PS],
+    ids=lambda n: f"len{n}",
+)
+def test_lengths_around_a_blocks_boundary(length):
+    """f32 at head_dim 128 walks 256 cells a block, in runs of 8
+    pages: empty, one cell, one under, at and one over a run's and a
+    block's boundary, and the whole table of 20 pages (a last block
+    of 4 pages)."""
+    assert _block_cells(128, jnp.float32, 20) == 256
+    case = _walk_case(2, 2, 128, 20, [length, 200])
+    ker, ref = _both(*case)
+    if length == 0:
+        # the reference's softmax over no column is NaN; the kernel
+        # writes zeros, and the row beside it is untouched by it
+        assert not ker[0].any()
+        ker, ref = ker[1:], ref[1:]
+    np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("n_rep", [1, 4, 8], ids=lambda n: f"rep{n}")
+@pytest.mark.parametrize("kv", [1, 4, 8], ids=lambda n: f"kv{n}")
+def test_group_sizes_and_head_counts(kv, n_rep):
+    """n_rep = 1 is the same products with one row; one KV head goes
+    without its axis; lengths end in the second block's middle."""
+    case = _walk_case(kv, n_rep, 128, 12, [130, 77, 192])
+    ker, ref = _both(*case)
+    np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [None, 1], ids=["one-layer", "stacked"])
+@pytest.mark.parametrize(
+    "kv,quant", [(2, False), (4, False), (4, True), (8, True), (1, True)],
+    ids=["bf16-kv2", "bf16-kv4", "int8-kv4", "int8-kv8", "int8-kv1"],
+)
+def test_packed_pools_over_two_blocks(kv, quant, layer):
+    """bf16 packs two heads a sublane word, int8 four: each head's
+    rows come out of the words as the reference's gather has them,
+    over a walk of two blocks of 512 cells, stacked and not."""
+    per_row = 40
+    case = _walk_case(
+        kv, 2, 128, per_row, [per_row * PS - 40, 17, per_row * PS],
+        dtype=jnp.bfloat16, quant=quant,
+        layers=3 if layer is not None else None,
+    )
+    ker, ref = _both(*case, layer=layer)
+    np.testing.assert_allclose(ker, ref, atol=3e-2, rtol=3e-2)
+    if not quant:
+        # bf16 in, f32 sums: the kernel and the gathered view differ
+        # by the output's rounding and the order of the sums
+        assert np.abs(ker - ref).max() < 2e-2
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_pages_past_the_length_are_not_read(quant):
+    """Every page past a slot's length, and every page of nobody,
+    holds NaN (an int8 pool: in its scales); the output is finite and
+    the reference's on the clean pool."""
+    q, pool, table, lengths, _ = _walk_case(
+        2, 2, 128, 12, [5, 130, 177], quant=quant
+    )
+    ker_clean, ref = _both(q, pool, table, lengths, None)
+    live = np.zeros(pool["k"].shape[0], bool)
+    for row, n in enumerate(np.asarray(lengths)):
+        live[np.asarray(table)[row, : -(-int(n) // PS)]] = True
+    names = ("k_scale", "v_scale") if quant else ("k", "v")
+    poisoned = dict(pool)
+    for name in names:
+        arr = np.asarray(pool[name].astype(jnp.float32)).copy()
+        arr[~live] = np.nan
+        poisoned[name] = jnp.asarray(arr, pool[name].dtype)
+    ker = np.asarray(
+        pa.paged_attention(q, poisoned, table, lengths, impl="kernel"),
+        np.float32,
+    )
+    assert np.isfinite(ker).all()
+    np.testing.assert_array_equal(ker, ker_clean)
+    np.testing.assert_allclose(
+        ker, ref, atol=3e-2 if quant else 2e-5, rtol=3e-2 if quant else 2e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "lengths",
+    [[2 * 304 + 150, 2 * 304 + 270], [304 + 5, 3 * 304 + 299], [40, 250]],
+    ids=["wrapped-twice", "straddles-the-end", "not-yet-wrapped"],
+)
+def test_window_over_a_ring_that_wrapped(lengths):
+    """window = 256 over a ring of 19 pages (304 cells), f32 blocks of
+    16 pages: the walk is 17 pages (two blocks), starts mid-ring
+    after two wraps and its first block straddles the ring's end; a
+    length under the window walks from page 0."""
+    window, ring = 256, 19
+    assert _block_cells(128, jnp.float32, ring) == 256
+    q, pool, table, lens, _ = _walk_case(2, 2, 128, ring, lengths)
+    ker, ref = _both(q, pool, table, lens, window)
+    np.testing.assert_allclose(ker, ref, atol=2e-5, rtol=2e-5)
+    # the window did something: the full attention differs
+    full = pa.paged_attention(
+        q, pool, table, jnp.minimum(lens, ring * PS), impl="reference"
+    )
+    if max(lengths) > window:
+        assert np.abs(np.asarray(full) - ref).max() > 1e-3
+
+
+def test_window_pages_behind_the_window_are_not_read():
+    """The ring's entries that hold no position of the window any
+    more (freed and handed to someone else) are NaN."""
+    window, ring = 256, 19
+    lengths = [2 * 304 + 150, 304 + 5]
+    q, pool, table, lens, _ = _walk_case(2, 2, 128, ring, lengths)
+    ker_clean, ref = _both(q, pool, table, lens, window)
+    k = np.asarray(pool["k"]).copy()
+    v = np.asarray(pool["v"]).copy()
+    tab = np.asarray(table)
+    for row, n in enumerate(lengths):
+        walked = {
+            p % ring for p in range((n - window) // PS, -(-n // PS))
+        }
+        for entry in set(range(ring)) - walked:
+            k[tab[row, entry]] = np.nan
+            v[tab[row, entry]] = np.nan
+    ker = np.asarray(pa.paged_attention(
+        q, {"k": jnp.asarray(k), "v": jnp.asarray(v)}, table, lens,
+        impl="kernel", window=window,
+    ))
+    assert np.isfinite(ker).all()
+    np.testing.assert_array_equal(ker, ker_clean)
+
+
+def test_supports_states_the_head_dim_and_word_rules(monkeypatch):
+    """head_dim in whole lane tiles (Mosaic's rule: the interpreter
+    has none); a sub-word pool's heads in whole words, or one head;
+    the buffers inside the VMEM budget."""
+    from dlrover_tpu.ops import flash_attention as fa
+
+    table = jnp.zeros((2, 8), jnp.int32)
+
+    def case(h, kv, hd, dtype):
+        q = jax.ShapeDtypeStruct((2, h, hd), jnp.bfloat16)
+        cell = jax.ShapeDtypeStruct((9, PS, kv, hd), dtype)
+        return q, {"k": cell, "v": cell}, table
+
+    assert pa.supports(*case(8, 2, 128, jnp.bfloat16))
+    assert pa.supports(*case(8, 1, 128, jnp.bfloat16))
+    assert pa.supports(*case(8, 4, 256, jnp.int8))
+    assert pa.supports(*case(6, 3, 128, jnp.float32))
+    assert not pa.supports(*case(6, 3, 128, jnp.bfloat16))
+    assert not pa.supports(*case(8, 2, 128, jnp.int8))
+    assert not pa.supports(*case(512, 512, 128, jnp.bfloat16))
+    assert pa.supports(*case(8, 2, 64, jnp.bfloat16))
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    assert pa.supports(*case(8, 2, 128, jnp.bfloat16))
+    assert not pa.supports(*case(8, 2, 64, jnp.bfloat16))
+    assert not pa.supports(*case(8, 2, 192, jnp.bfloat16))

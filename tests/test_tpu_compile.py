@@ -141,20 +141,14 @@ def test_flash_under_a_training_mesh(topo):
         assert kernel in text, kernel
 
 
-@pytest.mark.parametrize(
-    "kv_heads,quant,stack",
-    [
-        (32, False, None), (32, True, None), (8, False, None),
-        (8, False, (3, 2)), (8, True, (3, 2)),
-    ],
-    ids=["mha-bf16", "mha-int8", "gqa8-bf16", "gqa8-bf16-stacked",
-         "gqa8-int8-stacked"],
-)
-def test_paged_decode(chip, kv_heads, quant, stack):
-    """`stack` = (L, layer): the pool as the forward hands it over,
-    stacked over layers and addressed at one of them; None is one
-    layer's pool, the same kernel at L = 1."""
-    cell = (N_PAGES, PAGE, kv_heads, CFG.head_dim)
+def _paged_case(chip, heads, kv_heads, quant, stack=None, slots=SLOTS,
+                table_pages=SEQ // PAGE, window=None):
+    """Compile the paged kernel for `heads` query heads over a pool of
+    `kv_heads` (bf16, or int8 with bf16 scales). `stack` = (L, layer):
+    the pool as the forward hands it over, stacked over layers and
+    addressed at one of them; None is one layer's pool, the same
+    kernel at L = 1."""
+    cell = (slots * table_pages + 1, PAGE, kv_heads, CFG.head_dim)
     layer = None
     if stack is not None:
         cell = (stack[0],) + cell
@@ -167,16 +161,73 @@ def test_paged_decode(chip, kv_heads, quant, stack):
         }
     else:
         pool = {"k": S(cell, jnp.bfloat16), "v": S(cell, jnp.bfloat16)}
-    q = S((SLOTS, CFG.n_heads, CFG.head_dim), jnp.bfloat16)
-    table = S((SLOTS, SEQ // PAGE), jnp.int32)
+    q = S((slots, heads, CFG.head_dim), jnp.bfloat16)
+    table = S((slots, table_pages), jnp.int32)
     assert pa.supports(q, pool, table)
-    _compile(
+    return _compile(
         chip,
         functools.partial(
-            pa.paged_attention, impl="kernel", layer=layer
+            pa.paged_attention, impl="kernel", layer=layer, window=window
         ),
-        q, pool, table, S((SLOTS,), jnp.int32),
+        q, pool, table, S((slots,), jnp.int32),
     )
+
+
+@pytest.mark.parametrize(
+    "kv_heads,quant,stack",
+    [
+        (32, False, None), (32, True, None), (8, False, None),
+        (8, False, (3, 2)), (8, True, (3, 2)),
+    ],
+    ids=["mha-bf16", "mha-int8", "gqa8-bf16", "gqa8-bf16-stacked",
+         "gqa8-int8-stacked"],
+)
+def test_paged_decode(chip, kv_heads, quant, stack):
+    """Llama-2-7B's 32 query heads: the smoke's MHA (n_rep = 1, bf16
+    and int8 KV) and Mistral's 8 KV heads."""
+    text = _paged_case(chip, CFG.n_heads, kv_heads, quant, stack)
+    assert "paged_attention_decode" in text
+
+
+@pytest.mark.parametrize(
+    "heads,kv_heads,quant",
+    [(16, 16, False), (16, 16, True), (4, 1, False), (8, 1, True),
+     (8, 2, False)],
+    ids=["mha-tp2-bf16", "mha-tp2-int8", "one-kv-head-bf16",
+         "one-kv-head-int8", "two-kv-heads-bf16"],
+)
+def test_paged_decode_of_a_shard(chip, heads, kv_heads, quant):
+    """What one shard of a tensor-parallel replica hands the kernel:
+    the smoke's MHA at tp = 2 (n_rep = 1, int8 too), and GQA down to
+    ONE KV head a shard, whose sub-word rows fill no sublane word (the
+    pool goes without its head axis) and to the two of a word."""
+    _paged_case(chip, heads, kv_heads, quant, stack=(2, 1))
+
+
+def test_mellum2_full_paged_decode(chip):
+    """Mellum2's full layers as the benchmark serves them: 64 slots,
+    4 KV heads of 8 query heads each, a table of 224 pages."""
+    text = _paged_case(
+        chip, 32, 4, False, stack=(2, 1), slots=64, table_pages=224
+    )
+    assert "paged_attention_decode" in text
+
+
+def test_paged_head_dim_rule_is_mosaics(chip):
+    """A head_dim that is no multiple of 128: Mosaic copies no page
+    of such a pool out of HBM ("must be aligned to tiling (128)"),
+    which is why `supports()` refuses it on a TPU."""
+    cell = (2, N_PAGES, PAGE, 8, 64)
+    pool = {"k": S(cell, jnp.bfloat16), "v": S(cell, jnp.bfloat16)}
+    q = S((SLOTS, 32, 64), jnp.bfloat16)
+    table = S((SLOTS, 32), jnp.int32)
+    assert not pa.supports(q, pool, table)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(
+            chip,
+            functools.partial(pa.paged_attention, impl="kernel", layer=1),
+            q, pool, table, S((SLOTS,), jnp.int32),
+        )
 
 
 def test_paged_chunk_program_walks_pool_in_place(chip, monkeypatch):
