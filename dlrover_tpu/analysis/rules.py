@@ -1388,8 +1388,6 @@ _FRONTIER_WRITERS = frozenset(
         "_clear_prefill",
         "_run_pf",
         "_run_pf_paged",
-        "_run_pf_lora",
-        "_run_pf_paged_lora",
     }
 )
 

@@ -1507,6 +1507,21 @@ def _mask_top_p(logits: jax.Array, p: float) -> jax.Array:
     return jnp.where(logits < kth, -jnp.inf, logits)
 
 
+def _warp(
+    logits: jax.Array, temperature: float, top_k: int, top_p: float
+) -> jax.Array:
+    """The sampler's logits warp, for `generate` here and every
+    program of serving/engine.py. HF/vLLM order: temperature first,
+    then the filters (the nucleus set is computed on the TEMPERED
+    distribution). Static knobs, so an unset filter traces nothing."""
+    logits = logits / temperature
+    if 0 < top_k < logits.shape[-1]:
+        logits = _mask_top_k(logits, top_k)
+    if top_p < 1.0:
+        logits = _mask_top_p(logits, top_p)
+    return logits
+
+
 def generate(
     cfg: LlamaConfig,
     params: Params,
@@ -1562,16 +1577,9 @@ def generate(
     def sample(logits, key):
         if temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(prompt.dtype)
-        # HF/vLLM warp order: temperature first, then the filters (the
-        # nucleus set is computed on the TEMPERED distribution)
-        logits = logits / temperature
-        if top_k > 0 and top_k < logits.shape[-1]:
-            logits = _mask_top_k(logits, top_k)
-        if top_p < 1.0:
-            logits = _mask_top_p(logits, top_p)
-        return jax.random.categorical(key, logits).astype(
-            prompt.dtype
-        )
+        return jax.random.categorical(
+            key, _warp(logits, temperature, top_k, top_p)
+        ).astype(prompt.dtype)
 
     def emit(raw, done):
         """Apply the done mask: finished rows emit pad; a fresh eos

@@ -89,8 +89,7 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.decode import (
     _check_adapters,
     _check_positional_capacity,
-    _mask_top_k,
-    _mask_top_p,
+    _warp,
     decode_step,
     gather_pool_view,
     init_hybrid_pools,
@@ -355,10 +354,16 @@ def _paged_step_takes_kernel(cfg, n_slots, pool, table, mesh) -> bool:
     )
 
 
-def _lora_operand(abank, aidx):
+def _lora_operand(abank, aidx, row=None):
     """Assemble the `adapters` operand models/decode.py expects from
-    the stacked device bank + a per-row adapter-index vector. Shared
-    by the chunk/spec/admit lora program variants."""
+    the stacked device bank + a per-row adapter-index vector (`row`
+    picks one slot's entry: a one-row prefill). None when the program
+    was handed no bank: adapters are an optional operand of every
+    program, never a second program."""
+    if abank is None:
+        return None
+    if row is not None:
+        aidx = aidx[row][None]
     return {
         "bank": {k: v for k, v in abank.items() if k != "scale"},
         "idx": aidx,
@@ -366,246 +371,160 @@ def _lora_operand(abank, aidx):
     }
 
 
-def _build_chunk_program(
-    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None,
-    adapters=False,
-):
-    def _warp(logits):
-        logits = logits / temperature
-        if 0 < top_k < logits.shape[-1]:
-            logits = _mask_top_k(logits, top_k)
-        if top_p < 1.0:
-            logits = _mask_top_p(logits, top_p)
-        return logits
+# `keys` is PER-SLOT ([B, 2] uint32), not one engine-global key: a
+# slot's noise stream depends only on its own key, never on batch
+# composition. That is what makes crash resume exact — the scheduler
+# journals each slot's key after every dispatch, and a request
+# re-admitted elsewhere with that key draws the same sample an
+# uncrashed run would have. A live slot burns exactly one split per
+# scan step (== one per emitted token while live). Every layout and
+# every program shares this one post-logits advance, so they sample,
+# stop and cap identically — the byte-parity contract of
+# kv_layout="paged" reduces to the forward producing identical
+# logits, which the gathered-view attention guarantees.
+def _advance(sampling, logits, tok, pos, done, limit, keys):
+    pad_id, eos_id, temperature, top_k, top_p = sampling
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
+            keys, subs = pair[:, 0], pair[:, 1]
+            nxt = jax.vmap(
+                lambda l, kk: jax.random.categorical(kk, l)
+            )(_warp(logits, temperature, top_k, top_p), subs).astype(
+                jnp.int32
+            )
+    nxt = jnp.where(done, pad_id, nxt)
+    hit_eos = (
+        (nxt == eos_id) if eos_id is not None else jnp.zeros_like(done)
+    )
+    # tokens generated through this step = pos+2-prompt_len (carry
+    # enters at prompt_len-1), so the length cap
+    # limit = prompt_len + max_new fires at pos+2 >= limit
+    new_done = done | hit_eos | (pos + 2 >= limit)
+    pos = jnp.where(done, pos, pos + 1)
+    tok = jnp.where(done, tok, nxt)
+    return tok, pos, new_done, keys, nxt
 
-    # `keys` is PER-SLOT ([B, 2] uint32), not one engine-global key:
-    # a slot's noise stream depends only on its own key, never on
-    # batch composition. That is what makes crash resume exact — the
-    # scheduler journals each slot's key after every dispatch, and a
-    # request re-admitted elsewhere with that key draws the same
-    # sample an uncrashed run would have. A live slot burns exactly
-    # one split per scan step (== one per emitted token while live).
-    # The post-logits advance is shared between the dense and paged
-    # variants (same ops, same order), so the two layouts sample,
-    # stop and cap identically — the byte-parity contract of
-    # kv_layout="paged" reduces to the forward producing identical
-    # logits, which the gathered-view attention guarantees.
-    def _advance(logits, tok, pos, done, limit, keys):
-        with jax.named_scope("sample"):
-            if temperature <= 0.0:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-                keys, subs = pair[:, 0], pair[:, 1]
-                nxt = jax.vmap(
-                    lambda l, kk: jax.random.categorical(kk, l)
-                )(_warp(logits), subs).astype(jnp.int32)
-        nxt = jnp.where(done, pad_id, nxt)
-        hit_eos = (
-            (nxt == eos_id)
-            if eos_id is not None
-            else jnp.zeros_like(done)
+
+def _decode_scan(
+    cfg, mesh, sampling, cache, params, tok, pos, done, limit, keys, k,
+    table=None, table_win=None, adapters=None,
+):
+    """THE decode loop: k steps over every slot, whatever holds the
+    KV. Returns (cache, tok, pos, done, keys, emitted [B, k]), and the
+    experts' routed pairs after them where `table_win` is given.
+
+    `cache` is a dense bank (`table` None), a stacked page pool, or
+    the pair of pools of a model with window layers (`table_win`: the
+    slots' rings, as the host left them before this dispatch). The
+    page table rides read-only: it changes only via host-side
+    admission/CoW scatters, never inside a chunk. Done rows route
+    through the trash page (page 0) HERE, so releasing a finished
+    slot's pages is pure host accounting — no table-parking dispatch
+    on the finish/retire/preempt path; rows finishing MID-chunk still
+    own their pages (the host frees them only after harvesting this
+    dispatch), so their remaining frozen rewrites stay in-bounds
+    either way. `adapters` (see `_lora_operand`) rides read-only too:
+    base rows carry index 0 — the permanent zero adapter — so a mixed
+    batch is ONE dispatch whatever its adapter composition.
+
+    A pool is stepped one of two ways, chosen at trace time from
+    shapes (`_paged_step_takes_kernel`; two classes of pages always
+    the first):
+      kernel — per-step paged_decode_step, whose S==1 path streams
+      physical pages through the Pallas paged-attention kernel
+      without materializing a dense view;
+      reference — gather the dense view ONCE, scan the dense step
+      over it (byte parity by construction: it IS the dense program
+      over the same bytes), and scatter the k-wide written window
+      back to pages afterwards. A per-step gather would copy the full
+      cache once per token — the difference between ~parity and >2x
+      dense TPOT on the CPU smoke."""
+    pool, start = None, pos
+    page_native = False
+    if table is not None:
+        table = jnp.where(done[:, None], 0, table)
+        if table_win is not None:
+            table_win = jnp.where(done[:, None], 0, table_win)
+        page_native = table_win is not None or _paged_step_takes_kernel(
+            cfg, tok.shape[0], cache, table, mesh
         )
-        # tokens generated through this step = pos+2-prompt_len
-        # (carry enters at prompt_len-1), so the length cap
-        # limit = prompt_len + max_new fires at pos+2 >= limit
-        new_done = done | hit_eos | (pos + 2 >= limit)
-        pos = jnp.where(done, pos, pos + 1)
-        tok = jnp.where(done, tok, nxt)
-        return tok, pos, new_done, keys, nxt
+        if not page_native:
+            pool, cache = cache, gather_pool_view(cache, table)
+
+    def body(carry, _):
+        cache, tok, pos, done, keys, pairs = carry
+        if page_native:
+            logits, cache, *counts = paged_decode_step(
+                cfg, params, tok, cache, table, pos, mesh=mesh,
+                adapters=adapters, table_win=table_win,
+            )
+        else:
+            logits, cache, *counts = decode_step(
+                cfg, params, tok, cache, pos, mesh=mesh,
+                adapters=adapters,
+            )
+        tok, pos, done, keys, nxt = _advance(
+            sampling, logits, tok, pos, done, limit, keys
+        )
+        if counts and pairs is not None:
+            # dropless experts: routed pairs per expert, summed over
+            # the layers and the k steps
+            pairs = pairs + counts[0]
+        return (cache, tok, pos, done, keys, pairs), nxt
+
+    pairs = None
+    if table_win is not None:
+        pairs = jnp.zeros(
+            (max(getattr(cfg, "n_experts", 0), 1),), jnp.int32
+        )
+    (cache, tok, pos, done, keys, pairs), emitted = jax.lax.scan(
+        body, (cache, tok, pos, done, keys, pairs), None, length=k,
+    )
+    if pool is not None:
+        cache = scatter_pool_window(pool, cache, table, start, k)
+    out = (cache, tok, pos, done, keys, emitted.T)
+    return out if pairs is None else out + (pairs,)
+
+
+def _build_chunk_program(
+    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None
+):
+    """The chunk program: `_decode_scan` behind one jit a layout. The
+    cache (bank or page pool) is the donated argument; the adapter
+    bank and the per-slot adapter-index vector are optional trailing
+    operands, so an adapterless engine's programs hold nothing of them."""
+    scan = partial(
+        _decode_scan, cfg, mesh, (pad_id, eos_id, temperature, top_k, top_p)
+    )
 
     @partial(jax.jit, donate_argnums=(0,), static_argnums=(7,))
-    def _run_chunk(cache, params, tok, pos, done, limit, keys, k):
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (cache, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (cache, tok, pos, done, keys), None, length=k,
+    def _run_chunk(
+        cache, params, tok, pos, done, limit, keys, k,
+        abank=None, aidx=None,
+    ):
+        return scan(
+            cache, params, tok, pos, done, limit, keys, k,
+            adapters=_lora_operand(abank, aidx),
         )
-        return cache, tok, pos, done, keys, emitted.T  # [B, k]
 
-    # paged twin: the page POOL is the donated cache argument; the
-    # page table rides as a read-only operand (it changes only via
-    # host-side admission/CoW scatters, never inside a chunk). Done
-    # rows route through the trash page INSIDE the program (their
-    # frozen rewrites land where no live table reads), so releasing a
-    # finished slot's pages is pure host accounting — no table-parking
-    # dispatch on the finish/retire/preempt path.
-    # Two executions of the same math, chosen at trace time by
-    # `_paged_step_takes_kernel`:
-    #   kernel — per-step paged_decode_step, whose S==1 path streams
-    #   physical pages through the Pallas paged-attention kernel
-    #   without materializing a dense view;
-    #   reference — gather the dense view ONCE, run the scan body the
-    #   dense program uses (byte parity by construction: it IS the
-    #   dense program over the same bytes), and scatter the k-wide
-    #   written window back to pages afterwards. A per-step gather
-    #   would copy the full cache once per token — the difference
-    #   between ~parity and >2x dense TPOT on the CPU smoke.
     @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
     def _run_chunk_paged(
         pool, table, params, tok, pos, done, limit, keys, k,
-        table_win=None,
+        table_win=None, abank=None, aidx=None,
     ):
-        # done-at-entry rows read and write the trash page (page 0);
-        # rows finishing MID-chunk still own their pages (the host
-        # frees them only after harvesting this dispatch), so their
-        # remaining frozen rewrites stay in-bounds either way
-        table = jnp.where(done[:, None], 0, table)
-        if table_win is not None:
-            # a model with window layers: two classes of pages, two
-            # tables a slot (the second the slots' rings, as the host
-            # left them before this dispatch), always stepped page-
-            # natively; dropless experts add their routed pairs per
-            # expert, summed over the layers and the k steps
-            table_win = jnp.where(done[:, None], 0, table_win)
-
-            def body(carry, _):
-                pool, tok, pos, done, keys, pairs = carry
-                logits, pool, *counts = paged_decode_step(
-                    cfg, params, tok, pool, table, pos, mesh=mesh,
-                    table_win=table_win,
-                )
-                tok, pos, done, keys, nxt = _advance(
-                    logits, tok, pos, done, limit, keys
-                )
-                if counts:
-                    pairs = pairs + counts[0]
-                return (pool, tok, pos, done, keys, pairs), nxt
-
-            pairs0 = jnp.zeros(
-                (max(getattr(cfg, "n_experts", 0), 1),), jnp.int32
-            )
-            (pool, tok, pos, done, keys, pairs), emitted = jax.lax.scan(
-                body, (pool, tok, pos, done, keys, pairs0), None,
-                length=k,
-            )
-            return pool, tok, pos, done, keys, emitted.T, pairs
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            def body(carry, _):
-                pool, tok, pos, done, keys = carry
-                logits, pool = paged_decode_step(
-                    cfg, params, tok, pool, table, pos, mesh=mesh
-                )
-                tok, pos, done, keys, nxt = _advance(
-                    logits, tok, pos, done, limit, keys
-                )
-                return (pool, tok, pos, done, keys), nxt
-
-            (pool, tok, pos, done, keys), emitted = jax.lax.scan(
-                body, (pool, tok, pos, done, keys), None, length=k,
-            )
-            return pool, tok, pos, done, keys, emitted.T  # [B, k]
-
-        view = gather_pool_view(pool, table)
-        start = pos
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (view, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (view, tok, pos, done, keys), None, length=k,
+        return scan(
+            pool, params, tok, pos, done, limit, keys, k, table=table,
+            table_win=table_win, adapters=_lora_operand(abank, aidx),
         )
-        pool = scatter_pool_window(pool, view, table, start, k)
-        return pool, tok, pos, done, keys, emitted.T  # [B, k]
 
-    if not adapters:
-        return {"dense": _run_chunk, "paged": _run_chunk_paged}
-
-    # multi-adapter variants: same scan, same _advance, with the
-    # stacked adapter bank + the per-slot adapter-index vector riding
-    # as trailing read-only operands (the bank changes only via
-    # host-side upload scatters, never inside a chunk). Base rows
-    # carry index 0 — the permanent zero adapter — so a mixed batch
-    # is ONE dispatch whatever its adapter composition.
-    @partial(jax.jit, donate_argnums=(0,), static_argnums=(7,))
-    def _run_chunk_lora(
-        cache, params, tok, pos, done, limit, keys, k, abank, aidx
-    ):
-        ad = _lora_operand(abank, aidx)
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh, adapters=ad
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (cache, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (cache, tok, pos, done, keys), None, length=k,
-        )
-        return cache, tok, pos, done, keys, emitted.T  # [B, k]
-
-    @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
-    def _run_chunk_paged_lora(
-        pool, table, params, tok, pos, done, limit, keys, k,
-        abank, aidx,
-    ):
-        ad = _lora_operand(abank, aidx)
-        table = jnp.where(done[:, None], 0, table)
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            def body(carry, _):
-                pool, tok, pos, done, keys = carry
-                logits, pool = paged_decode_step(
-                    cfg, params, tok, pool, table, pos, mesh=mesh,
-                    adapters=ad,
-                )
-                tok, pos, done, keys, nxt = _advance(
-                    logits, tok, pos, done, limit, keys
-                )
-                return (pool, tok, pos, done, keys), nxt
-
-            (pool, tok, pos, done, keys), emitted = jax.lax.scan(
-                body, (pool, tok, pos, done, keys), None, length=k,
-            )
-            return pool, tok, pos, done, keys, emitted.T  # [B, k]
-
-        view = gather_pool_view(pool, table)
-        start = pos
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh, adapters=ad
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (view, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (view, tok, pos, done, keys), None, length=k,
-        )
-        pool = scatter_pool_window(pool, view, table, start, k)
-        return pool, tok, pos, done, keys, emitted.T  # [B, k]
-
-    return {"dense": _run_chunk_lora, "paged": _run_chunk_paged_lora}
+    return {"dense": _run_chunk, "paged": _run_chunk_paged}
 
 
 def _build_pf_chunk_program(
-    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None,
-    adapters=False,
+    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None
 ):
     """Interleaved chunked-prefill variant of the chunk program: ONE
     compiled dispatch runs up to `prefill_chunk` tokens of a pending
@@ -614,217 +533,66 @@ def _build_pf_chunk_program(
     admission stops monopolizing the step loop and decode TPOT stays
     bounded while long prompts stream in chunk by chunk.
 
-    The decode half is the `_build_chunk_program` scan verbatim (same
-    `_advance`, same trash-routing, same gather/scatter window off
-    TPU); the prefilling slot rides through it FROZEN (device
-    done=True — its rewrites are dead by the position mask dense-side
-    and trash-routed paged-side), so interleaving changes nothing the
+    The decode half IS the chunk program's `_decode_scan`; the
+    prefilling slot rides through it FROZEN (device done=True — its
+    rewrites are dead by the position mask dense-side and
+    trash-routed paged-side), so interleaving changes nothing the
     live rows can observe. The prefill half writes through
     models/decode.py's chunked-prefill primitives, which attend the
     already-installed cells — the `prefill_suffix_row` byte-exactness
-    argument, chunk by chunk.
+    argument, chunk by chunk — under the PREFILLING slot's adapter
+    (its prompt K/V must come from the adapted projections; the
+    decode half rides the full per-slot index vector as usual).
 
     `frontier` is the per-slot partial-write frontier ([B] int32,
     device-resident beside tok/pos/done); the program advances
     `pslot`'s entry past the chunk it just wrote. Built only when
     `prefill_chunk > 0`: the plain program, its cache keys, and the
     pc=0 engine are structurally untouched (the parity oracle)."""
-
-    def _warp(logits):
-        logits = logits / temperature
-        if 0 < top_k < logits.shape[-1]:
-            logits = _mask_top_k(logits, top_k)
-        if top_p < 1.0:
-            logits = _mask_top_p(logits, top_p)
-        return logits
-
-    def _advance(logits, tok, pos, done, limit, keys):
-        with jax.named_scope("sample"):
-            if temperature <= 0.0:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
-                keys, subs = pair[:, 0], pair[:, 1]
-                nxt = jax.vmap(
-                    lambda l, kk: jax.random.categorical(kk, l)
-                )(_warp(logits), subs).astype(jnp.int32)
-        nxt = jnp.where(done, pad_id, nxt)
-        hit_eos = (
-            (nxt == eos_id)
-            if eos_id is not None
-            else jnp.zeros_like(done)
-        )
-        new_done = done | hit_eos | (pos + 2 >= limit)
-        pos = jnp.where(done, pos, pos + 1)
-        tok = jnp.where(done, tok, nxt)
-        return tok, pos, new_done, keys, nxt
+    scan = partial(
+        _decode_scan, cfg, mesh, (pad_id, eos_id, temperature, top_k, top_p)
+    )
 
     @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
     def _run_pf(
         cache, params, tok, pos, done, limit, keys, frontier, k,
-        ptoks, pslot, pstart,
+        ptoks, pslot, pstart, abank=None, aidx=None,
     ):
         cache = prefill_chunk_into_slot(
-            cfg, params, ptoks, cache, pslot, pstart, mesh=mesh
+            cfg, params, ptoks, cache, pslot, pstart, mesh=mesh,
+            adapters=_lora_operand(abank, aidx, row=pslot),
         )
         frontier = frontier.at[pslot].set(pstart + ptoks.shape[0])
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (cache, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (cache, tok, pos, done, keys), None, length=k,
+        cache, tok, pos, done, keys, emitted = scan(
+            cache, params, tok, pos, done, limit, keys, k,
+            adapters=_lora_operand(abank, aidx),
         )
-        return cache, tok, pos, done, keys, frontier, emitted.T
+        return cache, tok, pos, done, keys, frontier, emitted
 
     @partial(jax.jit, donate_argnums=(0,), static_argnums=(9,))
     def _run_pf_paged(
         pool, table, params, tok, pos, done, limit, keys, frontier,
-        k, ptoks, pslot, pstart,
+        k, ptoks, pslot, pstart, abank=None, aidx=None,
     ):
         # the prefill writes through the slot's REAL table row —
         # gathered BEFORE the decode half trash-routes done rows
         # (the prefilling slot IS a done row to the decode scan)
         pool = paged_prefill_chunk(
-            cfg, params, ptoks, pool, table[pslot], pstart, mesh=mesh
-        )
-        frontier = frontier.at[pslot].set(pstart + ptoks.shape[0])
-        table = jnp.where(done[:, None], 0, table)
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            def body(carry, _):
-                pool, tok, pos, done, keys = carry
-                logits, pool = paged_decode_step(
-                    cfg, params, tok, pool, table, pos, mesh=mesh
-                )
-                tok, pos, done, keys, nxt = _advance(
-                    logits, tok, pos, done, limit, keys
-                )
-                return (pool, tok, pos, done, keys), nxt
-
-            (pool, tok, pos, done, keys), emitted = jax.lax.scan(
-                body, (pool, tok, pos, done, keys), None, length=k,
-            )
-            return pool, tok, pos, done, keys, frontier, emitted.T
-
-        view = gather_pool_view(pool, table)
-        start = pos
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (view, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (view, tok, pos, done, keys), None, length=k,
-        )
-        pool = scatter_pool_window(pool, view, table, start, k)
-        return pool, tok, pos, done, keys, frontier, emitted.T
-
-    if not adapters:
-        return {"dense": _run_pf, "paged": _run_pf_paged}
-
-    @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
-    def _run_pf_lora(
-        cache, params, tok, pos, done, limit, keys, frontier, k,
-        ptoks, pslot, pstart, abank, aidx,
-    ):
-        # the prefill half gathers the PREFILLING slot's adapter (its
-        # prompt K/V must come from the adapted projections); the
-        # decode half rides the full per-slot index vector as usual
-        ad1 = _lora_operand(abank, aidx[pslot][None])
-        cache = prefill_chunk_into_slot(
-            cfg, params, ptoks, cache, pslot, pstart, mesh=mesh,
-            adapters=ad1,
-        )
-        frontier = frontier.at[pslot].set(pstart + ptoks.shape[0])
-        ad = _lora_operand(abank, aidx)
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh, adapters=ad
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (cache, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (cache, tok, pos, done, keys), None, length=k,
-        )
-        return cache, tok, pos, done, keys, frontier, emitted.T
-
-    @partial(jax.jit, donate_argnums=(0,), static_argnums=(9,))
-    def _run_pf_paged_lora(
-        pool, table, params, tok, pos, done, limit, keys, frontier,
-        k, ptoks, pslot, pstart, abank, aidx,
-    ):
-        ad1 = _lora_operand(abank, aidx[pslot][None])
-        pool = paged_prefill_chunk(
             cfg, params, ptoks, pool, table[pslot], pstart, mesh=mesh,
-            adapters=ad1,
+            adapters=_lora_operand(abank, aidx, row=pslot),
         )
         frontier = frontier.at[pslot].set(pstart + ptoks.shape[0])
-        ad = _lora_operand(abank, aidx)
-        table = jnp.where(done[:, None], 0, table)
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            def body(carry, _):
-                pool, tok, pos, done, keys = carry
-                logits, pool = paged_decode_step(
-                    cfg, params, tok, pool, table, pos, mesh=mesh,
-                    adapters=ad,
-                )
-                tok, pos, done, keys, nxt = _advance(
-                    logits, tok, pos, done, limit, keys
-                )
-                return (pool, tok, pos, done, keys), nxt
-
-            (pool, tok, pos, done, keys), emitted = jax.lax.scan(
-                body, (pool, tok, pos, done, keys), None, length=k,
-            )
-            return pool, tok, pos, done, keys, frontier, emitted.T
-
-        view = gather_pool_view(pool, table)
-        start = pos
-
-        def body(carry, _):
-            cache, tok, pos, done, keys = carry
-            logits, cache = decode_step(
-                cfg, params, tok, cache, pos, mesh=mesh, adapters=ad
-            )
-            tok, pos, done, keys, nxt = _advance(
-                logits, tok, pos, done, limit, keys
-            )
-            return (cache, tok, pos, done, keys), nxt
-
-        (view, tok, pos, done, keys), emitted = jax.lax.scan(
-            body, (view, tok, pos, done, keys), None, length=k,
+        pool, tok, pos, done, keys, emitted = scan(
+            pool, params, tok, pos, done, limit, keys, k, table=table,
+            adapters=_lora_operand(abank, aidx),
         )
-        pool = scatter_pool_window(pool, view, table, start, k)
-        return pool, tok, pos, done, keys, frontier, emitted.T
+        return pool, tok, pos, done, keys, frontier, emitted
 
-    return {"dense": _run_pf_lora, "paged": _run_pf_paged_lora}
+    return {"dense": _run_pf, "paged": _run_pf_paged}
 
 
 def _build_spec_program(
-    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None,
-    adapters=False,
+    cfg, pad_id, eos_id, temperature, top_k, top_p, mesh=None
 ):
     """The speculative alternative to the chunk scan: ONE verify
     forward over K+1 positions per slot, acceptance on device, and
@@ -836,15 +604,9 @@ def _build_spec_program(
     host varies only the DATA (per-slot draft tokens and draft_len,
     zero for slots whose controller disabled speculation — those rows
     degenerate to a normal one-token step inside the same program).
+    The adapted projections run inside the SAME verify forward, so a
+    draft is judged against the adapter the slot decodes under.
     """
-
-    def _warp(logits):
-        logits = logits / temperature
-        if 0 < top_k < logits.shape[-1]:
-            logits = _mask_top_k(logits, top_k)
-        if top_p < 1.0:
-            logits = _mask_top_p(logits, top_p)
-        return logits
 
     def _accept(
         logits, tok, pos, done, limit, keys, drafts, draft_len
@@ -857,7 +619,9 @@ def _build_spec_program(
             # accept/resample noise comes from its own key stream
             pair = jax.vmap(jax.random.split)(keys)  # [B, 2, 2]
             keys, subs = pair[:, 0], pair[:, 1]
-            probs = jax.nn.softmax(_warp(logits), axis=-1)
+            probs = jax.nn.softmax(
+                _warp(logits, temperature, top_k, top_p), axis=-1
+            )
 
             def _row(kk, p, d, l):
                 mm, ee = spec_accept_sampled(
@@ -907,118 +671,95 @@ def _build_spec_program(
             accepted,
         )
 
-    @partial(jax.jit, donate_argnums=(0,))
-    def _run_spec(
-        cache, params, tok, pos, done, limit, keys, drafts, draft_len
+    def _verify(
+        cache, params, tok, pos, done, limit, keys, drafts, draft_len,
+        table=None, adapters=None,
     ):
+        """One verify forward + acceptance over a bank (`table` None)
+        or a page pool, with `_decode_scan`'s trash-routing and its
+        trace-time split: paged_verify_step where the decode step
+        takes the page kernel (page-native writes), gather /
+        dense-verify / scatter-back elsewhere (a verify is a single
+        step, so the one view copy is cost-neutral — it exists so
+        both programs share one execution strategy per backend)."""
         tokens = jnp.concatenate([tok[:, None], drafts], axis=1)
-        logits, cache = verify_step(
-            cfg, params, tokens, cache, pos, mesh=mesh
-        )
-        out = _accept(
+        if table is None:
+            logits, cache = verify_step(
+                cfg, params, tokens, cache, pos, mesh=mesh,
+                adapters=adapters,
+            )
+        else:
+            # done rows never touch live pages, so page release needs
+            # no device dispatch
+            table = jnp.where(done[:, None], 0, table)
+            if _paged_step_takes_kernel(
+                cfg, tok.shape[0], cache, table, mesh
+            ):
+                logits, cache = paged_verify_step(
+                    cfg, params, tokens, cache, table, pos, mesh=mesh,
+                    adapters=adapters,
+                )
+            else:
+                view = gather_pool_view(cache, table)
+                logits, view = verify_step(
+                    cfg, params, tokens, view, pos, mesh=mesh,
+                    adapters=adapters,
+                )
+                cache = scatter_pool_window(
+                    cache, view, table, pos, tokens.shape[1]
+                )
+        return (cache,) + _accept(
             logits, tok, pos, done, limit, keys, drafts, draft_len
         )
-        return (cache,) + out
 
-    # paged twin — identical acceptance, with the chunk program's
-    # trace-time split: paged_verify_step where the decode step takes
-    # the page kernel (page-native writes), gather/dense-verify/
-    # scatter-back elsewhere (one view
-    # copy per dispatch instead of one per step; a verify is a single
-    # step, so this is cost-neutral — it exists so both programs share
-    # one execution strategy per backend)
+    @partial(jax.jit, donate_argnums=(0,))
+    def _run_spec(
+        cache, params, tok, pos, done, limit, keys, drafts, draft_len,
+        abank=None, aidx=None,
+    ):
+        return _verify(
+            cache, params, tok, pos, done, limit, keys, drafts,
+            draft_len, adapters=_lora_operand(abank, aidx),
+        )
+
     @partial(jax.jit, donate_argnums=(0,))
     def _run_spec_paged(
         pool, table, params, tok, pos, done, limit, keys, drafts,
-        draft_len,
+        draft_len, abank=None, aidx=None,
     ):
-        tokens = jnp.concatenate([tok[:, None], drafts], axis=1)
-        # same trash-routing as the chunk program: done rows never
-        # touch live pages, so page release needs no device dispatch
-        table = jnp.where(done[:, None], 0, table)
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            logits, pool = paged_verify_step(
-                cfg, params, tokens, pool, table, pos, mesh=mesh
-            )
-        else:
-            view = gather_pool_view(pool, table)
-            logits, view = verify_step(
-                cfg, params, tokens, view, pos, mesh=mesh
-            )
-            pool = scatter_pool_window(
-                pool, view, table, pos, tokens.shape[1]
-            )
-        out = _accept(
-            logits, tok, pos, done, limit, keys, drafts, draft_len
+        return _verify(
+            pool, params, tok, pos, done, limit, keys, drafts,
+            draft_len, table=table, adapters=_lora_operand(abank, aidx),
         )
-        return (pool,) + out
 
-    if not adapters:
-        return {"dense": _run_spec, "paged": _run_spec_paged}
-
-    # multi-adapter verify: identical acceptance; the adapted
-    # projections run inside the SAME verify forward, so a draft is
-    # judged against the adapter the slot decodes under
-    @partial(jax.jit, donate_argnums=(0,))
-    def _run_spec_lora(
-        cache, params, tok, pos, done, limit, keys, drafts,
-        draft_len, abank, aidx,
-    ):
-        ad = _lora_operand(abank, aidx)
-        tokens = jnp.concatenate([tok[:, None], drafts], axis=1)
-        logits, cache = verify_step(
-            cfg, params, tokens, cache, pos, mesh=mesh, adapters=ad
-        )
-        out = _accept(
-            logits, tok, pos, done, limit, keys, drafts, draft_len
-        )
-        return (cache,) + out
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def _run_spec_paged_lora(
-        pool, table, params, tok, pos, done, limit, keys, drafts,
-        draft_len, abank, aidx,
-    ):
-        ad = _lora_operand(abank, aidx)
-        tokens = jnp.concatenate([tok[:, None], drafts], axis=1)
-        table = jnp.where(done[:, None], 0, table)
-        if _paged_step_takes_kernel(
-            cfg, tok.shape[0], pool, table, mesh
-        ):
-            logits, pool = paged_verify_step(
-                cfg, params, tokens, pool, table, pos, mesh=mesh,
-                adapters=ad,
-            )
-        else:
-            view = gather_pool_view(pool, table)
-            logits, view = verify_step(
-                cfg, params, tokens, view, pos, mesh=mesh,
-                adapters=ad,
-            )
-            pool = scatter_pool_window(
-                pool, view, table, pos, tokens.shape[1]
-            )
-        out = _accept(
-            logits, tok, pos, done, limit, keys, drafts, draft_len
-        )
-        return (pool,) + out
-
-    return {"dense": _run_spec_lora, "paged": _run_spec_paged_lora}
+    return {"dense": _run_spec, "paged": _run_spec_paged}
 
 
-def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
+def _build_admit_programs(cfg, max_len, mesh=None):
     """Admission + prefix-pool programs. Each retraces once per
     prompt/suffix BUCKET (log2(max_len) shapes total); slot/row/start
     are traced scalars so no recompile per slot, row, or prefix
     length. The cache/pool argument is donated: an admission updates
-    the bank in place instead of copying it."""
+    the bank in place instead of copying it.
+
+    The two cold admissions take an adaptered request's bank and
+    adapter slot as optional trailing operands: its prompt K/V must
+    come from the ADAPTED projections (RoPE is linear, so the
+    pre-rotation delta equals what merged weights would have
+    rotated). It bypasses the shared prefix pool entirely — published
+    prefixes are base-model K/V by contract, so warm/hit/publish take
+    no such operand."""
+
+    def _one_row(abank, aslot):
+        if abank is None:
+            return None
+        return _lora_operand(abank, jnp.full((1,), aslot, jnp.int32))
 
     @partial(jax.jit, donate_argnums=(0,))
-    def _admit_fn(cache, params, prompt, slot):
+    def _admit_fn(cache, params, prompt, slot, abank=None, aslot=None):
         return prefill_into_slot(
-            cfg, params, prompt, cache, slot, mesh=mesh
+            cfg, params, prompt, cache, slot, mesh=mesh,
+            adapters=_one_row(abank, aslot),
         )
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -1071,8 +812,14 @@ def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
     # host copy).
 
     @partial(jax.jit, donate_argnums=(0,))
-    def _paged_cold_fn(pages, table, params, prompt, slot, table_row):
-        row = prefill_exact_row(cfg, params, prompt, max_len, mesh=mesh)
+    def _paged_cold_fn(
+        pages, table, params, prompt, slot, table_row,
+        abank=None, aslot=None,
+    ):
+        row = prefill_exact_row(
+            cfg, params, prompt, max_len, mesh=mesh,
+            adapters=_one_row(abank, aslot),
+        )
         pages = paged_install_row(
             pages, row, table_row, 0, prompt.shape[0]
         )
@@ -1108,7 +855,7 @@ def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
         )
         return pools, table.at[slot].set(table_row)
 
-    progs = {
+    return {
         "admit": _admit_fn,
         "cold": _admit_cold_fn,
         "warm": _admit_warm_fn,
@@ -1119,43 +866,6 @@ def _build_admit_programs(cfg, max_len, mesh=None, adapters=False):
         "page_copy": _page_copy_fn,
         "paged_cold_hybrid": _paged_cold_hybrid_fn,
     }
-    if not adapters:
-        return progs
-
-    # ---- adaptered admissions ---------------------------------------
-    # An adaptered prompt's K/V must come from the ADAPTED projections
-    # (RoPE is linear, so the pre-rotation delta equals what merged
-    # weights would have rotated), and it bypasses the shared prefix
-    # pool entirely — published prefixes are base-model K/V by
-    # contract, so there is no warm/hit/publish lora variant at all.
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def _admit_lora_fn(cache, params, prompt, slot, abank, aslot):
-        ad = _lora_operand(
-            abank, jnp.full((1,), aslot, jnp.int32)
-        )
-        return prefill_into_slot(
-            cfg, params, prompt, cache, slot, mesh=mesh, adapters=ad
-        )
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def _paged_cold_lora_fn(
-        pages, table, params, prompt, slot, table_row, abank, aslot
-    ):
-        ad = _lora_operand(
-            abank, jnp.full((1,), aslot, jnp.int32)
-        )
-        row = prefill_exact_row(
-            cfg, params, prompt, max_len, mesh=mesh, adapters=ad
-        )
-        pages = paged_install_row(
-            pages, row, table_row, 0, prompt.shape[0]
-        )
-        return pages, table.at[slot].set(table_row), row
-
-    progs["admit_lora"] = _admit_lora_fn
-    progs["paged_cold_lora"] = _paged_cold_lora_fn
-    return progs
 
 
 # ---------------------------------------------------------------------------
@@ -1758,7 +1468,6 @@ class ContinuousBatcher:
         cfg = self.cfg
         temperature, top_k, top_p = self._sampling
         version = self._weight_version
-        lora_on = self._adapter_cache is not None
         self._bound_keys = []
         if self.spec is not None:
             key = (
@@ -1773,7 +1482,7 @@ class ContinuousBatcher:
                 key,
                 lambda: _build_spec_program(
                     cfg, self.pad_id, self.eos_id, temperature,
-                    top_k, top_p, mesh=self.mesh, adapters=lora_on,
+                    top_k, top_p, mesh=self.mesh,
                 ),
             )[self.kv_layout]
         key = (
@@ -1788,7 +1497,7 @@ class ContinuousBatcher:
             key,
             lambda: _build_chunk_program(
                 cfg, self.pad_id, self.eos_id, temperature, top_k,
-                top_p, mesh=self.mesh, adapters=lora_on,
+                top_p, mesh=self.mesh,
             ),
         )[self.kv_layout]
         # interleaved chunked-prefill variant: bound ONLY when the
@@ -1808,7 +1517,7 @@ class ContinuousBatcher:
                 key,
                 lambda: _build_pf_chunk_program(
                     cfg, self.pad_id, self.eos_id, temperature,
-                    top_k, top_p, mesh=self.mesh, adapters=lora_on,
+                    top_k, top_p, mesh=self.mesh,
                 ),
             )[self.kv_layout]
         key = (
@@ -1821,7 +1530,7 @@ class ContinuousBatcher:
             # graftlint: allow(JIT-003) reason=hashable tuple literal assigned above and recorded in _bound_keys so a weight refresh can retire the prior version's entries
             key,
             lambda: _build_admit_programs(
-                cfg, self.max_len, mesh=self.mesh, adapters=lora_on
+                cfg, self.max_len, mesh=self.mesh
             ),
         )
         self._admit_fn = admit["admit"]
@@ -1833,8 +1542,6 @@ class ContinuousBatcher:
         self._paged_warm_fn = admit["paged_warm"]
         self._page_copy_fn = admit["page_copy"]
         self._paged_cold_hybrid_fn = admit["paged_cold_hybrid"]
-        self._admit_lora_fn = admit.get("admit_lora")
-        self._paged_cold_lora_fn = admit.get("paged_cold_lora")
 
     def _wq_tag(self) -> tuple:
         """Program-cache key component for weight quantization: the
@@ -1858,14 +1565,17 @@ class ContinuousBatcher:
         c = self._adapter_cache
         return ("adapters", c.cache_slots, c.max_rank)
 
-    def _adapter_args(self) -> tuple:
-        """Trailing operands for the lora program variants: (stacked
-        device bank, per-slot adapter-index vector). Empty when
-        multi-adapter serving is off — the base programs take no such
-        operands."""
+    def _adapter_args(self) -> dict:
+        """The programs' optional adapter operands, by keyword: the
+        stacked device bank and the per-slot adapter-index vector.
+        Empty when multi-adapter serving is off — the programs are
+        then called, and traced, without them."""
         if self._adapter_cache is None:
-            return ()
-        return (self._adapter_cache.bank, self._dev["adapt"])
+            return {}
+        return {
+            "abank": self._adapter_cache.bank,
+            "aidx": self._dev["adapt"],
+        }
 
     def _probe_kernel_path(self) -> None:
         """Which attention body the per-token decode step traced into
@@ -2467,13 +2177,13 @@ class ContinuousBatcher:
                 # publishes into) the shared prefix pool — published
                 # prefixes are base-model K/V by contract
                 bucket = self._prompt_bucket(p)
-                self.cache = self._admit_lora_fn(
+                self.cache = self._admit_fn(
                     self.cache,
                     self.params,
                     jnp.asarray(self._pad_to(req.prompt, bucket)),
                     slot,
-                    self._adapter_cache.bank,
-                    req.adapter_slot,
+                    abank=self._adapter_cache.bank,
+                    aslot=req.adapter_slot,
                 )
             elif self.prefix_cache is None:
                 bucket = self._prompt_bucket(p)
@@ -3055,15 +2765,15 @@ class ContinuousBatcher:
             bucket = self._prompt_bucket(p)
             # adapted prefill; `work` stays None — the exact row this
             # program returns must never publish into the shared pool
-            self.page_pool, self._table, _ = self._paged_cold_lora_fn(
+            self.page_pool, self._table, _ = self._paged_cold_fn(
                 self.page_pool,
                 self._table,
                 self.params,
                 self._pad_to(req.prompt, bucket),
                 slot,
                 vals,
-                self._adapter_cache.bank,
-                req.adapter_slot,
+                abank=self._adapter_cache.bank,
+                aslot=req.adapter_slot,
             )
         else:
             bucket = self._prompt_bucket(p)
@@ -3579,32 +3289,16 @@ class ContinuousBatcher:
         d = self._dev
         k = self._next_chunk_len()
         with self._dispatch_span(chunk=k):
-            lora = self._adapter_args()
-            pairs = ()
+            rings = ()
             if self._hybrid:
                 self._hold_rings(k)
-                pool, tok, pos, done, keys, emitted, *pairs = (
-                    self._run_chunk(
-                        self.page_pool, self._table, self.params,
-                        d["tok"], d["pos"], d["done"], d["limit"],
-                        d["keys"], k, self.rings.table.copy(),
-                    )
-                )
-                self.page_pool = pool
-            elif self._paged:
-                pool, tok, pos, done, keys, emitted = self._run_chunk(
-                    self.page_pool, self._table, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                    k, *lora,
-                )
-                self.page_pool = pool
-            else:
-                cache, tok, pos, done, keys, emitted = self._run_chunk(
-                    self.cache, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                    k, *lora,
-                )
-                self.cache = cache
+                rings = (self.rings.table.copy(),)
+            kv, tok, pos, done, keys, emitted, *pairs = self._run_chunk(
+                *self._kv_operands(), self.params,
+                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                k, *rings, **self._adapter_args(),
+            )
+            self._set_kv(kv)
             d.update(tok=tok, pos=pos, done=done, keys=keys)
             # live steps form a prefix of the chunk (done is sticky), and
             # pos advances once per live step — at harvest the first
@@ -3621,6 +3315,20 @@ class ContinuousBatcher:
                     version=self._weight_version,
                 )
             )
+
+    def _kv_operands(self) -> tuple:
+        """What leads every program's operands: the page pool and the
+        slots' page table, or the dense bank. The first of them is
+        donated, and comes back first (`_set_kv`)."""
+        if self._paged:
+            return (self.page_pool, self._table)
+        return (self.cache,)
+
+    def _set_kv(self, kv) -> None:
+        if self._paged:
+            self.page_pool = kv
+        else:
+            self.cache = kv
 
     def _pf_chunk_len(self, rem: int) -> int:
         """Tokens of prefill this dispatch carries: prefill_chunk,
@@ -3659,27 +3367,13 @@ class ContinuousBatcher:
         plen = self._pf_chunk_len(p - start)
         ptoks = jnp.asarray(req.prompt[start:start + plen])
         with self._dispatch_span(chunk=k, prefill_tokens=plen):
-            lora = self._adapter_args()
-            if self._paged:
-                pool, tok, pos, done, keys, frontier, emitted = (
-                    self._run_pf(
-                        self.page_pool, self._table, self.params,
-                        d["tok"], d["pos"], d["done"], d["limit"],
-                        d["keys"], d["frontier"], k, ptoks, slot, start,
-                        *lora,
-                    )
-                )
-                self.page_pool = pool
-            else:
-                cache, tok, pos, done, keys, frontier, emitted = (
-                    self._run_pf(
-                        self.cache, self.params,
-                        d["tok"], d["pos"], d["done"], d["limit"],
-                        d["keys"], d["frontier"], k, ptoks, slot, start,
-                        *lora,
-                    )
-                )
-                self.cache = cache
+            kv, tok, pos, done, keys, frontier, emitted = self._run_pf(
+                *self._kv_operands(), self.params,
+                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                d["frontier"], k, ptoks, slot, start,
+                **self._adapter_args(),
+            )
+            self._set_kv(kv)
             d.update(
                 tok=tok, pos=pos, done=done, keys=keys, frontier=frontier
             )
@@ -3755,25 +3449,15 @@ class ContinuousBatcher:
     ) -> None:
         d = self._dev
         with self._dispatch_span(draft_len=int(dlens.max())):
-            lora = self._adapter_args()
-            if self._paged:
-                (
-                    pool, tok, pos, done, keys, emitted, n_emit, accepted
-                ) = self._run_spec(
-                    self.page_pool, self._table, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                    jnp.asarray(drafts), jnp.asarray(dlens), *lora,
-                )
-                self.page_pool = pool
-            else:
-                (
-                    cache, tok, pos, done, keys, emitted, n_emit, accepted
-                ) = self._run_spec(
-                    self.cache, self.params,
-                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                    jnp.asarray(drafts), jnp.asarray(dlens), *lora,
-                )
-                self.cache = cache
+            (
+                kv, tok, pos, done, keys, emitted, n_emit, accepted
+            ) = self._run_spec(
+                *self._kv_operands(), self.params,
+                d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
+                jnp.asarray(drafts), jnp.asarray(dlens),
+                **self._adapter_args(),
+            )
+            self._set_kv(kv)
             d.update(tok=tok, pos=pos, done=done, keys=keys)
             self._enqueue_fetch(
                 _Inflight(

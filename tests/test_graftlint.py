@@ -933,6 +933,12 @@ def test_prefill_rule_allows_engine_writers(tmp_path):
 
         def _clear_prefill(self, slot):
             self._frontier[slot] = 0
+
+        def _run_pf(cache, params, frontier, pslot):
+            frontier = frontier.at[pslot].set(pstart)
+
+        def _run_pf_paged(pool, table, params, frontier, pslot):
+            frontier = frontier.at[pslot].set(pstart)
         """,
         rel="dlrover_tpu/serving/engine.py",
     )
@@ -959,6 +965,17 @@ def test_prefill_rule_vacuity_of_engine_allowlist(tmp_path):
         """
         def _harvest(self):
             self._frontier[slot] = fetched
+        """,
+        rel="dlrover_tpu/serving/engine.py",
+    )
+    assert len(hits(PrefillFrontierRule(), src)) == 1
+    # adapters are an operand of the two fused programs, not a second
+    # pair of them: a `_lora` twin coming back is off the allowlist
+    src = probe(
+        tmp_path,
+        """
+        def _run_pf_lora(cache, params, frontier, pslot, abank, aidx):
+            frontier = frontier.at[pslot].set(pstart)
         """,
         rel="dlrover_tpu/serving/engine.py",
     )

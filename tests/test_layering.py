@@ -16,11 +16,17 @@ guards) so the contracts stay individually addressable:
    (DEVIATIONS §10 — paged layout).
 4. serving/ must not construct a raw jax.sharding.Mesh (DEVIATIONS
    §11 — the ONE factory is parallel/mesh.py).
+5. serving/engine.py holds ONE decode scan, ONE sampler and no
+   adapter twin of a program (plain `ast`, no registry rule: the
+   contract is a count, not a confinement).
 """
 
 import ast
 import pathlib
 
+import pytest
+
+import dlrover_tpu.models.decode
 import dlrover_tpu.serving
 from dlrover_tpu.analysis import SourceFile, run_rules, unsuppressed
 from dlrover_tpu.analysis.rules import (
@@ -110,3 +116,87 @@ def test_serving_never_constructs_raw_mesh():
         "m = jax.sharding.Mesh(devs, ('tp',))\n"
     )
     assert len(raw_mesh_uses(probe)) == 2
+
+
+def _engine_tree():
+    return ast.parse((SERVING_DIR / "engine.py").read_text())
+
+
+def _defs(tree, name):
+    return [
+        n.lineno for n in ast.walk(tree)
+        if isinstance(n, ast.FunctionDef) and n.name == name
+    ]
+
+
+def _scan_call_sites(tree):
+    return [
+        n.lineno for n in ast.walk(tree)
+        if isinstance(n, ast.Call)
+        and isinstance(n.func, ast.Attribute)
+        and n.func.attr in ("scan", "fori_loop", "while_loop")
+    ]
+
+
+def _program_builders(tree):
+    return [
+        n for n in tree.body
+        if isinstance(n, ast.FunctionDef)
+        and n.name.startswith("_build_") and n.name.endswith("_program")
+    ]
+
+
+@pytest.mark.parametrize(
+    "what", ["scan", "advance", "warp", "no_lora_twin", "two_layouts"]
+)
+def test_engine_holds_one_decode_loop_and_one_sampler(what):
+    """The chunk, interleaved-prefill and speculative builders share
+    one decode scan, one post-logits advance and decode.py's one
+    warp; adapters are optional operands of their programs. A copy
+    of any of them coming back (thirteen scans, three warps, six
+    `_lora` programs before this test) fails here."""
+    tree = _engine_tree()
+    builders = _program_builders(tree)
+    assert [b.name for b in builders] == [
+        "_build_chunk_program", "_build_pf_chunk_program",
+        "_build_spec_program",
+    ]
+    if what == "scan":
+        sites = _scan_call_sites(tree)
+        assert len(sites) == 1, sites
+        owners = [
+            n.name for n in tree.body
+            if isinstance(n, ast.FunctionDef)
+            and n.lineno <= sites[0] <= n.end_lineno
+        ]
+        assert owners == ["_decode_scan"]
+    elif what == "advance":
+        assert len(_defs(tree, "_advance")) == 1
+    elif what == "warp":
+        decode_tree = ast.parse(
+            pathlib.Path(dlrover_tpu.models.decode.__file__).read_text()
+        )
+        assert not _defs(tree, "_warp")
+        assert len(_defs(decode_tree, "_warp")) == 1
+        imported = [
+            a.name for n in tree.body if isinstance(n, ast.ImportFrom)
+            and n.module == "dlrover_tpu.models.decode" for a in n.names
+        ]
+        assert "_warp" in imported
+        assert not {"_mask_top_k", "_mask_top_p"} & set(imported)
+    elif what == "no_lora_twin":
+        twins = [
+            n.name for n in ast.walk(tree)
+            if isinstance(n, ast.FunctionDef)
+            and (n.name.endswith("_lora") or n.name.endswith("_lora_fn"))
+        ]
+        assert not twins, twins
+    else:
+        for b in builders:
+            jitted = [
+                n.name for n in b.body if isinstance(n, ast.FunctionDef)
+                and n.name.startswith("_run_")
+            ]
+            assert len(jitted) == 2, (b.name, jitted)
+            (ret,) = [n for n in b.body if isinstance(n, ast.Return)]
+            assert [k.value for k in ret.value.keys] == ["dense", "paged"]

@@ -272,8 +272,9 @@ class TestBatchedParity:
         assert tp2 == base
         assert eng.mesh_shape == {"tp": 2}
 
+    @pytest.mark.parametrize("layout", ["dense", "paged"])
     def test_base_traffic_matches_adapterless_engine(
-        self, model, adapters
+        self, model, adapters, layout
     ):
         """adapter_id=None rows ride the all-zero slot 0: output is
         byte-identical to an engine with no registry at all."""
@@ -281,11 +282,12 @@ class TestBatchedParity:
         reg, _ = adapters
         prompts = _prompts((5, 9), seed=5)
         with_reg, _ = _mixed_run(
-            cfg, params, reg, [(p, None) for p in prompts]
+            cfg, params, reg, [(p, None) for p in prompts],
+            kv_layout=layout,
         )
         without, _ = _mixed_run(
             cfg, params, None, [(p, None) for p in prompts],
-            adapter_registry=None,
+            adapter_registry=None, kv_layout=layout,
         )
         assert with_reg == without
 
@@ -322,6 +324,52 @@ class TestProgramKeys:
         lora_keys = [k for _, k in lora_eng._bound_keys]
         assert [k + tag for k in plain_keys] == lora_keys
         assert "adapt" in lora_eng._dev
+
+    @pytest.mark.parametrize("layout", ["dense", "paged"])
+    def test_adapters_are_operands_of_the_one_chunk_program(
+        self, model, adapters, layout
+    ):
+        """An adapter-enabled engine binds the SAME chunk program an
+        adapterless one does: lowered without the adapter operands it
+        is that program to the byte, and with them (every slot on
+        index 0, the zero adapter) it takes the bank's leaves and the
+        index vector as its only new inputs and returns the same
+        outputs."""
+        cfg, params = model
+        reg, _ = adapters
+        kw = dict(n_slots=2, max_len=32, eos_id=None, kv_layout=layout)
+        plain = ContinuousBatcher(cfg, params, **kw)
+        lora_eng = ContinuousBatcher(
+            cfg, params, adapter_registry=reg, adapter_cache_slots=3,
+            **kw,
+        )
+        assert plain._adapter_args() == {}
+
+        def lowered(eng, **operands):
+            d = eng._dev
+            kv = (
+                (eng.page_pool, eng._table) if layout == "paged"
+                else (eng.cache,)
+            )
+            return eng._run_chunk.lower(
+                *kv, eng.params, d["tok"], d["pos"], d["done"],
+                d["limit"], d["keys"], 4, **operands,
+            )
+
+        def n_inputs(low):
+            return len(jax.tree_util.tree_leaves(low.args_info))
+
+        base = lowered(plain)
+        assert lowered(lora_eng).as_text() == base.as_text()
+        operands = lora_eng._adapter_args()
+        assert sorted(operands) == ["abank", "aidx"]
+        assert not np.asarray(operands["aidx"]).any()
+        with_ops = lowered(lora_eng, **operands)
+        assert n_inputs(with_ops) == n_inputs(base) + len(
+            jax.tree_util.tree_leaves(operands)
+        )
+        assert with_ops.out_info == base.out_info
+        assert with_ops.as_text() != base.as_text()
 
 
 # ---------------------------------------------------------------------------
