@@ -441,7 +441,8 @@ def main():
         return [o.tolist() for o in eng.generate_all(spec_prompts)]
 
     async_parity_ok = all(
-        _parity_out(dict(kw, async_depth=1)) == _parity_out(kw)
+        _parity_out(dict(kw, async_depth=1))
+        == _parity_out(dict(kw, async_depth=0))
         for kw in (
             {},
             {"kv_quant": "int8"},
@@ -511,7 +512,9 @@ def main():
         )
         return reqs, cmetrics, cttfts
 
-    steady_reqs, _, steady_ttfts = _run_pool(FaultInjector(seed=0))
+    steady_reqs, _, steady_ttfts = _run_pool(
+        FaultInjector(seed=0), engine_kw={"async_depth": 0}
+    )
 
     def _arm(fi, creps):
         # warm-up advanced each engine's step counter; aim the crash

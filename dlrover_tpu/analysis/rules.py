@@ -138,17 +138,19 @@ HOST_COPY_CALLS = {
 
 # functions allowed to materialize host arrays, per file. engine.py:
 # the ONE designated device fetch point plus the host-data paths
-# (prompt normalization at submit, PRNG-key capture at admit,
-# output-list conversion at retire/drain, prompt-folding at
-# preemption — all of which only touch host-resident numpy data,
-# never a dispatch result). decode.py and paged_kv.py currently have
+# (prompt normalization at submit, output-list conversion at
+# retire/drain, prompt-folding at preemption — all of which only touch
+# host-resident numpy data, never a dispatch result). `_admit` is NOT
+# among them: its allowance once hid `np.asarray(sub)` of a key split
+# on the device, a fetch behind the admission's own prefill
+# (DEVIATIONS §9); the key is split on the host now and an admission
+# converts nothing. decode.py and paged_kv.py currently have
 # NO host-copy sites at all; the empty allowlists freeze that.
 HOST_COPY_ALLOWED: Dict[str, FrozenSet[str]] = {
     ENGINE_FILE: frozenset(
         {
             "_to_host",
             "submit",
-            "_admit",
             "retire",
             "generate_all",
             "_preempt_slot",

@@ -59,18 +59,31 @@ Device residency + async dispatch (the perf layer over both modes):
   fresh blocking copy — `_to_host` is the module's single
   device→host materialization point (tests/test_layering.py lints
   this).
-- `async_depth=1` pipelines one dispatch deep: dispatch N is
-  enqueued via JAX async dispatch with `copy_to_host_async()`
-  started on its outputs, and `step()` returns the events of
-  dispatch N-1 — so the host's event emission, streaming, journaling
-  and the next drafting/admission pass overlap dispatch N's device
-  compute instead of serializing with it. `async_depth=0` (default)
-  harvests in the same call: bit-exact legacy behavior, and the
-  parity oracle for the async path. Either way the dispatch
-  SEQUENCE is identical — drafting and admission always see the
-  fully-harvested state of dispatch N-1 before dispatch N is built —
-  so greedy streams are byte-identical across depths (DEVIATIONS
-  §9 records the staleness contract this leaves the scheduler).
+- A step keeps ONE dispatch in flight (`async_depth=1`, the default
+  since PR 36): dispatch N is enqueued via JAX async dispatch with
+  `copy_to_host_async()` started on its outputs, and `step()` returns
+  the events of dispatch N-1 — so the caller's event delivery,
+  streaming, journaling, metrics and its next admission pass run
+  under dispatch N's device compute instead of serializing with it.
+  On the chip a served step's idle share was 11-12% with the host and
+  the device taking turns (PERF.md §6, PR 36), and a closed loop
+  loses throughput one for one with it: so this is what an engine
+  built with no word on it does. `async_depth=0` harvests in the same
+  call and is the parity oracle the tests state by name. Either way
+  the dispatch SEQUENCE is identical — drafting and admission always
+  see the fully-harvested state of dispatch N-1 before dispatch N is
+  built — so token streams are byte-identical across depths
+  (DEVIATIONS §9 records the staleness contract this leaves the
+  scheduler).
+- An admission never fetches from the device: `_admit` returns once
+  the prompt's forward and the state scatters are ENQUEUED. The
+  request's key is split off the engine's on the host
+  (serving/host_prng.py: the same threefry bits), because a split on
+  the device is fetched behind the admission's own prefill, and the
+  second admission of a step, the rings and the chunk dispatch would
+  then be prepared with the device idle. tests/test_layering.py
+  lints `_admit` like any other step-path function, and
+  tests/test_serving_admit_no_fetch.py spies on every branch.
 """
 
 import contextlib
@@ -131,6 +144,7 @@ from dlrover_tpu.parallel.mesh import (
 )
 from dlrover_tpu.parallel.sharding import replicated, shard_tree
 from dlrover_tpu.serving.adapters import DeviceAdapterCache
+from dlrover_tpu.serving import host_prng
 from dlrover_tpu.serving import kv_tier as _kv_tier
 from dlrover_tpu.serving.paged_kv import (
     TRASH_PAGE,
@@ -1008,7 +1022,7 @@ class ContinuousBatcher:
         spec_probe_interval: int = 32,  # rounds between disabled-slot probes
         chaos=None,                  # serving/chaos.py FaultInjector
         chaos_tag: str = "engine",   # this engine's tag in fault plans
-        async_depth: int = 0,        # 1 = one-deep pipelined dispatch
+        async_depth: int = 1,        # 0 = harvest in the same step()
         kv_layout: str = "dense",    # "dense" bank | "paged" pool
         page_size: int = 0,          # cells per page (0 = auto pow2)
         n_pages: int = 0,            # pool size (0 = dense-equivalent)
@@ -1182,7 +1196,8 @@ class ContinuousBatcher:
             spec_accept_threshold, spec_probe_interval,
         )
         # engine key only SEEDS per-request keys (one split per
-        # admission); sampling itself runs on the per-slot keys below
+        # admission, on the host: see the `key` property); sampling
+        # itself runs on the per-slot keys below
         self.key = jax.random.PRNGKey(seed)
         self.slot_key = np.zeros((n_slots, 2), np.uint32)
         # the slot bank over-allocates by the draft width: a verify
@@ -1346,6 +1361,7 @@ class ContinuousBatcher:
         self._stat_overlap_ms = 0.0
         self._stat_dispatches = 0
         self._wait_this_step = 0.0   # s blocked on the device, this step
+        self._overlap_this_step = 0.0  # s of device span hidden, this step
         self._admit_this_step = 0.0  # s inside _admit, this step
         # extent of the last engine.step span: the scheduler's
         # straggler EWMA reads it instead of timing step() again
@@ -2126,6 +2142,18 @@ class ContinuousBatcher:
         self._requests[idx].adopted = pkg
         return idx
 
+    @property
+    def key(self) -> np.ndarray:
+        """The engine's own key, uint32[2] on the host: `_admit` splits
+        a request's key off it there, so that no admission waits for the
+        device. Setting it (the PPO rollout re-keys its engine before
+        every drain) is the one fetch, outside the step loop."""
+        return self._key
+
+    @key.setter
+    def key(self, value) -> None:
+        (self._key,) = _to_host(value)
+
     def _pad_to(self, toks: np.ndarray, bucket: int) -> np.ndarray:
         padded = np.full(bucket, self.pad_id, np.int32)
         padded[: len(toks)] = toks
@@ -2204,8 +2232,9 @@ class ContinuousBatcher:
                 p + (req.max_new or self.max_new), self.max_len
             )
             if req.prng_key is None:
-                self.key, sub = jax.random.split(self.key)
-                req.prng_key = np.asarray(sub, np.uint32)
+                # on the host: a split on the device is fetched behind
+                # this admission's own prefill (DEVIATIONS §9)
+                self._key, req.prng_key = host_prng.split(self._key)
             self.slot_key[slot] = req.prng_key
             self.done[slot] = False
             # mirror the admission onto the device copies as one scatter
@@ -3167,33 +3196,43 @@ class ContinuousBatcher:
         }
 
     def step(self) -> List[StepEvent]:
-        """One engine iteration. Sync (`async_depth=0`): admit, run
-        ONE dispatch, harvest it, return its events — the legacy
-        contract. Async (`async_depth=1`): harvest the PREVIOUS
-        dispatch first (its host copies were started at enqueue, so
-        the wait is only whatever device time the host failed to
-        hide), admit/draft from that fully-refreshed state, enqueue
-        the next dispatch without blocking on it, and return the
-        harvested events — so the caller streams/journals dispatch
-        N-1 while the device computes dispatch N. Returns [] when
-        there is no work. Either way drafting and admission see the
-        same state sequence, so the dispatches (and the emitted token
-        streams) are byte-identical across depths; only WHEN events
-        surface shifts by one call."""
+        """One engine iteration, a dispatch kept in flight
+        (`async_depth=1`, the default): harvest the PREVIOUS dispatch
+        first (its host copies were started at enqueue, so the wait is
+        only whatever device time the host failed to hide), admit and
+        draft from that fully-refreshed state WITHOUT fetching from
+        the device, enqueue the next dispatch without blocking on it,
+        and return the harvested events — so the caller streams and
+        journals dispatch N-1, and admits from its own queue, while
+        the device computes dispatch N. `async_depth=0` admits, runs
+        ONE dispatch, harvests it and returns its events in the same
+        call: the oracle the parity tests build by name. Returns []
+        when there is no work. Either way drafting and admission see
+        the same state sequence, so the dispatches (and the emitted
+        token streams) are byte-identical across depths; only WHEN
+        events surface shifts by one call. The span's `overlap_s` is
+        the device span of the harvested dispatch that the host did
+        not spend waiting (`step_stats()["overlap_ratio"]` sums it)."""
         with trace.span("engine.step") as sp:
             self._wait_this_step = 0.0
+            self._overlap_this_step = 0.0
             self._admit_this_step = 0.0
             self._window_freed_this_step = 0
             self._moe_pairs = None
             self._maybe_commit_refresh()  # deferred swap at idle fence
+            if self.chaos is not None:
+                # before the harvest, any admission or dispatch: an
+                # injected fault leaves the queue, ledger, cache AND the
+                # dispatch in flight untouched, which is the state
+                # between two steps. A caller that evacuates or re-forms
+                # the mesh drains the dispatch itself (drain_inflight);
+                # one that goes on with the same mesh (resize's no-op: the
+                # chip lost was none of this slice's) harvests it next
+                # step, and no token is lost
+                step_no = self._step_no
+                self._step_no += 1
+                self.chaos.on_engine_step(self.chaos_tag, step_no)
             try:
-                if self.chaos is not None:
-                    # before any admission or dispatch: an injected fault
-                    # leaves the queue, ledger and cache untouched, so the
-                    # caller can snapshot + evacuate from consistent state
-                    step_no = self._step_no
-                    self._step_no += 1
-                    self.chaos.on_engine_step(self.chaos_tag, step_no)
                 if self.kv_tier is not None:
                     # complete last step's demotion copies (started async
                     # at demote time — a whole dispatch has passed, so
@@ -3251,8 +3290,9 @@ class ContinuousBatcher:
                         # at the END of the previous step
                         events = self._harvest()
             except Exception:
-                # a raising step (injected fault or real failure) orphans
-                # any in-flight dispatch: its results must never surface
+                # a step that fails past the fault hook (a real failure of
+                # the harvest, an admission or the enqueue) orphans any
+                # in-flight dispatch: its results must never surface
                 # later — the caller snapshots from the last HARVESTED
                 # state, and failover replay regenerates the lost tokens
                 self._inflight = None
@@ -3263,6 +3303,7 @@ class ContinuousBatcher:
                 live_tokens=int(self.pos[live].sum()),
                 wait_s=self._wait_this_step,
                 admit_s=self._admit_this_step,
+                overlap_s=self._overlap_this_step,
             )
             if self._hybrid:
                 sp.set(
@@ -3499,12 +3540,15 @@ class ContinuousBatcher:
             w1 = time.perf_counter()
             wait_s = w1 - sp.t0
             sp.set(wait_s=wait_s)
-            wait_ms = wait_s * 1e3
-            span_ms = (w1 - pend.dispatched_at) * 1e3
+            # the device span runs from the enqueue to here; what of
+            # it the host did not spend waiting, it spent on other work
+            span_s = w1 - pend.dispatched_at
+            hidden_s = max(span_s - wait_s, 0.0)
             self._wait_this_step += wait_s
-            self._stat_wait_ms += wait_ms
-            self._stat_span_ms += span_ms
-            self._stat_overlap_ms += max(span_ms - wait_ms, 0.0)
+            self._overlap_this_step += hidden_s
+            self._stat_wait_ms += wait_s * 1e3
+            self._stat_span_ms += span_s * 1e3
+            self._stat_overlap_ms += hidden_s * 1e3
             self._stat_dispatches += 1
             if pend.kind == "chunk":
                 tok, pos, done, keys, emitted, *pairs = host

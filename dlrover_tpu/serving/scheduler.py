@@ -1142,8 +1142,9 @@ class RequestScheduler:
         # abandon any async-dispatched-but-unharvested step FIRST:
         # journal and req.tokens then describe the same (last
         # harvested) dispatch, and replay regenerates the rest.
-        # step() already drops its own in-flight record when it
-        # raises; this guards the paths that crash between steps.
+        # step() drops its own in-flight record when it fails past
+        # its fault hook; an injected fault, and a crash between
+        # steps, leave the record for this drain.
         drain = getattr(self.engine, "drain_inflight", None)
         if drain is not None:
             drain()

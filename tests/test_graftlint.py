@@ -163,6 +163,27 @@ def test_host_copy_rule_flags_stray_fetch(tmp_path):
     assert len(found) == 1 and "step" in found[0].message
 
 
+def test_host_copy_rule_flags_the_fetch_an_admission_made(tmp_path):
+    # the engine's `_admit` as it stood before PR 36: the request's key
+    # split on the device and fetched behind the admission's own
+    # prefill. `_admit` had the allowlist's wholesale leave, so the
+    # lint let it by; it is off the list now
+    src = probe(
+        tmp_path,
+        """
+        import jax
+        import numpy as np
+        class ContinuousBatcher:
+            def _admit(self, slot, req):
+                self.key, sub = jax.random.split(self.key)
+                req.prng_key = np.asarray(sub, np.uint32)
+        """,
+        rel=ENGINE_REL,
+    )
+    found = hits(HostCopyRule(), src)
+    assert len(found) == 1 and "_admit" in found[0].message
+
+
 def test_host_copy_rule_generalizes_beyond_engine(tmp_path):
     # decode.py and paged_kv.py have EMPTY allowlists: any host
     # materialization at all is a finding there

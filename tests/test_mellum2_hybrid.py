@@ -179,6 +179,60 @@ def test_preempted_wrapped_slot_replays_to_the_same_tokens(small, served):
     assert tight.rings.pages_held == 0
 
 
+def _stepped(cfg, params, prompts, depth, **kw):
+    """Drives `step()` by hand with admissions landing mid-run: two
+    requests at the start, one after three steps, one after five.
+    Which call a token surfaces in depends on `depth`; which tokens a
+    request gets does not. Returns every request's stream and key."""
+    eng = ContinuousBatcher(
+        cfg, params, n_slots=3, max_len=64, max_new_tokens=30, chunk=4,
+        kv_layout="paged", page_size=PAGE, pad_id=-1, async_depth=depth,
+        **kw,
+    )
+    late = {3: prompts[2], 5: prompts[3]}
+    ids = [eng.submit(p) for p in prompts[:2]]
+    streams, n = {}, 0
+    while eng.has_work() or n <= max(late):
+        if n in late:
+            ids.append(eng.submit(late[n]))
+        for idx, toks, _fin in eng.step():
+            streams.setdefault(idx, []).extend(toks)
+        n += 1
+        assert n < 200
+    keys = [eng._requests[i].prng_key.tolist() for i in ids]
+    eng.allocator.check()
+    eng.allocator_win.check()
+    assert eng.allocator.used_pages == 0 and eng.rings.pages_held == 0
+    return [streams[i] for i in ids], keys, eng
+
+
+@pytest.mark.parametrize(
+    "sampling",
+    [{}, dict(temperature=0.8, top_k=20, seed=11)],
+    ids=["greedy", "sampled"],
+)
+def test_a_dispatch_in_flight_serves_the_same_streams(small, served, sampling):
+    """Window and full layers, dropless experts, two classes of pages:
+    the engine's default order (a dispatch left in flight behind every
+    step) against the order that harvests in the same call."""
+    _, cfg, params = small
+    prompts, want = served
+    sync, sync_keys, _ = _stepped(cfg, params, prompts, 0, **sampling)
+    flight, keys, eng = _stepped(cfg, params, prompts, 1, **sampling)
+    assert flight == sync
+    assert keys == sync_keys
+    assert all(len(s) == 30 for s in flight)
+    if not sampling:
+        assert flight[:2] == want
+    # the rings went round under the dispatch in flight too
+    assert eng.rings.pages_freed_behind > 2 * eng.rings.ring_pages
+    # and the default is the order with a dispatch in flight
+    assert ContinuousBatcher(
+        cfg, params, n_slots=1, max_len=16, kv_layout="paged",
+        page_size=PAGE,
+    ).async_depth == 1
+
+
 def test_step_span_counts_pages_and_expert_load(small, served):
     from dlrover_tpu.common import trace
 

@@ -232,6 +232,40 @@ class TestShrinkParity:
         assert got == want
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_a_chip_lost_outside_the_slice_loses_no_token(model, temperature):
+    """A resize that turns out to be a no-op (the chip lost was none
+    of this slice's: tp stays 1) replays nothing, so the dispatch in
+    flight when the fault was injected has to survive it: the next
+    step harvests it. (It used to be dropped by the raising step, and
+    its tokens with it, while the device state had moved on.)"""
+    cfg, params = model
+    kw = dict(temperature=temperature, top_k=5, seed=3)
+    prompts = _prompts((6, 9, 13), seed=41)
+    want = [
+        list(o)
+        for o in _engine(cfg, params, async_depth=0, **kw).generate_all(
+            prompts
+        )
+    ]
+    fi = FaultInjector(seed=41)
+    fi.lose_chip("e", 1, at_step=2)
+    eng = _engine(cfg, params, chaos=fi, chaos_tag="e", **kw)
+    idxs = [eng.submit(pr) for pr in prompts]
+    reports = []
+    for _ in range(400):
+        if not eng.has_work():
+            break
+        try:
+            eng.step()
+        except ChipLost:
+            assert eng._inflight is not None  # left for the next step
+            reports.append(eng.resize(1))
+            assert eng._inflight is not None  # and by the no-op too
+    assert [r.direction for r in reports] == ["noop"]
+    assert [list(eng._requests[i].out) for i in idxs] == want
+
+
 # ---------------------------------------------------------------------------
 # grow-back
 

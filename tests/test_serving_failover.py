@@ -297,7 +297,9 @@ class TestProbeIsolation:
 
 
 def _reference(cfg, params, prompts, engine_kw=None):
-    eng = _engine(cfg, params, **(engine_kw or {}))
+    # the parity oracle harvests in the same step() that dispatched,
+    # whatever order the engine under test runs
+    eng = _engine(cfg, params, **{**(engine_kw or {}), "async_depth": 0})
     return {
         tuple(p): list(o)
         for p, o in zip(prompts, eng.generate_all(prompts))
@@ -347,10 +349,10 @@ class TestFailoverParity:
     @pytest.mark.parametrize(
         "engine_kw",
         [
-            {},
-            {"kv_quant": True},
-            {"prefix_cache_rows": 4},
-            {"spec_draft_len": 4},
+            {"async_depth": 0},
+            {"async_depth": 0, "kv_quant": True},
+            {"async_depth": 0, "prefix_cache_rows": 4},
+            {"async_depth": 0, "spec_draft_len": 4},
             {"async_depth": 1},
             {"async_depth": 1, "kv_quant": True},
             {"async_depth": 1, "prefix_cache_rows": 4},
@@ -366,14 +368,11 @@ class TestFailoverParity:
         prefix-warm resume, speculative decoding, async dispatch) —
         replay-resume must be byte-exact under every KV/decode
         discipline. The reference always runs SYNCHRONOUS
-        (async_depth stripped): the sync path is the parity oracle
+        (_reference builds it so): the sync path is the parity oracle
         the pipelined path must reproduce, crashes and all."""
         cfg, params = model
         prompts = _prompts((5, 9, 3, 7), seed=fuzz_seed)
-        ref_kw = {
-            k: v for k, v in engine_kw.items() if k != "async_depth"
-        }
-        want = _reference(cfg, params, prompts, ref_kw)
+        want = _reference(cfg, params, prompts, engine_kw)
         reqs, metrics, _ = self._crash_run(
             cfg, params, prompts, fuzz_seed, engine_kw
         )
@@ -644,7 +643,11 @@ class TestProbationCycle:
 class TestEngineLifecycle:
     def test_cancel_frees_slot_and_prefix_pin(self, model):
         cfg, params = model
-        eng = _engine(cfg, params, n_slots=1, prefix_cache_rows=4)
+        # async_depth=0: nothing is left in flight behind a step(), so
+        # a cancel of everything leaves no work at once
+        eng = _engine(
+            cfg, params, n_slots=1, prefix_cache_rows=4, async_depth=0
+        )
         prompts = _prompts((20, 5), seed=7)
         a = eng.submit(prompts[0])
         b = eng.submit(prompts[1])

@@ -86,10 +86,12 @@ CONFIGS = [
         },
     ),
     ("spec", {"spec_draft_len": 3}),
-    ("async", {"async_depth": 1}),
+    # every entry above keeps a dispatch in flight (the engine's
+    # default); these two harvest in the same step()
+    ("sync", {"async_depth": 0}),
     (
-        "paged-async",
-        {"kv_layout": "paged", "n_pages": 24, "async_depth": 1},
+        "paged-sync",
+        {"kv_layout": "paged", "n_pages": 24, "async_depth": 0},
     ),
 ]
 

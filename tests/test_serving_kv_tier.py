@@ -196,7 +196,8 @@ TIER_CONFIGS = [
     ("greedy", {}),
     ("sampled", dict(temperature=0.8, top_k=20, seed=3)),
     ("spec", dict(spec_draft_len=4)),
-    ("async", dict(async_depth=1)),
+    # the others keep a dispatch in flight (the engine's default)
+    ("sync", dict(async_depth=0)),
 ]
 
 
@@ -266,7 +267,7 @@ class TestDemotePromoteParity:
             if rng.integers(2):
                 kw["spec_draft_len"] = 4
             if rng.integers(2):
-                kw["async_depth"] = 1
+                kw["async_depth"] = 0
             rounds = [prompts, prompts]
             o = _churn(
                 _mk(cfg, params, kv_layout="paged",

@@ -591,7 +591,7 @@ class TestAsyncStaleness:
     ):
         cfg, params = model
         prompts = _mixed_prompts(seed=3)
-        e0 = _engine(cfg, params, spec_draft_len=4)
+        e0 = _engine(cfg, params, spec_draft_len=4, async_depth=0)
         e1 = _engine(cfg, params, spec_draft_len=4, async_depth=1)
         assert _drain(e0, prompts) == _drain(e1, prompts)
         # the controller's adaptive-k trajectory is part of the

@@ -267,10 +267,7 @@ class TestPreemptResumeParity:
         """Undisturbed reference: every prompt decodes to completion
         on one engine with the same pinned keys. Always SYNCHRONOUS —
         the sync path is the parity oracle (failover-suite idiom)."""
-        ref_kw = {
-            k: v for k, v in engine_kw.items() if k != "async_depth"
-        }
-        ref_kw["n_slots"] = len(prompts)
+        ref_kw = dict(engine_kw, async_depth=0, n_slots=len(prompts))
         eng = _engine(cfg, params, **ref_kw)
         ids = [
             eng.submit(p, max_new=8, prng_key=k)
@@ -286,10 +283,11 @@ class TestPreemptResumeParity:
     @pytest.mark.parametrize(
         "engine_kw",
         [
-            {},
-            {"kv_layout": "paged"},
-            {"temperature": 0.9, "top_k": 20, "seed": 5},
+            {"async_depth": 0},
+            {"async_depth": 0, "kv_layout": "paged"},
+            {"async_depth": 0, "temperature": 0.9, "top_k": 20, "seed": 5},
             {
+                "async_depth": 0,
                 "kv_layout": "paged",
                 "temperature": 0.9,
                 "top_k": 20,

@@ -269,6 +269,39 @@ class TestEngineSpans:
         assert steps[-1][COUNTS]["alive"] == 0
         assert all(type(r[COUNTS]["live_tokens"]) is int for r in steps)
 
+    @pytest.mark.parametrize("depth", [0, 1], ids=["sync", "in-flight"])
+    def test_step_carries_the_device_span_it_hid(self, model, depth):
+        """`overlap_s`: of the harvested dispatch's device span (its
+        enqueue to the end of its fetch), what the host did not spend
+        waiting. A caller that takes 20 ms between two steps has
+        hidden at least that, where a dispatch was left in flight."""
+        cfg, params = model
+        eng = _engine(cfg, params, async_depth=depth)
+        for p in _prompts((5, 12, 3), seed=4):
+            eng.submit(p)
+        while eng.has_work():
+            eng.step()
+            time.sleep(0.02)
+        steps = _named("engine.step")
+        harvests = _named("engine.harvest")
+        assert all(r[COUNTS]["overlap_s"] >= 0.0 for r in steps)
+        harvested = [
+            s for s in steps
+            if any(h[PARENT] == s[ID] for h in harvests)
+        ]
+        assert len(harvested) == len(harvests) >= 3
+        if depth:
+            assert all(s[COUNTS]["overlap_s"] >= 0.02 for s in harvested)
+        # the per-step counts sum to what /metrics' ratio is made of
+        stats = eng.step_stats()
+        hidden = sum(s[COUNTS]["overlap_s"] for s in steps)
+        waited = sum(s[COUNTS]["wait_s"] for s in steps)
+        assert stats["overlap_ratio"] == pytest.approx(
+            hidden / (hidden + waited), rel=1e-6
+        )
+        if depth:
+            assert stats["overlap_ratio"] > 0.0
+
     def test_admit_and_dispatch_carry_their_counts(self, model):
         cfg, params = model
         eng = _engine(cfg, params)
