@@ -27,6 +27,8 @@ from dlrover_tpu.models.llama import (
     _head_matrix,
     _mlp_residual,
     _rms_norm,
+    _rope,
+    _swiglu,
 )
 from dlrover_tpu.ops.quantization import matmul_any
 from dlrover_tpu.parallel.mesh import SERVING_TP_AXIS
@@ -92,6 +94,16 @@ def _dropless(cfg) -> bool:
     )
 
 
+def moe_counts_shape(cfg) -> Tuple:
+    """Shape of the int32 counts a paged forward with dropless
+    experts returns: the routed pairs per expert held here, summed
+    over the layers; where the chip holds a SHARE of the experts, a
+    second row: in how many layers each held expert got a pair at all
+    (an expert no pair lands on is not read)."""
+    held = cfg.held[1]
+    return (2, held) if cfg.experts_held else (held,)
+
+
 def _split_experts(cfg, layers):
     """(the leaves the layer loop scans over, the experts' stacks).
     Dropless experts stay OUT of the scan's xs: the grouped kernel
@@ -111,21 +123,34 @@ def _window_of(cfg, kind) -> int:
 def _ffn_residual(cfg, x, layer_params, lp, tp, layer, experts):
     """The feed-forward half of a served block: llama's own
     `_mlp_residual`, or where the configuration routes without
-    dropping, `moe.dropless_moe` over the stacked experts. Returns
-    (x, int32[E] routed pairs per expert, or None)."""
-    if experts is None:
+    dropping, `moe.dropless_moe` over the stacked experts (beside the
+    shared experts, where it has them); a leading dense layer of such
+    a model (`experts` None) is a plain SwiGLU. Returns (x,
+    int32[E_held] routed pairs per held expert, or None)."""
+    if experts is None and not _dropless(cfg):
         x, _aux = _mlp_residual(cfg, None, x, layer_params, lp, tp=tp)
         return x, None
-    from dlrover_tpu.models.moe import dropless_moe
-
     b, s, d = x.shape
     h = _rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
+    if experts is None:
+        with jax.named_scope("ffn_dense"):
+            return x + _swiglu(
+                None, h, lp["w_gate"], lp["w_up"], lp["w_down"], tp
+            ), None
+    from dlrover_tpu.models.moe import dropless_moe
+
     y, counts = dropless_moe(
         h.reshape(b * s, d), layer_params["router"],
         experts["we_gate"], experts["we_up"], experts["we_down"],
-        cfg.moe_top_k, layer=layer,
+        cfg.routing, layer=layer, bias=layer_params.get("router_bias"),
     )
-    return x + y.reshape(b, s, d), counts
+    x = x + y.reshape(b, s, d)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe_shared"):
+            x = x + _swiglu(
+                None, h, lp["ws_gate"], lp["ws_up"], lp["ws_down"], tp
+            )
+    return x, counts
 
 
 # Why byte parity survives head sharding (the tp>1 oracle of
@@ -157,7 +182,12 @@ def init_kv_cache(
     bound on reading the whole cache every step, reads half the
     bytes. Dequantization fuses into the attention einsum's loads.
     Opt-in: exact-parity paths (tests, PPO behavior-policy concerns)
-    keep the full-precision default."""
+    keep the full-precision default. A latent model's bank is
+    `init_latent_cache`'s."""
+    if getattr(cfg, "latent", False):
+        if quant:
+            raise NotImplementedError("a latent cache is not quantized")
+        return init_latent_cache(cfg, batch, max_len)
     kv_heads = getattr(cfg, "n_kv_heads", cfg.n_heads)
     shape = (cfg.n_layers, batch, max_len, kv_heads, cfg.head_dim)
     if not quant:
@@ -400,6 +430,209 @@ def _block_gpt(
     return x, layer_cache
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA). A token's keys and values are ONE normed
+# vector c of `kv_lora_rank` numbers and one rotary key r of
+# `qk_rope_head_dim` shared by every head; the cache holds (c, r) and
+# nothing else, in one leaf `ckv` whose rows are `cfg.latent_width`
+# wide (c, r, zeros up to whole 128-lane tiles). Two forms compute the
+# same attention: EXPANDED (a prefill: k and v of every head are made
+# from c, then plain causal attention, the flash kernel on a TPU) and
+# ABSORBED (every step over the cache: the head's key matrix is
+# multiplied into its query, so scores and value sums are taken
+# against the cached rows themselves and K and V never exist).
+# ---------------------------------------------------------------------------
+
+
+def init_latent_cache(cfg, batch: int, max_len: int):
+    """The dense bank of a latent model: `[L, B, M, W]`."""
+    shape = (cfg.n_layers, batch, max_len, cfg.latent_width)
+    return {"ckv": jnp.zeros(shape, cfg.dtype)}
+
+
+def init_latent_pool(cfg, n_pages: int, page_size: int):
+    """The latent class of page pool: `[L, n_pages, page_size, W]`,
+    one row a token and layer that is both key and value. W pads the
+    latent and the rotary key (512 + 64) to 640, whole lane tiles, so
+    that the kernel copies a page as it lies; page 0 is the trash page
+    as in `init_page_pool`."""
+    shape = (cfg.n_layers, n_pages, page_size, cfg.latent_width)
+    return {"ckv": jnp.zeros(shape, cfg.dtype)}
+
+
+def _leaf(cache):
+    """Any one leaf of a bank or pool (their leading dims agree)."""
+    return next(iter(cache.values()))
+
+
+def _latent_qkv(cfg, h, layer_params, lp, positions, tp: int = 1):
+    """(q_nope [B,S,H,nope], q_rope [B,S,H,rope] rotated, row [B,S,W]
+    as the cache holds it: the normed latent, the rotated shared key,
+    zeros)."""
+    b, s, _ = h.shape
+    H, cr = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    spec = cfg.rope_of("full")
+    cq = _rms_norm(
+        matmul_any(h, lp["wq_a"], tp=tp), layer_params["q_norm"],
+        cfg.norm_eps,
+    )
+    q = matmul_any(cq, lp["wq_b"], tp=tp).reshape(b, s, H, nope + rope)
+    q_nope = q[..., :nope]
+    q_rope = _rope(q[..., nope:], positions, spec)
+    ckv = matmul_any(h, lp["wkv_a"], tp=tp)
+    c = _rms_norm(ckv[..., :cr], layer_params["kv_norm"], cfg.norm_eps)
+    r = _rope(ckv[..., None, cr:], positions, spec)[:, :, 0]
+    pad = cfg.latent_width - cr - rope
+    row = jnp.concatenate(
+        [c, r, jnp.zeros((b, s, pad), c.dtype)], axis=-1
+    )
+    return q_nope, q_rope, row
+
+
+def _latent_expanded(cfg, q_nope, q_rope, row, lp, tp: int = 1):
+    """The expanded form over the chunk itself (a prefill from
+    position 0): every head's k = [c W_uk, r] and v = c W_uv, plain
+    causal attention -> [B, S, H * v_head_dim]."""
+    from dlrover_tpu.ops.attention import dot_product_attention
+
+    b, s, H, nope = q_nope.shape
+    cr, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    c = row[..., :cr]
+    k_nope = matmul_any(c, lp["wk_b"], tp=tp).reshape(b, s, H, nope)
+    v = matmul_any(c, lp["wv_b"], tp=tp).reshape(b, s, H, cfg.v_head_dim)
+    r = jnp.broadcast_to(
+        row[:, :, None, cr:cr + rope], (b, s, H, rope)
+    )
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, r], axis=-1)
+    # the flash kernel takes q, k and v of one width (192 each at the
+    # published sizes); another value width goes to XLA
+    impl = "auto"
+    if cfg.attn_impl == "reference" or v.shape[-1] != q.shape[-1]:
+        impl = "reference"
+    attn = dot_product_attention(
+        q, k, v, causal=True, scale=cfg.attn_scale, impl=impl
+    )
+    return attn.reshape(b, s, H * cfg.v_head_dim)
+
+
+def _latent_absorb_q(cfg, q_nope, q_rope, lp):
+    """A head's query against the cached rows: [W_uk q_nope, q_rope,
+    zeros] -> [B, S, H, W]."""
+    b, s, H, nope = q_nope.shape
+    with jax.named_scope("mla_absorb"):
+        wk = lp["wk_b"].reshape(cfg.kv_lora_rank, H, nope)
+        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, wk)
+    pad = cfg.latent_width - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+    return jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((b, s, H, pad), q_lat.dtype)], axis=-1
+    )
+
+
+def _latent_absorb_out(cfg, u, lp):
+    """The heads' sums of latents [B, S, H, cr] through their value
+    matrices -> [B, S, H * v_head_dim]."""
+    b, s, H, cr = u.shape
+    with jax.named_scope("mla_absorb"):
+        wv = lp["wv_b"].reshape(cr, H, cfg.v_head_dim)
+        o = jnp.einsum("bshc,chd->bshd", u, wv)
+    return o.reshape(b, s, H * cfg.v_head_dim)
+
+
+def _block_latent(
+    cfg, x, layer_params, layer_cache, positions, start,
+    plain_causal: bool = False, mesh=None, lora=None, kind=None,
+    layer=None, experts=None,
+):
+    """`_block` for latent attention over a dense bank: the chunk's
+    (c, r) rows are written at `start`; a prefill from 0 attends in
+    the expanded form, everything else in the absorbed form over the
+    bank (`pa.latent_scores_and_sums`: what the latent pool's
+    reference runs on the gathered pages, operation for operation)."""
+    from dlrover_tpu.ops import paged_attention as pa
+
+    lp = _compute_weights(cfg, layer_params)
+    tp = _mesh_tp(mesh)
+    scope = "attn_latent_prefill" if plain_causal else "attn_latent_decode"
+    with jax.named_scope(scope):
+        h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
+        q_nope, q_rope, row = _latent_qkv(
+            cfg, h, layer_params, lp, positions, tp
+        )
+        layer_cache = {
+            "ckv": _cache_write(layer_cache["ckv"], row, start)
+        }
+        if plain_causal:
+            attn = _latent_expanded(cfg, q_nope, q_rope, row, lp, tp)
+        else:
+            u = pa.latent_scores_and_sums(
+                _latent_absorb_q(cfg, q_nope, q_rope, lp),
+                layer_cache["ckv"], positions, cfg.attn_scale,
+                cfg.kv_lora_rank,
+            )
+            attn = _latent_absorb_out(cfg, u, lp)
+        x = x + matmul_any(attn, lp["wo"], tp=tp)
+    with jax.named_scope("mlp"):
+        x, _counts = _ffn_residual(
+            cfg, x, layer_params, lp, tp, layer, experts
+        )
+    return x, layer_cache
+
+
+def _block_latent_paged(
+    cfg, x, layer_params, pool, layer, table, positions, mesh=None,
+    lora=None, kind=None, abs_layer=None, experts=None,
+):
+    """`_block_paged` for latent attention: the chunk's rows are
+    scattered into the slot's pages of layer `layer` and the queries
+    attend in the absorbed form over them: one query a slot through
+    `ops/paged_attention.latent_paged_attention` (the kernel on a TPU,
+    the gathered view elsewhere), more of them over the gathered
+    view."""
+    from dlrover_tpu.ops import paged_attention as pa
+
+    lp = _compute_weights(cfg, layer_params)
+    tp = _mesh_tp(mesh)
+    with jax.named_scope("attn_latent_decode"):
+        h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
+        q_nope, q_rope, row = _latent_qkv(
+            cfg, h, layer_params, lp, positions, tp
+        )
+        arr = pool["ckv"]
+        ps = arr.shape[2]
+        pids = jnp.take_along_axis(table, positions // ps, axis=1)
+        with jax.named_scope("kv_pool_writeback"):
+            pool = {"ckv": arr.at[layer, pids, positions % ps].set(
+                row.astype(arr.dtype)
+            )}
+        qc = _latent_absorb_q(cfg, q_nope, q_rope, lp)
+        if qc.shape[1] == 1:
+            impl = "reference" if cfg.attn_impl == "reference" else "auto"
+            with jax.named_scope("paged_attn"):
+                u = pa.latent_paged_attention(
+                    qc[:, 0], pool, table, positions[:, 0] + 1,
+                    cfg.attn_scale, cfg.kv_lora_rank, layer=layer,
+                    impl=impl,
+                )[:, None]
+        else:
+            with jax.named_scope("kv_pool_slice"):
+                view = _paged_view(pool, layer, table)
+            u = pa.latent_scores_and_sums(
+                qc, view["ckv"], positions, cfg.attn_scale,
+                cfg.kv_lora_rank,
+            )
+        x = x + matmul_any(
+            _latent_absorb_out(cfg, u, lp), lp["wo"], tp=tp)
+    with jax.named_scope("mlp"):
+        x, counts = _ffn_residual(
+            cfg, x, layer_params, lp, tp, abs_layer, experts
+        )
+    if experts is None:
+        return x, pool
+    return x, pool, counts
+
+
 def _is_gpt(cfg) -> bool:
     from dlrover_tpu.models.gpt import GptConfig
 
@@ -455,10 +688,26 @@ def _forward_cached(
         block = _block_gpt
     else:
         x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
-        block = _block
+        block = _block_latent if cfg.latent else _block
 
     kinds = _kinds(cfg)
     scanned_params, experts = _split_experts(cfg, params["layers"])
+    # leading dense layers: a prologue before the scan (their leaves
+    # have other shapes than the expert layers' and cannot be stacked
+    # with them); the scan then runs over the rest of the bank
+    lead = getattr(cfg, "first_k_dense", 0)
+    lead_cache = []
+    for j in range(lead):
+        x, layer_cache = block(
+            cfg, x,
+            jax.tree_util.tree_map(
+                lambda a: a[j], params["dense_layers"]),
+            {name: arr[j] for name, arr in cache.items()},
+            positions, start, plain_causal=plain_causal, mesh=mesh,
+        )
+        lead_cache.append(layer_cache)
+    if lead:
+        cache = {name: arr[lead:] for name, arr in cache.items()}
 
     def body(carry, inp):
         # one PERIOD of layers, written out; a homogeneous model's
@@ -488,7 +737,7 @@ def _forward_cached(
 
     # the cache dict scans as a pytree: each layer body sees its own
     # {"k","v"[,"k_scale","v_scale"]} slice and emits the updated one
-    n_layers = cache["k"].shape[0]
+    n_layers = _leaf(cache).shape[0]
     xs = (
         _by_period(cfg, scanned_params),
         _by_period(cfg, dict(cache)),
@@ -503,6 +752,13 @@ def _forward_cached(
         cache_new = jax.tree_util.tree_map(
             lambda a: a.reshape((n_layers,) + a.shape[2:]), scanned
         )
+    if lead:
+        cache_new = {
+            name: jnp.concatenate(
+                [jnp.stack([c[name] for c in lead_cache]), arr]
+            )
+            for name, arr in cache_new.items()
+        }
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 
@@ -700,10 +956,10 @@ def prefill_into_slot(
     them one by one — so they are never attended. The same argument
     covers stale cells left by the slot's previous occupant."""
     p = prompt.shape[0]
-    if cache["k"].shape[2] < p:
+    if _leaf(cache).shape[2] < p:
         raise ValueError(
             f"prompt chunk {p} exceeds cache max_len "
-            f"{cache['k'].shape[2]}"
+            f"{_leaf(cache).shape[2]}"
         )
     mini = init_kv_cache(cfg, 1, p, quant="k_scale" in cache)
     _, mini = prefill(
@@ -932,7 +1188,12 @@ def init_page_pool(
     engine convention: retired/done slots' table rows point there so
     frozen rewrites land somewhere no live table reads. Every paged
     forward takes the pool in this stacked form and addresses a
-    layer by its index (`_forward_paged`)."""
+    layer by its index (`_forward_paged`). A latent model's pool is
+    `init_latent_pool`'s."""
+    if getattr(cfg, "latent", False):
+        if quant:
+            raise NotImplementedError("a latent cache is not quantized")
+        return init_latent_pool(cfg, n_pages, page_size)
     kv_heads = getattr(cfg, "n_kv_heads", cfg.n_heads)
     shape = (cfg.n_layers, n_pages, page_size, kv_heads, cfg.head_dim)
     if not quant:
@@ -1161,10 +1422,21 @@ def _forward_paged(
         block = _block_gpt_paged
     else:
         x = params["embed"]["weight"].astype(cfg.dtype)[tokens]
-        block = _block_paged
+        block = _block_latent_paged if cfg.latent else _block_paged
     kinds = _kinds(cfg)
-    classed = "k" not in pool  # {"full": {...}, "window": {...}}
+    classed = "full" in pool  # {"full": {...}, "window": {...}}
     scanned_params, experts = _split_experts(cfg, params["layers"])
+    # leading dense layers: a prologue before the scan, on the first
+    # layers of the pool (see `_forward_cached`)
+    lead = getattr(cfg, "first_k_dense", 0)
+    pool = {c: dict(p) for c, p in pool.items()} if classed else dict(pool)
+    for j in range(lead):
+        x, pool = block(
+            cfg, x,
+            jax.tree_util.tree_map(
+                lambda a: a[j], params["dense_layers"]),
+            pool, j, table, positions, mesh=mesh,
+        )
     # a layer's rank among its kind inside the period, and how many
     # of its kind a period holds
     rank = [kinds[:j].count(kind) for j, kind in enumerate(kinds)]
@@ -1196,12 +1468,16 @@ def _forward_paged(
             else:
                 out = block(
                     cfg, h, _place(cfg, period_params, j), pool,
-                    first + j, table, positions, mesh=mesh, lora=lora, **kw,
+                    lead + first + j, table, positions, mesh=mesh,
+                    lora=lora, **kw,
                 )
                 pool = out[1]
             h = out[0]
             if experts is not None:
-                counts = counts + out[2]
+                c = out[2]
+                if counts.ndim == 2:
+                    c = jnp.stack([c, (c > 0).astype(jnp.int32)])
+                counts = counts + c
         return (h, pool, counts), None
 
     n_layers = jax.tree_util.tree_leaves(scanned_params)[0].shape[0]
@@ -1215,14 +1491,10 @@ def _forward_paged(
         )
     )
     counts0 = (
-        jnp.zeros((cfg.n_experts,), jnp.int32)
+        jnp.zeros(moe_counts_shape(cfg), jnp.int32)
         if experts is not None else None
     )
-    carry0 = (
-        x,
-        {c: dict(p) for c, p in pool.items()} if classed else dict(pool),
-        counts0,
-    )
+    carry0 = (x, pool, counts0)
     with jax.named_scope("layers"):
         (x, pool_new, counts), _ = jax.lax.scan(body, carry0, xs)
     if gpt:
@@ -1348,17 +1620,16 @@ def paged_install_row(
     slice equal to slicing the quantized whole, so the installed
     bytes match the dense bank's cold path exactly. `length` is
     static (one program per suffix bucket), `start` traced."""
-    ps = pool["k"].shape[2]
+    ps = _leaf(pool).shape[2]
     start = jnp.asarray(start, jnp.int32)
     positions = start + jnp.arange(length, dtype=jnp.int32)  # [Sb]
     pids = table_row[positions // ps]
     offs = positions % ps
     src = {}
-    for name in ("k", "v"):
-        arr = row_cache[name]  # [L, 1, M, KV, hd]
+    for name, arr in row_cache.items():  # [L, 1, M, KV, hd] (ckv: [L, 1, M, W])
         sl = jax.lax.dynamic_slice(
             arr,
-            (0, 0, start, 0, 0),
+            (0, 0, start) + (0,) * (arr.ndim - 3),
             (arr.shape[0], 1, length) + arr.shape[3:],
         )
         src[name] = sl[:, 0]  # [L, Sb, KV, hd]
@@ -1368,6 +1639,17 @@ def paged_install_row(
         src = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     out = {}
     for name, arr in pool.items():
+        if arr.ndim == 4:
+            # a latent leaf: with the layers as a slice the TPU
+            # compiler re-lays the whole pool out for the scatter and
+            # back (two copies of 2.5 GB at the published sizes, my
+            # AOT compile); with the layer an index like the page and
+            # the cell it writes the rows where they lie
+            lay = jnp.arange(arr.shape[0], dtype=jnp.int32)[:, None]
+            out[name] = arr.at[lay, pids[None], offs[None]].set(
+                src[name].astype(arr.dtype)
+            )
+            continue
         out[name] = arr.at[:, pids, offs].set(
             src[name].astype(arr.dtype)
         )

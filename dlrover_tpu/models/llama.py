@@ -104,6 +104,41 @@ class LlamaConfig:
     # rotary parameters per kind of layer (None = plain, rope_theta)
     rope_full: Optional[RopeSpec] = None
     rope_window: Optional[RopeSpec] = None
+    # latent attention (MLA; kv_lora_rank 0 = plain attention): the
+    # query goes through a rank-`q_lora_rank` bottleneck, a token's
+    # keys and values are ONE normed vector of `kv_lora_rank` numbers
+    # beside one rotary key of `qk_rope_head_dim` shared by all heads;
+    # a head's query and key are qk_nope_head_dim + qk_rope_head_dim
+    # wide, its value `v_head_dim`. `rope_full` holds the rotary
+    # parameters; `rope_mscale_all_dim` (YaRN) multiplies the softmax
+    # scale by (0.1 * it * ln(yarn_factor) + 1) squared.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_mscale_all_dim: float = 0.0
+    # leading layers whose feed-forward is a dense SwiGLU of width
+    # `dense_mlp_dim` (a prologue before the expert layers, under
+    # params["dense_layers"]); dropless routing only
+    first_k_dense: int = 0
+    dense_mlp_dim: int = 0
+    # experts every token goes through beside the routed ones (their
+    # SwiGLU is n_shared_experts * mlp_dim wide)
+    n_shared_experts: int = 0
+    # the dropless router: "softmax" then top-k, or "sigmoid" scores
+    # with a bias that only the CHOICE sees, experts in `moe_n_group`
+    # groups of which the `moe_topk_group` best stay, weights
+    # normalised over the chosen and times `moe_routed_scaling`
+    moe_scoring: str = "softmax"
+    moe_n_group: int = 0
+    moe_topk_group: int = 0
+    moe_routed_scaling: float = 1.0
+    # (first, count): the routed experts THIS chip holds of n_experts
+    # (() = all). The router still ranks all n_experts; only the pairs
+    # that land on held experts are computed (expert parallelism's
+    # share of a layer, without its exchange)
+    experts_held: Tuple[int, ...] = ()
     # GPipe microbatch count when the mesh has a live "pipe" axis
     # (0 → default to the pipe degree)
     pipeline_microbatches: int = 0
@@ -123,8 +158,53 @@ class LlamaConfig:
         )
 
     @property
+    def routing(self):
+        from dlrover_tpu.models.moe import Routing
+
+        return Routing(
+            top_k=self.moe_top_k, scoring=self.moe_scoring,
+            n_group=self.moe_n_group, topk_group=self.moe_topk_group,
+            scaling=self.moe_routed_scaling, held=self.held,
+        )
+
+    @property
     def head_dim(self) -> int:
+        if self.latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attn_head_dim or self.dim // self.n_heads
+
+    @property
+    def latent(self) -> bool:
+        """Whether attention keeps one latent vector a token (MLA)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a cached token takes a layer: the latent and the
+        shared rotary key, padded to whole 128-lane tiles (Mosaic
+        copies no page whose last dim is not)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale: head_dim^-0.5, times YaRN's mscale
+        squared where the configuration gives `rope_mscale_all_dim`."""
+        scale = float(self.head_dim) ** -0.5
+        rope = self.rope_full
+        if self.rope_mscale_all_dim and rope and rope.yarn_factor > 1:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(
+                rope.yarn_factor) + 1.0
+            scale *= m * m
+        return scale
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_k_dense
 
     @property
     def period(self) -> Tuple[str, ...]:
@@ -168,6 +248,59 @@ class LlamaConfig:
             raise ValueError(
                 f"moe_routing must be 'capacity' or 'dropless', got "
                 f"{self.moe_routing!r}"
+            )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', got "
+                f"{self.moe_scoring!r}"
+            )
+        dropless = self.n_experts > 0 and self.moe_routing == "dropless"
+        shares = [
+            name for name, on in (
+                ("moe_scoring='sigmoid'", self.moe_scoring != "softmax"),
+                ("moe_n_group", self.moe_n_group > 0),
+                ("n_shared_experts", self.n_shared_experts > 0),
+                ("experts_held", bool(self.experts_held)),
+                ("first_k_dense", self.first_k_dense > 0),
+            ) if on
+        ]
+        if shares and not dropless:
+            raise ValueError(
+                f"{', '.join(shares)} need n_experts > 0 and "
+                "moe_routing='dropless'"
+            )
+        if self.moe_n_group and (
+            self.n_experts % self.moe_n_group
+            or not 0 < self.moe_topk_group <= self.moe_n_group
+        ):
+            raise ValueError(
+                f"moe_n_group={self.moe_n_group} must divide n_experts="
+                f"{self.n_experts}, with 0 < moe_topk_group <= it"
+            )
+        if self.experts_held:
+            first, count = self.experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} is no (first, "
+                    f"count) inside n_experts={self.n_experts}"
+                )
+        if self.first_k_dense and not (
+            0 < self.first_k_dense < self.n_layers
+            and self.dense_mlp_dim > 0 and not self.layer_pattern
+        ):
+            raise ValueError(
+                "first_k_dense needs dense_mlp_dim > 0, fewer dense "
+                "layers than n_layers, and no layer_pattern"
+            )
+        if self.latent and not (
+            self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+            and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+            and not self.layer_pattern
+        ):
+            raise ValueError(
+                "latent attention needs q_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim, and no layer_pattern"
             )
 
     # ---- presets (sizes follow the reference's benchmark configs) ----
@@ -236,6 +369,18 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             jax.random.normal(key, shape, pd) / math.sqrt(fan_in)
         )
 
+    if cfg.latent or cfg.first_k_dense or cfg.n_shared_experts:
+        params = _init_share_params(cfg, k_layers, dense_init, norm_init)
+        params["embed"] = {
+            "weight": jax.random.normal(
+                k_embed, (cfg.vocab_size, D), pd
+            ) * 0.02,
+        }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = {
+                "weight": dense_init(k_out, (D, cfg.vocab_size), D)
+            }
+        return params
     ks = jax.random.split(k_layers, 8)
     if cfg.n_experts > 0:
         from dlrover_tpu.models.moe import init_moe_mlp
@@ -272,6 +417,83 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "weight": dense_init(k_out, (D, cfg.vocab_size), D)
         }
     return params
+
+
+def _init_share_params(cfg, key, dense_init, norm_init) -> Params:
+    """The layers of a model with latent attention, leading dense
+    layers, shared experts or a held share of the routed experts:
+    `dense_layers` (the first_k_dense leading layers, stacked) and
+    `layers` (the expert layers, stacked), each with its own leaves.
+    The router's choice-only bias is drawn with a spread that moves
+    choices (a zero bias would leave choice and weight the same)."""
+    D, M = cfg.dim, cfg.mlp_dim
+    H = cfg.n_heads
+    n_held = cfg.held[1]
+
+    def attention(keys, L):
+        if not cfg.latent:
+            KV, hd = cfg.n_kv_heads, cfg.head_dim
+            return {
+                "wq": dense_init(keys[0], (L, D, H * hd), D),
+                "wk": dense_init(keys[1], (L, D, KV * hd), D),
+                "wv": dense_init(keys[2], (L, D, KV * hd), D),
+                "wo": dense_init(keys[3], (L, H * hd, D), H * hd),
+            }
+        qr, cr = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        )
+        return {
+            "wq_a": dense_init(keys[0], (L, D, qr), D),
+            "q_norm": norm_init(L, qr),
+            "wq_b": dense_init(keys[1], (L, qr, H * (nope + rope)), qr),
+            "wkv_a": dense_init(keys[2], (L, D, cr + rope), D),
+            "kv_norm": norm_init(L, cr),
+            "wk_b": dense_init(keys[3], (L, cr, H * nope), cr),
+            "wv_b": dense_init(keys[4], (L, cr, H * vd), cr),
+            "wo": dense_init(keys[5], (L, H * vd, D), H * vd),
+        }
+
+    def stack(keys, L, ffn):
+        return {
+            "attn_norm": norm_init(L, D),
+            **attention(keys, L),
+            "mlp_norm": norm_init(L, D),
+            **ffn,
+        }
+
+    k_dense, k_moe = jax.random.split(key)
+    out = {}
+    L0, L1 = cfg.first_k_dense, cfg.n_moe_layers
+    if L0:
+        ks = jax.random.split(k_dense, 9)
+        W = cfg.dense_mlp_dim
+        out["dense_layers"] = stack(ks, L0, {
+            "w_gate": dense_init(ks[6], (L0, D, W), D),
+            "w_up": dense_init(ks[7], (L0, D, W), D),
+            "w_down": dense_init(ks[8], (L0, W, D), W),
+        })
+    ks = jax.random.split(k_moe, 14)
+    ffn = {
+        "router": dense_init(ks[6], (L1, D, cfg.n_experts), D),
+        "we_gate": dense_init(ks[7], (L1, n_held, D, M), D),
+        "we_up": dense_init(ks[8], (L1, n_held, D, M), D),
+        "we_down": dense_init(ks[9], (L1, n_held, M, D), M),
+    }
+    if cfg.moe_scoring == "sigmoid":
+        ffn["router_bias"] = 0.1 * jax.random.normal(
+            ks[10], (L1, cfg.n_experts), jnp.float32
+        )
+    if cfg.n_shared_experts:
+        S = cfg.n_shared_experts * M
+        ffn.update(
+            ws_gate=dense_init(ks[11], (L1, D, S), D),
+            ws_up=dense_init(ks[12], (L1, D, S), D),
+            ws_down=dense_init(ks[13], (L1, S, D), S),
+        )
+    out["layers"] = stack(ks, L1, ffn)
+    out["final_norm"] = {"scale": norm_init(D)}
+    return out
 
 
 def partition_rules(cfg: LlamaConfig):
@@ -515,18 +737,24 @@ def _mlp_residual(cfg: LlamaConfig, mesh, x, layer_params, lp, tp: int = 1):
         )
         x = x + constrain(ff_out, mesh, ("data", "fsdp"), "seq", None)
         return x, moe_metrics["moe_aux_loss"]
+    x = x + _swiglu(mesh, h, lp["w_gate"], lp["w_up"], lp["w_down"], tp)
+    return x, jnp.zeros((), jnp.float32)
+
+
+def _swiglu(mesh, h, w_gate, w_up, w_down, tp: int = 1):
+    """down(silu(gate(h)) * up(h)): the dense feed-forward, and a
+    shared expert's (models/decode.py)."""
     gate = jax.nn.silu(
-        checkpoint_name(matmul_any(h, lp["w_gate"], tp=tp), "mlp_gate")
+        checkpoint_name(matmul_any(h, w_gate, tp=tp), "mlp_gate")
     )
-    up = checkpoint_name(matmul_any(h, lp["w_up"], tp=tp), "mlp_up")
+    up = checkpoint_name(matmul_any(h, w_up, tp=tp), "mlp_up")
     ff = constrain(
         gate * up, mesh, ("data", "fsdp"), "seq", "tensor"
     )
-    x = x + constrain(
-        checkpoint_name(matmul_any(ff, lp["w_down"], tp=tp), "mlp_down"),
+    return constrain(
+        checkpoint_name(matmul_any(ff, w_down, tp=tp), "mlp_down"),
         mesh, ("data", "fsdp"), "seq", None,
     )
-    return x, jnp.zeros((), jnp.float32)
 
 
 def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
@@ -586,6 +814,11 @@ def refuse_training(cfg: LlamaConfig) -> None:
              cfg.n_experts > 0 and cfg.moe_routing == "dropless"),
             ("rope_full/rope_window",
              cfg.rope_full is not None or cfg.rope_window is not None),
+            ("latent attention (kv_lora_rank)", cfg.latent),
+            ("first_k_dense leading dense layers", cfg.first_k_dense > 0),
+            ("n_shared_experts", cfg.n_shared_experts > 0),
+            ("moe_scoring='sigmoid'", cfg.moe_scoring != "softmax"),
+            ("experts_held", bool(cfg.experts_held)),
         ) if on
     ]
     if asked:

@@ -185,6 +185,59 @@ def moe_mlp(
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """How `dropless_moe` chooses and weights a token's experts, read
+    from the model's config (`LlamaConfig.routing`)."""
+
+    top_k: int
+    # "softmax": probabilities over every expert, the top_k largest,
+    # divided by their sum. "sigmoid": scores s = sigmoid(logits); the
+    # CHOICE is made on s + bias (the bias never reaches a weight),
+    # among the `topk_group` best of `n_group` groups of experts where
+    # groups are given (a group's score: the sum of its two largest
+    # s + bias); weights are `scaling` * s / (the chosen ones' sum).
+    scoring: str = "softmax"
+    n_group: int = 0
+    topk_group: int = 0
+    scaling: float = 1.0
+    # (first, count): the experts whose matrices are held here; the
+    # sum over the chosen runs over those of them only
+    held: Tuple[int, ...] = ()
+
+
+def route(logits: jax.Array, routing: Routing, bias=None):
+    """float32 logits [T, E] -> (weights [T, k] float32, chosen [T, k]
+    int32). Ties go to the lower index (`lax.top_k`)."""
+    k = routing.top_k
+    if routing.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, k)
+        return weights / jnp.sum(weights, axis=-1, keepdims=True), chosen
+    t, e = logits.shape
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if routing.n_group:
+        per = e // routing.n_group
+        grouped = choice.reshape(t, routing.n_group, per)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, routing.topk_group)
+        stays = jnp.any(
+            best[:, :, None]
+            == jnp.arange(routing.n_group, dtype=best.dtype)[None, None, :],
+            axis=1,
+        )                                                   # [T, n_group]
+        choice = jnp.where(
+            jnp.repeat(stays, per, axis=1), choice, -jnp.inf
+        )
+    _, chosen = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = routing.scaling * weights / jnp.sum(
+        weights, axis=-1, keepdims=True
+    )
+    return weights, chosen
+
+
 def _tile_rows(pairs: int, n_experts: int) -> int:
     """Rows of a tile of the padded layout: about half an expert's
     mean run, between a bf16 sublane tile and the MXU's height, so
@@ -198,68 +251,104 @@ def _tile_rows(pairs: int, n_experts: int) -> int:
 def dropless_moe(
     h: jax.Array,              # [T, D] normed tokens
     router: jax.Array,         # [D, E]
-    w_gate: jax.Array,         # [E, D, M], or [L, E, D, M] with `layer`
+    w_gate: jax.Array,         # [E_held, D, M], or [L, E_held, D, M] with `layer`
     w_up: jax.Array,
-    w_down: jax.Array,         # [E, M, D] / [L, E, M, D]
-    top_k: int,
+    w_down: jax.Array,         # [E_held, M, D] / [L, E_held, M, D]
+    routing,                   # a Routing, or top_k of the softmax router
     layer=None,
+    bias=None,                 # [E] float32: the sigmoid router's choice-only bias
 ) -> Tuple[jax.Array, jax.Array]:
-    """Softmax-routed experts without capacity: every (token, expert)
-    pair is computed, at any load. Returns (y [T, D] in h's dtype,
-    int32[E] pairs routed to each expert).
+    """Routed experts without capacity: every (token, expert) pair
+    whose expert is held here is computed, at any load. Returns
+    (y [T, D] in h's dtype, int32[E_held] pairs routed to each held
+    expert); T * top_k pairs were routed anywhere.
 
-    The router's softmax, the top-k weights and the combine are
-    float32. The T * top_k pairs are sorted by expert with a counting
-    sort (a pair's rank inside its expert is a running count, stable
-    in token order), laid out with every expert's run padded to whole
-    tiles of rows, multiplied group by group
-    (ops/grouped_matmul.expert_mlp: a Pallas kernel on the chip,
-    `lax.ragged_dot` elsewhere), and gathered back to their tokens,
-    where the k results are weighted and summed. One code path for a
-    prefill of thousands of tokens and a decode batch of tens."""
+    The router (`route`: softmax then top-k, or the group-limited
+    sigmoid), the weights and the combine are float32. The pairs are
+    sorted by expert with a counting sort (a pair's rank inside its
+    expert is a running count, stable in token order), laid out with
+    every expert's run padded to whole tiles of rows, multiplied
+    group by group (ops/grouped_matmul.expert_mlp: a Pallas kernel on
+    the chip, `lax.ragged_dot` elsewhere), and gathered back to their
+    tokens, where a token's results are weighted and summed. One code
+    path for a prefill of thousands of tokens and a decode batch of
+    tens.
+
+    Where `routing.held` names a share of the experts, the router
+    still ranks all E of them and the weights are normalised over all
+    the chosen; the pairs that land on experts held elsewhere never
+    enter the sort, and what they would add is left out (expert
+    parallelism's share of the layer, without its exchange). The rows'
+    bound is static and for the worst deal (every pair held here), so
+    the tiles past the last run are counted (`live`) and the kernels
+    skip them."""
     from dlrover_tpu.ops import grouped_matmul as gmm
 
+    if not isinstance(routing, Routing):
+        routing = Routing(top_k=routing)
+    top_k = routing.top_k
     t, d = h.shape
     e = router.shape[-1]
+    first, held = routing.held or (0, e)
+    share = held != e
     pairs = t * top_k
     with jax.named_scope("moe_route"):
         logits = jnp.dot(
             h.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, chosen = jax.lax.top_k(probs, top_k)      # [T, k]
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights, chosen = route(logits, routing, bias)     # [T, k]
         flat = chosen.reshape(pairs)                        # pair -> expert
+        if share:
+            flat = flat - first
+            here = (flat >= 0) & (flat < held)
+            weights = jnp.where(here.reshape(t, top_k), weights, 0.0)
         onehot = (
-            flat[:, None] == jnp.arange(e, dtype=flat.dtype)[None, :]
-        ).astype(jnp.int32)                                 # [pairs, E]
-        counts = jnp.sum(onehot, axis=0)                    # [E]
+            flat[:, None] == jnp.arange(held, dtype=flat.dtype)[None, :]
+        ).astype(jnp.int32)                                 # [pairs, E_held]
+        counts = jnp.sum(onehot, axis=0)                    # [E_held]
         rank = jnp.sum(
             (jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1
         )                                                   # [pairs]
-        tile = _tile_rows(pairs, e)
-        rows = -(-(pairs + e * (tile - 1)) // tile) * tile  # static bound
+        # the tile follows the pairs EXPECTED here, the rows' bound
+        # the most there can be
+        tile = _tile_rows(pairs * held // e, held)
+        rows = -(-(pairs + held * (tile - 1)) // tile) * tile
         group_rows = -(-counts // tile) * tile
         ends = jnp.cumsum(group_rows)
-        dest = (ends - group_rows)[flat] + rank             # pair -> row
+        if share:
+            # a pair held elsewhere lands past the last row: dropped
+            # by the scatter, read back as zeros by the gather
+            dest = jnp.where(
+                here, (ends - group_rows)[jnp.clip(flat, 0, held - 1)]
+                + rank, rows,
+            )
+        else:
+            dest = (ends - group_rows)[flat] + rank         # pair -> row
         # row -> token (T: the appended zero row, for padding rows)
         src = jnp.full((rows,), t, jnp.int32).at[dest].set(
-            jnp.arange(pairs, dtype=jnp.int32) // top_k
+            jnp.arange(pairs, dtype=jnp.int32) // top_k,
+            **({"mode": "drop"} if share else {}),
         )
         tile_group = jnp.minimum(
             jnp.searchsorted(
                 ends, jnp.arange(rows // tile, dtype=jnp.int32) * tile,
                 side="right",
             ),
-            e - 1,
+            held - 1,
         )
+        # the tiles that hold rows, where the bound is for more
+        live = ends[-1] // tile if share else None
         x = jnp.concatenate([h, jnp.zeros((1, d), h.dtype)])[src]
     y = gmm.expert_mlp(
         x, w_gate, w_up, w_down, group_rows, tile_group, tile,
-        layer=layer,
+        layer=layer, live=live,
     )
     with jax.named_scope("moe_combine"):
-        y = y[dest].reshape(t, top_k, d).astype(jnp.float32)
+        if share:
+            y = jnp.take(y, dest, axis=0, mode="fill", fill_value=0)
+        else:
+            y = y[dest]
+        y = y.reshape(t, top_k, d).astype(jnp.float32)
         out = jnp.sum(y * weights[:, :, None], axis=1)
     return out.astype(h.dtype), counts

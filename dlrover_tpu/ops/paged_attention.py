@@ -324,9 +324,17 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
                   q_ref, k_hbm, v_hbm, scales, o_ref,
                   k_buf, v_buf, sems, turn,
                   m_scr, l_scr, acc_scr, s_scr, pv_scr,
-                  *, scale, page_size, per_block, window=None):
+                  *, scale, page_size, per_block, window=None,
+                  latent=None):
     """Grid (B,): one invocation attends query row b, every KV head of
     it, over the pages its length covers, `per_block` pages at a time.
+
+    `latent` (static: the latent's rank) is the LATENT variant of the
+    same walk: the pool's one leaf holds a row a token that is both
+    key and value (`v_hbm` and `v_buf` are empty), a block of rows is
+    copied once, every head's scores are one product `[H, W] x [W,
+    cells]` against it and the value sums one product `[H, cells] x
+    [cells, latent]` against its first `latent` columns.
 
     K and V stay in HBM (`k_hbm`, `v_hbm`: the stacked pool's
     leaves); a slot's pages are not contiguous, so the body copies
@@ -358,6 +366,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
     ring = table_ref.shape[1]
     cells = per_block * page_size
     run_pages = min(_RUN_PAGES, per_block)
+    leaves = ((k_hbm, k_buf),)
+    if latent is None:
+        leaves += ((v_hbm, v_buf),)
 
     def walk(slot):
         """(first logical page, pages) of the slot's walk."""
@@ -383,7 +394,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
             if window is not None:
                 entry = jnp.where(entry >= ring, entry - ring, entry)
             src = (layer_ref[0], table_ref[slot, entry])
-            for hbm, vmem in ((k_hbm, k_buf), (v_hbm, v_buf)):
+            for hbm, vmem in leaves:
                 copy = pltpu.make_async_copy(
                     hbm.at[src], vmem.at[buf, i], sems.at[buf]
                 )
@@ -399,7 +410,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
                 return carry
             # a semaphore counts bytes: one wait a leaf for the run's
             # pages (the source only gives the size)
-            for vmem in (k_buf, v_buf):
+            for _, vmem in leaves:
                 dst = vmem.at[buf, pl.ds(r * run_pages, run_pages)]
                 pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
             return carry
@@ -412,7 +423,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
     def _first():
         # a block's unfilled tail is masked, but 0 x NaN is NaN: the
         # buffers only ever hold zeros or cells of the pool
-        for vmem in (k_buf, v_buf):
+        for _, vmem in leaves:
             vmem[...] = jnp.zeros_like(vmem)
         turn[0] = 0
 
@@ -453,10 +464,16 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
             # the oldest page's cells that have left the window
             live = live & (pos >= length - window)
         dtype = q_ref.dtype
-        k_rows, v_rows = (
-            _head_rows(x.at[cur].reshape((cells,) + x.shape[3:]))
-            for x in (k_buf, v_buf)
-        )
+        if latent is not None:
+            # one block of rows, read once: keys as they lie, values
+            # their first `latent` columns (whole lane tiles)
+            rows = k_buf.at[cur].reshape(cells, k_buf.shape[-1])[...]
+            k_rows = v_rows = None
+        else:
+            k_rows, v_rows = (
+                _head_rows(x.at[cur].reshape((cells,) + x.shape[3:]))
+                for x in (k_buf, v_buf)
+            )
         k_scale = v_scale = None
         if scales:
             # an int8 block's scales [cells, KV], heads on lanes; a
@@ -481,7 +498,9 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
 
         for g in range(kv):
             s_scr[g * n_rep:(g + 1) * n_rep] = jax.lax.dot_general(
-                q_ref[0, g], operand(k_rows, k_scale, g),
+                q_ref[0, g],
+                rows if latent is not None
+                else operand(k_rows, k_scale, g),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -497,7 +516,8 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
             # value product, as `_reference` rounds them
             pv_scr[g * n_rep:(g + 1) * n_rep] = jnp.dot(
                 s_scr[g * n_rep:(g + 1) * n_rep].astype(dtype),
-                operand(v_rows, v_scale, g),
+                rows[:, :latent] if latent is not None
+                else operand(v_rows, v_scale, g),
                 preferred_element_type=jnp.float32,
             )
         acc_scr[...] = acc_scr[...] * alpha + pv_scr[...]
@@ -606,6 +626,155 @@ def _kernel(q, pages, layer, table, lengths, scale, window=None):
     )(
         layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
         q.reshape(b, kv, n_rep, hd), *cells_kv, scales,
+    )
+
+
+# ---------------------------------------------------------------------------
+# the latent variant: one row a token that is both key and value
+# ---------------------------------------------------------------------------
+
+# What a block of latent rows may take of a block buffer: 512 cells of
+# bf16 at 640 numbers a row. One shared row serves every head, so the
+# block is sized from the row and not from a head's share of it.
+_LATENT_BLOCK_BYTES = 640 * 1024
+
+
+def _latent_pages_per_block(pool, table) -> int:
+    page_size, width = pool.shape[-2:]
+    page = page_size * width * max(2, pool.dtype.itemsize)
+    return min(max(1, _LATENT_BLOCK_BYTES // page), table.shape[1])
+
+
+def supports_latent(q, pages: Dict, table, rank: int) -> bool:
+    """Whether the latent variant of the kernel handles these shapes:
+    `q` [B, H, W] the absorbed queries, `pages` {"ckv": [.., n_pages,
+    page_size, W]}, `rank` the latent's width (the value columns).
+    Mosaic copies a page whose rows are whole 128-lane tiles and
+    slices the value columns at a tile's edge; the heads are the
+    products' rows, in sublane tiles."""
+    pool = pages["ckv"]
+    b, h, w = q.shape
+    page_size = pool.shape[-2]
+    if pool.shape[-1] != w or not 0 < rank <= w:
+        return False
+    if not fa._interpret() and (w % _LANES or rank % _LANES):
+        return False
+    if h % _SUBLANES or page_size < 8:
+        return False
+    if pool.dtype.itemsize not in (2, 4) or q.dtype != pool.dtype:
+        return False
+    return table.ndim == 2 and table.shape[0] == b
+
+
+def use_kernel_latent(q, pages: Dict, table, rank: int) -> bool:
+    """`use_kernel` for the latent variant (one chip: a latent pool
+    is not sharded over heads, it has none)."""
+    if jax.default_backend() != "tpu" and not fa.force_kernels():
+        return False
+    return supports_latent(q, pages, table, rank)
+
+
+def latent_scores_and_sums(qc, rows, q_positions, scale, rank: int):
+    """The absorbed form of latent attention on a dense view: qc
+    [B, S, H, W] (a head's query with its key matrix multiplied in)
+    against rows [B, M, W], query at position p seeing cells j <= p;
+    the probabilities against the rows' first `rank` columns ->
+    [B, S, H, rank]. float32 scores and softmax, the probabilities
+    rounded to the query's dtype before the sums (as `_reference`)."""
+    scores = jnp.einsum(
+        "bshw,bmw->bhsm", qc, rows, preferred_element_type=jnp.float32
+    ) * scale
+    cols = jnp.arange(rows.shape[1])[None, None, None, :]
+    live = cols <= q_positions[:, None, :, None]
+    p = jax.nn.softmax(
+        jnp.where(live, scores, -jnp.inf), axis=-1
+    ).astype(qc.dtype)
+    return jnp.einsum("bhsm,bmc->bshc", p, rows[..., :rank])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank"))
+def _latent_kernel(q, pool, layer, table, lengths, scale, rank):
+    """q [B, H, W] -> [B, H, rank] over layer `layer` (int32[1]) of
+    the stacked latent pool `[L, n_pages, page_size, W]`: `_kernel`'s
+    walk (scalar-prefetched layer, table and lengths; the pool whole
+    in HBM; a block of pages copied a grid step, two buffers) with
+    one leaf and one KV head that every query head shares."""
+    b, h, w = q.shape
+    page_size = pool.shape[2]
+    per_block = _latent_pages_per_block(pool, table)
+    index = lambda bi, lay, tab, lens: (bi, 0, 0, 0)  # noqa: E731
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, page_size=page_size,
+        per_block=per_block, latent=rank,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, 1, h, w), index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            (),   # no second leaf
+            (),   # no scales
+        ],
+        out_specs=pl.BlockSpec(
+            (1, h, rank), lambda bi, lay, tab, lens: (bi, 0, 0)
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2, per_block, page_size, w), pool.dtype),
+            (),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, _LANES), jnp.float32),        # max
+            pltpu.VMEM((h, _LANES), jnp.float32),        # sum
+            pltpu.VMEM((h, rank), jnp.float32),          # accumulator
+            pltpu.VMEM((h, per_block * page_size), jnp.float32),
+            pltpu.VMEM((h, rank), jnp.float32),          # a block's p v
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_SCOPED_DEFAULT + _VMEM_HEADROOM,
+        ),
+        interpret=fa._interpret(),
+        name="paged_attention_decode_latent",
+    )(
+        layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
+        q.reshape(b, 1, h, w), pool, (), (),
+    )
+
+
+def latent_paged_attention(
+    q: jax.Array,           # [B, H, W]: one absorbed query a head
+    pages: Dict[str, jax.Array],   # {"ckv": [L, n_pages, page_size, W]}
+    table: jax.Array,       # [B, P]
+    lengths: jax.Array,     # [B] valid cells per row (query at len-1)
+    scale: float,
+    rank: int,
+    layer=None,
+    impl: str = "auto",
+) -> jax.Array:
+    """Single-query latent attention over the paged latent pool ->
+    the heads' sums of latents [B, H, rank]. `pages` is the stacked
+    pool with `layer` the (traced) index, or one layer's with `layer`
+    None. impl "reference": `latent_scores_and_sums` on the gathered
+    view; "kernel": the latent variant of the Pallas walk; "auto":
+    the kernel where `use_kernel_latent` passes."""
+    if impl not in ("reference", "kernel", "auto"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if impl == "reference" or (
+        impl == "auto" and not use_kernel_latent(q, pages, table, rank)
+    ):
+        rows = gather_pages(pages, table, layer)["ckv"]
+        return latent_scores_and_sums(
+            q[:, None], rows, (lengths - 1)[:, None], scale, rank
+        )[:, 0]
+    pages, layer = _stacked(pages, layer)
+    return _latent_kernel(
+        q, pages["ckv"], layer, table, lengths, scale, rank
     )
 
 

@@ -102,7 +102,9 @@ from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.decode import (
     _check_adapters,
     _check_positional_capacity,
+    _dropless,
     _warp,
+    moe_counts_shape,
     decode_step,
     gather_pool_view,
     init_hybrid_pools,
@@ -225,14 +227,16 @@ def _parse_mesh_tp(mesh_spec) -> int:
 
 
 def _refuse_unserved(cfg, **asked) -> None:
-    """A model that mixes window and full attention layers, or routes
-    its experts without dropping, is served by the plain paged (or
-    dense) path only. Every other combination is refused here, at
-    construction and by name: none may mis-serve silently."""
+    """A model that mixes window and full attention layers, keeps a
+    latent cache, or routes its experts without dropping, is served
+    by the plain paged (or dense) path only. Every other combination
+    is refused here, at construction and by name: none may mis-serve
+    silently."""
     what = [
         name for name, on in (
             ("window and full attention layers mixed",
              getattr(cfg, "hybrid", False)),
+            ("a latent cache", getattr(cfg, "latent", False)),
             ("experts routed without dropping",
              getattr(cfg, "n_experts", 0) > 0
              and getattr(cfg, "moe_routing", "") == "dropless"),
@@ -245,8 +249,8 @@ def _refuse_unserved(cfg, **asked) -> None:
             f"{', '.join(knobs)}: the prefix cache, the host KV tier, "
             "the handoff between replicas, speculative decoding, "
             "adapters, int8 weights or KV, chunked prefill and tp > 1 "
-            "know one class of cached state and dense feed-forward "
-            "layers only"
+            "move runs of k and v pages of one class and know dense "
+            "feed-forward layers only"
         )
 
 
@@ -351,10 +355,21 @@ def _paged_step_takes_kernel(cfg, n_slots, pool, table, mesh) -> bool:
         return False
     from dlrover_tpu.ops import paged_attention as pa
 
+    if "ckv" in pool:
+        # a latent pool: the latent variant's own gate
+        return pa.use_kernel_latent(
+            jax.ShapeDtypeStruct(
+                (n_slots, cfg.n_heads, cfg.latent_width), cfg.dtype
+            ),
+            {"ckv": jax.ShapeDtypeStruct(
+                pool["ckv"].shape[1:], pool["ckv"].dtype)},
+            jax.ShapeDtypeStruct(tuple(table.shape), jnp.int32),
+            cfg.kv_lora_rank,
+        )
     probe_q = jax.ShapeDtypeStruct(
         (n_slots, cfg.n_heads, cfg.head_dim), cfg.dtype
     )
-    if "k" not in pool:
+    if "full" in pool:
         # two classes of pages (a window and a full one) of one page
         # shape: the full class answers for both
         pool = pool["full"]
@@ -428,11 +443,13 @@ def _decode_scan(
 ):
     """THE decode loop: k steps over every slot, whatever holds the
     KV. Returns (cache, tok, pos, done, keys, emitted [B, k]), and the
-    experts' routed pairs after them where `table_win` is given.
+    routed pairs per expert held here after them, where the pool is
+    stepped page by page and the experts are routed without dropping.
 
-    `cache` is a dense bank (`table` None), a stacked page pool, or
-    the pair of pools of a model with window layers (`table_win`: the
-    slots' rings, as the host left them before this dispatch). The
+    `cache` is a dense bank (`table` None), a stacked page pool (of k
+    and v, or of latent rows), or the pair of pools of a model with
+    window layers (`table_win`: the slots' rings, as the host left
+    them before this dispatch). The
     page table rides read-only: it changes only via host-side
     admission/CoW scatters, never inside a chunk. Done rows route
     through the trash page (page 0) HERE, so releasing a finished
@@ -445,8 +462,8 @@ def _decode_scan(
     batch is ONE dispatch whatever its adapter composition.
 
     A pool is stepped one of two ways, chosen at trace time from
-    shapes (`_paged_step_takes_kernel`; two classes of pages always
-    the first):
+    shapes (`_paged_step_takes_kernel`; two classes of pages and a
+    latent pool always the first):
       kernel — per-step paged_decode_step, whose S==1 path streams
       physical pages through the Pallas paged-attention kernel
       without materializing a dense view;
@@ -462,8 +479,10 @@ def _decode_scan(
         table = jnp.where(done[:, None], 0, table)
         if table_win is not None:
             table_win = jnp.where(done[:, None], 0, table_win)
-        page_native = table_win is not None or _paged_step_takes_kernel(
-            cfg, tok.shape[0], cache, table, mesh
+        page_native = (
+            table_win is not None or "ckv" in cache
+            or _paged_step_takes_kernel(
+                cfg, tok.shape[0], cache, table, mesh)
         )
         if not page_native:
             pool, cache = cache, gather_pool_view(cache, table)
@@ -490,9 +509,10 @@ def _decode_scan(
         return (cache, tok, pos, done, keys, pairs), nxt
 
     pairs = None
-    if table_win is not None:
+    if table_win is not None or (page_native and _dropless(cfg)):
         pairs = jnp.zeros(
-            (max(getattr(cfg, "n_experts", 0), 1),), jnp.int32
+            moe_counts_shape(cfg) if getattr(cfg, "n_experts", 0)
+            else (1,), jnp.int32,
         )
     (cache, tok, pos, done, keys, pairs), emitted = jax.lax.scan(
         body, (cache, tok, pos, done, keys, pairs), None, length=k,
@@ -1217,6 +1237,9 @@ class ContinuousBatcher:
         self._paged = kv_layout == "paged"
         # window and full layers mixed: two classes of pages
         self._hybrid = self._paged and bool(getattr(cfg, "hybrid", False))
+        # one latent row a token and layer in place of k and v: a
+        # third class of pool, under the one allocator and table
+        self._latent = self._paged and bool(getattr(cfg, "latent", False))
         bank_len = max_len + spec_draft_len
         if self._paged:
             # auto page size: the largest power of two <= 16 dividing
@@ -1292,6 +1315,10 @@ class ContinuousBatcher:
         # and the window class's counters of the current step
         self._moe_pairs: Optional[np.ndarray] = None
         self._moe_steps = 0
+        self._moe_touched = 0
+        self._moe_held_total = 0      # /metrics: the held-pairs share
+        self._moe_routed_total = 0
+        self._latent_cells = 0
         self._window_freed_this_step = 0
         # ---- multi-adapter LoRA serving (serving/adapters.py) -----------
         # One stacked device bank whose slot 0 is the permanent zero
@@ -1616,10 +1643,11 @@ class ContinuousBatcher:
                 "paged decode takes the XLA gather reference on this "
                 "TPU: ops/paged_attention.supports() refuses the "
                 "shapes (heads=%d kv_heads=%d head_dim=%d page_size=%d "
-                "tp=%d) or attn_impl pins the reference",
+                "tp=%d latent_width=%d) or attn_impl pins the reference",
                 self.cfg.n_heads,
                 getattr(self.cfg, "n_kv_heads", self.cfg.n_heads),
                 self.cfg.head_dim, self.page_size, self.mesh_tp,
+                self.cfg.latent_width if self._latent else 0,
             )
 
     # -- weight quantization -----------------------------------------------
@@ -3090,6 +3118,12 @@ class ContinuousBatcher:
             s["window_pages_freed"] = float(
                 self.rings.pages_freed_behind
             )
+        if self._moe_routed_total:
+            # a share of the experts is held here: of the pairs the
+            # router dealt, the share that landed on them
+            s["moe_held_pairs_share"] = (
+                self._moe_held_total / self._moe_routed_total
+            )
         return s
 
     def adapter_stats(self) -> Dict[str, float]:
@@ -3219,6 +3253,7 @@ class ContinuousBatcher:
             self._admit_this_step = 0.0
             self._window_freed_this_step = 0
             self._moe_pairs = None
+            self._latent_cells = 0
             self._maybe_commit_refresh()  # deferred swap at idle fence
             if self.chaos is not None:
                 # before the harvest, any admission or dispatch: an
@@ -3311,6 +3346,11 @@ class ContinuousBatcher:
                     pages_window=self.rings.pages_held,
                     window_pages_freed=self._window_freed_this_step,
                 )
+            if self._latent:
+                sp.set(
+                    pages_latent=self.allocator.used_pages,
+                    latent_cells=self._latent_cells,
+                )
             if self._moe_pairs is not None and self.cfg.n_experts > 0:
                 pairs = self._moe_pairs
                 sp.set(
@@ -3319,6 +3359,22 @@ class ContinuousBatcher:
                     moe_max_load=int(pairs.max()),
                     moe_mean_load=float(pairs.mean()),
                 )
+                if self.cfg.experts_held:
+                    # this chip's share: the pairs that landed on the
+                    # experts held here, of those routed anywhere
+                    # (every slot routes top_k a layer and step), and
+                    # the (layer, step, expert) triples that got one
+                    routed = (
+                        self._moe_steps * self.n_slots
+                        * self.cfg.moe_top_k * self.cfg.n_moe_layers
+                    )
+                    sp.set(
+                        moe_held_pairs=int(pairs.sum()),
+                        moe_routed_pairs=routed,
+                        moe_experts_touched=self._moe_touched,
+                    )
+                    self._moe_held_total += int(pairs.sum())
+                    self._moe_routed_total += routed
         self.last_step_s = sp.dur_s
         self._stat_host_ms += (sp.dur_s - self._wait_this_step) * 1e3
         return events
@@ -3554,8 +3610,18 @@ class ContinuousBatcher:
                 tok, pos, done, keys, emitted, *pairs = host
                 if pairs:
                     self._moe_pairs = pairs[0]
+                    if self._moe_pairs.ndim == 2:  # a held share
+                        self._moe_touched = int(self._moe_pairs[1].sum())
+                        self._moe_pairs = self._moe_pairs[0]
                     self._moe_steps = emitted.shape[1]
                 counts = pos - pend.old_pos
+                if self._latent:
+                    # a slot that took n steps from position p read
+                    # p + 1 .. p + n rows in every layer
+                    n = counts.astype(np.int64)
+                    self._latent_cells = int(self.cfg.n_layers * np.sum(
+                        n * pend.old_pos + n * (n + 1) // 2
+                    ))
             else:
                 tok, pos, done, keys, emitted, n_emit, accepted = host
                 counts = n_emit

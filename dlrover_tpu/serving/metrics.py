@@ -97,6 +97,7 @@ class ServingMetrics:
             "_paged_cow_copies",
             "_paged_swap_preemptions",
             "_paged_swap_resumes",
+            "_moe_held_pairs_share",
             "_kv_tier_bytes",
             "_kv_tier_capacity",
             "_kv_tier_entries",
@@ -216,6 +217,7 @@ class ServingMetrics:
         self._paged_cow_copies = 0
         self._paged_swap_preemptions = 0
         self._paged_swap_resumes = 0
+        self._moe_held_pairs_share = 0.0
         self._kv_tier_bytes = 0
         self._kv_tier_capacity = 0
         self._kv_tier_entries = 0
@@ -511,6 +513,9 @@ class ServingMetrics:
             self._paged_swap_resumes = max(
                 self._paged_swap_resumes,
                 int(stats.get("swap_resumes", 0)),
+            )
+            self._moe_held_pairs_share = float(
+                stats.get("moe_held_pairs_share", 0.0)
             )
 
     def update_kv_tier(self, stats: Dict[str, float]):
@@ -1362,6 +1367,13 @@ class ServingMetrics:
                 "serving_paged_swap_resumes_total",
                 "Preempted requests resumed by replay.",
                 self._paged_swap_resumes,
+            )
+            gauge(
+                "serving_moe_held_pairs_share",
+                "Of the (token, expert) pairs the router dealt, the "
+                "share on the experts this replica holds (0: it holds "
+                "them all, or has none).",
+                self._moe_held_pairs_share,
             )
             gauge(
                 "serving_kv_tier_bytes",

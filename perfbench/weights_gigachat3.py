@@ -1,0 +1,172 @@
+"""The weights of the GigaChat3.1 configuration (one chip's share of a
+16-chip deployment), made by the benchmark from --seed on the device
+in one jitted call, in the type they are used in, and handed to both
+the program and the plain reference (reference_gigachat3.py): neither
+makes weights of its own.
+
+Layout (what dlrover_tpu/models/llama.py reads for a model with latent
+attention, leading dense layers, a shared expert and a held share of
+the routed experts; a group's layer weights stacked on a leading
+axis; H heads, E the PUBLISHED number of routed experts, `held` how
+many of them live here):
+  embed/weight [V, D]        lm_head/weight [D, V]    (V: the slice)
+  final_norm/scale [D]
+  dense_layers/ and layers/, each:
+    attn_norm, mlp_norm [L, D]
+    wq_a [L, D, q_lora]      q_norm [L, q_lora]
+    wq_b [L, q_lora, H * (nope + rope)]
+    wkv_a [L, D, kv_lora + rope]      kv_norm [L, kv_lora]
+    wk_b [L, kv_lora, H * nope]       wv_b [L, kv_lora, H * v]
+    wo [L, H * v, D]
+  dense_layers/{w_gate, w_up [L0, D, W], w_down [L0, W, D]}
+  layers/router [L1, D, E]   layers/router_bias [L1, E] (float32)
+  layers/{ws_gate, ws_up [L1, D, S], ws_down [L1, S, D]}
+  layers/{we_gate, we_up [L1, held, D, M], we_down [L1, held, M, D]}
+
+`wk_b` and `wv_b` are the published W_kvb's columns, a head's
+[k_nope, v], as two leaves (a fixed permutation of columns). Norm
+scales 1, embedding N(0, 0.02), every matrix N(0, 1/fan_in), the
+router too; the router's bias N(0, 0.1), the spread of
+dlrover_tpu/models/llama.py's own draw: one that moves choices
+against s alone (so that the choice by s + b and the weight by s
+differ). No public source gives the bias's scale. At this spread
+experts 0-15 hold 0.37-0.77 pairs a token depending on the seed (0.5
+for an even router) and a run's rate follows the draw (PERF.md
+section 6, PR 39): what a `benchmark` PR would have to steady.
+"""
+
+import functools
+import math
+
+import weights as base
+
+hashable = base.hashable
+seed_key = base.seed_key
+
+KEYS = (
+    "num_hidden_layers", "first_k_dense_replace", "hidden_size",
+    "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+    "intermediate_size", "moe_intermediate_size", "n_routed_experts",
+    "n_shared_experts", "vocab_size", "routed_experts_published",
+)
+
+
+def shapes(model: dict) -> dict:
+    D, H = model["hidden_size"], model["num_attention_heads"]
+    qr, cr = model["q_lora_rank"], model["kv_lora_rank"]
+    nope, rope, vd = (
+        model["qk_nope_head_dim"], model["qk_rope_head_dim"],
+        model["v_head_dim"],
+    )
+    E, held = model["routed_experts_published"], model["n_routed_experts"]
+    M, W, V = (
+        model["moe_intermediate_size"], model["intermediate_size"],
+        model["vocab_size"],
+    )
+    S = model["n_shared_experts"] * M
+    L0 = model["first_k_dense_replace"]
+    L1 = model["num_hidden_layers"] - L0
+
+    def attention(L):
+        return {
+            "attn_norm": (L, D), "mlp_norm": (L, D),
+            "wq_a": (L, D, qr), "q_norm": (L, qr),
+            "wq_b": (L, qr, H * (nope + rope)),
+            "wkv_a": (L, D, cr + rope), "kv_norm": (L, cr),
+            "wk_b": (L, cr, H * nope), "wv_b": (L, cr, H * vd),
+            "wo": (L, H * vd, D),
+        }
+
+    return {
+        "embed": {"weight": (V, D)},
+        "dense_layers": {
+            **attention(L0),
+            "w_gate": (L0, D, W), "w_up": (L0, D, W), "w_down": (L0, W, D),
+        },
+        "layers": {
+            **attention(L1),
+            "router": (L1, D, E), "router_bias": (L1, E),
+            "ws_gate": (L1, D, S), "ws_up": (L1, D, S),
+            "ws_down": (L1, S, D),
+            "we_gate": (L1, held, D, M), "we_up": (L1, held, D, M),
+            "we_down": (L1, held, M, D),
+        },
+        "final_norm": {"scale": (D,)},
+        "lm_head": {"weight": (D, V)},
+    }
+
+
+def init_params(model: dict, key, dtype):
+    """Traced under jit by callers. The experts' stacks (1.9 GB a
+    leaf) are drawn a layer at a time, so that the generator's
+    temporaries are a layer's and not the stack's."""
+    import jax
+    import jax.numpy as jnp
+
+    tree = shapes(model)
+    flat = [
+        (group, name, shape)
+        for group, leaves in tree.items() for name, shape in leaves.items()
+    ]
+    keys = jax.random.split(key, len(flat))
+    out = {group: {} for group in tree}
+    for k, (group, name, shape) in zip(keys, flat):
+        if name.endswith("_norm") or name == "scale":
+            out[group][name] = jnp.ones(shape, dtype)
+        elif name == "router_bias":
+            out[group][name] = 0.1 * jax.random.normal(k, shape, jnp.float32)
+        elif len(shape) == 4:
+            scale = jnp.asarray(1.0 / math.sqrt(shape[-2]), dtype)
+            out[group][name] = jax.lax.map(
+                lambda kk, s=shape, c=scale: jax.random.normal(
+                    kk, s[1:], dtype) * c,
+                jax.random.split(k, shape[0]),
+            )
+        else:
+            scale = jnp.asarray(
+                0.02 if group == "embed" else 1.0 / math.sqrt(shape[-2]),
+                dtype,
+            )
+            out[group][name] = jax.random.normal(k, shape, dtype) * scale
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _maker():
+    import jax
+    import jax.numpy as jnp
+
+    dtypes = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+    return jax.jit(
+        lambda items, key, dtype: init_params(dict(items), key, dtypes[dtype]),
+        static_argnums=(0, 2),
+    )
+
+
+def make_params(model: dict, seed: int, dtype: str):
+    """The weights of `seed` on the device, in one jitted call (the
+    key is an argument: one program serves every seed)."""
+    items = hashable({k: v for k, v in model.items() if k in KEYS})
+    return _maker()(items, seed_key(seed), dtype)
+
+
+def tiny_model(model: dict) -> dict:
+    """The rehearsal's sizes under the same keys: every mechanism
+    kept (the five latent sizes, YaRN with its mscale, one leading
+    dense layer of another width, the sigmoid router with groups and a
+    bias, a shared expert, 8 of 32 routed experts held)."""
+    return dict(
+        model, hidden_size=64, num_attention_heads=8,
+        num_key_value_heads=8, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=24,
+        intermediate_size=160, moe_intermediate_size=32,
+        n_routed_experts=8, routed_experts_published=32,
+        experts_held=[8, 8], num_experts_per_tok=4, n_group=4,
+        topk_group=2, vocab_size=256, num_hidden_layers=3,
+        first_k_dense_replace=1, rope_theta=10000.0,
+        rope_scaling=dict(
+            model["rope_scaling"], factor=4.0,
+            original_max_position_embeddings=16,
+        ),
+    )

@@ -71,6 +71,24 @@ def chip(topo):
 S = jax.ShapeDtypeStruct
 
 
+def _census(compiled):
+    """(bytes of temporaries, instructions in all, counts of the
+    opcodes that move or multiply) of a compiled program: what a
+    model WITHOUT latent attention, a prologue or a held share must
+    keep to the byte when those are added beside it."""
+    import collections
+    import re
+
+    ops = collections.Counter(
+        re.findall(r"= \S+ ([a-z\-]+)\(", compiled.as_text()))
+    return (
+        compiled.memory_analysis().temp_size_in_bytes, sum(ops.values()),
+        {o: ops.get(o, 0) for o in (
+            "fusion", "custom-call", "scatter", "gather", "copy",
+            "dynamic-update-slice", "dynamic-slice", "convolution")},
+    )
+
+
 def _on_chip(chip, shapes):
     """A pytree of ShapeDtypeStructs, placed on the described chip."""
     return jax.tree_util.tree_map(
@@ -288,6 +306,15 @@ def test_paged_chunk_program_walks_pool_in_place(chip, monkeypatch):
     # homogeneous model is a period of one, through the same code):
     # 135,120,896 bytes of temporaries there and here
     assert temp <= 136 * 1000 * 1000, temp
+    # and to the byte and the instruction what the commit before
+    # latent attention, leading dense layers and held shares compiled
+    # to (my AOT compile of 6ba8b28, PR 39): a model without the new
+    # fields is the parent's program
+    assert _census(compiled) == (135120896, 841, {
+        "fusion": 49, "custom-call": 6, "scatter": 2, "gather": 2,
+        "copy": 13, "dynamic-update-slice": 1, "dynamic-slice": 10,
+        "convolution": 8,
+    })
 
 
 def _mellum2_served(depth):
@@ -378,6 +405,15 @@ def test_mellum2_chunk_program_fits_the_chip(chip, monkeypatch):
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used + 10**9 < V5E_HBM_BYTES, used
+    if _served_depth() == 8:
+        # the parent's program (6ba8b28), to the byte and the
+        # instruction: softmax routing over experts all held here goes
+        # through `dropless_moe` as it did
+        assert _census(compiled) == (285138432, 7565, {
+            "fusion": 228, "custom-call": 51, "scatter": 12, "gather": 15,
+            "copy": 49, "dynamic-update-slice": 1, "dynamic-slice": 15,
+            "convolution": 21,
+        })
 
 
 def test_mellum2_largest_prefill_fits_the_chip(chip, monkeypatch):
@@ -423,6 +459,178 @@ def test_grouped_expert_kernels(chip, rows, tile):
         S((rows // tile,), jnp.int32),
     )
     assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
+
+
+def _gigachat3_served():
+    """The GigaChat3.1 configuration as the benchmark serves it (the
+    published widths, one dense and four expert layers, 16 of 256
+    experts held, 96 slots x 4096 positions, 16-cell pages): (cfg,
+    params, pool, slots, max_len), all shapes only."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _gigachat3_tiny as tiny
+    from dlrover_tpu.models import decode, llama
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs",
+        "gigachat3.1-702b-a36b.serve-1chip-ep16.json",
+    )
+    with open(path) as f:
+        served = json.load(f)
+    run = served["run"]
+    slots, max_len = run["n_slots"], run["max_len"]
+    cfg = tiny.config(
+        tiny.published_model(
+            served["num_hidden_layers"], served["first_k_dense_replace"]),
+        held=tuple(served["experts_held"]), dtype=jnp.bfloat16,
+        max_seq_len=max_len,
+    )
+    assert cfg.vocab_size == served["vocab_size"]
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    pool = jax.eval_shape(
+        lambda: decode.init_page_pool(
+            cfg, slots * (max_len // PAGE) + 1, PAGE)
+    )
+    return cfg, params, pool, slots, max_len
+
+
+def _moved_whole(text, shapes):
+    """Instructions that slice, restack or copy an array of one of
+    `shapes` (or of one layer of it)."""
+    import re
+
+    big = set(shapes)
+    big |= {s[1:] for s in big} | {(1,) + s[1:] for s in big}
+    return [
+        m.group(0)
+        for m in re.finditer(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* "
+            r"(dynamic-slice|dynamic-update-slice|copy)\(", text
+        )
+        if tuple(int(d) for d in m.group(2).split(",")) in big
+    ]
+
+
+def test_latent_paged_decode(chip):
+    """`paged_attention_decode_latent` at the published sizes: 96
+    slots' 64 absorbed queries of 640 numbers against a pool of
+    16-cell pages of 640-number rows, 5 layers stacked."""
+    slots, width, rank = 96, 640, 512
+    text = _compile(
+        chip,
+        functools.partial(
+            pa.latent_paged_attention, scale=0.14468, rank=rank, layer=3,
+            impl="kernel"),
+        S((slots, 64, width), jnp.bfloat16),
+        {"ckv": S((5, 24577, PAGE, width), jnp.bfloat16)},
+        S((slots, 256), jnp.int32), S((slots,), jnp.int32),
+    )
+    assert "paged_attention_decode_latent" in text
+
+
+def test_latent_gate_is_mosaics(chip):
+    """What `supports_latent` refuses on the chip is what Mosaic
+    refuses: a row that is not whole lane tiles (576 numbers, the
+    latent and the rotary key as they are)."""
+    q = S((SLOTS, 64, 576), jnp.bfloat16)
+    pages = {"ckv": S((2, N_PAGES, PAGE, 576), jnp.bfloat16)}
+    table = S((SLOTS, 8), jnp.int32)
+    assert not pa.supports_latent(q, pages, table, 512)
+    with pytest.raises(Exception, match="aligned to tiling|tiling"):
+        _compile(
+            chip,
+            functools.partial(
+                pa.latent_paged_attention, scale=0.1, rank=512, layer=1,
+                impl="kernel"),
+            q, pages, table, S((SLOTS,), jnp.int32),
+        )
+
+
+@pytest.mark.parametrize("rows,tile", [(1008, 16), (25600, 64)])
+def test_grouped_expert_kernels_in_column_blocks(chip, rows, tile):
+    """The experts' two kernels at GigaChat3.1's widths over the 16
+    experts held here: a [7168, 2048] matrix is 29 MB, so it goes in
+    blocks of columns; a decode batch's worst-case rows in tiles of
+    16 and a prefill's in tiles of 64, the tiles past the live ones
+    skipped."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    d, m, e, layers = 7168, 2048, 16, 4
+    assert gmm._column_block(d, m, 2) == 512
+    assert gmm._column_block(m, d, 2) == 1792
+    assert gmm._column_block(2304, 896, 2) == 896  # Mellum2's: whole
+    text = _compile(
+        chip,
+        lambda x, wg, wu, wd, groups, live: gmm.expert_mlp_kernel(
+            x, wg, wu, wd, groups, tile, layer=1, live=live),
+        S((rows, d), jnp.bfloat16), S((layers, e, d, m), jnp.bfloat16),
+        S((layers, e, d, m), jnp.bfloat16),
+        S((layers, e, m, d), jnp.bfloat16),
+        S((rows // tile,), jnp.int32), S((), jnp.int32),
+    )
+    assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
+
+
+def test_gigachat3_chunk_program_fits_the_chip(chip, monkeypatch):
+    """The latent chunk program (k = 8) at the published widths and
+    the served depth: the latent kernel and the experts' grouped
+    kernels inside, no copy of the pool or of a layer's experts, and
+    arguments + temporaries under the chip's memory with 1 GB to
+    spare."""
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pool, slots, max_len = _gigachat3_served()
+    i32 = S((slots,), jnp.int32)
+    program = engine._build_chunk_program(cfg, -1, None, 0.0, 0, 1.0)
+    args = _on_chip(chip, (
+        pool, S((slots, max_len // PAGE), jnp.int32), params,
+        i32, i32, S((slots,), jnp.bool_), i32, S((slots, 2), jnp.uint32),
+    ))
+    compiled = program["paged"].lower(*args, 8).compile()
+    text = compiled.as_text()
+    for kernel in ("paged_attention_decode_latent", "moe_grouped_gate_up",
+                   "moe_grouped_down"):
+        assert kernel in text
+    moved = _moved_whole(text, {
+        tuple(pool["ckv"].shape),
+        tuple(params["layers"]["we_gate"].shape),
+        tuple(params["layers"]["we_down"].shape),
+    })
+    assert not moved, moved
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
+
+
+def test_gigachat3_largest_prefill_fits_the_chip(chip, monkeypatch):
+    """The admission program of the largest prompt bucket (max_len =
+    4096 tokens: 32768 routed pairs a layer, of which any number may
+    land here) beside the resident pool: the flash forward at head
+    width 192 and the grouped kernels inside, the prompt's rows
+    installed where they lie (no copy of the pool)."""
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pool, slots, max_len = _gigachat3_served()
+    program = engine._build_admit_programs(cfg, max_len)["paged_cold"]
+    args = _on_chip(chip, (
+        pool, S((slots, max_len // PAGE), jnp.int32), params,
+        S((max_len,), jnp.int32), S((), jnp.int32),
+        S((max_len // PAGE,), jnp.int32),
+    ))
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "moe_grouped_gate_up" in text
+    assert not _moved_whole(text, {tuple(pool["ckv"].shape)})
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
 
 
 def test_window_paged_decode(chip):
