@@ -238,14 +238,15 @@ def route(logits: jax.Array, routing: Routing, bias=None):
     return weights, chosen
 
 
-def _tile_rows(pairs: int, n_experts: int) -> int:
-    """Rows of a tile of the padded layout: about half an expert's
-    mean run, between a bf16 sublane tile and the MXU's height, so
-    that the padding stays under a half of the rows at any load."""
-    tile = 16
-    while tile < 128 and tile * 2 * n_experts < pairs:
-        tile *= 2
-    return tile
+def dropless_rows(pairs: int, held: int) -> int:
+    """Rows of the padded layout `dropless_moe` hands the grouped
+    kernels for `pairs` (token, expert) pairs over `held` experts:
+    every run is padded to whole sub-tiles (ops/grouped_matmul
+    `SUB_ROWS` rows), so at most 15 rows more for each expert that can
+    hold a pair at all. Static: the bound holds for any deal."""
+    from dlrover_tpu.ops.grouped_matmul import SUB_ROWS
+
+    return (pairs + (SUB_ROWS - 1) * min(pairs, held)) // SUB_ROWS * SUB_ROWS
 
 
 def dropless_moe(
@@ -267,21 +268,20 @@ def dropless_moe(
     sigmoid), the weights and the combine are float32. The pairs are
     sorted by expert with a counting sort (a pair's rank inside its
     expert is a running count, stable in token order), laid out with
-    every expert's run padded to whole tiles of rows, multiplied
-    group by group (ops/grouped_matmul.expert_mlp: a Pallas kernel on
-    the chip, `lax.ragged_dot` elsewhere), and gathered back to their
-    tokens, where a token's results are weighted and summed. One code
-    path for a prefill of thousands of tokens and a decode batch of
-    tens.
+    every expert's run padded to whole sub-tiles of 16 rows,
+    multiplied group by group (ops/grouped_matmul.expert_mlp: a
+    Pallas kernel on the chip that walks each run to its length,
+    `lax.ragged_dot` elsewhere), and gathered back to their tokens,
+    where a token's results are weighted and summed. One code path
+    for a prefill of thousands of tokens and a decode batch of tens.
 
     Where `routing.held` names a share of the experts, the router
     still ranks all E of them and the weights are normalised over all
     the chosen; the pairs that land on experts held elsewhere never
     enter the sort, and what they would add is left out (expert
     parallelism's share of the layer, without its exchange). The rows'
-    bound is static and for the worst deal (every pair held here), so
-    the tiles past the last run are counted (`live`) and the kernels
-    skip them."""
+    bound is static and for the worst deal (every pair held here);
+    the kernels walk the runs that came and nothing past them."""
     from dlrover_tpu.ops import grouped_matmul as gmm
 
     if not isinstance(routing, Routing):
@@ -310,11 +310,8 @@ def dropless_moe(
         rank = jnp.sum(
             (jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1
         )                                                   # [pairs]
-        # the tile follows the pairs EXPECTED here, the rows' bound
-        # the most there can be
-        tile = _tile_rows(pairs * held // e, held)
-        rows = -(-(pairs + held * (tile - 1)) // tile) * tile
-        group_rows = -(-counts // tile) * tile
+        rows = dropless_rows(pairs, held)
+        group_rows = -(-counts // gmm.SUB_ROWS) * gmm.SUB_ROWS
         ends = jnp.cumsum(group_rows)
         if share:
             # a pair held elsewhere lands past the last row: dropped
@@ -330,20 +327,8 @@ def dropless_moe(
             jnp.arange(pairs, dtype=jnp.int32) // top_k,
             **({"mode": "drop"} if share else {}),
         )
-        tile_group = jnp.minimum(
-            jnp.searchsorted(
-                ends, jnp.arange(rows // tile, dtype=jnp.int32) * tile,
-                side="right",
-            ),
-            held - 1,
-        )
-        # the tiles that hold rows, where the bound is for more
-        live = ends[-1] // tile if share else None
         x = jnp.concatenate([h, jnp.zeros((1, d), h.dtype)])[src]
-    y = gmm.expert_mlp(
-        x, w_gate, w_up, w_down, group_rows, tile_group, tile,
-        layer=layer, live=live,
-    )
+    y = gmm.expert_mlp(x, w_gate, w_up, w_down, group_rows, layer=layer)
     with jax.named_scope("moe_combine"):
         if share:
             y = jnp.take(y, dest, axis=0, mode="fill", fill_value=0)

@@ -128,6 +128,7 @@ from dlrover_tpu.models.decode import (
     spec_accept_sampled,
     verify_step,
 )
+from dlrover_tpu.models.moe import dropless_rows
 from dlrover_tpu.ops.quantization import (
     QuantizedWeight,
     quantize_int8,
@@ -2193,10 +2194,14 @@ class ContinuousBatcher:
         # below until the state scatters runs synchronously — with
         # prefill_chunk>0 it shrinks to host bookkeeping because the
         # prefill itself moves into the interleaved dispatches
-        with trace.span(
-            "engine.admit", prompt_tokens=p,
-            bucket=self._prompt_bucket(p),
-        ) as sp:
+        bucket = self._prompt_bucket(p)
+        with trace.span("engine.admit", prompt_tokens=p, bucket=bucket) as sp:
+            if _dropless(self.cfg):
+                # the rows a layer's grouped kernels are handed for
+                # this bucket (static: nothing is fetched for it);
+                # over bucket * top_k, the padding an admission carries
+                sp.set(moe_rows=dropless_rows(
+                    bucket * self.cfg.moe_top_k, self.cfg.held[1]))
             pf_start: Optional[int] = None
             if req.adopted is not None:
                 # cross-replica handoff: install the shipped KV run and
@@ -2232,7 +2237,6 @@ class ContinuousBatcher:
                 # ADAPTED projections, and it never installs from (or
                 # publishes into) the shared prefix pool — published
                 # prefixes are base-model K/V by contract
-                bucket = self._prompt_bucket(p)
                 self.cache = self._admit_fn(
                     self.cache,
                     self.params,
@@ -2242,7 +2246,6 @@ class ContinuousBatcher:
                     aslot=req.adapter_slot,
                 )
             elif self.prefix_cache is None:
-                bucket = self._prompt_bucket(p)
                 self.cache = self._admit_fn(
                     self.cache,
                     self.params,

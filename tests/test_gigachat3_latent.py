@@ -310,6 +310,12 @@ def test_step_spans_count_latent_rows_and_held_pairs(served):
         if r[trace.NAME] == "engine.admit"
     ]
     assert admits and admits[0]["prompt_tokens"] == 5
+    # a held share's rows are bounded for the worst deal: every pair
+    # of the bucket on the experts held here
+    from dlrover_tpu.models import moe
+
+    assert admits[0]["moe_rows"] == moe.dropless_rows(
+        admits[0]["bucket"] * cfg.moe_top_k, HELD[1])
 
 
 class _Registry:

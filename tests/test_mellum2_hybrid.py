@@ -258,6 +258,14 @@ def test_step_span_counts_pages_and_expert_load(small, served):
     assert sorted(a["window_cells"] for a in admits) == sorted(
         min(len(p), WINDOW) for p in prompts
     )
+    # the rows a layer's grouped kernels are handed for the bucket:
+    # every pair, and less than a sub-tile more for each expert
+    from dlrover_tpu.models import moe
+
+    for a in admits:
+        pairs = a["bucket"] * cfg.moe_top_k
+        assert a["moe_rows"] == moe.dropless_rows(pairs, cfg.n_experts)
+        assert pairs <= a["moe_rows"] <= pairs + 15 * cfg.n_experts
 
 
 # ---- the windowed kernel ---------------------------------------------------

@@ -207,3 +207,40 @@ def test_softmax_routing_is_as_it_was(layer):
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(want), atol=2e-5, rtol=2e-5)
     assert int(counts.sum()) == T * 4
+
+
+@pytest.mark.parametrize("first", [0, 8, 16, 24])
+def test_a_share_through_the_kernels_is_the_share(first, monkeypatch):
+    """At widths the grouped kernels take (lanes of 128): one chip's 8
+    of 32 sigmoid-routed experts through the kernels (interpret mode),
+    whose walk ends with the pairs that came, against the same share
+    through `lax.ragged_dot`, and the four shares against the uncut
+    layer."""
+    from dlrover_tpu.ops import grouped_matmul as gmm
+
+    d, m, t = 128, 128, 40
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    router = jax.random.normal(ks[0], (d, E)) / np.sqrt(d)
+    wg = jax.random.normal(ks[1], (E, d, m)) / np.sqrt(d)
+    wu = jax.random.normal(ks[2], (E, d, m)) / np.sqrt(d)
+    wd = jax.random.normal(ks[3], (E, m, d)) / np.sqrt(m)
+    bias = 0.1 * jax.random.normal(ks[4], (E,))
+    h = jax.random.normal(ks[5], (t, d))
+
+    def share(at, count):
+        return moe.dropless_moe(
+            h, router, wg[at:at + count], wu[at:at + count],
+            wd[at:at + count],
+            moe.Routing(**dict(ROUTING, held=(at, count))), bias=bias)
+
+    with jax.default_matmul_precision("highest"):
+        want, c_want = share(first, 8)
+        whole, _ = share(0, E)
+        parts = sum(share(at, 8)[0] for at in range(0, E, 8))
+        monkeypatch.setattr(fa, "force_kernels", lambda: True)
+        assert gmm.use_kernel(jnp.zeros((16, d)), wg)
+        got, c_got = share(first, 8)
+    np.testing.assert_array_equal(np.asarray(c_got), np.asarray(c_want))
+    assert 0 < int(c_got.sum()) < t * 4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole), atol=1e-5)

@@ -406,12 +406,14 @@ def test_mellum2_chunk_program_fits_the_chip(chip, monkeypatch):
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used + 10**9 < V5E_HBM_BYTES, used
     if _served_depth() == 8:
-        # the parent's program (6ba8b28), to the byte and the
-        # instruction: softmax routing over experts all held here goes
-        # through `dropless_moe` as it did
-        assert _census(compiled) == (285138432, 7565, {
-            "fusion": 228, "custom-call": 51, "scatter": 12, "gather": 15,
-            "copy": 49, "dynamic-update-slice": 1, "dynamic-slice": 15,
+        # this program to the byte and the instruction, re-pinned at
+        # PR 40 (the commit after f550a1b: the grouped kernels walk an
+        # expert's whole run a grid step and `dropless_moe` pads runs
+        # to 16 rows; f550a1b's read 285138432, 7565): what a later PR
+        # that adds beside this path must keep
+        assert _census(compiled) == (285009408, 7816, {
+            "fusion": 225, "custom-call": 47, "scatter": 12, "gather": 19,
+            "copy": 54, "dynamic-update-slice": 1, "dynamic-slice": 15,
             "convolution": 21,
         })
 
@@ -441,22 +443,24 @@ def test_mellum2_largest_prefill_fits_the_chip(chip, monkeypatch):
     assert used + 10**9 < V5E_HBM_BYTES, used
 
 
-@pytest.mark.parametrize("rows,tile", [(1472, 16), (36864, 128)])
-def test_grouped_expert_kernels(chip, rows, tile):
+@pytest.mark.parametrize("tokens", [64, 3584], ids=["decode", "prefill"])
+def test_grouped_expert_kernels(chip, tokens):
     """The experts' two kernels at Mellum2's widths: a decode batch's
-    padded rows in tiles of 16 and a prefill's in tiles of 128, whole
-    [2304, 896] matrices as blocks."""
+    padded rows (64 slots) and the largest prefill bucket's (3584
+    tokens), an expert's whole run a grid step, whole [2304, 896]
+    matrices as blocks."""
+    from dlrover_tpu.models import moe
     from dlrover_tpu.ops import grouped_matmul as gmm
 
     d, m, e, layers = 2304, 896, 64, 2
+    rows = moe.dropless_rows(tokens * 8, e)
     text = _compile(
         chip,
-        lambda x, wg, wu, wd, sizes, groups: gmm.expert_mlp_kernel(
-            x, wg, wu, wd, groups, tile, layer=1),
+        lambda x, wg, wu, wd, sizes: gmm.expert_mlp_kernel(
+            x, wg, wu, wd, sizes, layer=1),
         S((rows, d), jnp.bfloat16), S((layers, e, d, m), jnp.bfloat16),
         S((layers, e, d, m), jnp.bfloat16),
         S((layers, e, m, d), jnp.bfloat16), S((e,), jnp.int32),
-        S((rows // tile,), jnp.int32),
     )
     assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
 
@@ -551,27 +555,27 @@ def test_latent_gate_is_mosaics(chip):
         )
 
 
-@pytest.mark.parametrize("rows,tile", [(1008, 16), (25600, 64)])
-def test_grouped_expert_kernels_in_column_blocks(chip, rows, tile):
+@pytest.mark.parametrize("tokens", [96, 3072], ids=["decode", "prefill"])
+def test_grouped_expert_kernels_in_column_blocks(chip, tokens):
     """The experts' two kernels at GigaChat3.1's widths over the 16
     experts held here: a [7168, 2048] matrix is 29 MB, so it goes in
-    blocks of columns; a decode batch's worst-case rows in tiles of
-    16 and a prefill's in tiles of 64, the tiles past the live ones
-    skipped."""
+    blocks of columns; a decode batch's worst-case rows (96 slots)
+    and a prefill's (3072 tokens), whatever share of them came."""
+    from dlrover_tpu.models import moe
     from dlrover_tpu.ops import grouped_matmul as gmm
 
     d, m, e, layers = 7168, 2048, 16, 4
     assert gmm._column_block(d, m, 2) == 512
     assert gmm._column_block(m, d, 2) == 1792
     assert gmm._column_block(2304, 896, 2) == 896  # Mellum2's: whole
+    rows = moe.dropless_rows(tokens * 8, e)
     text = _compile(
         chip,
-        lambda x, wg, wu, wd, groups, live: gmm.expert_mlp_kernel(
-            x, wg, wu, wd, groups, tile, layer=1, live=live),
+        lambda x, wg, wu, wd, sizes: gmm.expert_mlp_kernel(
+            x, wg, wu, wd, sizes, layer=1),
         S((rows, d), jnp.bfloat16), S((layers, e, d, m), jnp.bfloat16),
         S((layers, e, d, m), jnp.bfloat16),
-        S((layers, e, m, d), jnp.bfloat16),
-        S((rows // tile,), jnp.int32), S((), jnp.int32),
+        S((layers, e, m, d), jnp.bfloat16), S((e,), jnp.int32),
     )
     assert "moe_grouped_gate_up" in text and "moe_grouped_down" in text
 

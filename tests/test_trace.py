@@ -313,6 +313,8 @@ class TestEngineSpans:
         admits = _named("engine.admit")
         assert [r[COUNTS]["prompt_tokens"] for r in admits] == [5, 20]
         assert [r[COUNTS]["bucket"] for r in admits] == [16, 32]
+        # no dropless experts in this model: no rows of theirs
+        assert all("moe_rows" not in r[COUNTS] for r in admits)
         assert eng.prefill_stats()["admission_stall_ms"] == pytest.approx(
             sum(r[DUR] for r in admits) * 1e3, rel=1e-9
         )
