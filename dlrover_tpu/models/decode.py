@@ -120,6 +120,25 @@ def _window_of(cfg, kind) -> int:
     return cfg.sliding_window if kind == "window" else 0
 
 
+def _qk_norms(cfg, layer_params):
+    """The layer's per-head scales of q and k where the configuration
+    norms them (`cfg.qk_norm`), else None."""
+    if not getattr(cfg, "qk_norm", False):
+        return None
+    return layer_params["q_norm"], layer_params["k_norm"]
+
+
+def _block_of(cfg) -> int:
+    """Positions a diffusion block holds (0: one token a forward)."""
+    return getattr(cfg, "block_length", 0)
+
+
+def _block_end(positions, block: int):
+    """The last position of each position's own block: what a query
+    sees up to, where attention is two-sided inside a block."""
+    return positions // block * block + (block - 1)
+
+
 def _ffn_residual(cfg, x, layer_params, lp, tp, layer, experts):
     """The feed-forward half of a served block: llama's own
     `_mlp_residual`, or where the configuration routes without
@@ -289,6 +308,7 @@ def _write_cache_and_attend(
     plain_causal: bool = False,
     mesh=None,
     window: int = 0,
+    block: int = 0,
 ):
     """THE decode-specific core, shared by both family blocks: write
     this chunk's K/V into the cache at `start` and attend over the
@@ -318,7 +338,10 @@ def _write_cache_and_attend(
     attention run shard-local, and the attention output is replicated
     (all-gather) before returning so every downstream op — out
     projection, MLP, logits — is the identical full-width program on
-    every shard (the byte-parity argument at the top of this file)."""
+    every shard (the byte-parity argument at the top of this file).
+
+    `block` > 0 (a block-diffusion model): a query sees every key up
+    to the END of its own block of `block` positions."""
     q = constrain(q, mesh, None, None, SERVING_TP_AXIS, None)
     k = constrain(k, mesh, None, None, SERVING_TP_AXIS, None)
     v = constrain(v, mesh, None, None, SERVING_TP_AXIS, None)
@@ -348,12 +371,13 @@ def _write_cache_and_attend(
         impl = "reference" if attn_impl == "reference" else "auto"
         attn = dot_product_attention(
             q, k, v, causal=True, impl=impl, tp=_mesh_tp(mesh),
-            mesh=mesh, window=window,
+            mesh=mesh, window=window, block=block,
         )
     else:
         attn = _cached_attention(
-            q, out_cache, positions, float(head_dim) ** -0.5,
-            window=window,
+            q, out_cache,
+            _block_end(positions, block) if block else positions,
+            float(head_dim) ** -0.5, window=window,
         )
     attn = constrain(attn, mesh)
     return attn, out_cache
@@ -387,7 +411,8 @@ def _block(
     with jax.named_scope("attn" if kind is None else "attn_" + kind):
         h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(
-            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind
+            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind,
+            qk_norms=_qk_norms(cfg, layer_params),
         )
         attn, layer_cache = _write_cache_and_attend(
             q, k, v, layer_cache, positions, start, cfg.head_dim,
@@ -395,6 +420,7 @@ def _block(
             plain_causal=plain_causal,
             mesh=mesh,
             window=_window_of(cfg, kind),
+            block=_block_of(cfg),
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):
@@ -1253,7 +1279,7 @@ def _paged_view(
 
 def _write_pages_and_attend(
     q, k, v, pool, layer, table, positions, head_dim, mesh=None,
-    attn_impl: str = "auto", window: int = 0,
+    attn_impl: str = "auto", window: int = 0, block: int = 0,
 ):
     """The paged counterpart of `_write_cache_and_attend`, on the
     STACKED pool and a traced layer index: scatter this chunk's K/V
@@ -1270,7 +1296,12 @@ def _write_pages_and_attend(
     collisions are done/retired rows parked on the trash page, whose
     cells no live mask ever admits. Quantized pools quantize the
     chunk with the same `_kv_quantize` as the dense write path, so
-    the stored bytes are identical either way."""
+    the stored bytes are identical either way.
+
+    `block` > 0 (a block-diffusion model): the chunk is ONE block of
+    `block` positions a slot, starting on a block boundary; every
+    query of it sees the pool up to the end of the block, its own
+    keys, just written, among them (`_attend_block`)."""
     q = constrain(q, mesh, None, None, SERVING_TP_AXIS, None)
     k = constrain(k, mesh, None, None, SERVING_TP_AXIS, None)
     v = constrain(v, mesh, None, None, SERVING_TP_AXIS, None)
@@ -1304,6 +1335,11 @@ def _write_pages_and_attend(
                 upd.astype(arr.dtype)
             )
     s = q.shape[1]
+    if block:
+        return _attend_block(
+            q, out_pool, layer, table, positions, head_dim, block,
+            attn_impl,
+        ), out_pool
     if window:
         from dlrover_tpu.ops import paged_attention as pa
 
@@ -1344,6 +1380,33 @@ def _write_pages_and_attend(
     return attn, out_pool
 
 
+def _attend_block(
+    q, pool, layer, table, positions, head_dim, block, attn_impl
+):
+    """One diffusion block a slot over the paged pool: q `[B, block,
+    H, hd]` at positions start .. start + block - 1, every query
+    seeing the cells 0 .. start + block - 1. With one length a slot
+    the block's queries are further query rows of their K/V head, so
+    the paged walk takes them as `block * H` heads
+    (`paged_attention(..., block=)`: the kernel on the chip, the
+    gathered view under the same mask off it or where the kernel
+    refuses the shapes)."""
+    from dlrover_tpu.ops import paged_attention as pa
+
+    if q.shape[1] != block or "k_scale" in pool:
+        raise NotImplementedError(
+            f"a block-diffusion model runs {block} queries a slot over "
+            "an unquantized pool"
+        )
+    with jax.named_scope("attn_block"):
+        return pa.paged_attention(
+            q, pool, table, positions[:, 0] + block,
+            scale=float(head_dim) ** -0.5,
+            impl="reference" if attn_impl == "reference" else "auto",
+            layer=layer, block=block,
+        )
+
+
 def _block_paged(
     cfg, x, layer_params, pool, layer, table, positions, mesh=None,
     lora=None, kind=None, abs_layer=None, experts=None,
@@ -1357,13 +1420,15 @@ def _block_paged(
     with jax.named_scope("attn" if kind is None else "attn_" + kind):
         h = _rms_norm(x, layer_params["attn_norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(
-            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind
+            cfg, None, h, lp, positions, lora=lora, tp=tp, kind=kind,
+            qk_norms=_qk_norms(cfg, layer_params),
         )
         attn, pool = _write_pages_and_attend(
             q, k, v, pool, layer, table, positions, cfg.head_dim,
             mesh=mesh,
             attn_impl=getattr(cfg, "attn_impl", "auto"),
             window=_window_of(cfg, kind),
+            block=_block_of(cfg),
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):

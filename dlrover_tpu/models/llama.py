@@ -139,6 +139,18 @@ class LlamaConfig:
     # that land on held experts are computed (expert parallelism's
     # share of a layer, without its exchange)
     experts_held: Tuple[int, ...] = ()
+    # per-head RMSNorm of q and k (over the head's own numbers, one
+    # learned scale each a layer: `q_norm`, `k_norm`) before the rotary
+    # turn. Serving only.
+    qk_norm: bool = False
+    # generation by diffusion over BLOCKS of `block_length` positions
+    # (0 = one token a forward): attention is causal across blocks and
+    # two-sided inside one (key j is seen by query i iff
+    # j // block_length <= i // block_length), a forward runs a whole
+    # block and fills in the positions that still hold `mask_token_id`
+    # (serving/engine.py's diffusion chunk program). Serving only.
+    block_length: int = 0
+    mask_token_id: int = 0
     # GPipe microbatch count when the mesh has a live "pipe" axis
     # (0 → default to the pipe degree)
     pipeline_microbatches: int = 0
@@ -293,6 +305,23 @@ class LlamaConfig:
                 "first_k_dense needs dense_mlp_dim > 0, fewer dense "
                 "layers than n_layers, and no layer_pattern"
             )
+        if self.block_length and (
+            self.block_length < 2
+            or self.block_length & (self.block_length - 1)
+            or self.latent or self.layer_pattern
+            or not 0 <= self.mask_token_id < self.vocab_size
+        ):
+            raise ValueError(
+                f"block_length={self.block_length} needs a power of two "
+                "of at least 2 positions a block (the flash kernel's "
+                "mask), a mask_token_id inside the "
+                "vocabulary, plain attention and no layer_pattern"
+            )
+        if self.qk_norm and self.latent:
+            raise ValueError(
+                "qk_norm is the plain attention block's; latent "
+                "attention norms its own bottlenecks"
+            )
         if self.latent and not (
             self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
             and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
@@ -412,6 +441,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         },
         "final_norm": {"scale": norm_init(D)},
     }
+    if cfg.qk_norm:
+        params["layers"].update(
+            q_norm=norm_init(L, hd), k_norm=norm_init(L, hd)
+        )
     if not cfg.tie_embeddings:
         params["lm_head"] = {
             "weight": dense_init(k_out, (D, cfg.vocab_size), D)
@@ -652,7 +685,7 @@ def _slot_lora_delta(h, a, b, idx, scale):
 
 def _attn_qkv(
     cfg: LlamaConfig, mesh, h, lp, positions, lora=None, tp: int = 1,
-    kind: Optional[str] = None,
+    kind: Optional[str] = None, qk_norms=None,
 ):
     """Projections + RoPE of one block — shared by the training layer
     and the KV-cache decoder (models/decode.py), so there is exactly
@@ -662,7 +695,11 @@ def _attn_qkv(
     layer's stacked adapter slices: per-row deltas are added to the
     raw projections BEFORE the head reshape and RoPE — RoPE is linear
     in its input, so a pre-rotation delta equals rotating the
-    merged-weight projection."""
+    merged-weight projection.
+
+    `qk_norms` (serving, `cfg.qk_norm`): the layer's (q_norm, k_norm)
+    scales; each head of q and k is RMS-normed over its own numbers
+    before the rotary turn."""
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     b, s, _ = h.shape
     hq = matmul_any(h, lp["wq"], tp=tp)
@@ -690,6 +727,9 @@ def _attn_qkv(
     rope = cfg.rope_theta if kind is None else cfg.rope_of(kind)
     if isinstance(rope, RopeSpec) and not rope.yarn_factor:
         rope = rope.theta
+    if qk_norms is not None:
+        q = _rms_norm(q, qk_norms[0], cfg.norm_eps)
+        k = _rms_norm(k, qk_norms[1], cfg.norm_eps)
     q = _rope(q, positions, rope)
     k = _rope(k, positions, rope)
     return q, k, v
@@ -819,6 +859,10 @@ def refuse_training(cfg: LlamaConfig) -> None:
             ("n_shared_experts", cfg.n_shared_experts > 0),
             ("moe_scoring='sigmoid'", cfg.moe_scoring != "softmax"),
             ("experts_held", bool(cfg.experts_held)),
+            ("normalised q and k (qk_norm)", cfg.qk_norm),
+            ("generation by diffusion over blocks (block_length: its "
+             "loss is a block-diffusion loss over a doubled sequence)",
+             cfg.block_length > 0),
         ) if on
     ]
     if asked:

@@ -35,9 +35,12 @@ def reference_attention(
     scale: Optional[float] = None,
     segment_ids: Optional[jax.Array] = None,
     window: int = 0,
+    block: int = 0,
 ) -> jax.Array:
     """Plain XLA attention, softmax in f32. [B, S, H, D] in and out.
-    `window` > 0 (causal): query i sees keys i - window < j <= i."""
+    `window` > 0 (causal): query i sees keys i - window < j <= i.
+    `block` > 0 (causal): the diagonal rounded up to its block of
+    `block` positions, key j is seen iff j // block <= i // block."""
     orig_dtype = q.dtype
     n_rep = q.shape[2] // k.shape[2]
     k = _kv_repeat(k, n_rep)
@@ -55,6 +58,8 @@ def reference_attention(
         keep = q_pos >= k_pos
         if window:
             keep = keep & (q_pos - k_pos < window)
+        if block:
+            keep = q_pos // block >= k_pos // block
         logits = jnp.where(keep, logits, NEG_INF)
     if segment_ids is not None:
         seg_mask = (
@@ -82,10 +87,13 @@ def dot_product_attention(
     tp: int = 1,
     mesh=None,
     window: int = 0,
+    block: int = 0,
 ) -> jax.Array:
     """Main entry. impl: 'auto' | 'flash' | 'reference'.
     `window` > 0 is a causal band (a window layer's serving prefill,
-    forward only, one device).
+    forward only, one device). `block` > 0 rounds the causal diagonal
+    up to blocks of `block` positions (a block-diffusion model's
+    prefill; forward only, one device, no window).
 
     'auto' uses the Pallas flash kernel on TPU when shapes allow
     (seq % block == 0, head_dim tile-able), else the XLA reference.
@@ -105,9 +113,16 @@ def dot_product_attention(
     """
     if window and mesh is not None and mesh.devices.size > 1:
         raise ValueError("window attention is not sharded over a mesh")
+    if block and (
+        window or not causal or (mesh is not None and mesh.devices.size > 1)
+    ):
+        raise ValueError(
+            "a block mask is causal, has no window and is not sharded "
+            "over a mesh"
+        )
     if impl == "reference":
         return reference_attention(
-            q, k, v, causal, scale, segment_ids, window
+            q, k, v, causal, scale, segment_ids, window, block
         )
     if impl in ("auto", "flash"):
         from dlrover_tpu.ops import flash_attention as fa
@@ -134,6 +149,7 @@ def dot_product_attention(
             return fa.flash_attention(
                 q, k, v, causal=causal, scale=scale,
                 block_q=block_q, block_k=block_k, window=window,
+                block=block,
             )
         if block_q or block_k:
             # explicit tuning blocks were given but the flash path was
@@ -155,6 +171,6 @@ def dot_product_attention(
                 segment_ids is not None, tp, mesh is not None,
             )
         return reference_attention(
-            q, k, v, causal, scale, segment_ids, window
+            q, k, v, causal, scale, segment_ids, window, block
         )
     raise ValueError(f"unknown attention impl: {impl}")

@@ -149,7 +149,8 @@ def supports(
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, num_kb, window=0):
+                *, scale, causal, block_q, block_k, num_kb, window=0,
+                block=0):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -190,6 +191,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             keep = rows >= cols
             if window:
                 keep = keep & (rows - cols < window)
+            if block:
+                # the diagonal rounded up to its block's last
+                # position (`_fwd`; a power of two: no vector division)
+                keep = (rows | (block - 1)) >= cols
             s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[:, :1]  # [bq, 1]
         l_prev = l_scr[:, :1]
@@ -218,9 +223,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, window=0):
+def _fwd(q, k, v, causal, scale, block_q, block_k, window=0, block=0):
     """q,k,v: [B, H, S, D] (equal head counts). Returns (o, lse).
-    `window` > 0 (with causal): query i sees keys i - window < j <= i."""
+    `window` > 0 (with causal): query i sees keys i - window < j <= i.
+    `block` > 0 (with causal, no window): key j is seen by query i iff
+    j // block <= i // block, the diagonal rounded up to its block of
+    `block` positions (a block-diffusion model's prefill). `block`
+    is a power of two that divides both tile sizes, so a tile the
+    diagonal skips holds no key of a rounded-up row either."""
+    if block and (
+        not causal or window or block & (block - 1)
+        or block_q % block or block_k % block
+    ):
+        raise ValueError(
+            f"a block mask of {block} is causal, has no window, is a "
+            f"power of two and divides the tiles ({block_q}, {block_k})"
+        )
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
     num_qb = s_q // block_q
@@ -229,6 +247,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, window=0):
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, num_kb=num_kb,
         **({"window": window} if window else {}),
+        **({"block": block} if block else {}),
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -513,6 +532,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     window: int = 0,
+    block: int = 0,
 ) -> jax.Array:
     """Flash attention on [B, S, H, D] tensors; returns [B, S, H, D].
 
@@ -520,10 +540,12 @@ def flash_attention(
     pass explicit sizes only for tuning experiments. `window` > 0 is a
     causal band (query i sees keys i - window < j <= i) in the
     FORWARD kernel only — the serving prefill of a window layer; it
-    has no backward."""
-    if window and not (causal and q.shape[1] == k.shape[1]):
+    has no backward. `block` > 0 rounds the causal diagonal up to
+    blocks of `block` positions (`_fwd`), forward only as well."""
+    if (window or block) and not (causal and q.shape[1] == k.shape[1]):
         raise ValueError(
-            "a window needs causal attention over equal q/k lengths"
+            "a window or a block mask needs causal attention over "
+            "equal q/k lengths"
         )
     if causal and q.shape[1] != k.shape[1]:
         if q.shape[1] == 1:
@@ -569,8 +591,10 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if window:
-        o, _ = _fwd(qt, kt, vt, causal, scale, block_q, block_k, window)
+    if window or block:
+        o, _ = _fwd(
+            qt, kt, vt, causal, scale, block_q, block_k, window, block
+        )
     else:
         o = _flash(qt, kt, vt, causal, scale, block_q, block_k)
     return o.transpose(0, 2, 1, 3)

@@ -533,8 +533,26 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
     )
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window"))
-def _kernel(q, pages, layer, table, lengths, scale, window=None):
+def block_rows(q, kv: int):
+    """A diffusion block's queries `[B, S, H, hd]` as the walk takes
+    them, `[B, S * H, hd]`: with one length a slot, the S queries of a
+    K/V head's group are S times the group's query rows (K/V head g
+    first, then the position, then the head inside the group)."""
+    b, s, h, hd = q.shape
+    q = q.reshape(b, s, kv, h // kv, hd)
+    return q.transpose(0, 2, 1, 3, 4).reshape(b, s * h, hd)
+
+
+def _block_rows_back(o, s: int, kv: int):
+    """`block_rows`' inverse on the walk's result."""
+    b, sh, hd = o.shape
+    o = o.reshape(b, kv, s, sh // (s * kv), hd)
+    return o.transpose(0, 2, 1, 3, 4).reshape(b, s, sh // s, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "block"))
+def _kernel(q, pages, layer, table, lengths, scale, window=None,
+            block=False):
     """q [B, H, hd] → [B, H, hd] over layer `layer` (int32[1]) of the
     stacked pool. (Jitted so that the engine's chunk programs, one a
     chunk length, and a period's layers of one kind trace and lower
@@ -619,9 +637,8 @@ def _kernel(q, pages, layer, table, lengths, scale, window=None):
             ),
         ),
         interpret=fa._interpret(),
-        name=(
-            "paged_attention_decode" if window is None
-            else "paged_attention_decode_window"
+        name="paged_attention_decode" + (
+            "_block" if block else "" if window is None else "_window"
         ),
     )(
         layer, table.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -839,6 +856,7 @@ def paged_attention(
     mesh=None,
     layer=None,
     window: Optional[int] = None,
+    block: int = 0,
 ) -> jax.Array:
     """Single-query attention over paged KV. `pages` is the stacked
     pool (`[L, n_pages, page_size, KV, ...]` leaves) with `layer` the
@@ -857,7 +875,33 @@ def paged_attention(
     is then the slot's ring (logical page p at entry p % R), the walk
     starts at the first page that still holds a position inside the
     last `window` positions, and that page's older cells are masked.
-    `window=None` is the program as it was, operation for operation."""
+    `window=None` is the program as it was, operation for operation.
+
+    `block` (static) > 0: `q` is `[B, block, H, hd]`, ONE diffusion
+    block a row, whose queries all see `lengths` cells (the block's
+    end). They ride the same walk as `block * H` heads over the pool's
+    K/V heads (`block_rows`); on the chip the call is named
+    `paged_attention_decode_block`, so that a trace tells it apart."""
+    if block:
+        if window is not None or mesh is not None or q.shape[1] != block:
+            raise ValueError(
+                "a block of queries has no window, no mesh and "
+                f"{block} positions"
+            )
+        kv = pages["k"].shape[-2]
+        rows = block_rows(q, kv)
+        if scale is None:
+            scale = float(q.shape[-1]) ** -0.5
+        if impl == "reference" or (
+            impl == "auto" and not use_kernel(rows, pages, table)
+        ):
+            out = _reference(rows, pages, table, lengths, scale, layer)
+        else:
+            pages, layer = _stacked(pages, layer)
+            out = _kernel(
+                rows, pages, layer, table, lengths, scale, block=True
+            )
+        return _block_rows_back(out, block, kv)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     from dlrover_tpu.parallel.mesh import serving_mesh_tp

@@ -100,9 +100,11 @@ import numpy as np
 from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.decode import (
+    _block_of,
     _check_adapters,
     _check_positional_capacity,
     _dropless,
+    _forward_paged,
     _warp,
     moe_counts_shape,
     decode_step,
@@ -241,6 +243,7 @@ def _refuse_unserved(cfg, **asked) -> None:
             ("experts routed without dropping",
              getattr(cfg, "n_experts", 0) > 0
              and getattr(cfg, "moe_routing", "") == "dropless"),
+            ("generation by diffusion over blocks", _block_of(cfg) > 0),
         ) if on
     ]
     knobs = sorted(name for name, on in asked.items() if on)
@@ -250,8 +253,8 @@ def _refuse_unserved(cfg, **asked) -> None:
             f"{', '.join(knobs)}: the prefix cache, the host KV tier, "
             "the handoff between replicas, speculative decoding, "
             "adapters, int8 weights or KV, chunked prefill and tp > 1 "
-            "move runs of k and v pages of one class and know dense "
-            "feed-forward layers only"
+            "move runs of k and v pages of one class, know dense "
+            "feed-forward layers only and count a token a forward"
         )
 
 
@@ -367,8 +370,10 @@ def _paged_step_takes_kernel(cfg, n_slots, pool, table, mesh) -> bool:
             jax.ShapeDtypeStruct(tuple(table.shape), jnp.int32),
             cfg.kv_lora_rank,
         )
+    # a diffusion block's queries ride the walk as further heads
     probe_q = jax.ShapeDtypeStruct(
-        (n_slots, cfg.n_heads, cfg.head_dim), cfg.dtype
+        (n_slots, cfg.n_heads * max(_block_of(cfg), 1), cfg.head_dim),
+        cfg.dtype,
     )
     if "full" in pool:
         # two classes of pages (a window and a full one) of one page
@@ -556,6 +561,106 @@ def _build_chunk_program(
         )
 
     return {"dense": _run_chunk, "paged": _run_chunk_paged}
+
+
+def _diffusion_scan(
+    cfg, steps, pool, params, blk, msk, pos, done, limit, k, table
+):
+    """The decode loop of a model that generates by diffusion over
+    blocks: k FORWARDS over every slot, each slot at its own phase of
+    its own block, no host round trip inside. A slot's state is its
+    block: `blk` [B, block] the ids (the mask id where still masked),
+    `msk` [B, block] which positions are masked, `pos` [B] the block's
+    first position (a multiple of the block length). One forward runs
+    the block's positions over the paged pool (`_forward_paged`: the
+    block's own keys and values are written into its cells, every
+    query sees the pool up to the block's end) and then, a slot:
+
+      DENOISES while a position is masked: every masked position
+      takes its arg-max id and its confidence (that id's softmax
+      probability), and the ceil(block / steps) most confident masked
+      positions are unmasked, ties to the lower position
+      (`low_confidence_static`);
+      COMMITS when none is: the keys and values the forward has just
+      stored are the block's final ones, the block's ids are emitted,
+      and the slot moves one block on, all masked, or is done where
+      the next block starts at or past its limit (its position then
+      stays, so a done row's frozen rewrites land in cells it owns).
+
+    Returns (pool, blk, msk, pos, done, emitted [B, k, block], phase
+    [B, k], routed pairs per expert). `phase` is 0 for a done row, 1
+    for a denoising forward (`emitted`: the ids unmasked, -1
+    elsewhere), 2 for a commit (`emitted`: the block's ids)."""
+    block = cfg.block_length
+    count = -(-block // steps)
+    table = jnp.where(done[:, None], 0, table)
+    offs = jnp.arange(block, dtype=jnp.int32)
+    before = offs[None, :] < offs[:, None]        # [i, j]: j lower than i
+    mask_id = jnp.int32(cfg.mask_token_id)
+
+    def body(carry, _):
+        pool, blk, msk, pos, done, pairs = carry
+        logits, pool, *counts = _forward_paged(
+            cfg, params, blk, pool, table, pos[:, None] + offs[None, :]
+        )
+        with jax.named_scope("diffusion_unmask"):
+            top = jnp.max(logits, axis=-1)
+            best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # the arg-max id's softmax probability
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            score = jnp.where(msk, conf, -1.0)
+            ahead = (score[:, None, :] > score[:, :, None]) | (
+                (score[:, None, :] == score[:, :, None]) & before[None]
+            )
+            take = (
+                msk & (jnp.sum(ahead, axis=-1) < count) & ~done[:, None]
+            )
+        with jax.named_scope("diffusion_commit"):
+            commit = ~jnp.any(msk, axis=-1) & ~done
+            fin = commit & (pos + block >= limit)
+            on = (commit & ~fin)[:, None]
+            emitted = jnp.where(
+                commit[:, None], blk, jnp.where(take, best, -1)
+            )
+            phase = jnp.where(done, 0, jnp.where(commit, 2, 1))
+            blk = jnp.where(on, mask_id, jnp.where(take, best, blk))
+            msk = jnp.where(on, True, msk & ~take)
+            pos = jnp.where(on[:, 0], pos + block, pos)
+            done = done | fin
+        if counts and pairs is not None:
+            pairs = pairs + counts[0]
+        return (pool, blk, msk, pos, done, pairs), (
+            emitted, phase.astype(jnp.int8)
+        )
+
+    pairs = (
+        jnp.zeros(moe_counts_shape(cfg), jnp.int32)
+        if _dropless(cfg) else None
+    )
+    (pool, blk, msk, pos, done, pairs), (emitted, phase) = jax.lax.scan(
+        body, (pool, blk, msk, pos, done, pairs), None, length=k,
+    )
+    out = (
+        pool, blk, msk, pos, done,
+        jnp.swapaxes(emitted, 0, 1), phase.T,
+    )
+    return out if pairs is None else out + (pairs,)
+
+
+def _build_diffusion_program(cfg, steps):
+    """The chunk program of a model that generates by diffusion over
+    blocks: `_diffusion_scan` behind one jit, over the paged pool
+    (donated). `steps` is the denoising steps a block, the sampling
+    parameter this family has (one chip: `_refuse_unserved`)."""
+    scan = partial(_diffusion_scan, cfg, steps)
+
+    @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
+    def _run_chunk_blocks(
+        pool, table, params, blk, msk, pos, done, limit, k
+    ):
+        return scan(pool, params, blk, msk, pos, done, limit, k, table)
+
+    return {"paged": _run_chunk_blocks}
 
 
 def _build_pf_chunk_program(
@@ -927,6 +1032,20 @@ def _state_admit_prog(tok, pos, done, limit, keys,
 
 
 @jax.jit
+def _state_admit_block_prog(blk, msk, pos, done, limit,
+                            slot, blk_v, msk_v, pos_v, limit_v):
+    """`_state_admit_prog` for a slot whose state is a diffusion
+    block."""
+    return (
+        blk.at[slot].set(blk_v),
+        msk.at[slot].set(msk_v),
+        pos.at[slot].set(pos_v),
+        done.at[slot].set(False),
+        limit.at[slot].set(limit_v),
+    )
+
+
+@jax.jit
 def _state_cancel_prog(done, slot):
     return done.at[slot].set(True)
 
@@ -996,7 +1115,7 @@ class _Inflight:
     arrays (host copies already in flight) plus the host-side context
     needed to turn them into events at harvest time."""
 
-    kind: str                       # "chunk" | "spec"
+    kind: str                       # "chunk" | "spec" | "blocks"
     arrays: tuple                   # device outputs, fetch order
     dispatched_at: float            # perf_counter at enqueue
     old_pos: Optional[np.ndarray] = None    # chunk: pos at dispatch
@@ -1062,6 +1181,8 @@ class ContinuousBatcher:
         kv_checksums: int = 0,   # 1 = content-verify KV in transit
         weight_quant: str = "none",  # | "int8" | "int8_stochastic":
                                  # per-block int8 matmul weights
+        denoising_steps: int = 0,    # a block-diffusion model's steps a
+                                 # block (0 = one position a forward)
     ):
         if eos_id is not None and eos_id == pad_id:
             raise ValueError(
@@ -1125,6 +1246,37 @@ class ContinuousBatcher:
             kv_quant=bool(kv_quant),
             prefill_chunk=prefill_chunk > 0,
         )
+        # ---- generation by diffusion over blocks ------------------------
+        # a slot's state is a block and a forward does not yield one
+        # token: ONE more chunk program (`_build_diffusion_program`)
+        # behind step / _dispatch_chunk / _harvest, over the paged pool
+        self._block = _block_of(cfg)
+        self._denoise_steps = 0
+        if self._block:
+            self._denoise_steps = denoising_steps or self._block
+            refused = [
+                name for name, on in (
+                    ("kv_layout='dense'", kv_layout != "paged"),
+                    ("temperature > 0", temperature > 0.0),
+                    ("eos_id", eos_id is not None),
+                    (f"denoising_steps outside 1..{self._block}",
+                     not 1 <= self._denoise_steps <= self._block),
+                    (f"max_len not a multiple of {self._block}",
+                     max_len % self._block != 0),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    "a model that generates by diffusion over blocks of "
+                    f"{self._block} positions is served greedy, with no "
+                    "end-of-sequence token, over the paged pool; not "
+                    "with " + ", ".join(refused)
+                )
+        elif denoising_steps:
+            raise ValueError(
+                "denoising_steps is a block-diffusion model's "
+                "(cfg.block_length > 0)"
+            )
         # ---- serving mesh (GSPMD tensor slice) --------------------------
         # tp=1 (or the knob unset) keeps mesh=None: the compiled
         # programs are then literally the single-device ones (the mesh
@@ -1262,6 +1414,12 @@ class ContinuousBatcher:
                     f"spec_draft_len = {bank_len}: a slot's logical "
                     "cells must map onto whole pages"
                 )
+            if self._block and page_size % self._block:
+                raise ValueError(
+                    f"page_size {page_size} must be a multiple of the "
+                    f"diffusion block of {self._block} positions: a "
+                    "block never straddles a page"
+                )
             if prefix_cache_rows > 0 and prefix_block % page_size:
                 raise ValueError(
                     f"page_size {page_size} must divide prefix_block "
@@ -1321,6 +1479,16 @@ class ContinuousBatcher:
         self._moe_routed_total = 0
         self._latent_cells = 0
         self._window_freed_this_step = 0
+        # a block-diffusion model: the harvested dispatch's live
+        # slot-forwards, commits, ids handed to streams and K/V cells
+        # read; their running totals (/metrics); and, where
+        # `record_blocks` is on, every dispatch's raw record
+        # (`block_trajectories`)
+        self._diff = None
+        self._diff_forwards_total = 0
+        self._diff_tokens_total = 0
+        self.record_blocks = False
+        self.block_log: List[tuple] = []
         # ---- multi-adapter LoRA serving (serving/adapters.py) -----------
         # One stacked device bank whose slot 0 is the permanent zero
         # adapter; every request gathers its slot's A/B slices inside
@@ -1376,6 +1544,10 @@ class ContinuousBatcher:
         # per-slot adapter-bank index (0 = the zero adapter); joins
         # the device state only when multi-adapter serving is on
         self.adapt = np.zeros(n_slots, np.int32)
+        # a diffusion block a slot: its ids and which are still masked
+        # (`pos` is then the block's first position)
+        self.blk = np.zeros((n_slots, self._block), np.int32)
+        self.msk = np.zeros((n_slots, self._block), bool)
         self.async_depth = async_depth
         self._dev = self._device_state()
         # the one dispatched-but-unharvested device step (async mode)
@@ -1534,12 +1706,18 @@ class ContinuousBatcher:
              self.mesh, version)
             + _kernel_cache_tag() + self._adapter_tag() + self._wq_tag()
         )
+        if self._block:
+            # a step there is a forward over a block: its own program,
+            # keyed by the denoising steps beside the sampling knobs
+            key = key + ("blocks", self._denoise_steps)
         self._bound_keys.append((_CHUNK_PROGRAMS, key))
         self._run_chunk = _cached_program(
             _CHUNK_PROGRAMS,
             # graftlint: allow(JIT-003) reason=hashable tuple literal assigned above and recorded in _bound_keys so a weight refresh can retire the prior version's entries
             key,
-            lambda: _build_chunk_program(
+            lambda: _build_diffusion_program(
+                cfg, self._denoise_steps
+            ) if self._block else _build_chunk_program(
                 cfg, self.pad_id, self.eos_id, temperature, top_k,
                 top_p, mesh=self.mesh,
             ),
@@ -1852,6 +2030,9 @@ class ContinuousBatcher:
             "limit": self._replicate(jnp.asarray(self.limit)),
             "keys": self._replicate(jnp.asarray(self.slot_key)),
         }
+        if self._block:
+            state["blk"] = self._replicate(jnp.asarray(self.blk))
+            state["msk"] = self._replicate(jnp.asarray(self.msk))
         if self._adapter_cache is not None:
             # joins the resident state ONLY when adapters are on: the
             # adapterless _dev keeps its exact pre-adapter structure
@@ -1883,6 +2064,8 @@ class ContinuousBatcher:
         # vectorized over the host-side [B] arrays (a Python generator
         # here costs O(n_slots) interpreter work EVERY chunk)
         live = ~self.done & ~self._prefilling & ~self._parked
+        if self._block and live.any():
+            return self._pow2_tail(int(self._forwards_left()[live].max()))
         if not live.any():
             # only mid-prefill slots occupied: the interleaved
             # dispatch still needs a (vacuous) decode scan — make it
@@ -1890,7 +2073,9 @@ class ContinuousBatcher:
             # _prefilling is identically False and step() gates on
             # not done.all())
             return 1
-        rem = int((self.limit - self.pos - 1)[live].max())
+        return self._pow2_tail(int((self.limit - self.pos - 1)[live].max()))
+
+    def _pow2_tail(self, rem: int) -> int:
         k_target = max(1, min(rem, self.chunk))
         if k_target == self.chunk:
             return k_target
@@ -1901,6 +2086,18 @@ class ContinuousBatcher:
         while k * 2 <= k_target:
             k *= 2
         return k
+
+    def _forwards_left(self) -> np.ndarray:
+        """[B]: the forwards each slot's request still takes, where
+        generation is by diffusion over blocks: its block's denoising
+        forwards and commit, then every further block's."""
+        block = self._block
+        count = -(-block // self._denoise_steps)
+        further = -(-(self.limit - self.pos) // block) - 1
+        return (
+            -(-self.msk.sum(axis=1) // count) + 1
+            + further * (-(-block // count) + 1)
+        )
 
     @property
     def weight_version(self) -> int:
@@ -2254,11 +2451,14 @@ class ContinuousBatcher:
                 )
             else:
                 self._admit_with_prefix(slot, req, p)
-            # carry = last REAL prompt token at its position: the first
-            # chunk step recomputes its logits (identical K/V rewrite)
-            # and samples the first new token from them
-            self.tok[slot] = req.prompt[-1]
-            self.pos[slot] = p - 1
+            if self._block:
+                self._admit_block_state(slot, req, p)
+            else:
+                # carry = last REAL prompt token at its position: the first
+                # chunk step recomputes its logits (identical K/V rewrite)
+                # and samples the first new token from them
+                self.tok[slot] = req.prompt[-1]
+                self.pos[slot] = p - 1
             self.limit[slot] = min(
                 p + (req.max_new or self.max_new), self.max_len
             )
@@ -2272,13 +2472,22 @@ class ContinuousBatcher:
             # (a failover re-admission's journaled key rides in key_v —
             # the resume re-key is this same program, not a re-upload)
             d = self._dev
-            d["tok"], d["pos"], d["done"], d["limit"], d["keys"] = (
-                _state_admit_prog(
-                    d["tok"], d["pos"], d["done"], d["limit"], d["keys"],
-                    slot, int(self.tok[slot]), p - 1,
-                    int(self.limit[slot]), self.slot_key[slot],
+            if self._block:
+                d["blk"], d["msk"], d["pos"], d["done"], d["limit"] = (
+                    _state_admit_block_prog(
+                        d["blk"], d["msk"], d["pos"], d["done"],
+                        d["limit"], slot, self.blk[slot], self.msk[slot],
+                        int(self.pos[slot]), int(self.limit[slot]),
+                    )
                 )
-            )
+            else:
+                d["tok"], d["pos"], d["done"], d["limit"], d["keys"] = (
+                    _state_admit_prog(
+                        d["tok"], d["pos"], d["done"], d["limit"],
+                        d["keys"], slot, int(self.tok[slot]), p - 1,
+                        int(self.limit[slot]), self.slot_key[slot],
+                    )
+                )
             if self._adapter_cache is not None:
                 self.adapt[slot] = req.adapter_slot
                 d["adapt"] = _state_adapt_prog(
@@ -2316,6 +2525,22 @@ class ContinuousBatcher:
                 # parked slot so the decode half cannot advance it
                 self._parked[slot] = True
                 d["done"] = _state_cancel_prog(d["done"], slot)
+
+    def _admit_block_state(self, slot: int, req: _Request, p: int):
+        """The first block of a request served by diffusion over
+        blocks. Block boundaries are absolute (`pos // block`): the
+        prompt's last p % block tokens open the block already
+        unmasked, the rest holds the mask id; the prompt before it is
+        what the admission prefilled under the block mask (the
+        prefill's cells past it are dead: no query sees past its own
+        block's end, and the block's forwards rewrite them). A replay
+        after preemption folds whole blocks into the prompt, so it
+        lands on the same boundaries."""
+        given = p % self._block
+        self.pos[slot] = p - given
+        self.blk[slot] = self.cfg.mask_token_id
+        self.blk[slot, :given] = req.prompt[p - given:]
+        self.msk[slot] = np.arange(self._block) >= given
 
     def _admit_with_prefix(self, slot: int, req: _Request, p: int):
         """Prefix-cached admission: install the longest cached
@@ -2729,6 +2954,10 @@ class ContinuousBatcher:
         its last cell; a verify window extends K past it)."""
         p = len(req.prompt)
         limit = min(p + (req.max_new or self.max_new), self.max_len)
+        if self._block:
+            # a forward writes its whole block: the END of the block
+            # that holds the request's last position
+            limit = -(-limit // self._block) * self._block
         return (
             (limit - 1 + self.spec_draft_len) // self.page_size + 1
         )
@@ -3127,6 +3356,10 @@ class ContinuousBatcher:
             s["moe_held_pairs_share"] = (
                 self._moe_held_total / self._moe_routed_total
             )
+        if self._diff_forwards_total:
+            s["diffusion_tokens_per_forward"] = (
+                self._diff_tokens_total / self._diff_forwards_total
+            )
         return s
 
     def adapter_stats(self) -> Dict[str, float]:
@@ -3257,6 +3490,7 @@ class ContinuousBatcher:
             self._window_freed_this_step = 0
             self._moe_pairs = None
             self._latent_cells = 0
+            self._diff = None
             self._maybe_commit_refresh()  # deferred swap at idle fence
             if self.chaos is not None:
                 # before the harvest, any admission or dispatch: an
@@ -3354,6 +3588,8 @@ class ContinuousBatcher:
                     pages_latent=self.allocator.used_pages,
                     latent_cells=self._latent_cells,
                 )
+            if self._diff is not None:
+                sp.set(**self._diff)
             if self._moe_pairs is not None and self.cfg.n_experts > 0:
                 pairs = self._moe_pairs
                 sp.set(
@@ -3388,6 +3624,27 @@ class ContinuousBatcher:
             return
         d = self._dev
         k = self._next_chunk_len()
+        if self._block:
+            with self._dispatch_span(chunk=k):
+                pool, blk, msk, pos, done, emitted, phase, *pairs = (
+                    self._run_chunk(
+                        self.page_pool, self._table, self.params,
+                        d["blk"], d["msk"], d["pos"], d["done"],
+                        d["limit"], k,
+                    )
+                )
+                self.page_pool = pool
+                d.update(blk=blk, msk=msk, pos=pos, done=done)
+                self._enqueue_fetch(
+                    _Inflight(
+                        kind="blocks",
+                        arrays=(msk, pos, done, emitted, phase, *pairs),
+                        dispatched_at=0.0,
+                        old_pos=self.pos.copy(),
+                        version=self._weight_version,
+                    )
+                )
+            return
         with self._dispatch_span(chunk=k):
             rings = ()
             if self._hybrid:
@@ -3609,6 +3866,15 @@ class ContinuousBatcher:
             self._stat_span_ms += span_s * 1e3
             self._stat_overlap_ms += hidden_s * 1e3
             self._stat_dispatches += 1
+            if pend.kind == "blocks":
+                msk, pos, done, emitted, phase, *pairs = host
+                if pairs:
+                    self._moe_pairs = pairs[0]
+                    self._moe_steps = phase.shape[1]
+                self.msk, self.pos = msk, pos
+                return self._emit_block_events(
+                    emitted, phase, pend.old_pos, done, pend.version
+                )
             if pend.kind == "chunk":
                 tok, pos, done, keys, emitted, *pairs = host
                 if pairs:
@@ -3698,6 +3964,94 @@ class ContinuousBatcher:
                     self._release_slot_row(slot)
             if new_toks or finished:
                 events.append((req.idx, new_toks, finished))
+        self._settle_done(new_done, pf_mask)
+        return events
+
+    def _emit_block_events(
+        self, emitted: np.ndarray, phase: np.ndarray,
+        old_pos: np.ndarray, new_done: np.ndarray, version: int = 0,
+    ) -> List[StepEvent]:
+        """`_emit_events` where a forward does not yield one token: of
+        a slot's k forwards (`phase` [B, k]: 0 a done row's, 1 a
+        denoising forward, 2 a commit) each COMMIT hands over its
+        block's ids (`emitted` [B, k, block]), in order, less the
+        prompt's own at the head of a request's first block and those
+        past its limit in its last. A dispatch in which a slot
+        committed nothing leaves no event for it."""
+        block = self._block
+        commits = phase == 2
+        alive = phase > 0
+        # the block each forward ran: `old_pos` plus a block a commit
+        # before it
+        start = old_pos[:, None] + block * (
+            np.cumsum(commits, axis=1) - commits
+        )
+        events: List[StepEvent] = []
+        n_tokens = 0
+        for slot in range(self.n_slots):
+            req = self.slot_req[slot]
+            if req is None or req.done:
+                continue
+            p, limit = len(req.prompt), int(self.limit[slot])
+            new_toks: List[int] = []
+            for f in np.flatnonzero(commits[slot]):
+                s0 = int(start[slot, f])
+                new_toks.extend(
+                    emitted[slot, f, max(p - s0, 0): limit - s0].tolist()
+                )
+            req.out.extend(new_toks)
+            n_tokens += len(new_toks)
+            if new_toks:
+                req.versions.add(version)
+            finished = bool(new_done[slot])
+            if finished:
+                req.done = True
+                self._release_slot_pages(slot)
+            if new_toks or finished:
+                events.append((req.idx, new_toks, finished))
+        idxs = np.fromiter(
+            (-1 if r is None else r.idx for r in self.slot_req),
+            np.int64, self.n_slots,
+        )
+        occupied = (idxs >= 0)[:, None]
+        live = alive & occupied
+        self._diff = dict(
+            diff_forwards=int(live.sum()),
+            diff_commits=int((commits & occupied).sum()),
+            diff_tokens=n_tokens,
+            # a live forward reads its slot's cells up to its block's
+            # end, in every layer
+            diff_cells=int(
+                self.cfg.n_layers * ((start + block) * live).sum()
+            ),
+        )
+        self._diff_forwards_total += self._diff["diff_forwards"]
+        self._diff_tokens_total += n_tokens
+        if self.record_blocks:
+            self.block_log.append((idxs, start, phase, emitted))
+        self._settle_done(new_done, None)
+        return events
+
+    def block_trajectories(self) -> Dict[int, List[tuple]]:
+        """What `record_blocks` kept, a request: idx -> its forwards in
+        order, each (the block's first position, phase 1 or 2, the
+        block's `emitted` row: the ids a denoising forward unmasked
+        (-1 elsewhere), or a commit's ids)."""
+        out: Dict[int, List[tuple]] = {}
+        for idxs, start, phase, emitted in self.block_log:
+            for slot in np.flatnonzero(idxs >= 0):
+                rows = out.setdefault(int(idxs[slot]), [])
+                for f in np.flatnonzero(phase[slot]):
+                    rows.append((
+                        int(start[slot, f]), int(phase[slot, f]),
+                        emitted[slot, f].tolist(),
+                    ))
+        return out
+
+    def _settle_done(
+        self, new_done: np.ndarray, pf_mask: Optional[np.ndarray]
+    ) -> None:
+        """The done mirror after a dispatch's events."""
         self.done = new_done
         # a cancel that landed while this dispatch was in flight set
         # the mirror before the dispatch's (older) done could overwrite
@@ -3715,7 +4069,6 @@ class ContinuousBatcher:
                 # this the scheduler would re-admit over a
                 # mid-prefill (or awaiting-export) slot
                 self.done[slot] = False
-        return events
 
     def retire(self, idx: int) -> np.ndarray:
         """Drop a request from the ledger and return its continuation
@@ -3877,6 +4230,9 @@ class ContinuousBatcher:
         self.done[:] = True
         self.slot_key[:] = 0
         self.adapt[:] = 0
+        self.blk[:] = 0
+        self.msk[:] = False
+        self.block_log = []
         # mid-prefill lifecycle state dies with the slots (the stall
         # and chunk counters survive: they are cumulative telemetry)
         self._prefilling[:] = False

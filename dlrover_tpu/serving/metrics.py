@@ -98,6 +98,7 @@ class ServingMetrics:
             "_paged_swap_preemptions",
             "_paged_swap_resumes",
             "_moe_held_pairs_share",
+            "_diffusion_tokens_per_forward",
             "_kv_tier_bytes",
             "_kv_tier_capacity",
             "_kv_tier_entries",
@@ -218,6 +219,7 @@ class ServingMetrics:
         self._paged_swap_preemptions = 0
         self._paged_swap_resumes = 0
         self._moe_held_pairs_share = 0.0
+        self._diffusion_tokens_per_forward = 0.0
         self._kv_tier_bytes = 0
         self._kv_tier_capacity = 0
         self._kv_tier_entries = 0
@@ -516,6 +518,9 @@ class ServingMetrics:
             )
             self._moe_held_pairs_share = float(
                 stats.get("moe_held_pairs_share", 0.0)
+            )
+            self._diffusion_tokens_per_forward = float(
+                stats.get("diffusion_tokens_per_forward", 0.0)
             )
 
     def update_kv_tier(self, stats: Dict[str, float]):
@@ -1374,6 +1379,13 @@ class ServingMetrics:
                 "share on the experts this replica holds (0: it holds "
                 "them all, or has none).",
                 self._moe_held_pairs_share,
+            )
+            gauge(
+                "serving_diffusion_tokens_per_forward",
+                "Ids handed to streams over the live slot-forwards "
+                "that made them, where the model generates by "
+                "diffusion over blocks (0: one token a forward).",
+                self._diffusion_tokens_per_forward,
             )
             gauge(
                 "serving_kv_tier_bytes",

@@ -637,6 +637,124 @@ def test_gigachat3_largest_prefill_fits_the_chip(chip, monkeypatch):
     assert used + 10**9 < V5E_HBM_BYTES, used
 
 
+def _sdar_served():
+    """The SDAR configuration as the benchmark serves it (the
+    published widths, 6 layers with all 128 experts, 96 slots x 2048
+    positions, 16-cell pages, blocks of 4): (cfg, params, pool, slots,
+    max_len, chunk, steps), all shapes only."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import _sdar_tiny as tiny
+    from dlrover_tpu.models import decode, llama
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "sdar-30b-a3b-chat.serve-1chip.json",
+    )
+    with open(path) as f:
+        served = json.load(f)
+    run, gen = served["run"], served["generation"]
+    slots, max_len = run["n_slots"], run["max_len"]
+    cfg = tiny.published_config(served["num_hidden_layers"])
+    assert (cfg.vocab_size, cfg.block_length, cfg.mask_token_id) == (
+        served["vocab_size"], gen["block_length"], gen["mask_token_id"])
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    pool = jax.eval_shape(
+        lambda: decode.init_page_pool(
+            cfg, slots * (max_len // PAGE) + 1, PAGE)
+    )
+    return (cfg, params, pool, slots, max_len, run["chunk"],
+            gen["denoising_steps"])
+
+
+def test_block_paged_decode(chip):
+    """The one paged walk with a diffusion block's queries as further
+    heads: 4 positions x 32 heads over 4 K/V heads, one length a slot,
+    under its own name."""
+    cell = (3, 96 * 128 + 1, PAGE, 4, 128)
+    pool = {"k": S(cell, jnp.bfloat16), "v": S(cell, jnp.bfloat16)}
+    text = _compile(
+        chip,
+        functools.partial(
+            pa.paged_attention, impl="kernel", layer=2, block=4),
+        S((96, 4, 32, 128), jnp.bfloat16), pool, S((96, 128), jnp.int32),
+        S((96,), jnp.int32),
+    )
+    assert "paged_attention_decode_block" in text
+
+
+def test_flash_forward_with_a_block_mask(chip):
+    text = _compile(
+        chip,
+        functools.partial(fa.flash_attention, block=4),
+        S((1, 512, 32, 128), jnp.bfloat16),
+        S((1, 512, 4, 128), jnp.bfloat16),
+        S((1, 512, 4, 128), jnp.bfloat16),
+    )
+    assert "flash_attention_fwd" in text
+
+
+def test_sdar_chunk_program_fits_the_chip(chip, monkeypatch):
+    """The diffusion chunk program (8 forwards) at the published
+    widths and the served depth: the block's paged call and the
+    experts' grouped kernels inside, no copy of the pool or of a
+    layer's experts, and arguments + temporaries under the chip's
+    memory with 1 GB to spare."""
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pool, slots, max_len, chunk, steps = _sdar_served()
+    i32 = S((slots,), jnp.int32)
+    program = engine._build_diffusion_program(cfg, steps)
+    args = _on_chip(chip, (
+        pool, S((slots, max_len // PAGE), jnp.int32), params,
+        S((slots, cfg.block_length), jnp.int32),
+        S((slots, cfg.block_length), jnp.bool_),
+        i32, S((slots,), jnp.bool_), i32,
+    ))
+    compiled = program["paged"].lower(*args, chunk).compile()
+    text = compiled.as_text()
+    for kernel in ("paged_attention_decode_block", "moe_grouped_gate_up",
+                   "moe_grouped_down"):
+        assert kernel in text
+    moved = _moved_whole(text, {
+        tuple(pool["k"].shape),
+        tuple(params["layers"]["we_gate"].shape),
+        tuple(params["layers"]["we_down"].shape),
+    })
+    assert not moved, moved
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
+
+
+def test_sdar_largest_prefill_fits_the_chip(chip, monkeypatch):
+    """The admission program of the largest prompt bucket the cell's
+    prompts touch (512 tokens) beside the resident pool: the flash
+    forward under the block mask and the grouped kernels inside."""
+    from dlrover_tpu.serving import engine
+
+    monkeypatch.setattr(fa, "force_kernels", lambda: True)
+    cfg, params, pool, slots, max_len, _, _ = _sdar_served()
+    program = engine._build_admit_programs(cfg, max_len)["paged_cold"]
+    args = _on_chip(chip, (
+        pool, S((slots, max_len // PAGE), jnp.int32), params,
+        S((512,), jnp.int32), S((), jnp.int32),
+        S((max_len // PAGE,), jnp.int32),
+    ))
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "moe_grouped_gate_up" in text
+    assert not _moved_whole(text, {tuple(pool["k"].shape)})
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used + 10**9 < V5E_HBM_BYTES, used
+
+
 def test_window_paged_decode(chip):
     """The paged kernel with a static window over a ring of 66 pages."""
     cell = (3, 64 * 66 + 1, PAGE, 4, 128)

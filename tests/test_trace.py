@@ -329,6 +329,56 @@ class TestEngineSpans:
         steps = {r[ID] for r in _named("engine.step")}
         assert all(r[PARENT] in steps for r in admits + dispatches)
 
+    def test_a_block_diffusion_step_carries_its_counts(self):
+        """Where the model generates by diffusion over blocks the
+        `engine.step` span carries, from the harvested dispatch: the
+        live slot-forwards, the commits among them, the ids handed to
+        streams and the K/V cells the forwards read (block end x
+        layers a live forward), beside the experts' counts with the
+        meanings they have (`moe_steps` the forwards)."""
+        import os
+
+        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+        import _sdar_tiny as tiny
+
+        model = tiny.model_dict()
+        cfg, params = tiny.config(model), tiny.params(model)
+        eng = ContinuousBatcher(
+            cfg, params, n_slots=2, max_len=64, max_new_tokens=16,
+            chunk=4, pad_id=-1, kv_layout="paged", page_size=8,
+            denoising_steps=2, async_depth=0,
+        )
+        eng.submit(_prompts((8,), seed=4)[0][:8], max_new=8)
+        while eng.has_work():
+            eng.step()
+        steps = [r[COUNTS] for r in _named("engine.step")]
+        assert [s["diff_forwards"] for s in steps] == [4, 2]
+        assert [s["diff_commits"] for s in steps] == [1, 1]
+        assert [s["diff_tokens"] for s in steps] == [4, 4]
+        # 2 layers; the block 8..11 three forwards, 12..15 three
+        assert [s["diff_cells"] for s in steps] == [
+            2 * (12 * 3 + 16), 2 * (16 * 2)]
+        assert [s["moe_steps"] for s in steps] == [4, 2]
+        # every slot's 4 positions route top-2 in 2 layers a forward
+        assert [s["moe_pairs"] for s in steps] == [
+            4 * 2 * 4 * 2 * 2, 2 * 2 * 4 * 2 * 2]
+        assert steps[0]["live_tokens"] == 12 and steps[0]["alive"] == 1
+        dispatch = [r[COUNTS]["chunk"] for r in _named("engine.dispatch")]
+        assert dispatch == [4, 2]
+        assert all("diff_forwards" not in r[COUNTS]
+                   for r in _named("engine.admit"))
+
+    def test_other_models_steps_carry_no_block_counts(self, model):
+        cfg, params = model
+        eng = _engine(cfg, params)
+        eng.submit(_prompts((5,), seed=5)[0])
+        while eng.has_work():
+            eng.step()
+        assert all(
+            not any(k.startswith("diff_") for k in r[COUNTS])
+            for r in _named("engine.step")
+        )
+
     def test_a_full_ring_keeps_no_engine_alive(self, model):
         cfg, params = model
         eng = _engine(cfg, params)
