@@ -1280,6 +1280,7 @@ def _paged_view(
 def _write_pages_and_attend(
     q, k, v, pool, layer, table, positions, head_dim, mesh=None,
     attn_impl: str = "auto", window: int = 0, block: int = 0,
+    carried=None,
 ):
     """The paged counterpart of `_write_cache_and_attend`, on the
     STACKED pool and a traced layer index: scatter this chunk's K/V
@@ -1301,7 +1302,12 @@ def _write_pages_and_attend(
     `block` > 0 (a block-diffusion model): the chunk is ONE block of
     `block` positions a slot, starting on a block boundary; every
     query of it sees the pool up to the end of the block, its own
-    keys, just written, among them (`_attend_block`)."""
+    keys, just written, among them (`_attend_block`). With `carried`
+    ([B] bool) the chunk is TWO blocks a slot: the finished block
+    before it, run once more so that its final keys and values land
+    in its cells, and then the block. Where `carried[b]` is false the
+    slot carries nothing and its first `block` rows are dead: they
+    write to the trash page."""
     q = constrain(q, mesh, None, None, SERVING_TP_AXIS, None)
     k = constrain(k, mesh, None, None, SERVING_TP_AXIS, None)
     v = constrain(v, mesh, None, None, SERVING_TP_AXIS, None)
@@ -1320,6 +1326,10 @@ def _write_pages_and_attend(
         )
     else:
         pids = jnp.take_along_axis(table, positions // ps, axis=1)
+    if carried is not None:
+        dead = ~carried[:, None] & (
+            jnp.arange(positions.shape[1]) < block)[None, :]
+        pids = jnp.where(dead, 0, pids)
     offs = positions % ps
     out_pool = dict(pool)
     if "k_scale" in pool:
@@ -1385,22 +1395,23 @@ def _attend_block(
 ):
     """One diffusion block a slot over the paged pool: q `[B, block,
     H, hd]` at positions start .. start + block - 1, every query
-    seeing the cells 0 .. start + block - 1. With one length a slot
-    the block's queries are further query rows of their K/V head, so
-    the paged walk takes them as `block * H` heads
-    (`paged_attention(..., block=)`: the kernel on the chip, the
-    gathered view under the same mask off it or where the kernel
-    refuses the shapes)."""
+    seeing the cells 0 .. start + block - 1; or `[B, 2 * block, H,
+    hd]`, the carried block before it first, whose queries see the
+    cells before `start`. With one length a slot the queries are
+    further query rows of their K/V head, so the paged walk takes
+    them as `S * H` heads, the carried block's a group of rows one
+    block shorter (`paged_attention(..., block=)`: the kernel on the
+    chip, the gathered view under the same mask off it or where the
+    kernel refuses the shapes)."""
     from dlrover_tpu.ops import paged_attention as pa
 
-    if q.shape[1] != block or "k_scale" in pool:
+    if "k_scale" in pool:
         raise NotImplementedError(
-            f"a block-diffusion model runs {block} queries a slot over "
-            "an unquantized pool"
+            "a block-diffusion model is served from an unquantized pool"
         )
     with jax.named_scope("attn_block"):
         return pa.paged_attention(
-            q, pool, table, positions[:, 0] + block,
+            q, pool, table, positions[:, -1] + 1,
             scale=float(head_dim) ** -0.5,
             impl="reference" if attn_impl == "reference" else "auto",
             layer=layer, block=block,
@@ -1409,12 +1420,13 @@ def _attend_block(
 
 def _block_paged(
     cfg, x, layer_params, pool, layer, table, positions, mesh=None,
-    lora=None, kind=None, abs_layer=None, experts=None,
+    lora=None, kind=None, abs_layer=None, experts=None, carried=None,
 ):
     """Llama block over paged KV — identical projections/residuals to
     `_block` (including the per-slot `lora` deltas); only the cache
     write + view differ. `pool` is the whole stacked pool and `layer`
-    this block's (traced) index into it."""
+    this block's (traced) index into it. `carried`: see
+    `_write_pages_and_attend`."""
     lp = _compute_weights(cfg, layer_params)
     tp = _mesh_tp(mesh)
     with jax.named_scope("attn" if kind is None else "attn_" + kind):
@@ -1428,7 +1440,7 @@ def _block_paged(
             mesh=mesh,
             attn_impl=getattr(cfg, "attn_impl", "auto"),
             window=_window_of(cfg, kind),
-            block=_block_of(cfg),
+            block=_block_of(cfg), carried=carried,
         )
         x = _attn_residual(cfg, None, x, attn, lp, lora=lora, tp=tp)
     with jax.named_scope("mlp"):
@@ -1460,7 +1472,7 @@ def _block_gpt_paged(
 
 def _forward_paged(
     cfg, params, tokens, pool, table, positions, mesh=None,
-    adapters=None, table_win=None,
+    adapters=None, table_win=None, carried=None,
 ):
     """tokens [B, S] → logits [B, S, V] over the paged pool. The
     pool goes through the layer scan as a CARRY, whole and stacked
@@ -1476,7 +1488,13 @@ def _forward_paged(
     `table_win`, the slots' rings. A layer's index into its class's
     pool is its rank among the layers of its kind. Where the experts
     are routed without dropping, a third value comes back: int32[E],
-    the routed pairs per expert summed over the layers."""
+    the routed pairs per expert summed over the layers.
+
+    `carried` ([B] bool, a block-diffusion model's): the tokens are
+    two blocks a slot, the finished block before it carried for its
+    keys and values (`_write_pages_and_attend`) and then the block.
+    The carried rows run the layers and nothing after them: logits
+    [B, block, V], the block's alone."""
     _check_adapters(cfg, adapters)
     gpt = _is_gpt(cfg)
     if gpt:
@@ -1522,6 +1540,8 @@ def _forward_paged(
                     adapters["scale"],
                 )
             kw = dict(kind=kind, abs_layer=first + j, experts=experts)
+            if carried is not None:
+                kw["carried"] = carried
             if classed:
                 out = block(
                     cfg, h, _place(cfg, period_params, j), pool[kind],
@@ -1562,6 +1582,8 @@ def _forward_paged(
     carry0 = (x, pool, counts0)
     with jax.named_scope("layers"):
         (x, pool_new, counts), _ = jax.lax.scan(body, carry0, xs)
+    if carried is not None:
+        x = x[:, -_block_of(cfg):]
     if gpt:
         from dlrover_tpu.models.gpt import _layer_norm
 

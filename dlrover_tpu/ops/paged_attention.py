@@ -171,7 +171,9 @@ def _reference(q, pages, table, lengths, scale, layer=None, window=None):
     OP-FOR-OP identical to models/decode.py::_cached_attention (same
     grouped einsum, same mask, same softmax axis) so the paged engine
     can be byte-compared against the dense oracle. q: [B, H, hd],
-    single decode query per row at position lengths-1."""
+    single decode query per row at position lengths-1 (`lengths`
+    [B], or [B, H] where a row's heads see different lengths: a
+    diffusion block's rows beside a carried block's)."""
     view = gather_pages(pages, table, layer)
     k_cache, v_cache = view["k"], view["v"]
     if "k_scale" in view:
@@ -191,7 +193,10 @@ def _reference(q, pages, table, lengths, scale, layer=None, window=None):
         preferred_element_type=jnp.float32,
     ) * scale
     cols = jnp.arange(m)[None, None, None, None, :]
-    rows = (lengths - 1)[:, None, None, None, None]
+    if lengths.ndim == 2:
+        rows = (lengths - 1).reshape(b, kv, n_rep, 1, 1)
+    else:
+        rows = (lengths - 1)[:, None, None, None, None]
     scores = jnp.where(cols <= rows, scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkrsm,bmkd->bskrd", p, v_cache)
@@ -325,9 +330,13 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
                   k_buf, v_buf, sems, turn,
                   m_scr, l_scr, acc_scr, s_scr, pv_scr,
                   *, scale, page_size, per_block, window=None,
-                  latent=None):
+                  latent=None, carried=None):
     """Grid (B,): one invocation attends query row b, every KV head of
     it, over the pages its length covers, `per_block` pages at a time.
+
+    `carried` (static: (rows, cells)) gives the first `rows` query
+    rows of every KV head's group a length `cells` shorter: a
+    diffusion block carried beside the next one, in the same walk.
 
     `latent` (static: the latent's rank) is the LATENT variant of the
     same walk: the pool's one leaf holds a row a token that is both
@@ -429,7 +438,12 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
 
     first, pages = walk(b)
     blocks = (pages + per_block - 1) // per_block
-    length = len_ref[b]
+    length = reach = len_ref[b]
+    if carried is not None:
+        # the cells a query row sees, [H, 1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (kv * n_rep, 1), 0)
+        reach = length - jnp.where(
+            row % n_rep < carried[0], carried[1], 0)
     buf0 = turn[0]
     # nobody has started the very first block; and a slot with no
     # block starts the next slot's first in its stead
@@ -459,7 +473,7 @@ def _paged_kernel(layer_ref, table_ref, len_ref,  # scalar prefetch
         copies(b, j, cur, False)
         at = (first + j * per_block) * page_size
         pos = at + jax.lax.broadcasted_iota(jnp.int32, (1, cells), 1)
-        live = pos < length
+        live = pos < reach
         if window is not None:
             # the oldest page's cells that have left the window
             live = live & (pos >= length - window)
@@ -550,18 +564,21 @@ def _block_rows_back(o, s: int, kv: int):
     return o.transpose(0, 2, 1, 3, 4).reshape(b, s, sh // s, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "block"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "window", "block", "carried"))
 def _kernel(q, pages, layer, table, lengths, scale, window=None,
-            block=False):
+            block=0, carried=0):
     """q [B, H, hd] → [B, H, hd] over layer `layer` (int32[1]) of the
-    stacked pool. (Jitted so that the engine's chunk programs, one a
-    chunk length, and a period's layers of one kind trace and lower
-    the body once between them: set-up time.) The layer index, the
-    page table and lengths ride as scalar-prefetch operands; the
-    pool's leaves are handed over whole and stay in HBM (`pl.ANY`),
-    so the body's copies dereference (layer[0], table[b, p]) and
-    stream the PHYSICAL pages of that layer out of the pool — never a
-    sliced or gathered copy. q travels group-major ([B, KV, n_rep,
+    stacked pool. `block` > 0: the rows are a diffusion block's
+    (`block_rows`), of which the first `carried` of every KV head's
+    see `block` fewer cells. (Jitted so that the engine's chunk
+    programs, one a chunk length, and a period's layers of one kind
+    trace and lower the body once between them: set-up time.) The
+    layer index, the page table and lengths ride as scalar-prefetch
+    operands; the pool's leaves are handed over whole and stay in HBM
+    (`pl.ANY`), so the body's copies dereference (layer[0],
+    table[b, p]) and stream the PHYSICAL pages of that layer out of
+    the pool — never a sliced or gathered copy. q travels group-major ([B, KV, n_rep,
     hd]): one KV head's query rows are one leading index. An int8
     pool's scales are the one thing gathered (`_walk_scales`: Mosaic
     copies no page whose last dim is not whole lane tiles, and theirs
@@ -592,6 +609,7 @@ def _kernel(q, pages, layer, table, lengths, scale, window=None,
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size,
         per_block=per_block, window=window,
+        carried=(carried, block) if carried else None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -879,14 +897,21 @@ def paged_attention(
 
     `block` (static) > 0: `q` is `[B, block, H, hd]`, ONE diffusion
     block a row, whose queries all see `lengths` cells (the block's
-    end). They ride the same walk as `block * H` heads over the pool's
-    K/V heads (`block_rows`); on the chip the call is named
+    end), or `[B, 2 * block, H, hd]`: the block before it, CARRIED
+    for its keys and values, and then the block. The carried block's
+    queries see `lengths - block` cells (their own block's end): a
+    second group of rows whose length is one block shorter. Both ride
+    the same walk, once, as `S * H` heads over the pool's K/V heads
+    (`block_rows`); on the chip the call is named
     `paged_attention_decode_block`, so that a trace tells it apart."""
     if block:
-        if window is not None or mesh is not None or q.shape[1] != block:
+        s = q.shape[1]
+        if window is not None or mesh is not None or s not in (
+            block, 2 * block
+        ):
             raise ValueError(
                 "a block of queries has no window, no mesh and "
-                f"{block} positions"
+                f"{block} positions, or {2 * block} with a carried block"
             )
         kv = pages["k"].shape[-2]
         rows = block_rows(q, kv)
@@ -895,13 +920,22 @@ def paged_attention(
         if impl == "reference" or (
             impl == "auto" and not use_kernel(rows, pages, table)
         ):
+            if s > block:
+                # a length a query row, in `block_rows`' order
+                short = block * (jnp.arange(s) < s - block)
+                lengths = jnp.broadcast_to(
+                    lengths[:, None, None, None]
+                    - short[None, None, :, None],
+                    (q.shape[0], kv, s, q.shape[2] // kv),
+                ).reshape(rows.shape[:2])
             out = _reference(rows, pages, table, lengths, scale, layer)
         else:
             pages, layer = _stacked(pages, layer)
             out = _kernel(
-                rows, pages, layer, table, lengths, scale, block=True
+                rows, pages, layer, table, lengths, scale, block=block,
+                carried=(s - block) * (q.shape[2] // kv),
             )
-        return _block_rows_back(out, block, kv)
+        return _block_rows_back(out, s, kv)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     from dlrover_tpu.parallel.mesh import serving_mesh_tp

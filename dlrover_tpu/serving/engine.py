@@ -370,9 +370,10 @@ def _paged_step_takes_kernel(cfg, n_slots, pool, table, mesh) -> bool:
             jax.ShapeDtypeStruct(tuple(table.shape), jnp.int32),
             cfg.kv_lora_rank,
         )
-    # a diffusion block's queries ride the walk as further heads
+    # a diffusion block's queries, and the carried block's before
+    # them, ride the walk as further heads
     probe_q = jax.ShapeDtypeStruct(
-        (n_slots, cfg.n_heads * max(_block_of(cfg), 1), cfg.head_dim),
+        (n_slots, cfg.n_heads * max(2 * _block_of(cfg), 1), cfg.head_dim),
         cfg.dtype,
     )
     if "full" in pool:
@@ -564,44 +565,63 @@ def _build_chunk_program(
 
 
 def _diffusion_scan(
-    cfg, steps, pool, params, blk, msk, pos, done, limit, k, table
+    cfg, steps, pool, params, blk, msk, prev, pos, done, limit, k, table
 ):
     """The decode loop of a model that generates by diffusion over
     blocks: k FORWARDS over every slot, each slot at its own phase of
     its own block, no host round trip inside. A slot's state is its
     block: `blk` [B, block] the ids (the mask id where still masked),
     `msk` [B, block] which positions are masked, `pos` [B] the block's
-    first position (a multiple of the block length). One forward runs
-    the block's positions over the paged pool (`_forward_paged`: the
-    block's own keys and values are written into its cells, every
-    query sees the pool up to the block's end) and then, a slot:
+    first position (a multiple of the block length), and `prev`
+    [B, block] the ids of the block before it while that block's keys
+    and values are still owed to the pool (-1: nothing is owed).
 
-      DENOISES while a position is masked: every masked position
-      takes its arg-max id and its confidence (that id's softmax
-      probability), and the ceil(block / steps) most confident masked
-      positions are unmasked, ties to the lower position
-      (`low_confidence_static`);
-      COMMITS when none is: the keys and values the forward has just
-      stored are the block's final ones, the block's ids are emitted,
-      and the slot moves one block on, all masked, or is done where
-      the next block starts at or past its limit (its position then
-      stays, so a done row's frozen rewrites land in cells it owns).
+    A block takes `steps` forwards and no more. One forward runs TWO
+    blocks' positions a slot over the paged pool (`_forward_paged`
+    with `carried`): `prev`, carried for its keys and values only,
+    which sees the pool up to its own end, and the block, which sees
+    the pool, the carried rows' cells written in this very forward,
+    and itself. The carried rows' inputs depend on nothing but the
+    pool and themselves, so what they store is what the published
+    loop's commit forward stores, and they stop before the head. A
+    slot that owes nothing (a block's later forwards; a request's
+    first block, whose earlier cells the prefill installed; a done
+    row) carries dead rows, which write to the trash page. Then, a
+    slot, the forward DENOISES: every masked position takes its
+    arg-max id and its confidence (that id's softmax probability),
+    and the ceil(block / steps) most confident masked positions are
+    unmasked, ties to the lower position (`low_confidence_static`).
+    Where that leaves no position masked the block's ids are FINAL:
+    they are emitted at once, and the slot moves one block on, all
+    masked, owing the finished block to its next forward; or it is
+    done, where the next block starts at or past its limit (nothing
+    reads a request's last block's cells; its position then stays,
+    so a done row's frozen rewrites land in cells it owns).
 
-    Returns (pool, blk, msk, pos, done, emitted [B, k, block], phase
-    [B, k], routed pairs per expert). `phase` is 0 for a done row, 1
-    for a denoising forward (`emitted`: the ids unmasked, -1
-    elsewhere), 2 for a commit (`emitted`: the block's ids)."""
+    Returns (pool, blk, msk, prev, pos, done, took [B, k, block], ids
+    [B, k, block], phase [B, k], fused [B, k], routed pairs per
+    expert). A forward: `took` the ids it unmasked, -1 elsewhere;
+    `ids` the block after it; `phase` 0 for a done row, 1 for a
+    forward that left the block unfinished, 2 for one that finished
+    it; `fused` whether it carried a finished block."""
     block = cfg.block_length
     count = -(-block // steps)
     table = jnp.where(done[:, None], 0, table)
     offs = jnp.arange(block, dtype=jnp.int32)
+    both = jnp.concatenate([offs - block, offs])
     before = offs[None, :] < offs[:, None]        # [i, j]: j lower than i
     mask_id = jnp.int32(cfg.mask_token_id)
 
     def body(carry, _):
-        pool, blk, msk, pos, done, pairs = carry
+        pool, blk, msk, prev, pos, done, pairs = carry
+        fused = (prev[:, 0] >= 0) & ~done
         logits, pool, *counts = _forward_paged(
-            cfg, params, blk, pool, table, pos[:, None] + offs[None, :]
+            cfg, params,
+            jnp.concatenate([jnp.maximum(prev, 0), blk], axis=1),
+            pool, table,
+            # a dead row's positions before the first block: anywhere
+            jnp.maximum(pos[:, None] + both[None, :], 0),
+            carried=fused,
         )
         with jax.named_scope("diffusion_unmask"):
             top = jnp.max(logits, axis=-1)
@@ -616,33 +636,33 @@ def _diffusion_scan(
                 msk & (jnp.sum(ahead, axis=-1) < count) & ~done[:, None]
             )
         with jax.named_scope("diffusion_commit"):
-            commit = ~jnp.any(msk, axis=-1) & ~done
-            fin = commit & (pos + block >= limit)
-            on = (commit & ~fin)[:, None]
-            emitted = jnp.where(
-                commit[:, None], blk, jnp.where(take, best, -1)
-            )
-            phase = jnp.where(done, 0, jnp.where(commit, 2, 1))
-            blk = jnp.where(on, mask_id, jnp.where(take, best, blk))
-            msk = jnp.where(on, True, msk & ~take)
+            ids = jnp.where(take, best, blk)
+            left = msk & ~take
+            final = ~jnp.any(left, axis=-1) & ~done
+            last = final & (pos + block >= limit)
+            on = (final & ~last)[:, None]
+            took = jnp.where(take, best, -1)
+            phase = jnp.where(done, 0, jnp.where(final, 2, 1))
+            prev = jnp.where(on, ids, -1)
+            blk = jnp.where(on, mask_id, ids)
+            msk = on | left
             pos = jnp.where(on[:, 0], pos + block, pos)
-            done = done | fin
+            done = done | last
         if counts and pairs is not None:
             pairs = pairs + counts[0]
-        return (pool, blk, msk, pos, done, pairs), (
-            emitted, phase.astype(jnp.int8)
+        return (pool, blk, msk, prev, pos, done, pairs), (
+            took, ids, phase.astype(jnp.int8), fused
         )
 
     pairs = (
         jnp.zeros(moe_counts_shape(cfg), jnp.int32)
         if _dropless(cfg) else None
     )
-    (pool, blk, msk, pos, done, pairs), (emitted, phase) = jax.lax.scan(
-        body, (pool, blk, msk, pos, done, pairs), None, length=k,
+    (pool, blk, msk, prev, pos, done, pairs), per_forward = jax.lax.scan(
+        body, (pool, blk, msk, prev, pos, done, pairs), None, length=k,
     )
-    out = (
-        pool, blk, msk, pos, done,
-        jnp.swapaxes(emitted, 0, 1), phase.T,
+    out = (pool, blk, msk, prev, pos, done) + tuple(
+        jnp.swapaxes(x, 0, 1) for x in per_forward
     )
     return out if pairs is None else out + (pairs,)
 
@@ -654,11 +674,13 @@ def _build_diffusion_program(cfg, steps):
     parameter this family has (one chip: `_refuse_unserved`)."""
     scan = partial(_diffusion_scan, cfg, steps)
 
-    @partial(jax.jit, donate_argnums=(0,), static_argnums=(8,))
+    @partial(jax.jit, donate_argnums=(0,), static_argnums=(9,))
     def _run_chunk_blocks(
-        pool, table, params, blk, msk, pos, done, limit, k
+        pool, table, params, blk, msk, prev, pos, done, limit, k
     ):
-        return scan(pool, params, blk, msk, pos, done, limit, k, table)
+        return scan(
+            pool, params, blk, msk, prev, pos, done, limit, k, table
+        )
 
     return {"paged": _run_chunk_blocks}
 
@@ -1032,13 +1054,15 @@ def _state_admit_prog(tok, pos, done, limit, keys,
 
 
 @jax.jit
-def _state_admit_block_prog(blk, msk, pos, done, limit,
+def _state_admit_block_prog(blk, msk, prev, pos, done, limit,
                             slot, blk_v, msk_v, pos_v, limit_v):
     """`_state_admit_prog` for a slot whose state is a diffusion
-    block."""
+    block. A request's first block owes the pool nothing: the
+    prefill installed every cell before it."""
     return (
         blk.at[slot].set(blk_v),
         msk.at[slot].set(msk_v),
+        prev.at[slot].set(-1),
         pos.at[slot].set(pos_v),
         done.at[slot].set(False),
         limit.at[slot].set(limit_v),
@@ -1480,8 +1504,9 @@ class ContinuousBatcher:
         self._latent_cells = 0
         self._window_freed_this_step = 0
         # a block-diffusion model: the harvested dispatch's live
-        # slot-forwards, commits, ids handed to streams and K/V cells
-        # read; their running totals (/metrics); and, where
+        # slot-forwards, those that carried a finished block, blocks
+        # finished, ids handed to streams and K/V cells read; their
+        # running totals (/metrics); and, where
         # `record_blocks` is on, every dispatch's raw record
         # (`block_trajectories`)
         self._diff = None
@@ -2033,6 +2058,11 @@ class ContinuousBatcher:
         if self._block:
             state["blk"] = self._replicate(jnp.asarray(self.blk))
             state["msk"] = self._replicate(jnp.asarray(self.msk))
+            # the finished block a slot's next forward carries (-1:
+            # none); it lives on the device alone
+            state["prev"] = self._replicate(
+                jnp.asarray(np.full_like(self.blk, -1))
+            )
         if self._adapter_cache is not None:
             # joins the resident state ONLY when adapters are on: the
             # adapterless _dev keeps its exact pre-adapter structure
@@ -2090,13 +2120,15 @@ class ContinuousBatcher:
     def _forwards_left(self) -> np.ndarray:
         """[B]: the forwards each slot's request still takes, where
         generation is by diffusion over blocks: its block's denoising
-        forwards and commit, then every further block's."""
+        forwards, then every further block's (a finished block's keys
+        and values ride with the next block's first forward, and
+        nothing reads the last block's)."""
         block = self._block
         count = -(-block // self._denoise_steps)
         further = -(-(self.limit - self.pos) // block) - 1
         return (
-            -(-self.msk.sum(axis=1) // count) + 1
-            + further * (-(-block // count) + 1)
+            -(-self.msk.sum(axis=1) // count)
+            + further * -(-block // count)
         )
 
     @property
@@ -2473,12 +2505,11 @@ class ContinuousBatcher:
             # the resume re-key is this same program, not a re-upload)
             d = self._dev
             if self._block:
-                d["blk"], d["msk"], d["pos"], d["done"], d["limit"] = (
-                    _state_admit_block_prog(
-                        d["blk"], d["msk"], d["pos"], d["done"],
-                        d["limit"], slot, self.blk[slot], self.msk[slot],
-                        int(self.pos[slot]), int(self.limit[slot]),
-                    )
+                (d["blk"], d["msk"], d["prev"], d["pos"], d["done"],
+                 d["limit"]) = _state_admit_block_prog(
+                    d["blk"], d["msk"], d["prev"], d["pos"], d["done"],
+                    d["limit"], slot, self.blk[slot], self.msk[slot],
+                    int(self.pos[slot]), int(self.limit[slot]),
                 )
             else:
                 d["tok"], d["pos"], d["done"], d["limit"], d["keys"] = (
@@ -3626,19 +3657,19 @@ class ContinuousBatcher:
         k = self._next_chunk_len()
         if self._block:
             with self._dispatch_span(chunk=k):
-                pool, blk, msk, pos, done, emitted, phase, *pairs = (
+                pool, blk, msk, prev, pos, done, *per_forward = (
                     self._run_chunk(
                         self.page_pool, self._table, self.params,
-                        d["blk"], d["msk"], d["pos"], d["done"],
-                        d["limit"], k,
+                        d["blk"], d["msk"], d["prev"], d["pos"],
+                        d["done"], d["limit"], k,
                     )
                 )
                 self.page_pool = pool
-                d.update(blk=blk, msk=msk, pos=pos, done=done)
+                d.update(blk=blk, msk=msk, prev=prev, pos=pos, done=done)
                 self._enqueue_fetch(
                     _Inflight(
                         kind="blocks",
-                        arrays=(msk, pos, done, emitted, phase, *pairs),
+                        arrays=(msk, pos, done, *per_forward),
                         dispatched_at=0.0,
                         old_pos=self.pos.copy(),
                         version=self._weight_version,
@@ -3867,13 +3898,14 @@ class ContinuousBatcher:
             self._stat_overlap_ms += hidden_s * 1e3
             self._stat_dispatches += 1
             if pend.kind == "blocks":
-                msk, pos, done, emitted, phase, *pairs = host
+                msk, pos, done, took, ids, phase, fused, *pairs = host
                 if pairs:
                     self._moe_pairs = pairs[0]
                     self._moe_steps = phase.shape[1]
                 self.msk, self.pos = msk, pos
                 return self._emit_block_events(
-                    emitted, phase, pend.old_pos, done, pend.version
+                    took, ids, phase, fused, pend.old_pos, done,
+                    pend.version,
                 )
             if pend.kind == "chunk":
                 tok, pos, done, keys, emitted, *pairs = host
@@ -3968,21 +4000,24 @@ class ContinuousBatcher:
         return events
 
     def _emit_block_events(
-        self, emitted: np.ndarray, phase: np.ndarray,
-        old_pos: np.ndarray, new_done: np.ndarray, version: int = 0,
+        self, took: np.ndarray, ids: np.ndarray, phase: np.ndarray,
+        fused: np.ndarray, old_pos: np.ndarray, new_done: np.ndarray,
+        version: int = 0,
     ) -> List[StepEvent]:
         """`_emit_events` where a forward does not yield one token: of
         a slot's k forwards (`phase` [B, k]: 0 a done row's, 1 a
-        denoising forward, 2 a commit) each COMMIT hands over its
-        block's ids (`emitted` [B, k, block]), in order, less the
-        prompt's own at the head of a request's first block and those
-        past its limit in its last. A dispatch in which a slot
-        committed nothing leaves no event for it."""
+        denoising forward that left its block unfinished, 2 one that
+        FINISHED it) each finishing one hands over its block's ids
+        (`ids` [B, k, block]: they are final then, though their keys
+        and values reach the pool with the slot's next forward), in
+        order, less the prompt's own at the head of a request's first
+        block and those past its limit in its last. A dispatch in
+        which a slot finished no block leaves no event for it."""
         block = self._block
         commits = phase == 2
         alive = phase > 0
-        # the block each forward ran: `old_pos` plus a block a commit
-        # before it
+        # the block each forward ran: `old_pos` plus a block a
+        # finished block before it
         start = old_pos[:, None] + block * (
             np.cumsum(commits, axis=1) - commits
         )
@@ -3997,7 +4032,7 @@ class ContinuousBatcher:
             for f in np.flatnonzero(commits[slot]):
                 s0 = int(start[slot, f])
                 new_toks.extend(
-                    emitted[slot, f, max(p - s0, 0): limit - s0].tolist()
+                    ids[slot, f, max(p - s0, 0): limit - s0].tolist()
                 )
             req.out.extend(new_toks)
             n_tokens += len(new_toks)
@@ -4017,6 +4052,8 @@ class ContinuousBatcher:
         live = alive & occupied
         self._diff = dict(
             diff_forwards=int(live.sum()),
+            # the live forwards that carried a finished block
+            diff_fused=int((fused & live).sum()),
             diff_commits=int((commits & occupied).sum()),
             diff_tokens=n_tokens,
             # a live forward reads its slot's cells up to its block's
@@ -4028,24 +4065,27 @@ class ContinuousBatcher:
         self._diff_forwards_total += self._diff["diff_forwards"]
         self._diff_tokens_total += n_tokens
         if self.record_blocks:
-            self.block_log.append((idxs, start, phase, emitted))
+            self.block_log.append((idxs, start, phase, took, ids))
         self._settle_done(new_done, None)
         return events
 
     def block_trajectories(self) -> Dict[int, List[tuple]]:
-        """What `record_blocks` kept, a request: idx -> its forwards in
-        order, each (the block's first position, phase 1 or 2, the
-        block's `emitted` row: the ids a denoising forward unmasked
-        (-1 elsewhere), or a commit's ids)."""
+        """What `record_blocks` kept, a request: idx -> rows in order,
+        (the block's first position, phase, ids). Every forward leaves
+        a phase-1 row, the ids it unmasked (-1 elsewhere); the forward
+        that finished a block leaves a phase-2 row after it, the
+        block's ids. So every block reads as its denoising rows and
+        then ONE row of what was committed, whichever forward stored
+        its keys and values."""
         out: Dict[int, List[tuple]] = {}
-        for idxs, start, phase, emitted in self.block_log:
+        for idxs, start, phase, took, ids in self.block_log:
             for slot in np.flatnonzero(idxs >= 0):
                 rows = out.setdefault(int(idxs[slot]), [])
                 for f in np.flatnonzero(phase[slot]):
-                    rows.append((
-                        int(start[slot, f]), int(phase[slot, f]),
-                        emitted[slot, f].tolist(),
-                    ))
+                    s0 = int(start[slot, f])
+                    rows.append((s0, 1, took[slot, f].tolist()))
+                    if phase[slot, f] == 2:
+                        rows.append((s0, 2, ids[slot, f].tolist()))
         return out
 
     def _settle_done(

@@ -89,6 +89,14 @@ def _census(compiled):
     )
 
 
+def _calls(text, kernel):
+    """The calls of a Pallas kernel by its name in a compiled
+    program's text (a loop's body counts once)."""
+    import re
+
+    return len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text))
+
+
 def _on_chip(chip, shapes):
     """A pytree of ShapeDtypeStructs, placed on the described chip."""
     return jax.tree_util.tree_map(
@@ -671,20 +679,23 @@ def _sdar_served():
             gen["denoising_steps"])
 
 
-def test_block_paged_decode(chip):
+@pytest.mark.parametrize("positions", [4, 8])
+def test_block_paged_decode(chip, positions):
     """The one paged walk with a diffusion block's queries as further
-    heads: 4 positions x 32 heads over 4 K/V heads, one length a slot,
-    under its own name."""
+    heads, under its own name: 4 positions x 32 heads over 4 K/V
+    heads, one length a slot; and the served loop's shape, the carried
+    block before it (2 x 4 x 32 = 256 query rows, two lengths: the
+    carried rows' one block shorter), in ONE call."""
     cell = (3, 96 * 128 + 1, PAGE, 4, 128)
     pool = {"k": S(cell, jnp.bfloat16), "v": S(cell, jnp.bfloat16)}
     text = _compile(
         chip,
         functools.partial(
             pa.paged_attention, impl="kernel", layer=2, block=4),
-        S((96, 4, 32, 128), jnp.bfloat16), pool, S((96, 128), jnp.int32),
-        S((96,), jnp.int32),
+        S((96, positions, 32, 128), jnp.bfloat16), pool,
+        S((96, 128), jnp.int32), S((96,), jnp.int32),
     )
-    assert "paged_attention_decode_block" in text
+    assert _calls(text, "paged_attention_decode_block") == 1
 
 
 def test_flash_forward_with_a_block_mask(chip):
@@ -699,11 +710,12 @@ def test_flash_forward_with_a_block_mask(chip):
 
 
 def test_sdar_chunk_program_fits_the_chip(chip, monkeypatch):
-    """The diffusion chunk program (8 forwards) at the published
-    widths and the served depth: the block's paged call and the
-    experts' grouped kernels inside, no copy of the pool or of a
-    layer's experts, and arguments + temporaries under the chip's
-    memory with 1 GB to spare."""
+    """The diffusion chunk program (8 forwards of two blocks a slot:
+    the carried one and the block) at the published widths and the
+    served depth: the block's paged call, ONE a layer, and the
+    experts' grouped kernels inside, the head over the block's rows
+    alone, no copy of the pool or of a layer's experts, and arguments
+    + temporaries under the chip's memory with 1 GB to spare."""
     from dlrover_tpu.serving import engine
 
     monkeypatch.setattr(fa, "force_kernels", lambda: True)
@@ -714,13 +726,21 @@ def test_sdar_chunk_program_fits_the_chip(chip, monkeypatch):
         pool, S((slots, max_len // PAGE), jnp.int32), params,
         S((slots, cfg.block_length), jnp.int32),
         S((slots, cfg.block_length), jnp.bool_),
+        S((slots, cfg.block_length), jnp.int32),
         i32, S((slots,), jnp.bool_), i32,
     ))
-    compiled = program["paged"].lower(*args, chunk).compile()
+    lowered = program["paged"].lower(*args, chunk)
+    logits = (slots, cfg.block_length, cfg.vocab_size)
+    assert f"tensor<{'x'.join(map(str, logits))}xf32>" in lowered.as_text()
+    assert f"tensor<{slots}x{2 * cfg.block_length}x{cfg.vocab_size}" not in (
+        lowered.as_text())
+    compiled = lowered.compile()
     text = compiled.as_text()
     for kernel in ("paged_attention_decode_block", "moe_grouped_gate_up",
                    "moe_grouped_down"):
-        assert kernel in text
+        # one call in the layer loop's body: the pool is walked once
+        # a forward and layer
+        assert _calls(text, kernel) == 1, kernel
     moved = _moved_whole(text, {
         tuple(pool["k"].shape),
         tuple(params["layers"]["we_gate"].shape),

@@ -332,10 +332,12 @@ class TestEngineSpans:
     def test_a_block_diffusion_step_carries_its_counts(self):
         """Where the model generates by diffusion over blocks the
         `engine.step` span carries, from the harvested dispatch: the
-        live slot-forwards, the commits among them, the ids handed to
-        streams and the K/V cells the forwards read (block end x
-        layers a live forward), beside the experts' counts with the
-        meanings they have (`moe_steps` the forwards)."""
+        live slot-forwards, those of them that carried a finished
+        block for its keys and values (`diff_fused`), the blocks
+        finished, the ids handed to streams and the K/V cells the
+        forwards read (block end x layers a live forward), beside the
+        experts' counts with the meanings they have (`moe_steps` the
+        forwards; a forward routes two blocks' positions a slot)."""
         import os
 
         sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -348,21 +350,23 @@ class TestEngineSpans:
             chunk=4, pad_id=-1, kv_layout="paged", page_size=8,
             denoising_steps=2, async_depth=0,
         )
-        eng.submit(_prompts((8,), seed=4)[0][:8], max_new=8)
+        eng.submit(_prompts((8,), seed=4)[0][:8], max_new=12)
         while eng.has_work():
             eng.step()
         steps = [r[COUNTS] for r in _named("engine.step")]
+        # three blocks of two forwards: dispatches of 4 and 2
         assert [s["diff_forwards"] for s in steps] == [4, 2]
-        assert [s["diff_commits"] for s in steps] == [1, 1]
-        assert [s["diff_tokens"] for s in steps] == [4, 4]
-        # 2 layers; the block 8..11 three forwards, 12..15 three
+        assert [s["diff_fused"] for s in steps] == [1, 1]
+        assert [s["diff_commits"] for s in steps] == [2, 1]
+        assert [s["diff_tokens"] for s in steps] == [8, 4]
+        # 2 layers; the blocks 8..11, 12..15 and 16..19 two forwards
         assert [s["diff_cells"] for s in steps] == [
-            2 * (12 * 3 + 16), 2 * (16 * 2)]
+            2 * (12 * 2 + 16 * 2), 2 * (20 * 2)]
         assert [s["moe_steps"] for s in steps] == [4, 2]
-        # every slot's 4 positions route top-2 in 2 layers a forward
+        # every slot's 2 x 4 positions route top-2 in 2 layers a forward
         assert [s["moe_pairs"] for s in steps] == [
-            4 * 2 * 4 * 2 * 2, 2 * 2 * 4 * 2 * 2]
-        assert steps[0]["live_tokens"] == 12 and steps[0]["alive"] == 1
+            4 * 2 * 8 * 2 * 2, 2 * 2 * 8 * 2 * 2]
+        assert steps[0]["live_tokens"] == 16 and steps[0]["alive"] == 1
         dispatch = [r[COUNTS]["chunk"] for r in _named("engine.dispatch")]
         assert dispatch == [4, 2]
         assert all("diff_forwards" not in r[COUNTS]
