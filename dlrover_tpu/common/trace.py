@@ -4,7 +4,11 @@ A span is a named stretch of host time at a layer boundary. Closing it
 appends one record to a process-wide bounded ring; while a
 `jax.profiler` session runs, the span also lies on the device trace's
 own clock as the TraceAnnotation ``dlrover:<name>``. An event is a
-record with no extent (a request's legs).
+record with no extent (a request's legs); `record()` leaves one that
+someone else timed, which is how every compilation gets here: once
+`watch_compiles()` is called, each leg of jax's compile path (a
+function traced, lowered, compiled or read back from the persistent
+cache) is a record `compile` under the span that caused it.
 
 A record is the plain tuple ``(name, wall, dur_s, id, parent, req,
 counts)``, indexed by NAME .. COUNTS: `wall` is `time.time()` at the
@@ -19,9 +23,9 @@ may stand: per engine step, per pump, per admission, per request —
 never per token, per slot, or inside a loop over either.
 
 Stdlib only, and jax is never imported from here: the annotation is
-opened only where `jax` already is in `sys.modules`, so the agent and
-other processes that must not touch the chip can trace with the ring
-alone.
+opened, and the compile path listened to, only where `jax` already is
+in `sys.modules`, so the agent and other processes that must not touch
+the chip can trace with the ring alone.
 """
 
 import collections
@@ -122,13 +126,21 @@ def span(name: str, req: Optional[int] = None, **counts) -> Span:
     return Span(name, req, counts)
 
 
-def event(name: str, req: Optional[int] = None, **stamps) -> None:
-    """A record with no extent, under the span open on this thread."""
+def record(name: str, wall: float, dur_s: float,
+           req: Optional[int] = None, /, **counts) -> None:
+    """A stretch that someone else timed, `wall` on `time.time()`:
+    the tuple a span closed now would leave, under the span open on
+    this thread."""
     stack = _stack()
     _ring.append((
-        name, time.time(), 0.0, next(_ids), stack[-1] if stack else 0,
-        req, _plain(stamps),
+        name, wall, dur_s, next(_ids), stack[-1] if stack else 0,
+        req, _plain(counts),
     ))
+
+
+def event(name: str, req: Optional[int] = None, **stamps) -> None:
+    """A record with no extent, under the span open on this thread."""
+    record(name, time.time(), 0.0, req, **stamps)
 
 
 def snapshot(since: float = 0.0, until: float = float("inf")) -> List[tuple]:
@@ -146,3 +158,145 @@ def snapshot(since: float = 0.0, until: float = float("inf")) -> List[tuple]:
 
 def clear() -> None:
     _ring.clear()
+
+
+# ---- compilations ----------------------------------------------------
+# jax times its own compile path and tells `jax.monitoring`'s listeners,
+# synchronously on the thread that compiles: when a leg opens (a scalar),
+# what the persistent cache said inside the backend leg (events), and
+# when a leg closes (a time span on `time.time()`, the ring's clock).
+
+_LEGS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE = {  # in the order jax fires them inside a backend leg
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# the least compile time `runtime.enable_compile_cache` has jax store:
+# a faster program "misses" on every start and says nothing
+STORED_COMPILE_S = 1.0
+
+
+class _Compiling(threading.local):
+    """This thread's place on jax's compile path and what it has
+    spent there: the legs open (a function traced inside another
+    nests), the cache's word inside the open backend leg, and two
+    running totals a span reads as a difference (`compiled()`)."""
+
+    depth = 0
+    cache = "off"
+    seconds = 0.0  # outermost legs only: no stretch counted twice
+    programs = 0  # backend legs closed
+
+
+_compiling = _Compiling()
+_watching = False
+_watch_lock = threading.Lock()
+
+
+def _on_leg_open(event: str, _value=None, **_kw) -> None:
+    if event in _LEGS:
+        _compiling.depth += 1
+
+
+def _on_cache(event: str, **_kw) -> None:
+    said = _CACHE.get(event)
+    if said is not None:
+        _compiling.cache = said
+
+
+def _on_leg(event: str, start: float, end: float, **kw) -> None:
+    leg = _LEGS.get(event)
+    if leg is None:
+        return
+    own = _compiling
+    own.depth = max(0, own.depth - 1)  # 0: a leg open before the watch
+    if own.depth == 0:
+        own.seconds += end - start
+    counts = {"leg": leg, "program": str(kw.get("fun_name", ""))}
+    if leg == "backend":
+        own.programs += 1
+        counts["cache"], own.cache = own.cache, "off"
+    record("compile", start, end - start, **counts)
+
+
+def watch_compiles() -> bool:
+    """Leave a record `compile` (counts `leg`: trace, lower or
+    backend; `program`: jax's name for the function; on the backend
+    leg `cache`: hit, miss or off) for every leg of jax's compile
+    path from now on. Idempotent; False, and nothing registered, in a
+    process that has not imported jax."""
+    global _watching
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
+        return False
+    with _watch_lock:
+        if not _watching:
+            _watching = True
+            monitoring.register_scalar_listener(_on_leg_open)
+            monitoring.register_event_listener(_on_cache)
+            monitoring.register_event_time_span_listener(_on_leg)
+    return True
+
+
+def compiled() -> tuple:
+    """(seconds, programs) this thread has spent on jax's compile
+    path and compiled or read back since the watch began: a span that
+    wants its own share reads it before and after."""
+    return _compiling.seconds, _compiling.programs
+
+
+def _union_s(legs: List[tuple]) -> float:
+    """Seconds covered by the records' [start, end] stretches."""
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted((r[WALL], r[WALL] + r[DUR]) for r in legs):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+def compile_totals(
+    since: float = 0.0, until: float = float("inf")
+) -> Optional[dict]:
+    """What the `compile` records that start in [since, until] come
+    to: `trace_lower_s` and `backend_s` (the legs' unions: a function
+    traced inside another is counted once), `programs` (backend
+    legs), `cache_hits`, `cache_misses` (of the programs slow enough
+    to be stored), `slowest` (the program whose legs sum longest) and
+    `first_wall` (the earliest leg's start). None where there is no
+    such record, or where the ring is full and no longer reaches back
+    to `since`: a sum over what is left would be a wrong number."""
+    records = snapshot()
+    if len(records) == RING_SIZE and records[0][WALL] > since:
+        return None
+    legs = [
+        r for r in records
+        if r[NAME] == "compile" and since <= r[WALL] <= until
+    ]
+    if not legs:
+        return None
+    backend = [r for r in legs if r[COUNTS]["leg"] == "backend"]
+    by_program: dict = {}
+    for r in legs:
+        name = r[COUNTS]["program"]
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]  # the trace leg's name has no wrapper
+        by_program[name] = by_program.get(name, 0.0) + r[DUR]
+    return {
+        "trace_lower_s": _union_s(
+            [r for r in legs if r[COUNTS]["leg"] != "backend"]),
+        "backend_s": _union_s(backend),
+        "programs": len(backend),
+        "cache_hits": sum(r[COUNTS]["cache"] == "hit" for r in backend),
+        "cache_misses": sum(
+            r[COUNTS]["cache"] == "miss" and r[DUR] >= STORED_COMPILE_S
+            for r in backend
+        ),
+        "slowest": max(by_program, key=by_program.get),
+        "first_wall": min(r[WALL] for r in legs),
+    }

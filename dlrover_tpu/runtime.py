@@ -31,6 +31,7 @@ import os
 import threading
 from typing import Callable, Optional
 
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
 
@@ -99,12 +100,19 @@ def enable_compile_cache() -> str:
     JAX_COMPILATION_CACHE_DIR is set (or a caller configured
     `jax_compilation_cache_dir` already), jax's own handling of it is
     the cache and this function sets no directory. Only when nothing
-    is configured does it point jax at the fixed in-tree directory."""
+    is configured does it point jax at the fixed in-tree directory.
+
+    Every entry point that compiles comes through here, so this is
+    also where the process starts leaving a `compile` record for each
+    leg of jax's compile path (common/trace.py `watch_compiles`)."""
     import jax
 
+    trace.watch_compiles()
     # worth caching: anything that took a second to compile, however
     # small the executable
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", trace.STORED_COMPILE_S
+    )
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     current = jax.config.jax_compilation_cache_dir
     if current:
@@ -132,51 +140,57 @@ def init(
     Re-init: if the process is already initialized with different
     coordinates, the previous runtime is shut down first (the
     `reset_distributed` path in the reference).
-    """
-    enable_compile_cache()
-    addr = coordinator_addr or os.environ.get(NodeEnv.COORDINATOR_ADDR)
-    num = (
-        num_processes
-        if num_processes is not None
-        else int(os.environ.get(NodeEnv.NODE_NUM, "1"))
-    )
-    rank = (
-        process_id
-        if process_id is not None
-        else int(os.environ.get(NodeEnv.NODE_RANK, "0"))
-    )
-    _ctx.node_rank = rank
-    _ctx.node_num = num
-    _ctx.rdzv_round = int(
-        os.environ.get("DLROVER_TPU_RDZV_ROUND", "0")
-    )
-    if num > 1 and addr:
-        import jax
 
-        if _ctx.initialized:
-            if _ctx.coordinator_addr == addr and _ctx.node_num == num:
-                return _ctx  # idempotent
-            shutdown()
-        logger.info(
-            "jax.distributed.initialize coordinator=%s rank=%d/%d",
-            addr,
-            rank,
-            num,
+    The whole of it is the span `runtime.init` (count `nodes`): what
+    a worker's start-up spends joining its world, which the trainer's
+    start-up line reads beside the compile legs.
+    """
+    with trace.span("runtime.init") as sp:
+        enable_compile_cache()
+        addr = coordinator_addr or os.environ.get(NodeEnv.COORDINATOR_ADDR)
+        num = (
+            num_processes
+            if num_processes is not None
+            else int(os.environ.get(NodeEnv.NODE_NUM, "1"))
         )
-        jax.distributed.initialize(
-            coordinator_address=addr,
-            num_processes=num,
-            process_id=rank,
+        rank = (
+            process_id
+            if process_id is not None
+            else int(os.environ.get(NodeEnv.NODE_RANK, "0"))
         )
-        _ctx.initialized = True
-        _ctx.coordinator_addr = addr
-        atexit.register(_shutdown_quietly)
-    else:
-        _ctx.initialized = False
-        _ctx.coordinator_addr = None
-    if membership_watch and os.environ.get(NodeEnv.MASTER_ADDR):
-        start_membership_watch(interval=watch_interval)
-    return _ctx
+        sp.set(nodes=num)
+        _ctx.node_rank = rank
+        _ctx.node_num = num
+        _ctx.rdzv_round = int(
+            os.environ.get("DLROVER_TPU_RDZV_ROUND", "0")
+        )
+        if num > 1 and addr:
+            import jax
+
+            if _ctx.initialized:
+                if _ctx.coordinator_addr == addr and _ctx.node_num == num:
+                    return _ctx  # idempotent
+                shutdown()
+            logger.info(
+                "jax.distributed.initialize coordinator=%s rank=%d/%d",
+                addr,
+                rank,
+                num,
+            )
+            jax.distributed.initialize(
+                coordinator_address=addr,
+                num_processes=num,
+                process_id=rank,
+            )
+            _ctx.initialized = True
+            _ctx.coordinator_addr = addr
+            atexit.register(_shutdown_quietly)
+        else:
+            _ctx.initialized = False
+            _ctx.coordinator_addr = None
+        if membership_watch and os.environ.get(NodeEnv.MASTER_ADDR):
+            start_membership_watch(interval=watch_interval)
+        return _ctx
 
 
 def shutdown():

@@ -90,7 +90,7 @@ import contextlib
 import dataclasses
 import time
 from collections import deque
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -1154,6 +1154,31 @@ class _Inflight:
     pf_mask: Optional[np.ndarray] = None
 
 
+def _spanned_build(init):
+    """The constructor as the span `engine.build` (pools on the
+    device, programs bound; counts `slots`, `max_len`, `compile_s`).
+    `serving/` has no entry point of its own, so this is also where a
+    server built by a user's script starts counting its compilations
+    (common/trace.py `watch_compiles`); the build's own open the
+    totals that `step` goes on adding to."""
+
+    @wraps(init)
+    def build(self, *args, **kwargs):
+        trace.watch_compiles()
+        seconds, programs = trace.compiled()
+        with trace.span("engine.build") as sp:
+            init(self, *args, **kwargs)
+            spent, compiled = trace.compiled()
+            self._stat_compile_s += spent - seconds
+            self._stat_compilations += compiled - programs
+            sp.set(
+                slots=self.n_slots, max_len=self.max_len,
+                compile_s=spent - seconds,
+            )
+
+    return build
+
+
 class ContinuousBatcher:
     """Greedy/sampling rollouts over a slot bank.
 
@@ -1162,6 +1187,7 @@ class ContinuousBatcher:
     llama/GPT-family pytree models/decode.py serves.
     """
 
+    @_spanned_build
     def __init__(
         self,
         cfg,
@@ -1585,6 +1611,10 @@ class ContinuousBatcher:
         self._stat_span_ms = 0.0
         self._stat_overlap_ms = 0.0
         self._stat_dispatches = 0
+        # what this engine's build and steps spent on jax's compile
+        # path, and the programs they compiled or read back
+        self._stat_compile_s = 0.0
+        self._stat_compilations = 0
         self._wait_this_step = 0.0   # s blocked on the device, this step
         self._overlap_this_step = 0.0  # s of device span hidden, this step
         self._admit_this_step = 0.0  # s inside _admit, this step
@@ -3483,7 +3513,10 @@ class ContinuousBatcher:
         device_wait_ms (time blocked on device results), dispatches,
         and overlap_ratio = hidden device span / total device span —
         ~0 in sync mode, approaching 1 when the host fully hides the
-        device under async dispatch."""
+        device under async dispatch. compile_s and compilations: what
+        the build and the steps since spent tracing, lowering and
+        compiling (or reading the cache), and the programs that took —
+        level once every shape is warm."""
         ratio = (
             self._stat_overlap_ms / self._stat_span_ms
             if self._stat_span_ms > 0
@@ -3494,6 +3527,8 @@ class ContinuousBatcher:
             "device_wait_ms": self._stat_wait_ms,
             "dispatches": float(self._stat_dispatches),
             "overlap_ratio": ratio,
+            "compile_s": self._stat_compile_s,
+            "compilations": float(self._stat_compilations),
         }
 
     def step(self) -> List[StepEvent]:
@@ -3513,7 +3548,11 @@ class ContinuousBatcher:
         token streams) are byte-identical across depths; only WHEN
         events surface shifts by one call. The span's `overlap_s` is
         the device span of the harvested dispatch that the host did
-        not spend waiting (`step_stats()["overlap_ratio"]` sums it)."""
+        not spend waiting (`step_stats()["overlap_ratio"]` sums it),
+        its `compile_s` what the step spent on jax's compile path: 0.0
+        unless an admission or a dispatch met a shape for the first
+        time (the `compile` records under it say which)."""
+        compile_s, compiled = trace.compiled()
         with trace.span("engine.step") as sp:
             self._wait_this_step = 0.0
             self._overlap_this_step = 0.0
@@ -3601,12 +3640,17 @@ class ContinuousBatcher:
                 self._inflight = None
                 raise
             live = ~self.done
+            spent, programs = trace.compiled()
+            compile_s = spent - compile_s
+            self._stat_compile_s += compile_s
+            self._stat_compilations += programs - compiled
             sp.set(
                 alive=int(live.sum()),
                 live_tokens=int(self.pos[live].sum()),
                 wait_s=self._wait_this_step,
                 admit_s=self._admit_this_step,
                 overlap_s=self._overlap_this_step,
+                compile_s=compile_s,
             )
             if self._hybrid:
                 sp.set(

@@ -87,6 +87,8 @@ class ServingMetrics:
             "_step_device_wait_ms",
             "_step_dispatches",
             "_step_overlap_ratio",
+            "_compilations",
+            "_compile_s",
             "_paged_occupancy",
             "_paged_shared_ratio",
             "_paged_used_pages",
@@ -205,6 +207,11 @@ class ServingMetrics:
         self._step_device_wait_ms = 0.0
         self._step_dispatches = 0
         self._step_overlap_ratio = 0.0
+        # what the engine's build and steps spent on jax's compile
+        # path (common/trace.py's `compile` records, summed by the
+        # engine): level once every shape is warm
+        self._compilations = 0
+        self._compile_s = 0.0
         # page-pool counters/gauges: copied from the engine's
         # paged_stats() each pump (kv_layout="paged" only — all zero
         # under the dense bank)
@@ -466,13 +473,16 @@ class ServingMetrics:
     def update_step_timing(
         self, host_ms: float, device_wait_ms: float,
         dispatches: int, overlap_ratio: float,
+        compilations: int, compile_s: float,
     ):
         """Refresh step-latency stats from the engine's step_stats().
-        The time totals and dispatch count get the same max() monotonic
-        guard as the blocks above; overlap_ratio is a gauge and is set
-        directly (it legitimately moves both ways as traffic shifts
-        between sync-like and fully-hidden regimes)."""
+        The time totals and the dispatch and compilation counts get the
+        same max() monotonic guard as the blocks above; overlap_ratio
+        is a gauge and is set directly (it legitimately moves both ways
+        as traffic shifts between sync-like and fully-hidden regimes)."""
         with self._lock:
+            self._compilations = max(self._compilations, int(compilations))
+            self._compile_s = max(self._compile_s, compile_s)
             self._step_host_ms = max(self._step_host_ms, host_ms)
             self._step_device_wait_ms = max(
                 self._step_device_wait_ms, device_wait_ms
@@ -1295,6 +1305,20 @@ class ServingMetrics:
                 "Fraction of device span hidden behind host work "
                 "(~0 synchronous, toward 1 under async dispatch).",
                 self._step_overlap_ratio,
+            )
+            counter(
+                "serving_compilations_total",
+                "Programs the engine's build and steps compiled or "
+                "read back from the persistent cache; a rise under "
+                "traffic is a shape nobody warmed.",
+                self._compilations,
+            )
+            counter(
+                "serving_compile_seconds_total",
+                "Time the engine's build and steps spent tracing, "
+                "lowering and compiling (every stream stands still "
+                "meanwhile), s.",
+                f"{self._compile_s:.6g}",
             )
             counter(
                 "serving_admission_stall_ms",

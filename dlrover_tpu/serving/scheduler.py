@@ -891,6 +891,7 @@ class RequestScheduler:
                     self.metrics.update_step_timing(
                         st["host_ms"], st["device_wait_ms"],
                         int(st["dispatches"]), st["overlap_ratio"],
+                        int(st["compilations"]), st["compile_s"],
                     )
                     kp = getattr(self.engine, "kernel_path", None)
                     if kp is not None:

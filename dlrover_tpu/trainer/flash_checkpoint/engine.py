@@ -50,10 +50,13 @@ class StorageType:
 def _log_legs(what: str, step: int, parent: trace.Span, extra: str = ""):
     """One line per save and per restore: the spans recorded under
     `parent`, each leg's own time (its extent less its children's),
-    and the bytes moved."""
+    and the bytes moved. A `compile` record under a leg (a restore
+    that met a shape for the first time) stays part of that leg's own
+    time: it is jax's stretch, not a leg of the checkpoint."""
     by_parent: Dict[int, list] = {}
     for r in trace.snapshot(since=parent.wall):
-        by_parent.setdefault(r[trace.PARENT], []).append(r)
+        if r[trace.NAME] != "compile":
+            by_parent.setdefault(r[trace.PARENT], []).append(r)
     legs: Dict[str, float] = {}
     n_bytes = 0
     todo = list(by_parent.get(parent.id, ()))

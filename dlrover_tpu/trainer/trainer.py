@@ -28,6 +28,7 @@ from dlrover_tpu.agent.monitor import (
     publish_chip_metrics,
     write_step_metrics,
 )
+from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.trainer.flash_checkpoint.engine import (
     Checkpointer,
@@ -118,6 +119,32 @@ class Trainer:
         self._model_info_reported = False
         self._hang = HangingDetector(
             timeout=self.args.hang_timeout, master_client=master_client
+        )
+
+    @staticmethod
+    def _log_startup():
+        """One line when the first step has returned: what this
+        process spent before it could train, from the ring's
+        `runtime.init` span and `compile` records (a process that
+        never called `dlrover_tpu.init()` left none: no line). A
+        respawned worker's line is the other half of the agent's
+        "worker restart: persist, respawn": programs read back from
+        the persistent cache are hits, and misses are what the
+        recovery waited for."""
+        totals = trace.compile_totals()
+        if totals is None:
+            return
+        joined = sum(
+            r[trace.DUR] for r in trace.snapshot()
+            if r[trace.NAME] == "runtime.init"
+        )
+        logger.info(
+            "worker start-up: runtime.init %.1f s, traced and lowered "
+            "%.1f s, compiled %.1f s: %d programs, %d cache hits, %d "
+            "misses, slowest %s",
+            joined, totals["trace_lower_s"], totals["backend_s"],
+            totals["programs"], totals["cache_hits"],
+            totals["cache_misses"], totals["slowest"],
         )
 
     def _report_model_info(self, state, batch):
@@ -298,6 +325,7 @@ class Trainer:
                     )
                     if not self._model_info_reported:
                         self._model_info_reported = True
+                        self._log_startup()
                         self._report_model_info(state, batch)
                     self.global_step += 1
                     window_steps += 1

@@ -636,3 +636,355 @@ class TestCheckpointLegs:
             assert leg in lines[0] and leg in lines[1]
         assert "restore step 2" in lines[2]
         assert "shm_read=" in lines[2] and "h2d=" in lines[2]
+
+    def test_a_compile_record_under_a_leg_is_not_a_leg(self, caplog):
+        """A restore that compiles (a respawned worker's first) still
+        logs its line: jax's stretch stays in the leg's own time."""
+        from dlrover_tpu.common.log import default_logger
+        from dlrover_tpu.trainer.flash_checkpoint import engine as flash
+
+        with trace.span("ckpt.restore") as sp:
+            with trace.span("ckpt.h2d", bytes=64) as h2d:
+                trace.record("compile", time.time(), 0.25, leg="backend",
+                             program="jit(_put)", cache="off")
+        default_logger.addHandler(caplog.handler)
+        try:
+            with caplog.at_level("INFO"):
+                flash._log_legs("restore", 3, sp)
+        finally:
+            default_logger.removeHandler(caplog.handler)
+        (line,) = [r.getMessage() for r in caplog.records
+                   if r.getMessage().startswith("flash checkpoint")]
+        assert "restore step 3" in line and "64 bytes" in line
+        assert f"h2d={h2d.dur_s * 1e3:.1f}ms" in line
+        assert "compile" not in line
+
+
+def _legs(program, records=None):
+    """The `compile` records of one program, by jax's name for it
+    with or without the `jit(...)` around it."""
+    return [
+        r for r in _named("compile", records)
+        if r[COUNTS]["program"] in (program, f"jit({program})")
+    ]
+
+
+class TestRecord:
+    def test_record_leaves_the_tuple_a_span_would(self):
+        with trace.span("outer") as sp:
+            trace.record("timed.elsewhere", 123.5, 0.25, 7, leg="x", n=3)
+        trace.record("alone", 124.0, 0.0)
+        (rec,) = _named("timed.elsewhere")
+        (outer,) = _named("outer")
+        assert type(rec) is tuple and len(rec) == len(outer) == 7
+        assert rec[WALL] == 123.5 and rec[DUR] == 0.25 and rec[REQ] == 7
+        assert rec[PARENT] == sp.id and rec[COUNTS] == {"leg": "x", "n": 3}
+        assert rec[ID] not in (0, sp.id)
+        (alone,) = _named("alone")
+        assert alone[PARENT] == 0 and alone[REQ] is None
+        # a count may carry any name, the record's own fields' too
+        trace.record("named", 1.0, 2.0, None, name="n", wall=3.0, dur_s=4.0)
+        assert _named("named")[0][COUNTS] == {
+            "name": "n", "wall": 3.0, "dur_s": 4.0}
+        with pytest.raises(TypeError):
+            trace.record("bad", 1.0, 2.0, None, arr=np.zeros(2))
+
+
+class TestCompileRecords:
+    def test_watching_twice_registers_once(self):
+        from jax._src import monitoring
+
+        assert trace.watch_compiles() and trace.watch_compiles()
+        for listeners, mine in (
+            (monitoring.get_event_time_span_listeners(), trace._on_leg),
+            (monitoring.get_event_listeners(), trace._on_cache),
+            (monitoring.get_scalar_listeners(), trace._on_leg_open),
+        ):
+            assert listeners.count(mine) == 1
+
+    def test_a_first_call_leaves_three_legs_and_a_second_none(self):
+        trace.watch_compiles()
+
+        @jax.jit
+        def first_call_probe(x):
+            return x * 3 + 1
+
+        before = trace.compiled()
+        with trace.span("caller") as sp:
+            first_call_probe(jnp.ones(5)).block_until_ready()
+        legs = _legs("first_call_probe")
+        assert sorted(r[COUNTS]["leg"] for r in legs) == [
+            "backend", "lower", "trace"]
+        assert all(r[PARENT] == sp.id and r[DUR] > 0 for r in legs)
+        (backend,) = [r for r in legs if r[COUNTS]["leg"] == "backend"]
+        assert backend[COUNTS]["cache"] in ("hit", "miss", "off")
+        assert all(
+            "cache" not in r[COUNTS] for r in legs if r is not backend)
+        # the legs lie inside the span that caused them, on its clock
+        (caller,) = _named("caller")
+        assert all(
+            caller[WALL] <= r[WALL]
+            and r[WALL] + r[DUR] <= caller[WALL] + caller[DUR] + 1e-3
+            for r in legs
+        )
+        seconds, programs = trace.compiled()
+        assert programs - before[1] >= 1
+        assert seconds - before[0] >= max(r[DUR] for r in legs)
+        trace.clear()
+        first_call_probe(jnp.ones(5)).block_until_ready()
+        assert _named("compile") == []
+        assert trace.compiled() == (seconds, programs)
+
+    def test_nested_jits_give_a_union_shorter_than_the_sum(self):
+        trace.watch_compiles()
+
+        @jax.jit
+        def nested_inner_probe(x):
+            return jnp.tanh(x) * 2
+
+        @jax.jit
+        def nested_outer_probe(x):
+            return nested_inner_probe(x) + 1
+
+        before = trace.compiled()[0]
+        nested_outer_probe(jnp.ones(5)).block_until_ready()
+        (inner,) = [
+            r for r in _legs("nested_inner_probe")
+            if r[COUNTS]["leg"] == "trace"
+        ]
+        (outer,) = [
+            r for r in _legs("nested_outer_probe")
+            if r[COUNTS]["leg"] == "trace"
+        ]
+        assert outer[WALL] <= inner[WALL]
+        assert inner[WALL] + inner[DUR] <= outer[WALL] + outer[DUR]
+        totals = trace.compile_totals()
+        not_backend = [
+            r for r in _named("compile") if r[COUNTS]["leg"] != "backend"]
+        assert totals["trace_lower_s"] <= (
+            sum(r[DUR] for r in not_backend) - inner[DUR] + 1e-9)
+        # the thread's running total counts outermost legs only
+        outermost = trace.compiled()[0] - before
+        assert outermost < sum(r[DUR] for r in _named("compile"))
+        assert outermost >= outer[DUR]
+
+    @pytest.mark.parametrize("event, kw", [
+        ("/jax/core/compile/backend_compile_duration", {}),
+        ("/jax/core/compile/jaxpr_trace_duration", {"fun_name": None}),
+        ("/jax/some/other/duration", {"fun_name": "f"}),
+    ], ids=["no_name", "odd_name", "other_event"])
+    def test_the_listener_takes_what_jax_hands_it(self, event, kw):
+        trace._on_leg_open(event, 10.0, **kw)
+        trace._on_cache("/jax/compilation_cache/other", **kw)
+        trace._on_leg(event, 10.0, 10.5, **kw)
+        records = _named("compile")
+        if "other" in event:
+            assert records == []
+            return
+        (rec,) = records
+        assert rec[WALL] == 10.0 and rec[DUR] == 0.5
+        assert type(rec[COUNTS]["program"]) is str
+        assert ("cache" in rec[COUNTS]) == ("backend" in event)
+
+    def test_totals_union_overlaps_and_count_only_slow_misses(self):
+        def leg(kind, start, dur, program="jit(p)", **counts):
+            trace.record(
+                "compile", start, dur, leg=kind, program=program, **counts)
+
+        leg("trace", 100.0, 4.0, program="p")
+        leg("trace", 101.0, 1.0, program="helper")  # inside the first
+        leg("lower", 103.5, 1.5)  # overlaps its end
+        leg("backend", 105.0, 2.0, cache="miss")
+        leg("backend", 108.0, 0.5, program="jit(add)", cache="miss")
+        leg("backend", 109.0, 3.0, program="jit(q)", cache="hit")
+        leg("backend", 113.0, 1.5, program="jit(r)", cache="off")
+        with trace.span("engine.step"):
+            pass
+        totals = trace.compile_totals()
+        assert totals["trace_lower_s"] == pytest.approx(5.0)
+        assert totals["backend_s"] == pytest.approx(7.0)
+        assert (totals["programs"], totals["cache_hits"],
+                totals["cache_misses"]) == (4, 1, 1)
+        assert totals["slowest"] == "p" and totals["first_wall"] == 100.0
+        cut = trace.compile_totals(since=104.0, until=108.5)
+        assert cut["backend_s"] == pytest.approx(2.5)
+        assert cut["trace_lower_s"] == 0.0 and cut["programs"] == 2
+        assert trace.compile_totals(since=200.0) is None
+
+    def test_totals_are_none_on_an_empty_and_on_a_full_ring(self):
+        assert trace.compile_totals() is None
+        trace.record("compile", 5.0, 1.0, leg="backend", program="p",
+                     cache="off")
+        assert trace.compile_totals()["programs"] == 1
+        for _ in range(trace.RING_SIZE - 1):
+            trace.event("filler")
+        # full, and the oldest record is still the first: nothing lost
+        assert len(trace.snapshot()) == trace.RING_SIZE
+        assert trace.compile_totals(since=5.0)["programs"] == 1
+        assert trace.compile_totals() is None  # does not reach back to 0
+        trace.event("one more")
+        assert trace.compile_totals(since=5.0) is None
+
+    def test_a_second_compile_of_one_program_reads_the_cache(self, tmp_path):
+        from jax.experimental.compilation_cache import (
+            compilation_cache as cc,
+        )
+
+        trace.watch_compiles()
+        names = (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+        old = [getattr(jax.config, n) for n in names]
+
+        def make():
+            @jax.jit
+            def cached_twice_probe(x):
+                return jnp.cos(x) * 5 - 2
+
+            return cached_twice_probe
+
+        try:
+            for n, v in zip(names, (str(tmp_path), 0.0, 0)):
+                jax.config.update(n, v)
+            cc.reset_cache()
+            make()(jnp.ones(9)).block_until_ready()
+            (cold,) = [
+                r for r in _legs("cached_twice_probe")
+                if r[COUNTS]["leg"] == "backend"
+            ]
+            assert cold[COUNTS]["cache"] == "miss"
+            # too fast to be worth storing: says nothing, counts nothing
+            assert cold[DUR] < trace.STORED_COMPILE_S
+            assert trace.compile_totals()["cache_misses"] == 0
+            trace.clear()
+            # the same program from a function jax has not seen
+            make()(jnp.ones(9)).block_until_ready()
+            (warm,) = [
+                r for r in _legs("cached_twice_probe")
+                if r[COUNTS]["leg"] == "backend"
+            ]
+            assert warm[COUNTS]["cache"] == "hit"
+            totals = trace.compile_totals()
+            assert totals["cache_hits"] >= 1 and totals["cache_misses"] == 0
+        finally:
+            for n, v in zip(names, old):
+                jax.config.update(n, v)
+            cc.reset_cache()
+
+
+class TestEngineCompiles:
+    """One engine a class, over a configuration of its own (no other
+    test's programs are its programs), warmed on its smallest bucket."""
+
+    @pytest.fixture(scope="class")
+    def warm(self, model):
+        cfg, params = model
+        cfg = dataclasses.replace(cfg, norm_eps=cfg.norm_eps * 1.5)
+        metrics = ServingMetrics()
+        eng = _engine(cfg, params)
+        sched = RequestScheduler(
+            eng, slo=SloConfig(max_new_tokens=8), metrics=metrics)
+        sched.submit(_prompts((5,), seed=11)[0])
+        sched.run_to_completion()
+        return eng, sched, metrics, trace.snapshot()
+
+    def test_the_build_is_a_span_with_its_counts(self, warm):
+        eng, _, _, records = warm
+        (build,) = _named("engine.build", records)
+        assert build[PARENT] == 0
+        assert build[COUNTS]["slots"] == eng.n_slots == 2
+        assert build[COUNTS]["max_len"] == eng.max_len == 64
+        assert 0.0 <= build[COUNTS]["compile_s"] <= build[DUR]
+        # the warm request compiled under the steps that met its shapes
+        steps = {r[ID]: r for r in _named("engine.step", records)}
+        under = {r[ID]: r for r in records if r[PARENT] in steps}
+        caused = [
+            r for r in _named("compile", records)
+            if r[COUNTS]["leg"] == "backend" and r[PARENT] in under
+        ]
+        assert {under[r[PARENT]][NAME] for r in caused} >= {
+            "engine.admit", "engine.dispatch"}
+
+    def test_an_unmet_bucket_compiles_under_its_admission(self, warm):
+        eng, sched, _, _ = warm
+        sched.submit(_prompts((20,), seed=12)[0])  # bucket 32: not met
+        sched.run_to_completion()
+        steps = _named("engine.step")
+        (admit,) = _named("engine.admit")
+        assert admit[COUNTS]["bucket"] == 32
+        backend = [
+            r for r in _named("compile") if r[COUNTS]["leg"] == "backend"]
+        assert backend and all(r[PARENT] == admit[ID] for r in backend)
+        first = steps[0]
+        assert admit[PARENT] == first[ID]
+        assert first[COUNTS]["compile_s"] > 0.0
+        assert first[COUNTS]["compile_s"] == pytest.approx(
+            trace.compile_totals()["trace_lower_s"]
+            + trace.compile_totals()["backend_s"], rel=0.05)
+        assert first[COUNTS]["compile_s"] <= first[DUR]
+        assert len(steps) > 1
+        assert all(s[COUNTS]["compile_s"] == 0.0 for s in steps[1:])
+        # again, now warm: no record, and no step pays
+        trace.clear()
+        sched.submit(_prompts((21,), seed=13)[0])
+        sched.run_to_completion()
+        assert _named("compile") == []
+        assert all(
+            s[COUNTS]["compile_s"] == 0.0 for s in _named("engine.step"))
+
+    def test_metrics_render_the_engines_compile_totals(self, warm):
+        eng, sched, metrics, records = warm
+        sched.pump()  # a pump with no work still publishes the totals
+        stats = eng.step_stats()
+        assert stats["compilations"] >= 2 and stats["compile_s"] > 0.0
+        text = metrics.render()
+        assert "# TYPE serving_compilations_total counter" in text
+        assert "# TYPE serving_compile_seconds_total counter" in text
+        assert (
+            f"serving_compilations_total {int(stats['compilations'])}"
+            in text)
+        assert (
+            f"serving_compile_seconds_total {stats['compile_s']:.6g}"
+            in text)
+        # the same totals the spans carry: the build's and every step's
+        (build,) = _named("engine.build", records)
+        assert stats["compile_s"] >= build[COUNTS]["compile_s"] + sum(
+            r[COUNTS]["compile_s"] for r in _named("engine.step", records)
+        ) - 1e-9
+
+
+class TestStartUpLine:
+    def test_runtime_init_is_a_span(self, monkeypatch):
+        from dlrover_tpu import runtime
+
+        monkeypatch.delenv("DLROVER_TPU_COORDINATOR_ADDR", raising=False)
+        monkeypatch.delenv("DLROVER_TPU_MASTER_ADDR", raising=False)
+        runtime.init(num_processes=1, process_id=0, membership_watch=False)
+        (joined,) = _named("runtime.init")
+        assert joined[COUNTS] == {"nodes": 1} and joined[PARENT] == 0
+
+    def test_the_trainer_logs_one_line_from_the_ring(self, caplog):
+        from dlrover_tpu.common.log import default_logger
+        from dlrover_tpu.trainer.trainer import Trainer
+
+        default_logger.addHandler(caplog.handler)  # it does not propagate
+        try:
+            with caplog.at_level("INFO"):
+                Trainer._log_startup()  # no compile record: no line
+                with trace.span("runtime.init", nodes=1):
+                    pass
+                trace.record("compile", 50.0, 2.0, leg="trace", program="step")
+                trace.record("compile", 52.0, 3.0, leg="backend",
+                             program="jit(step)", cache="hit")
+                Trainer._log_startup()
+        finally:
+            default_logger.removeHandler(caplog.handler)
+        lines = [
+            r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("worker start-up")
+        ]
+        assert len(lines) == 1
+        assert "traced and lowered 2.0 s, compiled 3.0 s" in lines[0]
+        assert "1 programs, 1 cache hits, 0 misses, slowest step" in lines[0]
