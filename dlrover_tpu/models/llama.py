@@ -64,9 +64,17 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16          # compute dtype
     param_dtype: Any = jnp.float32     # storage dtype
     remat: bool = True                 # checkpoint each layer in scan
-    # remat.resolve_policy name: "full" recomputes everything (min HBM);
-    # "dots_no_batch" saves matmul outputs (≈no recompute, more HBM)
-    remat_policy: str = "full"
+    # what the layer scan keeps for its backward pass. "auto": what
+    # the device has room for: `accelerate()` takes the first rung of
+    # parallel/remat.py's LADDER ("none", "proj_mlp", "full") whose
+    # compiled step fits the chip's memory, again after every
+    # rebuild of an elastic job; "full" (recompute everything, least
+    # HBM) wherever no one chooses: a bare `loss_fn`, an evaluation,
+    # a backend that states no limit. Any remat.resolve_policy name,
+    # or remat=False, is obeyed as written: pin one to compare
+    # programs, or where the process keeps more on the device beside
+    # its step than remat.MARGIN_BYTES
+    remat_policy: str = "auto"
     attn_impl: str = "auto"            # auto | flash | reference
     # explicit flash block sizes for tuning sweeps (0 = VMEM-aware auto,
     # ops/flash_attention.auto_blocks). Single-device attention only:
@@ -931,10 +939,10 @@ def apply(
             return y, aux
 
         if cfg.remat:
-            from dlrover_tpu.parallel.remat import resolve_policy
+            from dlrover_tpu.parallel import remat
 
-            body = jax.checkpoint(
-                body, policy=resolve_policy(cfg.remat_policy)
+            body = remat.apply_remat(
+                body, remat.scan_policy(cfg.remat_policy)
             )
         with jax.named_scope("layers"):
             x, aux_per_layer = jax.lax.scan(body, x, params["layers"])

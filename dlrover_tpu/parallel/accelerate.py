@@ -9,7 +9,7 @@ jits one SPMD program over the mesh and GSPMD inserts the collectives.
 """
 
 from dataclasses import dataclass, field
-from functools import partial
+from functools import wraps
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -24,6 +24,11 @@ from dlrover_tpu.parallel.sharding import (
     _filter_spec,
     constrain,
     tree_shardings,
+)
+from dlrover_tpu.utils.program_stats import (
+    abstractify,
+    device_memory_bytes,
+    extract_program_stats,
 )
 
 TrainState = Dict[str, Any]
@@ -111,13 +116,21 @@ class Accelerated:
         AOT lower+compile on abstract avals — hits the compilation
         cache when the step already ran, so this is cheap after the
         first step. `batch` may be real arrays or avals (abstract_batch)."""
-        from dlrover_tpu.utils.program_stats import (
-            abstractify,
-            extract_program_stats,
-        )
-
         lowered = self.train_step.lower(*abstractify((state, batch)))
         return extract_program_stats(lowered.compile())
+
+
+def _at_rung(rung: str, fn: Callable) -> Callable:
+    """`fn` traced with the model's "auto" layer scans at `rung`: a
+    function of its own, so a trace of its own in the caches of
+    `jax.jit` and `jax.checkpoint`."""
+
+    @wraps(fn)
+    def at_rung(*args):
+        with remat.tracing_at(rung):
+            return fn(*args)
+
+    return at_rung
 
 
 def accelerate(
@@ -133,18 +146,36 @@ def accelerate(
     init_params(key) -> params pytree
     loss_fn(params, batch, mesh) -> (loss, metrics)
     rules: partition rules for the param pytree
+
+    Where this process's devices of the mesh state a memory limit,
+    `train_step` is a `remat.LadderStep`: a model whose layer scans
+    run `remat_policy="auto"` gets the rung the compiled step has
+    room for, chosen when the step first meets real shapes, and again
+    by the next `accelerate()` of a rebuilt job. It is called and
+    lowered like the jitted function it is elsewhere, and is the kept
+    rung's jitted function once that has run.
     """
     strategy = strategy or Strategy()
     mesh = strategy.mesh.build(devices)
     policy = amp.get_policy(strategy.precision)
 
-    def _loss_body(params, batch):
-        return loss_fn(policy.cast_to_compute(params), batch, mesh)
+    def _loss_at(rung: Optional[str] = None):
+        """The loss, a model's "auto" layer scans at `rung` (None: no
+        one chooses, "full"). A function of its own a rung, under a
+        `jax.checkpoint` of its own: that too keeps its traces by
+        function and avals, and one wrap for all would trace the top
+        rung once and hand it to every rung below."""
 
-    if strategy.remat != "none":
-        _loss_body = remat.apply_remat(
-            _loss_body, strategy.remat, strategy.remat_save_names
-        )
+        def _loss_body(params, batch):
+            return loss_fn(policy.cast_to_compute(params), batch, mesh)
+
+        if rung is not None:
+            _loss_body = _at_rung(rung, _loss_body)
+        if strategy.remat != "none":
+            _loss_body = remat.apply_remat(
+                _loss_body, strategy.remat, strategy.remat_save_names
+            )
+        return _loss_body
 
     def _constrain_tree(tree):
         """Apply partition rules anywhere in the state tree: optimizer
@@ -169,9 +200,9 @@ def accelerate(
 
     init_jit = jax.jit(_init)
 
-    def _grads(params, batch, scale=None):
+    def _grads(loss_body, params, batch, scale=None):
         def f(p, b):
-            loss, m = _loss_body(p, b)
+            loss, m = loss_body(p, b)
             if scale is not None:
                 loss = loss * scale.astype(loss.dtype)
             return loss, m
@@ -183,7 +214,7 @@ def accelerate(
             loss = loss / scale.astype(loss.dtype)
         return loss, metrics, grads
 
-    def _train_step(state, batch):
+    def _step(loss_body, state, batch):
         params = state["params"]
         ls = state.get("loss_scale") if strategy.loss_scale else None
         scale = ls.scale if ls is not None else None
@@ -194,7 +225,7 @@ def accelerate(
             # step instead of over-weighting sparse microbatches.
             def micro(carry, mb):
                 acc_grads, acc_loss, acc_w = carry
-                loss, m, grads = _grads(params, mb, scale)
+                loss, m, grads = _grads(loss_body, params, mb, scale)
                 w = m.get("loss_weight", jnp.ones((), jnp.float32))
                 w = w.astype(jnp.float32)
                 acc_grads = jax.tree_util.tree_map(
@@ -215,7 +246,7 @@ def accelerate(
             loss = loss_sum * inv
             metrics = {"loss": loss}
         else:
-            loss, metrics, grads = _grads(params, batch, scale)
+            loss, metrics, grads = _grads(loss_body, params, batch, scale)
 
         if ls is not None:
             grads = amp.unscale_grads(grads, ls)
@@ -247,13 +278,31 @@ def accelerate(
         new_state = _constrain_tree(new_state)
         return new_state, metrics
 
-    train_jit = jax.jit(
-        _train_step,
-        donate_argnums=(0,) if strategy.donate_state else (),
-    )
+    def _jit_step(rung: Optional[str] = None):
+        loss_body = _loss_at(rung)
+
+        def _train_step(state, batch):
+            return _step(loss_body, state, batch)
+
+        return jax.jit(
+            _train_step,
+            donate_argnums=(0,) if strategy.donate_state else (),
+        )
+
+    # the limit of a device this process owns: the mesh's first may
+    # be another process's, which states nothing here, and every
+    # process of a job has to decide alike
+    limit = device_memory_bytes(mesh.local_devices[0])
+    if limit > 0:
+        train_jit = remat.LadderStep(_jit_step, limit)
+    else:
+        # no limit stated (the CPU): a model's "auto" is "full"
+        train_jit = _jit_step()
+
+    eval_loss = _loss_at()
 
     def _eval_step(state, batch):
-        loss, metrics = _loss_body(state["params"], batch)
+        loss, metrics = eval_loss(state["params"], batch)
         return metrics
 
     # the NamedSharding tree of the train state, derived without

@@ -28,6 +28,7 @@ import numpy as np
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.parallel.accelerate import Strategy, accelerate
 from dlrover_tpu.parallel.mesh import MeshSpec
+from dlrover_tpu.utils.program_stats import device_memory_bytes
 
 # conservative per-chip peaks used when the backend exposes nothing
 # (v5p-class: 459 TFLOP/s bf16, 2765 GB/s HBM, 100 GB/s/link ICI)
@@ -69,7 +70,7 @@ class DryRunner:
         self.peak_flops = peak_flops
         self.hbm_gbps = hbm_gbps
         self.hbm_bytes = (
-            hbm_bytes_per_device or _device_memory_bytes()
+            hbm_bytes_per_device or device_memory_bytes()
         )
 
     def profile(
@@ -145,17 +146,6 @@ class DryRunner:
             except Exception as e:  # noqa: BLE001
                 report.error = f"run: {type(e).__name__}: {e}"
         return report
-
-
-def _device_memory_bytes() -> float:
-    try:
-        d = jax.devices()[0]
-        stats = d.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return float(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 — CPU backend has no stats
-        pass
-    return 0.0  # unknown → never reject on memory
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +298,7 @@ def tune_batchsize(
     doubling ascent, last fitting value wins. On backends without memory
     stats every size 'fits' — the caller should pass an explicit
     budget there."""
-    runner_mem = hbm_bytes_per_device or _device_memory_bytes()
+    runner_mem = hbm_bytes_per_device or device_memory_bytes()
     best = 0
     bs = start
     while bs <= limit:

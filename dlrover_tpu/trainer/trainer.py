@@ -30,6 +30,7 @@ from dlrover_tpu.agent.monitor import (
 )
 from dlrover_tpu.common import trace
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.parallel import remat
 from dlrover_tpu.trainer.flash_checkpoint.engine import (
     Checkpointer,
     StorageType,
@@ -130,7 +131,9 @@ class Trainer:
         respawned worker's line is the other half of the agent's
         "worker restart: persist, respawn": programs read back from
         the persistent cache are hits, and misses are what the
-        recovery waited for."""
+        recovery waited for. Where the step's layer scans took their
+        rung from the device's memory (`remat.LadderStep`), the line
+        ends with the rung and what each tried rung compiled to."""
         totals = trace.compile_totals()
         if totals is None:
             return
@@ -138,13 +141,15 @@ class Trainer:
             r[trace.DUR] for r in trace.snapshot()
             if r[trace.NAME] == "runtime.init"
         )
+        ladder = remat.ladder_summary()
         logger.info(
             "worker start-up: runtime.init %.1f s, traced and lowered "
             "%.1f s, compiled %.1f s: %d programs, %d cache hits, %d "
-            "misses, slowest %s",
+            "misses, slowest %s%s",
             joined, totals["trace_lower_s"], totals["backend_s"],
             totals["programs"], totals["cache_hits"],
             totals["cache_misses"], totals["slowest"],
+            "; " + ladder if ladder else "",
         )
 
     def _report_model_info(self, state, batch):

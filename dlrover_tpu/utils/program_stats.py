@@ -138,6 +138,22 @@ def extract_program_stats(compiled: Any) -> ProgramStats:
     return stats
 
 
+def device_memory_bytes(device: Any = None) -> float:
+    """The `bytes_limit` one device states (the first, by default):
+    what a compiled program's `peak_hbm_bytes` is held against. 0.0
+    where the backend states none (the CPU's): unknown, so nothing is
+    ever refused, or chosen, on memory there."""
+    import jax
+
+    try:
+        stats = (device or jax.devices()[0]).memory_stats()
+        if stats and "bytes_limit" in stats:
+            return float(stats["bytes_limit"])
+    except Exception:  # noqa: BLE001 — CPU backend has no stats
+        pass
+    return 0.0
+
+
 def abstractify(tree: Any) -> Any:
     """Array-likes → ShapeDtypeStruct avals (sharding preserved when
     present) so lowering never touches real buffers."""

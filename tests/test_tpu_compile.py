@@ -167,6 +167,77 @@ def test_flash_under_a_training_mesh(topo):
         assert kernel in text, kernel
 
 
+# what one v5e states as its `bytes_limit` (my chip run, PR 51)
+V5E_BYTES_LIMIT = 16909336064
+
+
+def test_mistral_train_step_takes_the_top_rung_on_the_chip(
+        topo, monkeypatch):
+    """The training cell's step (Mistral-7B-v0.3's widths, 2 layers,
+    f32 parameters and AdamW, 2 x 2048 tokens) through
+    `remat.LadderStep` with the limit the chip states: the layer scan
+    keeps everything ("none", ONE compile), the compiled peak leaves
+    the margin free, and the flash kernels are in the program. A PR
+    that grows the step's temporaries past the room sees the rung
+    fall HERE, before the chip does."""
+    import json
+
+    import optax
+
+    from dlrover_tpu.common import trace
+    from dlrover_tpu.models import llama
+    from dlrover_tpu.ops import attention
+    from dlrover_tpu.parallel import accelerate as acc_mod, remat
+    from dlrover_tpu.parallel.mesh import MeshSpec
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perfbench", "configs", "mistral-7b-v0.3.train-1chip.json",
+    )
+    with open(path) as f:
+        model = json.load(f)
+    run = model["run"]
+    cfg = LlamaConfig(
+        vocab_size=model["vocab_size"], dim=model["hidden_size"],
+        n_layers=model["num_hidden_layers"],
+        n_heads=model["num_attention_heads"],
+        n_kv_heads=model["num_key_value_heads"],
+        mlp_dim=model["intermediate_size"], max_seq_len=run["max_seq_len"],
+        rope_theta=model["rope_theta"], norm_eps=model["rms_norm_eps"],
+    )
+    assert cfg.remat and cfg.remat_policy == "auto"
+    monkeypatch.setattr(attention, "_tpu_available", lambda: True)
+    monkeypatch.setattr(
+        acc_mod, "device_memory_bytes", lambda device=None: V5E_BYTES_LIMIT
+    )
+    acc = acc_mod.accelerate(
+        init_params=lambda k: llama.init_params(cfg, k),
+        loss_fn=lambda p, b, m: llama.loss_fn(cfg, p, b, mesh=m),
+        rules=llama.partition_rules(cfg),
+        optimizer=optax.adamw(run["learning_rate"]),
+        strategy=acc_mod.Strategy(mesh=MeshSpec.fit(1)),
+        devices=topo.devices[:1],
+    )
+    state = jax.tree_util.tree_map(
+        lambda a, sh: S(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(acc.init, jax.random.PRNGKey(0)),
+        acc.state_shardings,
+    )
+    batch = acc.abstract_batch(
+        {"tokens": S((run["batch"], run["seq"] + 1), jnp.int32)})
+    text = acc.train_step.lower(state, batch).compile().as_text()
+    (said,) = [
+        r[trace.COUNTS] for r in trace.snapshot()
+        if r[trace.NAME] == "remat.ladder"
+    ][-1:]
+    assert (said["rung"], said["compiled"]) == ("none", 1), said
+    assert said["peak_none"] + remat.MARGIN_BYTES <= V5E_BYTES_LIMIT
+    assert 0 <= said["room_bytes"] < 2**30, said  # it is the top for now
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert _calls(text, kernel) == 1, kernel  # no forward run twice
+
+
 def _paged_case(chip, heads, kv_heads, quant, stack=None, slots=SLOTS,
                 table_pages=SEQ // PAGE, window=None):
     """Compile the paged kernel for `heads` query heads over a pool of
