@@ -15,8 +15,10 @@ these batch sizes is bound by bytes, not by operations).
 no clock: generate.client_requests' own deal, a slot that gives a
 token every slots / rate seconds, a freed slot refilled at once from
 a first-in-first-out queue, no cost of admission (which makes the
-model harsher than any chip). By hand, for every closed-loop cell of
-BENCHMARK.json:
+model harsher than any chip). A mix whose `deal` is "fixed_order"
+deals every seed the same sizes in the same order, so the rule reads
+that ONE deal; any other mix is read over --seeds deals. By hand, for
+every closed-loop cell of BENCHMARK.json:
 
     python3 perfbench/closed_loop.py [--seeds 24]
 """
@@ -82,9 +84,17 @@ def run_dry(outputs: list, slots: int, tokens_per_s: float, until_s: float):
     return sorted(ran_out), min(len(o) - s for o, s in zip(outputs, sent))
 
 
-def deal_outputs(seed: int, mix: dict) -> list:
+def deal_outputs(seed: int, mix: dict, slots: int) -> list:
     return [[r["max_new"] for r in reqs]
-            for reqs in generate.client_requests(seed, mix, vocab=2)]
+            for reqs in generate.client_requests(seed, mix, 2, slots)]
+
+
+def deals(mix: dict, slots: int, seeds: int) -> list:
+    """The deals the rule reads: one where the seed does not move
+    the sizes, `seeds` of them where it does."""
+    if mix.get("deal") == "fixed_order":
+        seeds = 1
+    return [deal_outputs(2 ** 31 + s, mix, slots) for s in range(seeds)]
 
 
 def closed_loop_cells(manifest: dict) -> list:
@@ -101,14 +111,15 @@ def main() -> int:
         mix, slots = cell["mix"], cell["model"]["run"]["n_slots"]
         roof = roofline_tokens_per_s(cell)
         until = horizon_s(mix, manifest["run_seconds"])
-        deals = [deal_outputs(2 ** 31 + s, mix) for s in range(args.seeds)]
+        dealt = deals(mix, slots, args.seeds)
         lib.log(f"{cell['name']}: {mix['requests_per_client']} requests a "
-                f"client, roofline {roof:.0f} tokens/s, {until:.0f} s")
+                f"client, roofline {roof:.0f} tokens/s, {until:.0f} s, "
+                f"deal {mix.get('deal', 'by_seed')}")
         for times in (0.25, 0.5, 1.0, HEADROOM, 2.0):
-            runs = [run_dry(d, slots, times * roof, until) for d in deals]
+            runs = [run_dry(d, slots, times * roof, until) for d in dealt]
             lib.log(f"  at {times:4.2f} x roofline ({times * roof:6.0f} tokens/s): "
                     f"a client ran out in {sum(bool(r[0]) for r in runs)} of "
-                    f"{len(runs)} seeds, least_requests_left "
+                    f"{len(runs)} deals, least_requests_left "
                     f"{min(r[1] for r in runs)}")
     return 0
 

@@ -1,8 +1,17 @@
 """The load generator of the serving cells: a child process that never
 imports jax, so that its Python shares no interpreter lock with the
 engine's host loop. It reads the traffic file's parameters, makes the
-requests from --seed (generate.py), and drives POST /v1/generate with
-streamed replies from one thread per client.
+requests from --seed (generate.py; --slots is the configuration's
+run.n_slots, which a mix whose `deal` is "fixed_order" needs: it cuts
+the first requests of clients 0 .. slots-1 only), and drives POST
+/v1/generate with streamed replies from one thread per client. The
+clients are started in index order. Under deal "fixed_order" the
+order of the scheduler's queue IS the clients' order, by construction:
+the gateway answers a request's status line once the request stands
+in the queue (before any token), and client i + 1 is started when
+client i has read that line; so clients 0 .. slots-1 start in a slot
+and every run queues the others in one order. Any other mix's
+clients are started one after the other without waiting, as ever.
 
 Closed loop: each client sends its next request when the last one has
 ended. The window opens at the wall-clock time --open-at (the clients
@@ -36,17 +45,24 @@ class Client(threading.Thread):
         self.done = []      # one record per request that ended
         self.sent = 0       # requests handed to the server so far
         self.ran_out = False
+        # set when the first request's status line is read (the
+        # gateway writes it once the request is queued), or when the
+        # client ended before that
+        self.queued = threading.Event()
 
     def run(self):
-        for k, request in enumerate(self.requests):
-            if time.time() >= self.close_at:
-                return
-            self.sent = k + 1
-            record = self.one(k, request)
-            self.done.append(record)
-            if record["state"] == "open_at_close":
-                return  # the window closed under the request
-        self.ran_out = True
+        try:
+            for k, request in enumerate(self.requests):
+                if time.time() >= self.close_at:
+                    return
+                self.sent = k + 1
+                record = self.one(k, request)
+                self.done.append(record)
+                if record["state"] == "open_at_close":
+                    return  # the window closed under the request
+            self.ran_out = True
+        finally:
+            self.queued.set()
 
     def one(self, k, request):
         body = json.dumps({
@@ -65,6 +81,7 @@ class Client(threading.Thread):
             conn.request("POST", "/v1/generate", body=body,
                          headers={"Content-Type": "application/json"})
             resp = conn.getresponse()
+            self.queued.set()
             if resp.status != 200:
                 record["state"] = f"http_{resp.status}"
                 record["t_end"] = time.time()
@@ -101,6 +118,7 @@ def main() -> int:
     ap.add_argument("--traffic", required=True, help="the mix, as JSON")
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--vocab", type=int, required=True)
+    ap.add_argument("--slots", type=int, default=0)
     ap.add_argument("--open-at", type=float, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--out", required=True)
@@ -108,15 +126,20 @@ def main() -> int:
     mix = json.loads(args.traffic)
     if mix["loop"] != "closed":
         raise SystemExit(f"loop {mix['loop']!r}: only 'closed' is built")
-    per_client = generate.client_requests(args.seed, mix, args.vocab)
+    per_client = generate.client_requests(
+        args.seed, mix, args.vocab, args.slots or None)
     close_at = args.open_at + args.seconds
     clients = [
         Client(i, args.addr, reqs, close_at, timeout=600.0)
         for i, reqs in enumerate(per_client)
     ]
+    in_order = mix.get("deal") == "fixed_order"
     t_started = time.time()
     for c in clients:
         c.start()
+        if in_order:
+            c.queued.wait(timeout=30.0)
+    t_all_started = time.time()
     for c in clients:
         c.join(timeout=max(0.0, close_at - time.time()) + 30.0)
     stuck = [c.index for c in clients if c.is_alive()]
@@ -130,6 +153,7 @@ def main() -> int:
     out = {
         "open_at": args.open_at, "close_at": close_at,
         "started_at": t_started, "records": records,
+        "clients_started_s": t_all_started - t_started,
         "clients_ran_out": [c.index for c in clients if c.ran_out],
         # the loop's margin: the fewest requests any client still had
         # to send when it stopped (0 with its last one sent)

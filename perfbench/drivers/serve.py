@@ -293,6 +293,37 @@ def summarize(load, spans, open_at, close_at, n_slots):
     }
 
 
+def deal_census(load, window, mix, n_slots):
+    """What the deal put into the window, for the `[serve]` line
+    (logged: no metric, no limit). Of the first `n_slots` requests to
+    get a first token, which are the first admissions (a prefill is
+    one prompt a call, so first tokens come in the order of
+    admission), how many were cut ones: all `n_slots` where the
+    clients that start in a slot are the ones generate.py cut. Then
+    the requests that ended in the window, how many of those were cut
+    ones, the fewest tokens one of them had, and the loader's own
+    count of the seconds it took to start its clients."""
+    cut = generate.cut_clients(mix, n_slots)
+
+    def is_cut(r):
+        return r["k"] == 0 and r["client"] < cut
+
+    firsts = sorted(
+        (r["chunks"][0][0], is_cut(r)) for r in load["records"]
+        if r["k"] == 0 and r["chunks"]
+    )[:n_slots]
+    ended = window["ended"]
+    return {
+        "first_admissions_cut": sum(c for _, c in firsts),
+        "ended_in_window": len(ended),
+        "ended_cut": sum(is_cut(r) for r in ended),
+        "ended_shortest_tokens": min(
+            (len(r["tokens"]) for r in ended), default=None),
+        # how long the loader took to queue every first request
+        "clients_started_s": load.get("clients_started_s"),
+    }
+
+
 def course(load, spans, close_at, n_slots, bucket_s=2.0):
     """How the batch filled from the clients' start to the window's
     close: per bucket of seconds, the mean share of slots alive after

@@ -123,7 +123,7 @@ def rehearsal_sizes(model, run, mix):
     return model, run, mix
 
 
-def check_served(args, model, mix, good, limits, checks):
+def check_served(args, model, mix, n_slots, good, limits, checks):
     """serve.check_served with this configuration's weights and
     reference (computed in blocks, a layer a call, given the same
     share of the experts and of the vocabulary). What is held to
@@ -152,7 +152,8 @@ def check_served(args, model, mix, good, limits, checks):
     gaps, control = [], []
     t0 = time.time()
     requests = dict(enumerate(
-        generate.client_requests(args.seed, mix, model["vocab_size"])))
+        generate.client_requests(
+            args.seed, mix, model["vocab_size"], n_slots)))
     with jax.default_matmul_precision("highest"):
         r = good[picked[0]]
         flips = reference.routing_choice_differs_share(
@@ -258,6 +259,7 @@ def run(cell, args, t_start: float) -> dict:
             sys.executable, os.path.join(lib.BENCH, "drivers", "loadgen.py"),
             "--addr", gateway.addr, "--traffic", json.dumps(mix),
             "--seed", str(args.seed), "--vocab", str(model["vocab_size"]),
+            "--slots", str(run_["n_slots"]),
             "--open-at", repr(open_at), "--seconds", str(args.seconds),
             "--out", out_path,
         ])
@@ -266,6 +268,8 @@ def run(cell, args, t_start: float) -> dict:
         serve.wait_for_backlog(
             sched, mix["clients"], load_proc, 0.75 * mix["ramp_s"])
         sched.start()
+        lib.log(f"[serve] t+{time.time() - t_start:.1f}s the backlog stands, "
+                f"{open_at - time.time():.1f} s before the window opens")
         if args.trace:
             trace_thread = serve.trace_slice(
                 open_at, args.seconds, mix["trace_s"],
@@ -311,6 +315,15 @@ def run(cell, args, t_start: float) -> dict:
                        "submits": spans.submits}, f)
     tpot_p95 = statistics.quantiles(
         window["tpot_ms"], n=20, method="inclusive")[18]
+    cell_view = dict(cell, model=dict(model, run=run_), mix=mix)
+    # the router's deal as the program counted it, in untraced runs
+    # too (logged: the metrics of these names are a traced run's)
+    experts = {
+        name: lib.read_layer_metric(
+            name, {"cell": cell_view, "window": window})
+        for name in ("moe_held_pairs_per_token",
+                     "moe_expert_load_max_over_mean")
+    }
     lib.log("[serve] " + json.dumps({
         "ended": len(window["ended"]), "good": len(window["good"]),
         "tokens_in_window": window["tokens_in_window"],
@@ -322,6 +335,8 @@ def run(cell, args, t_start: float) -> dict:
         "clients_ran_out": load["clients_ran_out"],
         "least_requests_left": load["least_requests_left"],
         "clients_stuck": load["clients_stuck"],
+        **serve.deal_census(load, window, mix, run_["n_slots"]),
+        **experts,
         "kernel_path": engine.kernel_path,
         "paged": engine.paged_stats(),
     }))
@@ -359,7 +374,8 @@ def run(cell, args, t_start: float) -> dict:
         array.delete()
     del held
     if window["good"]:
-        check_served(args, model, mix, window["good"], limits, checks)
+        check_served(
+            args, model, mix, run_["n_slots"], window["good"], limits, checks)
 
     failed = len(window["ended"]) - len(window["good"])
     out = {
@@ -368,8 +384,8 @@ def run(cell, args, t_start: float) -> dict:
         "device": dict(device, memory_peak_bytes=memory_peak),
     }
     run_view = {
-        "cell": dict(cell, model=dict(model, run=run_), mix=mix),
-        "window": window, "trace": trace, "rehearsal": args.rehearsal,
+        "cell": cell_view, "window": window, "trace": trace,
+        "rehearsal": args.rehearsal,
         "device_kind": device["kind"], "events": [],
     }
     if args.trace:

@@ -139,7 +139,7 @@ class HybridSpans(serve.Spans):
         engine.step = step
 
 
-def check_served(args, model, mix, good, limits, checks):
+def check_served(args, model, mix, n_slots, good, limits, checks):
     """serve.check_served with this configuration's weights and
     reference (computed in blocks, a layer a call). What is held to
     the limit is the MEAN of the served tokens' gaps, over every
@@ -167,7 +167,8 @@ def check_served(args, model, mix, good, limits, checks):
     gaps, control = [], []
     t0 = time.time()
     requests = dict(enumerate(
-        generate.client_requests(args.seed, mix, model["vocab_size"])))
+        generate.client_requests(
+            args.seed, mix, model["vocab_size"], n_slots)))
     with jax.default_matmul_precision("highest"):
         r = good[picked[0]]
         flips = reference.routing_choice_differs_share(
@@ -273,6 +274,7 @@ def run(cell, args, t_start: float) -> dict:
             sys.executable, os.path.join(lib.BENCH, "drivers", "loadgen.py"),
             "--addr", gateway.addr, "--traffic", json.dumps(mix),
             "--seed", str(args.seed), "--vocab", str(model["vocab_size"]),
+            "--slots", str(run_["n_slots"]),
             "--open-at", repr(open_at), "--seconds", str(args.seconds),
             "--out", out_path,
         ])
@@ -281,6 +283,8 @@ def run(cell, args, t_start: float) -> dict:
         serve.wait_for_backlog(
             sched, mix["clients"], load_proc, 0.75 * mix["ramp_s"])
         sched.start()
+        lib.log(f"[serve] t+{time.time() - t_start:.1f}s the backlog stands, "
+                f"{open_at - time.time():.1f} s before the window opens")
         if args.trace:
             trace_thread = serve.trace_slice(
                 open_at, args.seconds, mix["trace_s"],
@@ -342,6 +346,7 @@ def run(cell, args, t_start: float) -> dict:
         "clients_ran_out": load["clients_ran_out"],
         "least_requests_left": load["least_requests_left"],
         "clients_stuck": load["clients_stuck"],
+        **serve.deal_census(load, window, mix, run_["n_slots"]),
         "kernel_path": engine.kernel_path,
         "paged": engine.paged_stats(),
     }))
@@ -380,7 +385,8 @@ def run(cell, args, t_start: float) -> dict:
         array.delete()
     del held
     if window["good"]:
-        check_served(args, model, mix, window["good"], limits, checks)
+        check_served(
+            args, model, mix, run_["n_slots"], window["good"], limits, checks)
 
     failed = len(window["ended"]) - len(window["good"])
     out = {

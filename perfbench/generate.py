@@ -5,9 +5,12 @@ that the load generator can import it without touching the chip.
 Every seed is given the same amount of work. A training batch is
 `batch(seed, step)`: all rows differ, every step differs. A serving
 mix fixes its SET of (prompt, output) sizes once, from the mix's own
-`sizes_seed`; --seed only deals that set to the clients in another
-order and draws the token ids, so two seeds differ in order and not
-in load.
+`sizes_seed`. How the set is dealt to the clients is the mix's `deal`:
+absent, --seed deals it in another order and cuts every client's
+first request, so two seeds differ in order and not in load;
+"fixed_order" takes the order from `sizes_seed` too and cuts only the
+requests that start in a slot, so two seeds differ in token ids (and
+weights) and in nothing the clock can see.
 """
 
 import math
@@ -56,27 +59,58 @@ def request_sizes(mix: dict) -> list:
     return [(int(a), int(b)) for a, b in zip(prompts, outputs)]
 
 
-def client_requests(seed: int, mix: dict, vocab: int) -> list:
+DEALS = ("by_seed", "fixed_order")
+
+
+def client_requests(seed: int, mix: dict, vocab: int, slots: int = None) -> list:
     """What each client sends, in order: a list per client of
     {"tokens": [...], "max_new": n}. A closed loop in steady state
-    finds each client part-way through a request, so every client's
-    FIRST request has its output cut to a share of its length that is
-    spread evenly over the clients: the window then opens on a batch
-    whose slots already end at different times."""
+    finds each slot part-way through a request, so a FIRST request
+    has its output cut to a share of its length, the shares spread
+    evenly over (0, 1): the window then opens on a batch whose slots
+    already end at different times.
+
+    The mix's `deal` says whose and in what order. Absent (by_seed):
+    --seed permutes the set of sizes and every client's first request
+    is cut, the ones that only wait in the queue too. "fixed_order":
+    the permutation and the shares come from `sizes_seed`, so every
+    run of the cell gives client c the same sizes in the same order,
+    and only clients 0 .. slots-1, which loadgen.py starts first and
+    which therefore start in a slot, are cut; the clients that wait
+    in the queue send whole requests. --seed draws every token id in
+    both."""
+    deal = mix.get("deal", "by_seed")
+    if deal not in DEALS:
+        raise ValueError(f"unknown deal {deal!r}")
     sizes = request_sizes(mix)
     rng = _rng(seed, 3)
-    order = rng.permutation(len(sizes))
     clients = mix["clients"]
-    head_share = (rng.permutation(clients) + 0.5) / clients
+    if deal == "fixed_order":
+        if not slots or slots > clients:
+            raise ValueError(
+                f"deal fixed_order cuts the first {slots!r} clients' "
+                f"requests: give the configuration's run.n_slots")
+        order_rng = _rng(mix["sizes_seed"], 6)
+        order = order_rng.permutation(len(sizes))
+        head_share = (order_rng.permutation(slots) + 0.5) / slots
+    else:
+        order = rng.permutation(len(sizes))
+        head_share = (rng.permutation(clients) + 0.5) / clients
     out = [[] for _ in range(clients)]
     for k, idx in enumerate(order):
         c = k % clients
         prompt_len, max_new = sizes[idx]
-        if not out[c]:
+        if not out[c] and c < len(head_share):
             max_new = max(2, int(math.ceil(max_new * head_share[c])))
         tokens = rng.integers(1, vocab, size=prompt_len, dtype=np.int64)
         out[c].append({"tokens": tokens.tolist(), "max_new": int(max_new)})
     return out
+
+
+def cut_clients(mix: dict, slots: int) -> int:
+    """How many clients, counted from client 0, send a cut first
+    request: all of them, or the slots' under deal fixed_order."""
+    return slots if mix.get("deal") == "fixed_order" else mix["clients"]
 
 
 def warm_requests(seed: int, mix: dict, vocab: int) -> list:

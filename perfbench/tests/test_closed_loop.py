@@ -3,7 +3,10 @@ chip allows: the model of the loop (closed_loop.run_dry, over
 generate.client_requests' own deal) runs no client out at 1.5 times
 each cell's roofline rate, nor at today's; it DOES run one out where
 the chip did (4 a client at 2900 tokens/s, PR 32's runs), which keeps
-the model honest. And the load generator reports the margin it models."""
+the model honest. And the load generator reports the margin it models.
+Since PR 52 a mix whose `deal` is "fixed_order" deals every seed the
+same sizes in the same order: the rule then reads ONE deal, and the
+twelve seeds below are twelve readings of it."""
 
 import functools
 import http.server
@@ -34,7 +37,8 @@ ROOFLINE = {  # tokens/s, as the two mixes' `why` texts give it (PR 33)
 @functools.lru_cache(maxsize=None)
 def deals(name, requests_per_client):
     mix = dict(CELLS[name]["mix"], requests_per_client=requests_per_client)
-    return [closed_loop.deal_outputs(seed, mix) for seed in SEEDS]
+    slots = CELLS[name]["model"]["run"]["n_slots"]
+    return [closed_loop.deal_outputs(seed, mix, slots) for seed in SEEDS]
 
 
 def dry_runs(name, tokens_per_s, requests_per_client=None):
@@ -73,6 +77,52 @@ def test_no_client_runs_out_at_one_and_a_half_times_the_roofline(name):
     assert min(left for _, left in runs) >= 1
 
 
+FIXED_ORDER = sorted(
+    name for name, c in CELLS.items() if c["mix"].get("deal") == "fixed_order")
+
+
+def test_the_two_unsteady_cells_took_the_fixed_order():
+    assert FIXED_ORDER == [
+        "gigachat3_serve_latent_decode", "mellum2_serve_context_decode"]
+
+
+@pytest.mark.parametrize("name", FIXED_ORDER)
+def test_a_fixed_order_is_one_deal_and_the_rule_reads_it(name):
+    """Every seed's deal of output sizes is the same one, the rule's
+    `deals` holds it once, and at 1.5 times the roofline it leaves
+    every client two requests or more; four a client fewer run
+    clients out; the mix's `why` carries the numbers."""
+    cell = CELLS[name]
+    mix, slots = cell["mix"], cell["model"]["run"]["n_slots"]
+    dealt = deals(name, mix["requests_per_client"])
+    assert all(d == dealt[0] for d in dealt)
+    assert closed_loop.deals(mix, slots, 24) == [dealt[0]]
+    # only the slots' clients are cut: the others' first outputs are whole
+    lo = mix["output_tokens"]["min"]
+    assert min(d[0] for d in dealt[0][slots:]) >= lo
+    assert min(d[0] for d in dealt[0][:slots]) < lo // 8
+    roof = closed_loop.roofline_tokens_per_s(cell)
+    until = closed_loop.horizon_s(mix, MANIFEST["run_seconds"])
+    out, left = closed_loop.run_dry(
+        dealt[0], slots, closed_loop.HEADROOM * roof, until)
+    assert out == [] and left >= 2
+    assert f"least_requests_left {left}" in mix["why"]
+    fewer = dict(mix, requests_per_client=mix["requests_per_client"] - 4)
+    out, _ = closed_loop.run_dry(
+        closed_loop.deals(fewer, slots, 1)[0], slots,
+        closed_loop.HEADROOM * roof, until)
+    assert out
+    assert f"{sum(d[0] for d in dealt[0][:slots]) / 1e3:.1f} k tokens" \
+        in mix["why"]
+
+
+def test_a_mix_without_the_key_is_read_over_as_many_deals_as_asked():
+    cell = CELLS["mistral7b_serve_decode"]
+    dealt = closed_loop.deals(
+        cell["mix"], cell["model"]["run"]["n_slots"], 3)
+    assert len(dealt) == 3 and dealt[0] != dealt[1] != dealt[2]
+
+
 @pytest.mark.parametrize("name", sorted(TODAY))
 def test_no_client_runs_out_at_todays_rate_and_eight_are_left(name):
     runs = dry_runs(name, TODAY[name])
@@ -100,8 +150,11 @@ class _Server(http.server.BaseHTTPRequestHandler):
     """Answers POST /v1/generate as the gateway streams: the tokens in
     one chunk, then the closing line."""
 
+    arrivals = []  # (prompt tokens, max_new) in the order they came
+
     def do_POST(self):
         body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
+        self.arrivals.append((len(body["tokens"]), body["max_new"]))
         self.send_response(200)
         self.end_headers()
         threading.Event().wait(0.05 * body["max_new"])
@@ -113,9 +166,10 @@ class _Server(http.server.BaseHTTPRequestHandler):
         pass
 
 
-def run_loadgen(tmp_path, monkeypatch, mix, seconds):
+def run_loadgen(tmp_path, monkeypatch, mix, seconds, slots=0):
     loadgen = lib.load_module(
         os.path.join(lib.BENCH, "drivers", "loadgen.py"), "perfbench_loadgen")
+    _Server.arrivals.clear()
     server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _Server)
     thread = threading.Thread(target=server.serve_forever, daemon=True)
     thread.start()
@@ -124,7 +178,8 @@ def run_loadgen(tmp_path, monkeypatch, mix, seconds):
         monkeypatch.setattr(sys, "argv", [
             "loadgen.py", "--addr", f"http://127.0.0.1:{server.server_port}",
             "--traffic", json.dumps(mix), "--seed", str(SEEDS[0]),
-            "--vocab", "100", "--open-at", repr(time.time()),
+            "--vocab", "100", "--slots", str(slots),
+            "--open-at", repr(time.time()),
             "--seconds", str(seconds), "--out", out,
         ])
         assert loadgen.main() == 0
@@ -161,3 +216,19 @@ def test_loadgen_reports_nought_left_where_a_client_ran_out(tmp_path, monkeypatc
     load = run_loadgen(tmp_path, monkeypatch, small_mix(2), seconds=2.0)
     assert sorted(load["clients_ran_out"]) == [0, 1, 2]
     assert load["least_requests_left"] == 0
+
+
+def test_a_fixed_order_queues_the_clients_in_their_order(tmp_path, monkeypatch):
+    """Under deal fixed_order client i + 1 is started when client i
+    has read its status line, which the server writes once it has the
+    request: the first requests arrive in the clients' order, the
+    slots' cut ones first, whatever the threads' luck."""
+    import generate
+
+    mix = dict(small_mix(40), clients=24, deal="fixed_order")
+    load = run_loadgen(tmp_path, monkeypatch, mix, seconds=0.5, slots=8)
+    dealt = generate.client_requests(SEEDS[0], mix, 100, 8)
+    firsts = [(len(reqs[0]["tokens"]), reqs[0]["max_new"]) for reqs in dealt]
+    assert _Server.arrivals[:24] == firsts
+    assert load["clients_started_s"] < 5.0 and load["clients_stuck"] == []
+

@@ -81,7 +81,10 @@ def test_the_mix_is_the_issues():
     assert mix["output_tokens"] == {
         "min": 384, "max": 768, "distribution": "log_uniform"}
     assert mix["requests_per_client"] % 4 == 0
-    assert mix["ramp_s"] == 12.0
+    # ISSUE 52: from the `course` line, 12 s if that opens on the steady
+    # loop and 24 at most (setup_s holds ramp_s whole)
+    assert 12.0 <= mix["ramp_s"] <= 24.0
+    assert mix["deal"] == "fixed_order"
     run = load_cell()["model"]["run"]
     assert (run["n_slots"], run["max_len"], run["chunk"]) == (96, 4096, 8)
     assert mix["clients"] == 3 * run["n_slots"]
@@ -91,8 +94,9 @@ def test_the_mix_is_the_issues():
 
 def test_requests_per_client_holds_at_one_and_a_half_times_the_roofline():
     """closed_loop.py's rule for this cell: no client runs out at 1.5
-    times the roofline rate in 24 seeds of the model of the loop, and
-    four requests a client fewer would fail it."""
+    times the roofline rate in the model of the loop (since PR 52 the
+    mix's ONE deal, whatever the seed), and four requests a client
+    fewer would fail it."""
     import closed_loop
 
     cell = load_cell()
@@ -106,9 +110,8 @@ def test_requests_per_client_holds_at_one_and_a_half_times_the_roofline():
         m = dict(mix, requests_per_client=per_client)
         return sum(
             bool(closed_loop.run_dry(
-                closed_loop.deal_outputs(2 ** 31 + s, m), slots,
-                closed_loop.HEADROOM * roof, until)[0])
-            for s in range(24))
+                deal, slots, closed_loop.HEADROOM * roof, until)[0])
+            for deal in closed_loop.deals(m, slots, 24))
 
     assert ran_out(mix["requests_per_client"]) == 0
     assert ran_out(mix["requests_per_client"] - 4) > 0
@@ -197,6 +200,104 @@ def test_the_routers_bias_is_drawn_as_the_program_draws_it():
     assert a.shape == b.shape and a.size >= 64
     assert abs(a.std() / b.std() - 1.0) < 0.4
     assert 0.05 < b.std() < 0.2
+
+
+def rehearsal_parts():
+    driver = lib.load_driver("serve_gigachat3")
+    cell = load_cell()
+    model, run, _ = driver.rehearsal_sizes(
+        cell["model"], cell["model"]["run"], cell["mix"])
+    return driver, model, run
+
+
+@pytest.mark.parametrize("seed", [2 ** 31 + 200 + s for s in range(8)])
+def test_the_held_experts_take_the_even_share_and_the_bias_keeps_its_spread(seed):
+    """PR 52: one scalar a layer on the held block's values puts the
+    held experts' share of the pairs at the even one (4 x 8 / 32 = 1
+    pair a token at the rehearsal's size) on rows the bisection did
+    not read, by the REFERENCE's choice and by the PROGRAM's; the
+    bias still moves choices (its spread), the 16 values keep their
+    differences and the others their draw; two calls give one bias."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import reference_gigachat3
+    import weights_gigachat3
+
+    from dlrover_tpu.models import moe
+
+    driver, model, run = rehearsal_parts()
+    cfg = driver.gigachat3_config(model, run)
+    first, held = model["experts_held"]
+    even = model["num_experts_per_tok"] * held / model["routed_experts_published"]
+    assert even == 1.0
+    params = weights_gigachat3.make_params(model, seed, "float32")
+    again = weights_gigachat3.make_params(model, seed, "float32")
+    bias = np.asarray(params["layers"]["router_bias"])
+    assert np.array_equal(bias, np.asarray(again["layers"]["router_bias"]))
+    assert bias.dtype == np.float32 and bias.std() >= 0.05
+    rows = np.random.RandomState(seed % 1000).randn(
+        8192, model["hidden_size"]).astype(np.float32)
+    rows /= np.sqrt(np.mean(rows * rows, -1, keepdims=True))
+    for layer in range(bias.shape[0]):
+        router = jnp.asarray(params["layers"]["router"][layer], jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            _, chosen = reference_gigachat3.routing_weights(
+                model, jnp.asarray(rows), router, jnp.asarray(bias[layer]))
+            _, theirs = moe.route(
+                jnp.asarray(rows) @ router, cfg.routing, jnp.asarray(bias[layer]))
+        chosen = np.asarray(chosen)
+        on_chip = (chosen >= first) & (chosen < first + held)
+        for here in (on_chip, (np.asarray(theirs) >= first)
+                     & (np.asarray(theirs) < first + held)):
+            assert abs(here.sum(-1).mean() / even - 1.0) < 0.1
+        # within the held block the loads stay uneven: the offset is one
+        # scalar, so the block's values differ as they were drawn
+        block = bias[layer, first:first + held]
+        assert block.std() > 0.03
+        loads = np.bincount(chosen[on_chip], minlength=first + held)[first:]
+        assert loads.max() > 1.3 * loads.mean()
+
+
+def test_the_offset_is_one_scalar_a_layer_on_the_held_block():
+    """The bias is the draw plus one number a layer on the held
+    block: take the block's offset away and what is left is N(0, 0.1)
+    drawn from the configuration's `router_bias_seed`, the same for
+    every --seed, as the other experts' values are."""
+    import jax
+    import numpy as np
+
+    import weights_gigachat3
+
+    _, model, _ = rehearsal_parts()
+    seed = 2 ** 31 + 77
+    first, held = model["experts_held"]
+    items = weights_gigachat3.hashable(dict(
+        {k: v for k, v in model.items() if k in weights_gigachat3.KEYS},
+        held_first=first))
+    drawn = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(model["router_bias_seed"]),
+        weights_gigachat3.shapes(model)["layers"]["router_bias"],
+        "float32")
+    bias = np.asarray(weights_gigachat3.make_params(
+        model, seed, "float32")["layers"]["router_bias"])
+    other = weights_gigachat3.make_params(model, seed + 1, "float32")
+    # another seed: other matrices, the same draw of the bias
+    assert not np.array_equal(
+        np.asarray(other["layers"]["router"]),
+        np.asarray(weights_gigachat3.make_params(
+            model, seed, "float32")["layers"]["router"]))
+    assert np.abs(np.delete(
+        np.asarray(other["layers"]["router_bias"]) - bias,
+        np.s_[first:first + held], axis=1)).max() < 1e-7
+    delta = bias - np.asarray(drawn)
+    outside = np.delete(delta, np.s_[first:first + held], axis=1)
+    assert np.abs(outside).max() < 1e-7  # the draw, to rounding under jit
+    inside = delta[:, first:first + held]
+    assert np.allclose(inside, inside[:, :1], atol=1e-6)
+    assert 0.0 < np.abs(inside[:, 0]).max() < 0.5
+    assert dict(items)["held_first"] == first
 
 
 def test_full_config_object_at_the_published_widths():

@@ -28,10 +28,14 @@ def test_the_entries_name_the_cells_files():
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [x["name"] for x in manifest[key]]
         assert len(names) == len(set(names)), key
-    # new entries stand at the end of their lists
-    assert manifest["configs"][-1]["name"] == "mellum2-12b-a2.5b.serve-1chip"
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == list(NEW_METRICS)
+    # the entries stand together, in the order they were appended
+    # (later PRs append after them)
+    assert "mellum2-12b-a2.5b.serve-1chip" in [
+        c["name"] for c in manifest["configs"]]
+    assert CELL in [w["name"] for w in manifest["workloads"]]
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert names[at:at + len(NEW_METRICS)] == list(NEW_METRICS)
     cell = load_cell()
     assert {m["name"] for m in cell["end_to_end"]} == {
         "setup_s", "serve_tokens_per_s", "tpot_p95_ms"}
@@ -50,7 +54,7 @@ def test_the_entries_name_the_cells_files():
         module = lib.load_module(path, "m_" + name)
         assert (module.LAYER, module.UNIT, module.SOURCE, module.MOVES) == (
             metric["layer"], metric["unit"], metric["source"], metric["moves"])
-        assert metric["workloads"] == [CELL]
+        assert CELL in metric["workloads"]
         assert metric["moves"] in ("serve_tokens_per_s", "tpot_p95_ms")
 
 

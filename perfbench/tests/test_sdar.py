@@ -53,7 +53,14 @@ def test_the_entries_name_the_cells_files():
         "sched_lock_wait_p50_ms", "sched_lock_held_pct",
         "queue_wait_p50_ms", "admit_to_first_token_p50_ms",
         "pump_outside_step_ms", "moe_expert_load_max_over_mean",
-    } | set(NEW_METRICS) == set(listed)
+    } | set(NEW_METRICS) <= set(listed)
+    # later PRs list the cell under metrics of their own (PR 46: the
+    # four setup_* legs); the readers that do not fit it stay out
+    for name in ("moe_grouped_matmul_roofline",
+                 "paged_attention_decode_roofline",
+                 "hybrid_paged_attention_roofline",
+                 "latent_paged_attention_roofline"):
+        assert name not in listed
     for name in NEW_METRICS:
         metric = listed[name]
         path = os.path.join(lib.BENCH, "layer_metrics", name + ".py")
@@ -114,9 +121,8 @@ def test_requests_per_client_holds_at_one_and_a_half_times_the_roofline():
         m = dict(mix, requests_per_client=per_client)
         runs = [
             closed_loop.run_dry(
-                closed_loop.deal_outputs(2 ** 31 + s, m), slots,
-                closed_loop.HEADROOM * roof, until)
-            for s in range(24)]
+                deal, slots, closed_loop.HEADROOM * roof, until)
+            for deal in closed_loop.deals(m, slots, 24)]
         return sum(bool(out) for out, _ in runs), min(n for _, n in runs)
 
     # 12 a client: no client runs out and none is down to its last

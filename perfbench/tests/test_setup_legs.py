@@ -139,6 +139,12 @@ def test_a_traced_rehearsal_reports_all_four():
     sizes, through the driver as run.py would call it."""
     import argparse
 
+    import jax
+
+    # a rehearsal of this cell earlier in the same process (a whole run
+    # of perfbench/tests has two) leaves every program in jax's own
+    # caches, and a start-up that compiles nothing leaves no legs
+    jax.clear_caches()
     driver = lib.load_driver("serve")
     args = argparse.Namespace(
         rehearsal=True, seed=2 ** 31 + 46, seconds=3.0, trace=1, control="",

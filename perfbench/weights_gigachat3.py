@@ -26,13 +26,44 @@ many of them live here):
 `wk_b` and `wv_b` are the published W_kvb's columns, a head's
 [k_nope, v], as two leaves (a fixed permutation of columns). Norm
 scales 1, embedding N(0, 0.02), every matrix N(0, 1/fan_in), the
-router too; the router's bias N(0, 0.1), the spread of
+router too.
+
+The router's bias is drawn N(0, 0.1), the spread of
 dlrover_tpu/models/llama.py's own draw: one that moves choices
 against s alone (so that the choice by s + b and the weight by s
-differ). No public source gives the bias's scale. At this spread
-experts 0-15 hold 0.37-0.77 pairs a token depending on the seed (0.5
-for an even router) and a run's rate follows the draw (PERF.md
-section 6, PR 39): what a `benchmark` PR would have to steady.
+differ; a bias near zero would blind `correct` to a bias that leaks
+into the weights). No public source gives the bias's scale. Until PR
+52 it was drawn from --seed and left at that: experts 0-15 then held
+0.31-0.77 pairs a token depending on the seed's draw (0.5 for an even
+router) and a run's rate followed the draw with r = -0.97 (PERF.md
+section 6, PR 39). Two things are the deployment's and not the
+seed's since PR 52:
+
+- The draw itself comes from the configuration's `router_bias_seed`,
+  as a mix's set of sizes comes from its `sizes_seed`: ONE bias for
+  every run. With the share alone made even (below) the rate still
+  followed the seed by 4% (my chip runs, PR 52), because the 16 held
+  values decide how many of the held experts a decode step of 96
+  tokens touches at all (6-12 a layer, 31-40 over the four layers
+  from draw to draw; 38-39 for every seed under the one draw), and an
+  untouched expert's 88 MB are not streamed. The matrices, the
+  router's among them, stay --seed's.
+- ONE scalar a layer is added to the held block's values
+  (`balance_held_share`), found by bisection inside the same jitted
+  call: the offset at which, on BALANCE_ROWS rows of unit RMS drawn
+  from --seed (what a normed token is to the router: its matrix is
+  N(0, 1/fan_in), so the logits have unit spread), the held experts
+  take the EVEN share under the published choice (groups, noaux_tc,
+  top 8): num_experts_per_tok x held / routed = 8 x 16 / 256 = 0.5
+  pairs a token. The ground: a deployment places its experts so that
+  its chips' loads are even (the published balancing update of this
+  very bias in training, expert placement in serving), so a CHIP's
+  share of the pairs is the even one, while the loads of the experts
+  WITHIN the chip stay as uneven as the draw makes them (the 16
+  values keep their differences, and the other 240 their draw).
+
+The program and the reference get the same bias: both call
+make_params.
 """
 
 import functools
@@ -49,7 +80,11 @@ KEYS = (
     "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
     "intermediate_size", "moe_intermediate_size", "n_routed_experts",
     "n_shared_experts", "vocab_size", "routed_experts_published",
+    "num_experts_per_tok", "n_group", "topk_group", "routed_scaling_factor",
+    "router_bias_seed",
 )
+BALANCE_ROWS = 4096   # rows of unit RMS the bisection reads
+BALANCE_STEPS = 24    # halvings of an offset in [-1, 1]
 
 
 def shapes(model: dict) -> dict:
@@ -97,6 +132,46 @@ def shapes(model: dict) -> dict:
     }
 
 
+def balance_held_share(model: dict, router, bias, key):
+    """bias [L, E] with one scalar a layer added to the held block,
+    so that the held experts take the even share of the pairs on
+    seeded rows of unit RMS, under the published choice as the
+    reference spells it (reference_gigachat3.routing_weights: the
+    benchmark has one copy of it). The share only grows with the
+    offset, so a bisection finds it."""
+    import jax
+    import jax.numpy as jnp
+
+    import reference_gigachat3
+
+    E = model["routed_experts_published"]
+    first, held = model["held_first"], model["n_routed_experts"]
+    even = model["num_experts_per_tok"] * held / E
+    rows = jax.random.normal(
+        key, (BALANCE_ROWS, router.shape[1]), jnp.float32)
+    rows = rows * jax.lax.rsqrt(jnp.mean(rows * rows, -1, keepdims=True))
+    block = (jnp.arange(E) >= first) & (jnp.arange(E) < first + held)
+
+    def one(layer):
+        w, b = layer
+        w = w.astype(jnp.float32)
+
+        def halve(_, ends):
+            lo, hi = ends
+            mid = 0.5 * (lo + hi)
+            _, chosen = reference_gigachat3.routing_weights(
+                model, rows, w, b + jnp.where(block, mid, 0.0))
+            here = (chosen >= first) & (chosen < first + held)
+            low = jnp.mean(jnp.sum(here, -1).astype(jnp.float32)) < even
+            return jnp.where(low, mid, lo), jnp.where(low, hi, mid)
+
+        lo, hi = jax.lax.fori_loop(
+            0, BALANCE_STEPS, halve, (jnp.float32(-1.0), jnp.float32(1.0)))
+        return b + jnp.where(block, 0.5 * (lo + hi), 0.0)
+
+    return jax.lax.map(one, (router, bias))
+
+
 def init_params(model: dict, key, dtype):
     """Traced under jit by callers. The experts' stacks (1.9 GB a
     leaf) are drawn a layer at a time, so that the generator's
@@ -115,7 +190,9 @@ def init_params(model: dict, key, dtype):
         if name.endswith("_norm") or name == "scale":
             out[group][name] = jnp.ones(shape, dtype)
         elif name == "router_bias":
-            out[group][name] = 0.1 * jax.random.normal(k, shape, jnp.float32)
+            out[group][name] = 0.1 * jax.random.normal(
+                jax.random.PRNGKey(model["router_bias_seed"]), shape,
+                jnp.float32)
         elif len(shape) == 4:
             scale = jnp.asarray(1.0 / math.sqrt(shape[-2]), dtype)
             out[group][name] = jax.lax.map(
@@ -129,6 +206,10 @@ def init_params(model: dict, key, dtype):
                 dtype,
             )
             out[group][name] = jax.random.normal(k, shape, dtype) * scale
+    layers = out["layers"]
+    layers["router_bias"] = balance_held_share(
+        model, layers["router"], layers["router_bias"],
+        jax.random.fold_in(key, len(flat)))
     return out
 
 
@@ -147,7 +228,9 @@ def _maker():
 def make_params(model: dict, seed: int, dtype: str):
     """The weights of `seed` on the device, in one jitted call (the
     key is an argument: one program serves every seed)."""
-    items = hashable({k: v for k, v in model.items() if k in KEYS})
+    items = hashable(dict(
+        {k: v for k, v in model.items() if k in KEYS},
+        held_first=model["experts_held"][0]))
     return _maker()(items, seed_key(seed), dtype)
 
 
